@@ -50,11 +50,17 @@ launch() { # name -- argv...
   echo "started $name (pid $(cat "$RUN/$name.pid"), log $RUN/$name.log)"
 }
 
-launch kv python -m m3_tpu.services kv \
+# One chip, one process: on an accelerator host only the coordinator
+# (the role that runs the device query tier and seals its embedded
+# database) may initialise the chip; every other role stays on the CPU
+# backend.  JAX_PLATFORMS in the caller's environment still wins for
+# the coordinator (e.g. JAX_PLATFORMS=cpu on a host without a chip).
+launch kv env JAX_PLATFORMS=cpu python -m m3_tpu.services kv \
   --kv "$RUN/kv-data" --listen "127.0.0.1:$KV_PORT"
 wait_port 127.0.0.1 "$KV_PORT" kv
 
-M3TPU_DATA="$RUN/dbnode" launch dbnode python -m m3_tpu.services dbnode \
+M3TPU_DATA="$RUN/dbnode" launch dbnode env JAX_PLATFORMS=cpu \
+  python -m m3_tpu.services dbnode \
   -f "$REPO/deploy/config/dbnode.yml" --kv "127.0.0.1:$KV_PORT"
 wait_port 127.0.0.1 "$DB_PORT" dbnode
 
@@ -70,7 +76,8 @@ if [ "${1:-}" = "--with-aggregator" ]; then
     -d '{"name": "aggregator_ingest", "numberOfShards": 64}' >/dev/null
   curl -fsS -X POST "http://127.0.0.1:$CO_PORT/api/v1/topic/init" \
     -d '{"name": "aggregated_metrics", "numberOfShards": 64}' >/dev/null
-  launch aggregator python -m m3_tpu.services aggregator \
+  launch aggregator env JAX_PLATFORMS=cpu \
+    python -m m3_tpu.services aggregator \
     -f "$REPO/deploy/config/aggregator.yml" --kv "127.0.0.1:$KV_PORT"
   wait_port 127.0.0.1 "${M3TPU_AGG_ADMIN_PORT:-6002}" aggregator-admin
 fi

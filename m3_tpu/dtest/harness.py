@@ -95,7 +95,9 @@ class ProcessHarness:
     def __init__(self, workdir: str):
         self.workdir = pathlib.Path(workdir)
         self.env = dict(os.environ)
-        self.env["M3_TPU_PLATFORM"] = "cpu"
+        # spawned roles stay off the accelerator: at most one process
+        # per host may own a chip, and the harness owns none
+        self.env["JAX_PLATFORMS"] = "cpu"
         self.env["PYTHONPATH"] = str(
             pathlib.Path(__file__).resolve().parents[2])
         self.procs: list[ServiceProc] = []

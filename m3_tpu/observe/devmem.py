@@ -95,7 +95,9 @@ class DeviceMemLedger:
     compile-cache inventory."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        # re-entrant: track()'s weakref finalizers call _adjust, and a
+        # GC pass can fire one on the thread that is inside _adjust
+        self._lock = threading.RLock()
         self._bytes: Dict[str, int] = {}
         self._counts: Dict[str, int] = {}
         self._kernel_peaks: Dict[str, int] = {}

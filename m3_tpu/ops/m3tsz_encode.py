@@ -524,10 +524,7 @@ def _evict_encode_cache() -> int:
     with _FP_LOCK:
         n = len(_FP_SEEN)
         _FP_SEEN.clear()
-    try:
-        _pack_encode_jit.clear_cache()
-    except AttributeError:  # older jax without per-function clearing
-        pass
+    _pack_encode_jit.clear_cache()
     return n
 
 

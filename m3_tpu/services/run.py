@@ -459,6 +459,10 @@ def main(argv=None) -> int:
     ap.add_argument("--listen", default="127.0.0.1:0",
                     help="kv role: host:port to serve the KV store on")
     args = ap.parse_args(argv)
+    # compiled device programs survive a restart: JAX_COMPILATION_CACHE_DIR
+    # places the cache, else <checkout>/.jax_cache
+    from m3_tpu.utils import compile_cache
+    compile_cache.configure()
     if args.role == "kv":
         from m3_tpu.cluster.kv import DirStore, MemStore
         from m3_tpu.cluster.kv_net import KVServer

@@ -35,7 +35,9 @@ def load(name: str) -> ctypes.CDLL:
     try:
         if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
             subprocess.run(
-                ["g++", "-O2", "-march=native", "-shared", "-fPIC",
+                # no -march=native: the object is cached beside the
+                # source and a copied tree may run on another CPU
+                ["g++", "-O2", "-shared", "-fPIC",
                  "-pthread", "-o", str(so), str(src)],
                 check=True,
                 capture_output=True,

@@ -147,7 +147,7 @@ def test_three_role_multiprocess_over_sockets(tmp_path):
     driven via the coordinator admin API, with data flowing end to
     end (remote write -> query)."""
     env = dict(os.environ)
-    env["M3_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(pathlib.Path(__file__).resolve().parents[1])
     procs = []
 

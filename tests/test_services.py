@@ -219,3 +219,71 @@ def test_coordinator_service_from_yaml(tmp_path):
             assert r.status == 200
     finally:
         svc.stop()
+
+
+# --- compile-cache placement + device-tier auto mode (process start-up)
+
+
+def test_compile_cache_dir_from_environment_is_left_alone(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX read it itself, the helper
+    sets no directory in code."""
+    import jax
+
+    from m3_tpu.utils import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    monkeypatch.delenv("M3_NO_COMPILE_CACHE", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    calls = []
+    real = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda key, value: (calls.append(key), real(key, value)))
+    assert compile_cache.configure() == before
+    assert "jax_compilation_cache_dir" not in calls
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_defaults_to_checkout(monkeypatch):
+    import pathlib
+
+    import jax
+
+    import m3_tpu
+    from m3_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("M3_NO_COMPILE_CACHE", raising=False)
+    want = str(pathlib.Path(m3_tpu.__file__).resolve().parents[1]
+               / ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.configure() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_device_serving_auto_asks_the_public_backend(monkeypatch,
+                                                     tmp_path):
+    """Auto mode is jax.default_backend() != "cpu", asked the public
+    way: no private jax module is read."""
+    import inspect
+
+    import jax
+
+    from m3_tpu.query.engine import Engine
+
+    assert "jax._src" not in inspect.getsource(
+        Engine._device_serving_active)
+    db = Database(DatabaseOptions(path=str(tmp_path), num_shards=2,
+                                  commit_log_enabled=False))
+    try:
+        assert jax.default_backend() == "cpu"
+        assert Engine(db)._device_serving_active() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert Engine(db)._device_serving_active() is True
+        assert Engine(db, device_serving=False)\
+            ._device_serving_active() is False
+    finally:
+        db.close()

@@ -1,0 +1,294 @@
+"""Compile the served path's device programs for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with jax lowers
+each program for `v5e:2x2` device 0 from `jax.ShapeDtypeStruct`s at the
+shapes `chip_smoke.py` dispatches (50,000 series, 10 s cadence, 2 h
+blocks, 6 h span, 60 s steps), so anything the chip's compiler refuses
+— a missing X64 rewrite, a program that does not fit 16 GB of HBM —
+fails a CPU test run instead of a chip call.  Nothing executes; a pass
+is not a chip run.
+
+Rules this file keeps (a second process cannot load libtpu, and xdist
+workers must all collect the same tests): the topology is described in
+a module-scoped fixture, never at import / skipif / parametrize time;
+no child process; every compile happens in the test's own process with
+the persistent compilation cache off (a described-device executable
+can be written to it but never read back).
+
+The TPU compiler takes 1-4 minutes per query program whatever its size,
+so the tier-1 run keeps the seal kernel and the rate pipeline (every
+query program shares its decode + merge front half) and the rest are
+`slow`: run them all before a chip call with
+`pytest tests/test_tpu_aot_compile.py -m "slow or not slow"`.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import SingleDeviceSharding
+
+from m3_tpu.models import query_pipeline as qp
+from m3_tpu.ops import m3tsz_encode
+from m3_tpu.query import plan as qplan
+from m3_tpu.query.engine import Engine
+from m3_tpu.storage.database import Database, DatabaseOptions
+from m3_tpu.storage.namespace import NamespaceOptions, RetentionOptions
+from m3_tpu.storage.shard import _pow2_at_least
+from m3_tpu.utils import xtime
+
+SEC = xtime.SECOND
+BLOCK = 2 * xtime.HOUR
+T0 = (1_600_000_000 * SEC // BLOCK) * BLOCK
+
+# chip_smoke.py's deployment (BASELINE.json config 4)
+SERIES = 50_000
+HOURS = 4
+SHARDS = 64           # deploy/config/coordinator.yml num_shards
+DP_PER_BLOCK = 720    # 2 h at 10 s
+JOBS = 32
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def build_tiny_db(path: str, hours: int = HOURS):
+    """64 series x the smoke's real span: per-stream shapes (words per
+    block, samples per block and lane, steps) come out at their real
+    size and only the series-proportional dims need rescaling."""
+    db = Database(DatabaseOptions(path=path, num_shards=4,
+                                  commit_log_enabled=False))
+    db.create_namespace(NamespaceOptions(
+        name="default", retention=RetentionOptions(block_size=BLOCK)))
+    rng = np.random.default_rng(0)
+    n = hours * 360
+    ts = (T0 + np.arange(n) * 10 * SEC).tolist()
+    for i in range(64):
+        tags = {b"__name__": b"http_requests_total",
+                b"job": b"job-%02d" % (i % JOBS),
+                b"host": b"host-%05d" % i}
+        vs = np.cumsum(rng.integers(0, 100, n)).astype(np.float64)
+        db.write_batch("default", [b"s%05d" % i] * n, [tags] * n, ts,
+                       vs.tolist())
+    db.tick(now_nanos=T0 + (hours // 2 + 2) * BLOCK)
+    db.flush()
+    return db
+
+
+@pytest.fixture(scope="module")
+def tiny_db(tmp_path_factory):
+    db = build_tiny_db(str(tmp_path_factory.mktemp("aotdb")))
+    yield db
+    db.close()
+
+
+def _capture(monkeypatch, tiny_db, kernel: str, expr: str,
+             fused: bool = False):
+    """Run `expr` through the engine's device tier on the CPU backend
+    and return the (args, kwargs) it handed `kernel`."""
+    seen = []
+    orig = getattr(qp, kernel)
+
+    def spy(*a, **k):
+        seen.append((a, k))
+        return orig(*a, **k)
+
+    monkeypatch.setattr(qp, kernel, spy)
+    eng = Engine(tiny_db, "default", lookback_nanos=300 * SEC,
+                 device_serving=True)
+    start, end = T0 + 600 * SEC, T0 + HOURS * 3600 * SEC - 60 * SEC
+    steps = np.arange(start, end + 1, 60 * SEC, dtype=np.int64)
+    if fused:
+        # the plan compiler itself, past serve_fused's engagement gate
+        # (which leaves a lone agg-over-rate to the per-node kernels)
+        from m3_tpu.query import promql
+        node = promql.parse(expr)
+        counts = {"ops": 0, "fns": [], "aggs": [], "new": False}
+        sym = qplan._extract(node, counts, root=True)
+        assert qplan.run_sym(eng, sym, steps, counts, 3) is not None
+    else:
+        eng.query_range(expr, start, end, 60 * SEC)
+    assert len(seen) == 1, f"{kernel} not dispatched once for {expr}"
+    monkeypatch.undo()
+    return seen[0]
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _per_node_args(args, kw, sharding):
+    """Rescale a per-node pipeline call (words, nbits, slots, steps +
+    statics) from 64 series to SERIES with the engine's own bucketing."""
+    words, nbits, slots, steps = args
+    blocks = words.shape[0] // 64            # streams per series
+    m_pad = Engine._bucket(SERIES * blocks, 64)
+    lanes_pad = Engine._bucket(SERIES, 64)
+    if m_pad > SERIES * blocks and lanes_pad == SERIES:
+        lanes_pad = Engine._bucket(SERIES + 1, 64)
+    w_pad = words.shape[1]
+    new_args = (_sds((m_pad, w_pad), words.dtype, sharding),
+                _sds((m_pad,), nbits.dtype, sharding),
+                _sds((m_pad,), slots.dtype, sharding),
+                _sds(steps.shape, steps.dtype, sharding))
+    new_kw = dict(kw)
+    new_kw["n_lanes"] = lanes_pad
+    return new_args, new_kw
+
+
+def _rescale_plan(plan, leaves, params, sharding):
+    """Rewrite a fused plan captured at 64 series to SERIES: every
+    series-proportional static (lanes, stream rows, per-host groups)
+    is re-bucketed with the plan compiler's own pow2 quantizer, and
+    leaves/params become ShapeDtypeStructs of the matching shapes."""
+    new_leaves = [None] * len(leaves)
+    new_params = [None] * len(params)
+
+    def scalars(p):
+        return tuple(_sds((), np.asarray(x).dtype, sharding) for x in p)
+
+    def walk(node):
+        """-> (new node, padded row count of its output)."""
+        tag = node[0]
+        if tag == "leaf":
+            (_, idx, pidx, kind, fn, _lanes, n_cap, n_dp, n_tiers,
+             m_tiny, w_pad, s_pad, sf, tf) = node
+            assert kind == "words"
+            blocks = -(-m_tiny // 64)
+            lanes_pad = qplan._bucket_pow2(SERIES, 64)
+            m_pad = qplan._bucket_pow2(SERIES * HOURS // 2, 64)
+            assert blocks >= HOURS // 2
+            lf = leaves[idx]
+            new_leaves[idx] = {
+                "words": _sds((m_pad, w_pad), lf["words"].dtype, sharding),
+                "nbits": _sds((m_pad,), lf["nbits"].dtype, sharding),
+                "slots": _sds((m_pad,), lf["slots"].dtype, sharding),
+                "tiers": _sds((m_pad,), lf["tiers"].dtype, sharding),
+                "steps": _sds((s_pad,), lf["steps"].dtype, sharding),
+                "rng": _sds((), np.int64, sharding),
+                "valid": _sds((lanes_pad,), np.bool_, sharding),
+            }
+            new_params[pidx] = scalars(params[pidx])
+            return (("leaf", idx, pidx, kind, fn, lanes_pad, n_cap, n_dp,
+                     n_tiers, m_pad, w_pad, s_pad, sf, tf), lanes_pad)
+        if tag == "agg":
+            _, op, g_tiny, pidx, child = node
+            new_child, rows = walk(child)
+            # by (job) keeps its 32 groups; by (host) grows with SERIES
+            g_pad = (g_tiny if g_tiny <= JOBS
+                     else qplan._bucket_pow2(SERIES, 8))
+            groups, gvalid, tval = params[pidx]
+            new_params[pidx] = (
+                _sds((rows,), groups.dtype, sharding),
+                _sds((g_pad,), gvalid.dtype, sharding),
+                _sds((), np.asarray(tval).dtype, sharding))
+            return ("agg", op, g_pad, pidx, new_child), g_pad
+        if tag == "topk":
+            _, op, k, g_pad, pidx, child = node
+            new_child, rows = walk(child)
+            (groups,) = params[pidx]
+            new_params[pidx] = (_sds((rows,), groups.dtype, sharding),)
+            return ("topk", op, k, g_pad, pidx, new_child), rows
+        raise AssertionError(f"unexpected plan node {tag!r}")
+
+    new_plan, _ = walk(plan)
+    return new_plan, tuple(new_leaves), tuple(new_params)
+
+
+def _compile(jitted, *args, **kw):
+    compiled = jitted.lower(*args, **kw).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    print(f"\n{getattr(jitted, '__name__', jitted)}: args "
+          f"{mem.argument_size_in_bytes} out {mem.output_size_in_bytes} "
+          f"temp {mem.temp_size_in_bytes} total {total}")
+    assert total < V5E_HBM_BYTES
+    return compiled
+
+
+def test_seal_pack_encode_compiles(one_chip):
+    """The seal path's device half at the bucket storage/shard.py picks
+    for one of 64 shards' 2 h block: ~781 lanes -> 1024, 720 -> 1024."""
+    L = _pow2_at_least(-(-SERIES // SHARDS), 8)
+    T = 1 << (DP_PER_BLOCK - 1).bit_length()
+    assert (L, T) == (1024, 1024)
+    i64 = lambda *s: _sds(s, np.int64, one_chip)      # noqa: E731
+    i32 = lambda *s: _sds(s, np.int32, one_chip)      # noqa: E731
+    u64 = lambda *s: _sds(s, np.uint64, one_chip)     # noqa: E731
+    _compile(m3tsz_encode._pack_encode_jit, i64(L, T), i64(L), i32(L),
+             u64(L, T), i32(L, T), u64(L, T), i32(L, T))
+
+
+def test_headline_decode_downsample_compiles(one_chip):
+    """README's headline shape: 1,000,000 series x 360 dp (1 h at 10 s)
+    -> 1 m means; 57 words is what bench.gen_streams' integer gauges
+    pack to."""
+    from m3_tpu.models.read_pipeline import decode_downsample
+    _compile(decode_downsample,
+             _sds((1_000_000, 57), np.uint32, one_chip),
+             _sds((1_000_000,), np.int32, one_chip), 360, 6)
+
+
+@pytest.mark.parametrize("kernel,expr", [
+    ("device_rate_pipeline", "rate(http_requests_total[5m])"),
+    pytest.param("device_reduce_pipeline",
+                 "count_over_time(http_requests_total[5m])",
+                 marks=pytest.mark.slow),
+])
+def test_per_node_pipeline_compiles(one_chip, tiny_db, monkeypatch,
+                                    kernel, expr):
+    args, kw = _capture(monkeypatch, tiny_db, kernel, expr)
+    new_args, new_kw = _per_node_args(args, kw, one_chip)
+    assert new_kw["n_lanes"] >= SERIES
+    _compile(getattr(qp, kernel), *new_args, **new_kw)
+
+
+@pytest.mark.slow
+def test_grouped_pipeline_compiles(one_chip, tiny_db, monkeypatch):
+    """sum by (job)(rate(...)) as the engine dispatches it: the
+    per-node grouped kernel (the fusion gate leaves a lone
+    agg-over-rate to it)."""
+    args, kw = _capture(monkeypatch, tiny_db, "device_grouped_pipeline",
+                        "sum by (job)(rate(http_requests_total[5m]))")
+    *head, groups = args
+    new_head, new_kw = _per_node_args(tuple(head), kw, one_chip)
+    new_groups = _sds((new_kw["n_lanes"],), groups.dtype, one_chip)
+    _compile(qp.device_grouped_pipeline, *new_head, new_groups, **new_kw)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("expr,fused", [
+    ("sum by (job)(rate(http_requests_total[5m]))", True),
+    ("topk(5, sum by (host)(rate(http_requests_total[5m])))", False),
+])
+def test_fused_expr_pipeline_compiles(one_chip, tiny_db, monkeypatch,
+                                      expr, fused):
+    (plan, leaves, params, steps), _ = _capture(
+        monkeypatch, tiny_db, "device_expr_pipeline", expr, fused=fused)
+    new_plan, new_leaves, new_params = _rescale_plan(
+        plan, leaves, params, one_chip)
+    _compile(qp.device_expr_pipeline, new_plan, new_leaves, new_params,
+             _sds(steps.shape, steps.dtype, one_chip))
