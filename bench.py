@@ -2473,8 +2473,7 @@ def bench_retention_ladder(n_series: int) -> dict:
         eng = Engine(db, "default", planner=planner)
         lad_walls, lad_stats, lad_vals = timed_queries(
             eng, "sum(m)", q_start, q_end, q_step)
-        rungs = dict(getattr(eng._qrange_local, "rung_selections",
-                             None) or {})
+        rungs = dict(eng._cost().rung_selections)
         db.close()
 
     # both engines read the same linear counter: a sum over n_series
@@ -3087,8 +3086,7 @@ def bench_graphite_device(n_series: int = 512, hours: int = 1) -> dict:
             t0 = time.perf_counter()
             d = dev.render(target, start, end, step)
             dev_times.append(time.perf_counter() - t0)
-            if (getattr(dev._engine._qrange_local,
-                        "fused_compile_cache", None) == "hit"):
+            if dev._engine._cost().fused_compile_cache == "hit":
                 cache_hits += 1
         stats = dev.last_render_stats
         match = (h.names == d.names

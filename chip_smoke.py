@@ -558,10 +558,13 @@ def phase_mesh(svc, wl, client, n_chips):
         seconds, rows, rec, kernels = http_query_range(
             client, expr, start_s, end_s)
         # the per-node shard_map pipelines are not instrument_kernel
-        # entry points: the engine's own stats carry the shard count
+        # entry points: the query's record carries the shard count (the
+        # engine's last_fetch_stats are the serving thread's own)
         check_device_record(label, rec, kernels, warm=False,
                             need_kernel=False)
-        served = dict(eng.last_fetch_stats or {})
+        served = {"device_serving": rec["device_serving"],
+                  "n_shards": rec.get("n_shards"), "fn": rec.get("fn"),
+                  "device_s": rec["phases"]["device_s"]}
         assert served.get("device_serving") is True, served
         assert served.get("n_shards") == n_chips, served
         per_device = [
@@ -574,8 +577,7 @@ def phase_mesh(svc, wl, client, n_chips):
         assert stats.get("device_serving") is True, stats
         emit("mesh_query", query=label, expr=expr, n_shards=n_chips,
              series=len(rows), seconds=round(seconds, 3),
-             served_stats={k: served.get(k) for k in (
-                 "device_serving", "n_shards", "device_s", "fn", "agg")},
+             served_stats=served,
              per_device_memory=per_device,
              single_device_seconds=round(single_s, 3),
              max_rel_err_vs_single_device=worst, rtol=RTOL)

@@ -186,16 +186,18 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
     the finest tier's earliest sample, so the merged lane stays
     time-ascending — violations trip the unsorted flag)."""
     T = n_cap if n_dp is None else n_dp
-    ts, vs, valid, _count, error = decode_batched(
-        words, nbits, T, int_optimized=True, unit_nanos=unit_nanos,
-        flag_truncation=True)
-    if n_tiers > 1 and tiers is not None:
-        valid = _tier_cut(ts, valid, slots, tiers, n_lanes, n_tiers)
-    times, values, counts = _merge_device(ts, vs, valid, slots,
-                                          n_lanes, n_cap)
-    error = error | (counts > n_cap)[slots]
-    unsorted = jnp.any(jnp.diff(times, axis=1) < 0, axis=1)
-    error = error | unsorted[slots]
+    with jax.named_scope("m3.decode"):
+        ts, vs, valid, _count, error = decode_batched(
+            words, nbits, T, int_optimized=True, unit_nanos=unit_nanos,
+            flag_truncation=True)
+    with jax.named_scope("m3.merge"):
+        if n_tiers > 1 and tiers is not None:
+            valid = _tier_cut(ts, valid, slots, tiers, n_lanes, n_tiers)
+        times, values, counts = _merge_device(ts, vs, valid, slots,
+                                              n_lanes, n_cap)
+        error = error | (counts > n_cap)[slots]
+        unsorted = jnp.any(jnp.diff(times, axis=1) < 0, axis=1)
+        error = error | unsorted[slots]
     return times, values, error
 
 
@@ -707,6 +709,7 @@ DEVICE_REDUCERS = ("sum_over_time", "avg_over_time", "count_over_time",
                    "stdvar_over_time")
 
 
+@jax.named_scope("m3.temporal")
 def _temporal_eval(fn: str, times, values, steps, range_nanos,
                    horizon=0.0, hw_sf: float = 0.5, hw_tf: float = 0.5,
                    phi=0.5):
@@ -801,9 +804,10 @@ def device_rate_pipeline(
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
                                          tiers, n_tiers)
-    rate = _rate_device(times, values, steps, range_nanos,
-                        is_counter, is_rate)
-    fleet = jnp.nansum(rate, axis=0)
+    with jax.named_scope("m3.temporal"):
+        rate = _rate_device(times, values, steps, range_nanos,
+                            is_counter, is_rate)
+        fleet = jnp.nansum(rate, axis=0)
     return rate, fleet, error
 
 
@@ -811,6 +815,7 @@ DEVICE_GROUP_AGGS = ("sum", "avg", "min", "max", "count", "group",
                      "stddev", "stdvar", "quantile")
 
 
+@jax.named_scope("m3.group")
 def _grouped_reduce_sharded(out, groups_l, n_groups: int, agg: str,
                             phi, axis: str):
     """Sharded counterpart of _grouped_reduce, shared by the per-node
@@ -912,6 +917,7 @@ def _grouped_quantile(out, groups, n_groups: int, phi):
     return jnp.where(npres > 0, q, jnp.nan)
 
 
+@jax.named_scope("m3.group")
 def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5):
     """Segment-reduce a served [L, S] temporal matrix over the lane axis
     by group id — the device form of the engine's _eval_agg loop
@@ -1475,6 +1481,7 @@ def _plan_sharded(node) -> bool:
     return False
 
 
+@jax.named_scope("m3.expr")
 def _expr_eval(plan, leaves, params, steps, errors,
                axis=None, n_shards: int = 1):
     """The fused-query interpreter body, shared by the single-chip and

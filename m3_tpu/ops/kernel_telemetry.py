@@ -18,10 +18,19 @@ jitted entry point and records, per kernel:
   ``m3_kernel_bytes_total{kernel}`` — call rate and input volume
 - ``m3_kernel_result_bytes_total{kernel}`` — device->host result
   volume (the transfer the fused path pays to bring answers back)
+- ``m3_kernel_queued_ahead_total{kernel}`` and the process-wide gauge
+  ``m3_device_inflight`` — the device's queue, counted where calls
+  are dispatched: one device runs one program at a time, so a call
+  that finds N instrumented calls in flight waits for N runs before
+  its own.  ``execute_s`` cannot tell that wait from running;
+  ``queued_ahead / invocations`` is the mean depth a call met, and
+  ``dispatch_s`` (the jitted call's return) against ``wait_s`` (from
+  there to ready) splits the host's part from the device's.
 
-and opens a ``device.Kernel`` span so device time shows up inside
-distributed query traces (the Monarch-style cost attribution the
-slow-query log consumes).
+and opens a ``device.Kernel`` span (tagged ``queued_ahead``) so device
+time shows up inside distributed query traces (the Monarch-style cost
+attribution the slow-query log consumes), and an
+``m3:kernel:<name>`` annotation so it shows up in a profiler trace.
 
 Two contract details worth their weight:
 
@@ -48,6 +57,23 @@ _metrics = instrument.registry()
 # name -> InstrumentedKernel, for bench/debug snapshots
 _KERNELS: dict[str, "InstrumentedKernel"] = {}
 _KERNELS_LOCK = threading.Lock()
+
+# instrumented calls dispatched and not yet ready, over all kernels:
+# one device, one queue
+_INFLIGHT_LOCK = threading.Lock()
+_inflight = 0
+
+
+def _inflight_add(delta: int) -> int:
+    """-> the count in flight before this change."""
+    global _inflight
+    with _INFLIGHT_LOCK:
+        before = _inflight
+        _inflight = before + delta
+        # under the lock: a late write of an older count would leave
+        # an idle device reading busy
+        _metrics.gauge("m3_device_inflight").set(_inflight)
+    return before
 
 
 def _is_traced(args, kwargs) -> bool:
@@ -91,6 +117,7 @@ class InstrumentedKernel:
         self.__dict__["_stats"] = {
             "invocations": 0, "compiles": 0,
             "compile_s": 0.0, "execute_s": 0.0,
+            "dispatch_s": 0.0, "wait_s": 0.0, "queued_ahead": 0,
             "elements": 0, "bytes": 0, "result_bytes": 0,
         }
         try:
@@ -110,11 +137,20 @@ class InstrumentedKernel:
             before = fn._cache_size()
         except (AttributeError, TypeError):
             before = None
-        t0 = time.perf_counter()
-        with tracing.span(tracing.DEVICE_KERNEL, kernel=name):
-            out = fn(*args, **kwargs)
-            out = jax.block_until_ready(out)
-        elapsed = time.perf_counter() - t0
+        queued_ahead = _inflight_add(1)
+        try:
+            with tracing.span(tracing.DEVICE_KERNEL, kernel=name,
+                              queued_ahead=queued_ahead), \
+                    tracing.TraceAnnotation("m3:kernel:" + name):
+                t0 = time.perf_counter_ns()
+                out = fn(*args, **kwargs)
+                t_dispatched = time.perf_counter_ns()
+                out = jax.block_until_ready(out)
+                t_ready = time.perf_counter_ns()
+        finally:
+            _inflight_add(-1)
+        elapsed = (t_ready - t0) / 1e9
+        dispatch_s = (t_dispatched - t0) / 1e9
         compiled = False
         if before is not None:
             try:
@@ -129,12 +165,17 @@ class InstrumentedKernel:
             st["elements"] += elements
             st["bytes"] += nbytes
             st["result_bytes"] += result_bytes
+            st["queued_ahead"] += queued_ahead
             if compiled:
                 st["compiles"] += 1
                 st["compile_s"] += elapsed
             else:
                 st["execute_s"] += elapsed
+                st["dispatch_s"] += dispatch_s
+                st["wait_s"] += elapsed - dispatch_s
         _metrics.counter("m3_kernel_invocations_total", kernel=name).inc()
+        _metrics.counter("m3_kernel_queued_ahead_total",
+                         kernel=name).inc(queued_ahead)
         _metrics.counter("m3_kernel_elements_total",
                          kernel=name).inc(elements)
         _metrics.counter("m3_kernel_bytes_total", kernel=name).inc(nbytes)
@@ -208,8 +249,10 @@ def kernels() -> dict[str, InstrumentedKernel]:
 
 
 def snapshot() -> dict[str, dict]:
-    """{kernel: {invocations, compiles, compile_s, execute_s, elements,
-    bytes}} — consumed by bench.py's BENCH_*.json emitter."""
+    """{kernel: stats()} — read before and after a window by
+    benchmark/traffic_kinds/query_closed_loop.py (its delta is what
+    benchmark/readers/kernel_telemetry.py reads), by chip_smoke.py's
+    phase lines and by bench.py's kernel table."""
     with _KERNELS_LOCK:
         items = list(_KERNELS.items())
     return {name: k.stats() for name, k in items}

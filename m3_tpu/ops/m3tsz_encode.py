@@ -42,6 +42,7 @@ import numpy as np
 
 from m3_tpu.ops import m3tsz_scalar as tsz
 from m3_tpu.ops.bitstream import PAD_WORDS, unpack_stream
+from m3_tpu.ops.kernel_telemetry import instrument_kernel
 from m3_tpu.utils import instrument, xtime
 
 U64 = jnp.uint64
@@ -475,7 +476,10 @@ def pack_encode(
     return _pack_fields(fields, fields_n, n_words_for(T))
 
 
-_pack_encode_jit = jax.jit(pack_encode)
+# the seal's device program, under kernel telemetry as `pack_encode`;
+# its one production caller (encode_to_streams) copies the result to
+# the host at once, so the wrapper's fence moves no synchronisation
+_pack_encode_jit = instrument_kernel("pack_encode")(jax.jit(pack_encode))
 
 
 # compile-cache fingerprint memo behind

@@ -122,6 +122,58 @@ def _ast_size(node) -> int:
     return 1
 
 
+class QueryCost:
+    """What one query cost, written where it is paid: the phase stamps
+    (``phase``, seconds by ``<phase>_s``), the serving path's stats
+    (``Engine.last_fetch_stats``), the fused planner's tallies and the
+    device tier's declines.  One per query and thread, on
+    ``Engine._qrange_local.cost``; it stays until the thread's next
+    query begins, so a caller reads ``last_fetch_stats`` after the
+    call, and never another thread's."""
+
+    __slots__ = ("phases", "stats", "declines", "gather_bytes",
+                 "ast_nodes", "fused_nodes", "fused_compile_cache",
+                 "fused_compile_s", "fused_transfer_bytes",
+                 "fused_n_shards", "fused_batched", "fused_batch_size",
+                 "fused_batch_wait_s", "fused_error", "fused_poisoned",
+                 "host_split_reasons", "rung_selections")
+
+    def __init__(self):
+        self.phases: dict[str, float] = {}
+        self.stats: dict | None = None
+        self.declines: dict[str, int] = {}    # device tier, by reason
+        self.gather_bytes = 0
+        # whole-query fusion (query/plan.py): how much of the tree the
+        # fused device program served, what it cost to (re)compile,
+        # and how many bytes crossed back
+        self.ast_nodes = 0
+        self.fused_nodes = 0
+        self.fused_compile_cache = None
+        self.fused_compile_s = 0.0
+        self.fused_transfer_bytes = 0
+        self.fused_n_shards = 1
+        self.fused_batched = False
+        self.fused_batch_size = 0
+        self.fused_batch_wait_s = 0.0
+        self.fused_error = None
+        self.fused_poisoned = False
+        self.host_split_reasons: dict[str, int] = {}
+        self.rung_selections: dict[str, int] = {}
+
+    def phase(self, name: str):
+        """``with cost.phase("pack"):`` — the one stamp of a phase
+        (utils/tracing.phase): record, span and trace annotation."""
+        return tracing.phase(name, self.phases)
+
+    def split(self, reason: str) -> None:
+        """A subtree the fused planner left to the host, by cause (the
+        slugs of ``m3_query_host_split_total``)."""
+        instrument.bounded_counter(
+            "m3_query_host_split_total").labels(reason=reason).inc()
+        self.host_split_reasons[reason] = (
+            self.host_split_reasons.get(reason, 0) + 1)
+
+
 def _sig(labels: dict, match: promql.VectorMatch | None) -> tuple:
     """Label signature for vector matching (on/ignoring semantics)."""
     if match is not None and match.on:
@@ -183,14 +235,13 @@ class Engine:
         if cache is not None and key in cache:
             return cache[key]
         plan = self.planner.plan(start_nanos, end_nanos)
-        sel = getattr(self._qrange_local, "rung_selections", None)
+        sel = self._cost().rung_selections
         fam = instrument.bounded_counter(
             "m3_query_resolution_selected_total", cap=32)
         for band in plan.bands:
             lab = band.resolution_label
             fam.labels(resolution=lab).inc()
-            if sel is not None:
-                sel[lab] = sel.get(lab, 0) + 1
+            sel[lab] = sel.get(lab, 0) + 1
         if cache is not None:
             cache[key] = plan
         return plan
@@ -241,9 +292,44 @@ class Engine:
 
     # --- fetch + decode ---
 
-    # stage timings of the most recent hot-path fetch (observability +
-    # the bench leg's per-stage breakdown); overwritten per query
-    last_fetch_stats: dict | None = None
+    def _cost(self) -> QueryCost:
+        """The calling thread's cost object: the running query's, or
+        (a direct ``_fetch_raw`` caller, no query scope) one that the
+        thread keeps until its next query."""
+        cost = getattr(self._qrange_local, "cost", None)
+        if cost is None:
+            cost = self._qrange_local.cost = QueryCost()
+        return cost
+
+    def _begin_cost(self) -> QueryCost:
+        cost = self._qrange_local.cost = QueryCost()
+        return cost
+
+    @property
+    def last_fetch_stats(self) -> dict | None:
+        """Stats of the calling thread's most recent serving path
+        (phase seconds so far, stream and datapoint counts,
+        ``device_serving``): the thread's own, whatever other server
+        threads run through this engine meanwhile."""
+        return self._cost().stats
+
+    @last_fetch_stats.setter
+    def last_fetch_stats(self, stats: dict | None) -> None:
+        self._cost().stats = stats
+
+    def _publish_stats(self, **fields) -> None:
+        """A serving path's stats: the phases stamped so far in this
+        query, unrounded, and the path's own fields."""
+        cost = self._cost()
+        cost.stats = {**cost.phases, **fields}
+
+    def _decline(self, reason: str) -> None:
+        """The per-node device tier hands a selector to the host tier:
+        counted by cause, and kept for the query's record."""
+        instrument.bounded_counter(
+            "m3_query_device_decline_total").labels(reason=reason).inc()
+        declines = self._cost().declines
+        declines[reason] = declines.get(reason, 0) + 1
 
     def _gather(self, matchers, start_nanos: int, end_nanos: int):
         """Collect the namespace fan-out's raw block payloads without
@@ -298,7 +384,7 @@ class Engine:
                         nb += payload[0].nbytes + payload[1].nbytes
             if nb:
                 ns_bytes[ns] = ns_bytes.get(ns, 0) + nb
-        self._qrange_local.last_gather_bytes = sum(ns_bytes.values())
+        self._cost().gather_bytes = sum(ns_bytes.values())
         if self.planner is not None and ns_bytes:
             # per-rung read-bytes accounting (grafana panel 45): label
             # by declared resolution, "raw" for the unaggregated tier
@@ -322,16 +408,23 @@ class Engine:
         query-scoped thread-local and is released at query end
         (query_range_with_meta's finally), so it can never serve a
         stale storage snapshot to a later query — cross-query caching
-        belongs to m3_tpu/cache, which sees invalidations."""
+        belongs to m3_tpu/cache, which sees invalidations.
+
+        The one stamp of the ``fetch`` phase: the walk on a miss, next
+        to nothing on a hit, so a query's ``fetch_s`` is the time it
+        spent gathering, each walk counted once."""
+        with self._cost().phase("fetch"):
+            return self._gather_memoized(matchers, start_nanos,
+                                         end_nanos)
+
+    def _gather_memoized(self, matchers, start_nanos: int,
+                         end_nanos: int):
         memo = getattr(self._qrange_local, "gather_cache", None)
         if memo is None:
             # no query scope on this thread (a direct _fetch_raw
             # caller, e.g. a live tailer): nothing would ever release
             # a memo, and repeated fetches must see fresh storage
-            t0 = time.perf_counter()
-            g = self._gather(matchers, start_nanos, end_nanos)
-            self._qrange_local.last_gather_s = time.perf_counter() - t0
-            return g
+            return self._gather(matchers, start_nanos, end_nanos)
         key = (tuple(matchers), start_nanos, end_nanos)
         ent = memo.get(key)
         if ent is None:
@@ -346,13 +439,9 @@ class Engine:
             if ent is not None:
                 memo[key] = ent
         if ent is not None:
-            # memo hit: report the ORIGINAL walk's cost, not ~0 — the
-            # bench per-stage breakdown reads fetch_s from stats
-            self._qrange_local.last_gather_s = ent["dur"]
-            self._qrange_local.last_gather_bytes = ent["bytes"]
+            self._cost().gather_bytes = ent["bytes"]
             return ent["g"]
         from m3_tpu import serving
-        t0 = time.perf_counter()
         try:
             g = self._gather(matchers, start_nanos, end_nanos)
         except BaseException:
@@ -360,11 +449,7 @@ class Engine:
             # it so fleet peers stop waiting on a gather that died
             serving.shared_fetch_memo_abort(self, key)
             raise
-        dur = time.perf_counter() - t0
-        self._qrange_local.last_gather_s = dur
-        memo[key] = {"g": g, "dur": dur,
-                     "bytes": getattr(self._qrange_local,
-                                      "last_gather_bytes", 0)}
+        memo[key] = {"g": g, "bytes": self._cost().gather_bytes}
         serving.shared_fetch_memo_put(self, key, memo[key])
         return g
 
@@ -419,16 +504,18 @@ class Engine:
                 "datapoints": int(counts.sum()),
             }
 
-        if ent is None:
-            return _assemble()
-        # entries adopted from the cross-query fetch memo are shared
-        # by reference across a batched fleet: assemble once, under a
-        # per-entry lock (setdefault is atomic), never once per member
-        with ent.setdefault("lock", threading.Lock()):
-            grid = ent.get("arrays")
-            if grid is None:
-                grid = ent["arrays"] = _assemble()
-        return grid
+        with self._cost().phase("pack"):
+            if ent is None:
+                return _assemble()
+            # entries adopted from the cross-query fetch memo are
+            # shared by reference across a batched fleet: assemble
+            # once, under a per-entry lock (setdefault is atomic),
+            # never once per member
+            with ent.setdefault("lock", threading.Lock()):
+                grid = ent.get("arrays")
+                if grid is None:
+                    grid = ent["arrays"] = _assemble()
+            return grid
 
     def _check_deadline(self, what: str) -> None:
         """Deadline hop for decode batching: device/host decode of a
@@ -439,7 +526,7 @@ class Engine:
         task = getattr(self._qrange_local, "task", None)
         if task is not None:
             task.set_phase(what)
-            if (self.last_fetch_stats or {}).get("device_serving"):
+            if (self._cost().stats or {}).get("device_serving"):
                 task.device_tier = "device"
             task.check_cancelled()
         limits = getattr(self._qrange_local, "limits", None)
@@ -449,12 +536,10 @@ class Engine:
     def _fetch_raw(self, matchers, start_nanos: int, end_nanos: int):
         """-> (labels, times [L, N], values [L, N]) batched, decoded,
         stitched across the namespace fan-out."""
-        # stats note: fetch_s comes from the gather memo
-        # (last_gather_s), never from a local timer — a memo hit must
-        # report the original walk's cost, not ~0
         labels, parts, compressed, stream_counts = self._gather_cached(
             matchers, start_nanos, end_nanos)
         self._check_deadline("host decode")
+        cost = self._cost()
         if compressed and not parts and all(
                 tier == compressed[0][1] for _, tier, _ in compressed):
             # hot path (warm node, single namespace, everything served
@@ -465,42 +550,33 @@ class Engine:
             # [start, end], and every consumer (step consolidation,
             # temporal windows) selects samples by time, so they are
             # simply never picked.
-            t1 = time.perf_counter()
-            streams = [p for _, _, p in compressed]
-            slots = np.asarray([slot for slot, _, _ in compressed],
-                               dtype=np.int64)
-            known = (None if any(c is None for c in stream_counts)
-                     else np.asarray(stream_counts, dtype=np.int64))
-            fused = decode_streams_merged(streams, slots, len(labels),
-                                          counts=known)
+            with cost.phase("decode"):
+                streams = [p for _, _, p in compressed]
+                slots = np.asarray([slot for slot, _, _ in compressed],
+                                   dtype=np.int64)
+                known = (None if any(c is None for c in stream_counts)
+                         else np.asarray(stream_counts, dtype=np.int64))
+                fused = decode_streams_merged(
+                    streams, slots, len(labels), counts=known)
+                if fused is None:
+                    # out-of-order data / no toolchain: general decode
+                    # + merge
+                    ts, vs, valid = decode_streams_adaptive(streams)
             if fused is not None:
                 times2, values2, lane_counts = fused
-                self.last_fetch_stats = {
-                    "fetch_s": round(self._qrange_local.last_gather_s, 3),
-                    "decode_s": round(time.perf_counter() - t1, 3),
-                    "merge_s": 0.0,
-                    "n_streams": len(streams),
-                    "datapoints": int(lane_counts.sum()),
-                    "read_bytes": int(getattr(
-                        self._qrange_local, "last_gather_bytes", 0)),
-                }
+                self._publish_stats(
+                    n_streams=len(streams),
+                    datapoints=int(lane_counts.sum()),
+                    read_bytes=int(cost.gather_bytes))
                 return labels, times2, values2
-            # out-of-order data / no toolchain: general decode + merge
-            ts, vs, valid = decode_streams_adaptive(streams)
-            t2 = time.perf_counter()
-            times2, values2, _ = cons.merge_grids(
-                slots, ts, vs, valid, len(labels),
-                t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
-            t3 = time.perf_counter()
-            self.last_fetch_stats = {
-                "fetch_s": round(self._qrange_local.last_gather_s, 3),
-                "decode_s": round(t2 - t1, 3),
-                "merge_s": round(t3 - t2, 3),
-                "n_streams": len(streams),
-                "datapoints": int(np.asarray(valid).sum()),
-                "read_bytes": int(getattr(
-                    self._qrange_local, "last_gather_bytes", 0)),
-            }
+            with cost.phase("merge"):
+                times2, values2, _ = cons.merge_grids(
+                    slots, ts, vs, valid, len(labels),
+                    t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
+            self._publish_stats(
+                n_streams=len(streams),
+                datapoints=int(np.asarray(valid).sum()),
+                read_bytes=int(cost.gather_bytes))
             return labels, times2, values2
         if compressed and not parts and _VECTORIZED_STITCH:
             # multi-tier, all-compressed (raw + aggregated namespaces
@@ -508,67 +584,63 @@ class Engine:
             # decoded grids — per-slot tier cuts computed with
             # minimum-scatters, then one merge — instead of the
             # per-(series, block) fragment slicing below
-            t1 = time.perf_counter()
-            streams = [p for _, _, p in compressed]
-            known = (None if any(c is None for c in stream_counts)
-                     else np.asarray(stream_counts, dtype=np.int64))
-            ts, vs, valid = decode_streams_adaptive(streams, counts=known)
-            t2 = time.perf_counter()
-            slots = np.asarray([s for s, _, _ in compressed],
-                               dtype=np.int64)
-            tiers = np.asarray([t for _, t, _ in compressed],
-                               dtype=np.int64)
-            valid = np.array(valid)  # writable: cuts mask rows below
-            n_lanes = len(labels)
-            cut = np.full(n_lanes, cons._INF, dtype=np.int64)
-            for tier in np.unique(tiers):  # ascending = finest first
-                rows = np.nonzero(tiers == tier)[0]
-                keep = valid[rows] & (
-                    ts[rows] < cut[slots[rows]][:, None])
-                valid[rows] = keep
-                row_min = np.where(keep, ts[rows], cons._INF).min(axis=1)
-                np.minimum.at(cut, slots[rows], row_min)
-            times2, values2, _ = cons.merge_grids(
-                slots, ts, vs, valid, n_lanes,
-                t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
-            self.last_fetch_stats = {
-                "fetch_s": round(self._qrange_local.last_gather_s, 3),
-                "decode_s": round(t2 - t1, 3),
-                "merge_s": round(time.perf_counter() - t2, 3),
-                "n_streams": len(streams),
-                "datapoints": int(valid.sum()),
-                "read_bytes": int(getattr(
-                    self._qrange_local, "last_gather_bytes", 0)),
-                "tiers": int(len(np.unique(tiers))),
-            }
+            with cost.phase("decode"):
+                streams = [p for _, _, p in compressed]
+                known = (None if any(c is None for c in stream_counts)
+                         else np.asarray(stream_counts, dtype=np.int64))
+                ts, vs, valid = decode_streams_adaptive(streams,
+                                                        counts=known)
+            with cost.phase("merge"):
+                slots = np.asarray([s for s, _, _ in compressed],
+                                   dtype=np.int64)
+                tiers = np.asarray([t for _, t, _ in compressed],
+                                   dtype=np.int64)
+                valid = np.array(valid)  # writable: cuts mask rows below
+                n_lanes = len(labels)
+                cut = np.full(n_lanes, cons._INF, dtype=np.int64)
+                for tier in np.unique(tiers):  # ascending = finest first
+                    rows = np.nonzero(tiers == tier)[0]
+                    keep = valid[rows] & (
+                        ts[rows] < cut[slots[rows]][:, None])
+                    valid[rows] = keep
+                    row_min = np.where(keep, ts[rows],
+                                       cons._INF).min(axis=1)
+                    np.minimum.at(cut, slots[rows], row_min)
+                times2, values2, _ = cons.merge_grids(
+                    slots, ts, vs, valid, n_lanes,
+                    t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
+            self._publish_stats(
+                n_streams=len(streams),
+                datapoints=int(valid.sum()),
+                read_bytes=int(cost.gather_bytes),
+                tiers=int(len(np.unique(tiers))))
             return labels, times2, values2
-        t1 = time.perf_counter()
-        if compressed:
-            streams = [p for _, _, p in compressed]
-            ts, vs, valid = decode_streams_adaptive(streams)
-            # copy: `parts` may be the list held by the gather cache —
-            # appending in place would poison a later cache hit with
-            # doubled (raw + decoded) fragments
-            parts = list(parts)
-            for i, (slot, tier, _) in enumerate(compressed):
-                sel = valid[i]
-                parts.append((slot, tier, ts[i][sel], vs[i][sel]))
-        raw_parts = self._stitch(parts)
-        times, values, _counts = cons.merge_packed(raw_parts, len(labels))
-        # clamp to the query range (blocks overfetch)
-        inside = (times > start_nanos - 1) & (times <= end_nanos) | (times == cons._INF)
-        values = np.where(inside, values, np.nan)
-        tmask = inside & (times != cons._INF)
-        times2, values2, _ = cons.pack_valid(times, values, tmask)
-        self.last_fetch_stats = {
-            "fetch_s": round(self._qrange_local.last_gather_s, 3),
-            "decode_s": round(time.perf_counter() - t1, 3),
-            "merge_s": 0.0,
-            "n_streams": len(parts),  # raw + decoded-compressed fragments
-            "datapoints": int(tmask.sum()),
-            "read_bytes": int(getattr(
-                self._qrange_local, "last_gather_bytes", 0)),
-        }
+        # mutable buffers in the mix: decode, stitch, merge and clamp
+        # as one step (the fragments interleave), filed under decode
+        with cost.phase("decode"):
+            if compressed:
+                streams = [p for _, _, p in compressed]
+                ts, vs, valid = decode_streams_adaptive(streams)
+                # copy: `parts` may be the list held by the gather
+                # cache — appending in place would poison a later cache
+                # hit with doubled (raw + decoded) fragments
+                parts = list(parts)
+                for i, (slot, tier, _) in enumerate(compressed):
+                    sel = valid[i]
+                    parts.append((slot, tier, ts[i][sel], vs[i][sel]))
+            raw_parts = self._stitch(parts)
+            times, values, _counts = cons.merge_packed(raw_parts,
+                                                       len(labels))
+            # clamp to the query range (blocks overfetch)
+            inside = ((times > start_nanos - 1) & (times <= end_nanos)
+                      | (times == cons._INF))
+            values = np.where(inside, values, np.nan)
+            tmask = inside & (times != cons._INF)
+            times2, values2, _ = cons.pack_valid(times, values, tmask)
+        self._publish_stats(
+            n_streams=len(parts),  # raw + decoded-compressed fragments
+            datapoints=int(tmask.sum()),
+            read_bytes=int(cost.gather_bytes))
         return labels, times2, values2
 
     @staticmethod
@@ -865,7 +937,8 @@ class Engine:
         fusion on each supported subtree underneath it."""
         if not self._device_serving_active():
             return None
-        if getattr(self._qrange_local, "fused_poisoned", False):
+        cost = self._cost()
+        if cost.fused_poisoned:
             # a fused attempt already hit a decode-error fallback this
             # query: serve the rest on the host instead of re-running
             # the failing device program for every subtree
@@ -875,13 +948,7 @@ class Engine:
             # steps land in coarse rung bands: the fused pipeline
             # consolidates with the base lookback only, so the host
             # path's per-band widening must serve this query
-            reason = "retention_coarse_lookback"
-            instrument.bounded_counter(
-                "m3_query_host_split_total").labels(reason=reason).inc()
-            splits = getattr(self._qrange_local,
-                             "host_split_reasons", None)
-            if splits is not None:
-                splits[reason] = splits.get(reason, 0) + 1
+            cost.split("retention_coarse_lookback")
             return None
         from m3_tpu.query import plan as qplan
         try:
@@ -889,14 +956,7 @@ class Engine:
         except qplan.Unsupported as exc:
             # every host split is countable by cause: a bounded slug
             # per decline reason (the slowlog only shows examples)
-            reason = getattr(exc, "reason", "unknown_node")
-            instrument.bounded_counter(
-                "m3_query_host_split_total").labels(
-                    reason=reason).inc()
-            splits = getattr(self._qrange_local,
-                             "host_split_reasons", None)
-            if splits is not None:
-                splits[reason] = splits.get(reason, 0) + 1
+            cost.split(getattr(exc, "reason", "unknown_node"))
             return None
         except (observe.QueryCancelled, QueryDeadlineExceeded):
             # cooperative cancel / deadline raised inside the fused
@@ -906,8 +966,7 @@ class Engine:
         except Exception as exc:  # noqa: BLE001 — never fail a query
             # that the host tier can still answer; keep the reason for
             # the slow-query record
-            self._qrange_local.fused_error = (
-                f"{type(exc).__name__}: {exc}"[:200])
+            cost.fused_error = f"{type(exc).__name__}: {exc}"[:200]
             return None
 
     def _device_serving_active(self) -> bool:
@@ -953,22 +1012,35 @@ class Engine:
         power-of-two bucketing so a cardinality sweep lands in a
         handful of compiled programs; default: linear _bucket).
 
-        Returns None (caller falls back to the host tier: mixed/mutable
-        payloads, unknown counts) or a dict with the packed numpy
-        arrays plus the shape metadata."""
+        Returns (pk, None), pk a dict with the packed numpy arrays plus
+        the shape metadata, or (None, reason) where only compressed
+        streams with known counts can be packed: ``mixed_payloads``,
+        ``empty``, ``unknown_counts``.  The per-node tier then falls
+        back to the host and counts the reason (``_decline``); the
+        fused planner tries its arrays bridge first.  The gather is
+        the ``fetch`` phase; everything after it here is ``pack``."""
         bucket = self._bucket if bucket is None else bucket
         shifted = self._eval_times(rv, step_times)
         rng = rv.range_nanos if range_nanos is None else range_nanos
         # cached: on fallback, _range_samples -> _fetch_raw reuses this
-        # exact gather (same matchers, same range) for free; fetch_s in
-        # stats comes from the memo's last_gather_s
+        # exact gather (same matchers, same range) for free
         lo, hi = int(shifted[0]) - rng, int(shifted[-1])
-        labels, parts, compressed, stream_counts = self._gather_cached(
-            rv.matchers, lo, hi)
-        if not compressed or parts or not labels:
-            return None
+        gathered = self._gather_cached(rv.matchers, lo, hi)
+        with self._cost().phase("pack"):
+            return self._pack_gathered(rv, shifted, rng, lo, hi,
+                                       gathered, bucket)
+
+    def _pack_gathered(self, rv, shifted, rng, lo, hi, gathered,
+                       bucket):
+        labels, parts, compressed, stream_counts = gathered
+        if parts:
+            # mutable-buffer reads among (or instead of) the sealed
+            # blocks: only compressed streams decode on the device
+            return None, "mixed_payloads"
+        if not compressed or not labels:
+            return None, "empty"
         if any(c is None for c in stream_counts):
-            return None
+            return None, "unknown_counts"
         streams = [p for _, _, p in compressed]
         slots_np = np.asarray([s for s, _, _ in compressed],
                               dtype=np.int64)
@@ -1039,7 +1111,7 @@ class Engine:
             "n_streams": len(streams),
             "datapoints": int(counts_np.sum()),
             "tiers": tiers_p, "n_tiers": n_tiers,
-        }
+        }, None
 
     def _shard_repack(self, pk, n_shards: int):
         """Re-lay a packed batch for the shard_map'd pipelines: equal
@@ -1116,8 +1188,9 @@ class Engine:
         Returns (labels, out) or None to fall back to the host tier
         (mixed/mutable payloads, multi-tier stitch, unknown counts, or
         any per-stream decode error flagged by the device)."""
-        pk = self._device_gather_pack(rv, step_times, range_nanos)
+        pk, why = self._device_gather_pack(rv, step_times, range_nanos)
         if pk is None:
+            self._decline(why)
             return None
         self._check_deadline("device decode")
         import jax.numpy as jnp
@@ -1126,10 +1199,11 @@ class Engine:
             device_rate_pipeline, device_reduce_pipeline,
             device_temporal_sharded)
 
-        t1 = time.perf_counter()
+        cost = self._cost()
         n_shards = self._serving_shards()
         if n_shards > 1:
-            pk = self._shard_repack(pk, n_shards)
+            with cost.phase("pack"):
+                pk = self._shard_repack(pk, n_shards)
         if fn == "quantile_over_time":
             elements = (pk["lanes_pad"] // max(n_shards, 1)
                         * len(pk["steps"]) * pk["n_cap"])
@@ -1141,46 +1215,53 @@ class Engine:
             if elements > self._QOT_MAX_ELEMENTS:
                 instrument.counter(
                     "m3_device_hbm_gate_rejections_total").inc()
+                self._decline("hbm_gate")
                 return None  # PER-DEVICE window grid too large: host
                 # native kernel (sharded meshes split the lane axis, so
                 # each device materializes only its shard's slice)
         labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
-        words_p, nbits_p = pk["words"], pk["nbits"]
-        slots_p, steps_p = pk["slots"], pk["steps"]
         n_dp, n_cap, lanes_pad = pk["n_dp"], pk["n_cap"], pk["lanes_pad"]
         n_lanes = pk["n_lanes"]
-        tiers_p = (None if pk["tiers"] is None
-                   else jnp.asarray(pk["tiers"]))
         try:
-            if n_shards > 1:
-                rate, err = device_temporal_sharded(
-                    self.serving_mesh, jnp.asarray(words_p),
-                    jnp.asarray(nbits_p), jnp.asarray(slots_p),
-                    jnp.asarray(steps_p), n_lanes=lanes_pad,
-                    n_cap=n_cap, range_nanos=rng, fn=fn, n_dp=n_dp,
-                    tiers=tiers_p, n_tiers=pk["n_tiers"],
-                    horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
-                    phi=phi)
-            elif fn in ("rate", "increase", "delta"):
-                rate, _fleet, err = device_rate_pipeline(
-                    jnp.asarray(words_p), jnp.asarray(nbits_p),
-                    jnp.asarray(slots_p), jnp.asarray(steps_p),
-                    n_lanes=lanes_pad, n_cap=n_cap, range_nanos=rng,
-                    is_counter=fn != "delta", is_rate=fn == "rate",
-                    n_dp=n_dp, tiers=tiers_p, n_tiers=pk["n_tiers"])
-            else:
-                rate, err = device_reduce_pipeline(
-                    jnp.asarray(words_p), jnp.asarray(nbits_p),
-                    jnp.asarray(slots_p), jnp.asarray(steps_p),
-                    n_lanes=lanes_pad, n_cap=n_cap, range_nanos=rng,
-                    reducer=fn, n_dp=n_dp, tiers=tiers_p,
-                    n_tiers=pk["n_tiers"], horizon=horizon,
-                    hw_sf=hw_sf, hw_tf=hw_tf, phi=phi)
-            out = np.asarray(rate)
-            err_np = np.asarray(err)
+            with cost.phase("device"):
+                # not fenced: a block_until_ready on the arguments
+                # would change what is measured
+                with cost.phase("h2d"):
+                    words_d, nbits_d, slots_d, steps_d = (
+                        jnp.asarray(pk[k])
+                        for k in ("words", "nbits", "slots", "steps"))
+                    tiers_d = (None if pk["tiers"] is None
+                               else jnp.asarray(pk["tiers"]))
+                if n_shards > 1:
+                    rate, err = device_temporal_sharded(
+                        self.serving_mesh, words_d, nbits_d, slots_d,
+                        steps_d, n_lanes=lanes_pad,
+                        n_cap=n_cap, range_nanos=rng, fn=fn, n_dp=n_dp,
+                        tiers=tiers_d, n_tiers=pk["n_tiers"],
+                        horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
+                        phi=phi)
+                elif fn in ("rate", "increase", "delta"):
+                    rate, _fleet, err = device_rate_pipeline(
+                        words_d, nbits_d, slots_d, steps_d,
+                        n_lanes=lanes_pad, n_cap=n_cap,
+                        range_nanos=rng, is_counter=fn != "delta",
+                        is_rate=fn == "rate", n_dp=n_dp,
+                        tiers=tiers_d, n_tiers=pk["n_tiers"])
+                else:
+                    rate, err = device_reduce_pipeline(
+                        words_d, nbits_d, slots_d, steps_d,
+                        n_lanes=lanes_pad, n_cap=n_cap,
+                        range_nanos=rng, reducer=fn, n_dp=n_dp,
+                        tiers=tiers_d, n_tiers=pk["n_tiers"],
+                        horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
+                        phi=phi)
+                with cost.phase("d2h"):
+                    out = np.asarray(rate)
+                    err_np = np.asarray(err)
         except Exception as exc:  # noqa: BLE001 - serving must not
             # hard-fail on a device runtime error (HBM OOM on a huge
             # fan-out): the host tier can still answer
+            self._decline("device_error")
             self.last_fetch_stats = {
                 "device_serving": False,
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
@@ -1190,17 +1271,15 @@ class Engine:
         flagged = (err_np[real] if real is not None
                    else err_np[:pk["n_streams"]])
         if flagged.any():
+            self._decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
-        self.last_fetch_stats = {
-            "fetch_s": round(self._qrange_local.last_gather_s, 3),
-            "device_s": round(time.perf_counter() - t1, 3),
-            "n_streams": pk["n_streams"],
-            "datapoints": pk["datapoints"],
-            "device_serving": True,
-            "fn": fn,  # which temporal actually ran on device —
-            # the differential suite keys its tolerance on this
-            "n_shards": n_shards,
-        }
+        self._publish_stats(
+            n_streams=pk["n_streams"],
+            datapoints=pk["datapoints"],
+            device_serving=True,
+            fn=fn,  # which temporal actually ran on device — the
+            # differential suite keys its tolerance on this
+            n_shards=n_shards)
         return labels, out[:n_lanes, :len(shifted)]
 
     # aggregations with a device grouped form (topk/bottomk/count_values
@@ -1231,8 +1310,9 @@ class Engine:
             # last_over_time over the engine lookback
             rv, fn, rng_override = node.expr, "last_over_time", \
                 self.lookback
-        pk = self._device_gather_pack(rv, step_times, rng_override)
+        pk, why = self._device_gather_pack(rv, step_times, rng_override)
         if pk is None:
+            self._decline(why)
             return None
         self._check_deadline("device decode")
         import jax.numpy as jnp
@@ -1240,68 +1320,77 @@ class Engine:
         from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
                                                   device_grouped_sharded)
 
-        t1 = time.perf_counter()
-        n_shards = self._serving_shards()
-        # padded-lanes-are-NaN invariant (models/query_pipeline
-        # _grouped_quantile sort layout depends on it): every real
-        # stream row targets a real lane and every padding row is
-        # zero-length, so lanes >= n_lanes can only decode to all-NaN
-        # rows and are inert wherever groups_p parks them
-        m_real = pk["n_streams"]
-        assert (int(pk["slots"][:m_real].max()) < pk["n_lanes"]
-                and not pk["nbits"][m_real:].any()), \
-            "device pack violated the padded-lanes-are-NaN invariant"
-        if n_shards > 1:
-            pk = self._shard_repack(pk, n_shards)
-        labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
-        n_lanes, lanes_pad = pk["n_lanes"], pk["lanes_pad"]
-        if isinstance(node.expr, promql.Call):
-            # group keys over name-dropped labels: the host path
-            # aggregates the drop_name()'d temporal matrix
-            # (_eval_temporal return)
-            key_labels = [
-                {k: v for k, v in ls.items() if k != b"__name__"}
-                for ls in labels]
-        else:
-            # a plain selector keeps __name__ (host _fetch_consolidated
-            # does not drop it, so `by (__name__)` groups on it)
-            key_labels = labels
-        keys = self._group_keys(Matrix(key_labels, None), node)
-        uniq = sorted(set(keys))
-        group_of = {k: i for i, k in enumerate(uniq)}
-        g_pad = self._bucket(len(uniq), 8)
-        # padding lanes are all-NaN rows (no streams, asserted above):
-        # they contribute to no group, so parking them on group 0 is
-        # harmless — for the quantile sort layout this is load-bearing
-        # (see _grouped_quantile's padded-lanes-are-NaN invariant)
-        groups_p = np.zeros(lanes_pad, dtype=np.int64)
-        groups_p[:n_lanes] = [group_of[k] for k in keys]
-        try:
+        cost = self._cost()
+        with cost.phase("pack"):
+            n_shards = self._serving_shards()
+            # padded-lanes-are-NaN invariant (models/query_pipeline
+            # _grouped_quantile sort layout depends on it): every real
+            # stream row targets a real lane and every padding row is
+            # zero-length, so lanes >= n_lanes can only decode to
+            # all-NaN rows and are inert wherever groups_p parks them
+            m_real = pk["n_streams"]
+            assert (int(pk["slots"][:m_real].max()) < pk["n_lanes"]
+                    and not pk["nbits"][m_real:].any()), \
+                "device pack violated the padded-lanes-are-NaN invariant"
             if n_shards > 1:
-                tiers_p = (None if pk["tiers"] is None
-                           else jnp.asarray(pk["tiers"]))
-                out_g, err = device_grouped_sharded(
-                    self.serving_mesh, jnp.asarray(pk["words"]),
-                    jnp.asarray(pk["nbits"]), jnp.asarray(pk["slots"]),
-                    jnp.asarray(pk["steps"]), jnp.asarray(groups_p),
-                    n_lanes=lanes_pad, n_groups=g_pad,
-                    n_cap=pk["n_cap"], range_nanos=rng,
-                    fn=fn, agg=node.op, n_dp=pk["n_dp"],
-                    tiers=tiers_p, n_tiers=pk["n_tiers"], phi=phi)
+                pk = self._shard_repack(pk, n_shards)
+            labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
+            n_lanes, lanes_pad = pk["n_lanes"], pk["lanes_pad"]
+            if isinstance(node.expr, promql.Call):
+                # group keys over name-dropped labels: the host path
+                # aggregates the drop_name()'d temporal matrix
+                # (_eval_temporal return)
+                key_labels = [
+                    {k: v for k, v in ls.items() if k != b"__name__"}
+                    for ls in labels]
             else:
-                tiers_p = (None if pk["tiers"] is None
-                           else jnp.asarray(pk["tiers"]))
-                out_g, err = device_grouped_pipeline(
-                    jnp.asarray(pk["words"]), jnp.asarray(pk["nbits"]),
-                    jnp.asarray(pk["slots"]), jnp.asarray(pk["steps"]),
-                    jnp.asarray(groups_p), n_lanes=lanes_pad,
-                    n_groups=g_pad, n_cap=pk["n_cap"], range_nanos=rng,
-                    fn=fn, agg=node.op, n_dp=pk["n_dp"],
-                    tiers=tiers_p, n_tiers=pk["n_tiers"], phi=phi)
-            out = np.asarray(out_g)
-            err_np = np.asarray(err)
+                # a plain selector keeps __name__ (host
+                # _fetch_consolidated does not drop it, so
+                # `by (__name__)` groups on it)
+                key_labels = labels
+            keys = self._group_keys(Matrix(key_labels, None), node)
+            uniq = sorted(set(keys))
+            group_of = {k: i for i, k in enumerate(uniq)}
+            g_pad = self._bucket(len(uniq), 8)
+            # padding lanes are all-NaN rows (no streams, asserted
+            # above): they contribute to no group, so parking them on
+            # group 0 is harmless — for the quantile sort layout this
+            # is load-bearing (see _grouped_quantile's
+            # padded-lanes-are-NaN invariant)
+            groups_p = np.zeros(lanes_pad, dtype=np.int64)
+            groups_p[:n_lanes] = [group_of[k] for k in keys]
+        try:
+            with cost.phase("device"):
+                # not fenced: a block_until_ready on the arguments
+                # would change what is measured
+                with cost.phase("h2d"):
+                    words_d, nbits_d, slots_d, steps_d = (
+                        jnp.asarray(pk[k])
+                        for k in ("words", "nbits", "slots", "steps"))
+                    groups_d = jnp.asarray(groups_p)
+                    tiers_d = (None if pk["tiers"] is None
+                               else jnp.asarray(pk["tiers"]))
+                if n_shards > 1:
+                    out_g, err = device_grouped_sharded(
+                        self.serving_mesh, words_d, nbits_d, slots_d,
+                        steps_d, groups_d,
+                        n_lanes=lanes_pad, n_groups=g_pad,
+                        n_cap=pk["n_cap"], range_nanos=rng,
+                        fn=fn, agg=node.op, n_dp=pk["n_dp"],
+                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi)
+                else:
+                    out_g, err = device_grouped_pipeline(
+                        words_d, nbits_d, slots_d, steps_d, groups_d,
+                        n_lanes=lanes_pad, n_groups=g_pad,
+                        n_cap=pk["n_cap"], range_nanos=rng,
+                        fn=fn, agg=node.op, n_dp=pk["n_dp"],
+                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi)
+                with cost.phase("d2h"):
+                    out = np.asarray(out_g)
+                    err_np = np.asarray(err)
         except Exception as exc:  # noqa: BLE001 - serving must not
             # hard-fail on a device runtime error: host can still answer
+            self._decline("device_error")
             self.last_fetch_stats = {
                 "device_serving": False,
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
@@ -1311,19 +1400,17 @@ class Engine:
         flagged = (err_np[real] if real is not None
                    else err_np[:pk["n_streams"]])
         if flagged.any():
+            self._decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
-        self.last_fetch_stats = {
-            "fetch_s": round(self._qrange_local.last_gather_s, 3),
-            "device_s": round(time.perf_counter() - t1, 3),
-            "n_streams": pk["n_streams"],
-            "datapoints": pk["datapoints"],
-            "n_groups": len(uniq),
-            "device_serving": True,
-            "device_grouped": True,
-            "fn": fn,  # device-served temporal + aggregation — the
-            "agg": node.op,  # differential suite keys tolerance on these
-            "n_shards": n_shards,
-        }
+        self._publish_stats(
+            n_streams=pk["n_streams"],
+            datapoints=pk["datapoints"],
+            n_groups=len(uniq),
+            device_serving=True,
+            device_grouped=True,
+            fn=fn,  # device-served temporal + aggregation — the
+            agg=node.op,  # differential suite keys tolerance on these
+            n_shards=n_shards)
         return Matrix([dict(k) for k in uniq],
                       out[:len(uniq), :len(shifted)])
 
@@ -1883,7 +1970,7 @@ class Engine:
         session/remote fan-out degradation accumulate in the returned
         meta (ref: src/query/block/meta.go ResultMetadata threading)."""
         meta = ResultMeta()
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         with tracing.span(tracing.ENGINE_QUERY_RANGE, query=query[:200]):
             ctx = tracing.current_context()
             task = observe.task_ledger().begin_query(
@@ -1898,12 +1985,11 @@ class Engine:
             self._qrange_local.task = task
             self._qrange_local.limits = limits
             self._qrange_local.meta = meta
-            self._qrange_local.parse_s = 0.0
+            self._begin_cost()
             # the gather memo exists ONLY between here and the finally
             # below; _gather_cached bypasses memoization when it is None
             self._qrange_local.gather_cache = {}
             self._qrange_local.plan_cache = {}
-            self.last_fetch_stats = None
             result = None
             error = None
             cache_stats.begin()  # per-query cache hit/miss scoreboard
@@ -1932,34 +2018,46 @@ class Engine:
                 task.finish()
                 self._qrange_local.task = None
 
-    def _record_query_cost(self, query: str, t0: float, result, meta,
+    # the stamped phases that tile a query's time; h2d and d2h lie
+    # inside device and are recorded beside it
+    _TILING_PHASES = ("parse_s", "fetch_s", "pack_s", "decode_s",
+                      "merge_s", "device_s")
+
+    def _record_query_cost(self, query: str, t0_ns: int, result, meta,
                            error: str | None) -> None:
         """One Monarch-style cost record per query into the slow-query
-        ring; best-effort — accounting must never fail the query."""
+        ring; best-effort — accounting must never fail the query.
+
+        ``phases`` carries every key in every record (0.0 where the
+        path has no such step).  ``self_s`` is the engine's self time:
+        ``total_s`` minus the phases that tile it, so those and
+        ``self_s`` sum to ``total_s``.  ``frontend_s`` is the HTTP
+        front end's, added by query/http.py once the reply is
+        written; it lies outside ``total_s``."""
         try:
-            total_s = time.perf_counter() - t0
-            stats = self.last_fetch_stats or {}
-            phases = {
-                "parse_s": round(
-                    getattr(self._qrange_local, "parse_s", 0.0), 6),
-                "fetch_s": stats.get("fetch_s", 0.0),
-                "decode_s": stats.get("decode_s", 0.0),
-                "device_s": stats.get("device_s", 0.0),
-                "total_s": round(total_s, 6),
-            }
+            total_s = (time.perf_counter_ns() - t0_ns) / 1e9
+            cost = self._cost()
+            stats = cost.stats or {}
+            phases = {k: cost.phases.get(k, 0.0)
+                      for k in self._TILING_PHASES + ("h2d_s", "d2h_s")}
+            phases["self_s"] = total_s - sum(
+                phases[k] for k in self._TILING_PHASES)
+            phases["frontend_s"] = 0.0
+            phases["total_s"] = total_s
             ctx = tracing.current_context()
             tenant = tracing.current_tenant() or self.ns
             rec = {
                 "expr": query[:500],
                 "tenant": tenant,
                 "initiator": slowlog.current_initiator(),
-                "total_s": round(total_s, 6),
+                "total_s": total_s,
                 "phases": phases,
                 "series": (len(result.labels)
                            if isinstance(result, Matrix) else 0),
                 "datapoints": stats.get("datapoints", 0),
                 "device_serving": bool(stats.get("device_serving")),
                 "fn": stats.get("fn"),
+                "n_shards": stats.get("n_shards", 1),
                 "warnings": (meta.warning_strings()
                              if meta is not None else []),
                 "exhaustive": (meta.exhaustive
@@ -1972,49 +2070,43 @@ class Engine:
                 # thread-local scoreboard armed in query_range_with_meta
                 "cache": cache_stats.snapshot(),
             }
-            fused_nodes = getattr(self._qrange_local, "fused_nodes", 0)
-            if fused_nodes:
-                # whole-query fusion phase fields: how much of the tree
-                # the fused device program served, what it cost to
-                # (re)compile, and how many bytes crossed back
-                ast_nodes = getattr(self._qrange_local, "ast_nodes",
-                                    fused_nodes)
+            if cost.declines:
+                # where the per-node device tier handed a selector to
+                # the host: {reason: n}, the slugs of
+                # m3_query_device_decline_total
+                rec["device_declines"] = dict(cost.declines)
+            if cost.fused_nodes:
                 rec["device_tier"] = {
-                    "compile_cache": getattr(
-                        self._qrange_local, "fused_compile_cache", None),
-                    "compile_s": round(getattr(
-                        self._qrange_local, "fused_compile_s", 0.0), 6),
-                    "device_nodes": fused_nodes,
-                    "host_nodes": max(ast_nodes - fused_nodes, 0),
-                    "transfer_bytes": getattr(
-                        self._qrange_local, "fused_transfer_bytes", 0),
-                    "n_shards": getattr(
-                        self._qrange_local, "fused_n_shards", 1),
+                    "compile_cache": cost.fused_compile_cache,
+                    "compile_s": cost.fused_compile_s,
+                    "device_nodes": cost.fused_nodes,
+                    "host_nodes": max(
+                        (cost.ast_nodes or cost.fused_nodes)
+                        - cost.fused_nodes, 0),
+                    "transfer_bytes": cost.fused_transfer_bytes,
+                    "n_shards": cost.fused_n_shards,
                 }
-                if getattr(self._qrange_local, "fused_batched", False):
+                if cost.fused_batched:
                     # served through a shared cross-query dispatch
                     # (m3_tpu/serving/): how many queries shared the
                     # program and what the admission window cost us
                     rec["device_tier"]["batched"] = True
-                    rec["device_tier"]["batch_size"] = getattr(
-                        self._qrange_local, "fused_batch_size", 0)
-                    rec["device_tier"]["batch_wait_s"] = round(getattr(
-                        self._qrange_local, "fused_batch_wait_s", 0.0), 6)
-                splits = getattr(self._qrange_local,
-                                 "host_split_reasons", None)
-                if splits:
-                    rec["device_tier"]["host_splits"] = dict(splits)
-            rungs = getattr(self._qrange_local, "rung_selections", None)
-            if rungs:
+                    rec["device_tier"]["batch_size"] = (
+                        cost.fused_batch_size)
+                    rec["device_tier"]["batch_wait_s"] = (
+                        cost.fused_batch_wait_s)
+                if cost.host_split_reasons:
+                    rec["device_tier"]["host_splits"] = dict(
+                        cost.host_split_reasons)
+            if cost.rung_selections:
                 # retention-ladder rung choices for this query:
                 # {resolution label: bands served at it}
-                rec.setdefault("device_tier", {})["rungs"] = dict(rungs)
+                rec.setdefault("device_tier", {})["rungs"] = dict(
+                    cost.rung_selections)
                 rec["device_tier"].setdefault("read_bytes",
                                               stats.get("read_bytes", 0))
-            fused_error = getattr(self._qrange_local, "fused_error",
-                                  None)
-            if fused_error:
-                rec["device_tier_error"] = fused_error
+            if cost.fused_error:
+                rec["device_tier_error"] = cost.fused_error
             slowlog.log().record(rec)
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
@@ -2024,8 +2116,7 @@ class Engine:
                 cache = rec["cache"] or {}
                 attribution.account_read(
                     tenant,
-                    transfer_bytes=getattr(
-                        self._qrange_local, "fused_transfer_bytes", 0),
+                    transfer_bytes=cost.fused_transfer_bytes,
                     cache_hit_bytes=int(sum(
                         v for k, v in cache.items()
                         if k.endswith("_hit_bytes"))),
@@ -2040,24 +2131,10 @@ class Engine:
 
     def _query_range(self, query: str, start_nanos: int, end_nanos: int,
                      step_nanos: int):
-        t_parse = time.perf_counter()
-        ast = promql.parse(query)
-        self._qrange_local.parse_s = time.perf_counter() - t_parse
-        # whole-query fusion accounting (query/plan.py): per-query
-        # accumulators for the slow-query log's device_tier phase
-        self._qrange_local.ast_nodes = _ast_size(ast)
-        self._qrange_local.fused_nodes = 0
-        self._qrange_local.fused_compile_cache = None
-        self._qrange_local.fused_compile_s = 0.0
-        self._qrange_local.fused_transfer_bytes = 0
-        self._qrange_local.fused_n_shards = 1
-        self._qrange_local.fused_batched = False
-        self._qrange_local.fused_batch_size = 0
-        self._qrange_local.fused_batch_wait_s = 0.0
-        self._qrange_local.fused_error = None
-        self._qrange_local.fused_poisoned = False
-        self._qrange_local.host_split_reasons = {}
-        self._qrange_local.rung_selections = {}
+        cost = self._cost()
+        with cost.phase("parse"):
+            ast = promql.parse(query)
+        cost.ast_nodes = _ast_size(ast)
         # @ start()/end() resolve against the outer query range,
         # regardless of subquery nesting (upstream semantics)
         self._qrange_local.value = (int(start_nanos), int(end_nanos))

@@ -255,28 +255,19 @@ class GraphiteEngine:
         if len(steps) == 0:
             raise ValueError("graphite: empty time range")
         from m3_tpu.query import graphite_device as gdev
-        t0 = time.perf_counter()
-        ast = parse(target)
+        t0 = time.perf_counter_ns()
         eng = self._engine
         ql = eng._qrange_local
         # arm the same per-query thread-local state the PromQL path
         # sets up in query_range_with_meta/_query_range, so the fused
         # lowerer's accounting and the gather memo work under render()
-        ql.parse_s = time.perf_counter() - t0
-        ql.ast_nodes = gdev.ast_size(ast)
-        ql.fused_nodes = 0
-        ql.fused_compile_cache = None
-        ql.fused_compile_s = 0.0
-        ql.fused_transfer_bytes = 0
-        ql.fused_n_shards = 1
-        ql.fused_error = None
-        ql.fused_poisoned = False
-        ql.host_split_reasons = {}
-        ql.rung_selections = {}
+        cost = eng._begin_cost()
+        with cost.phase("parse"):
+            ast = parse(target)
+        cost.ast_nodes = gdev.ast_size(ast)
         ql.value = (int(start_nanos), int(end_nanos))
         ql.gather_cache = {}
         ql.plan_cache = {}
-        eng.last_fetch_stats = None
         error = None
         cache_stats.begin()
         try:
@@ -286,10 +277,9 @@ class GraphiteEngine:
             raise
         finally:
             self.last_render_stats = {
-                "ast_nodes": ql.ast_nodes,
-                "device_nodes": getattr(ql, "fused_nodes", 0),
-                "host_splits": dict(getattr(ql, "host_split_reasons",
-                                            None) or {}),
+                "ast_nodes": cost.ast_nodes,
+                "device_nodes": cost.fused_nodes,
+                "host_splits": dict(cost.host_split_reasons),
             }
             # slowlog cost record (device_tier et al.) — best-effort
             eng._record_query_cost(f"graphite://{target}", t0, None,

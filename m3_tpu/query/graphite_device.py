@@ -39,7 +39,6 @@ from m3_tpu.query.graphite import (
     Call, Path, SeriesList, _AGG_DELEGATES, SECOND,
     pattern_matchers, split_components,
 )
-from m3_tpu.utils import instrument
 
 _REQ = object()
 
@@ -685,11 +684,7 @@ def _lower(node, step: int, step_times):
 
 
 def _count_split(eng, reason: str) -> None:
-    instrument.bounded_counter("m3_query_host_split_total").labels(
-        reason=reason).inc()
-    splits = getattr(eng._qrange_local, "host_split_reasons", None)
-    if splits is not None:
-        splits[reason] = splits.get(reason, 0) + 1
+    eng._cost().split(reason)
 
 
 def try_device(geng, node, step_times, step):
@@ -700,8 +695,8 @@ def try_device(geng, node, step_times, step):
     eng = geng._engine
     if not eng._device_serving_active():
         return None
-    ql = eng._qrange_local
-    if getattr(ql, "fused_poisoned", False):
+    cost = eng._cost()
+    if cost.fused_poisoned:
         return None
     step_times = np.asarray(step_times, dtype=np.int64)
     if eng.planner is not None \
@@ -724,7 +719,7 @@ def try_device(geng, node, step_times, step):
         _count_split(eng, getattr(exc, "reason", "unknown_node"))
         return None
     except Exception as exc:  # noqa: BLE001 — host must still serve
-        ql.fused_error = f"{type(exc).__name__}: {exc}"[:200]
+        cost.fused_error = f"{type(exc).__name__}: {exc}"[:200]
         return None
     if mat is None:
         return None

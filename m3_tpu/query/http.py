@@ -258,6 +258,10 @@ class _Handler(BaseHTTPRequestHandler):
         path = urllib.parse.urlparse(self.path).path
         route = self._route_label(path)
         t0 = time.perf_counter()
+        # the front end's time goes into the record of the query this
+        # request runs: drop what an earlier request left on this thread
+        slowlog.take_last_record()
+        front: dict = {}
         # count on ENTRY: a client that saw this request's reply must
         # see it in a subsequent /metrics scrape (a finally-increment
         # races the next request on another server thread)
@@ -281,6 +285,11 @@ class _Handler(BaseHTTPRequestHandler):
                                   method=self.command) as sp:
                     self._trace_ctx = (tracing.current_context()
                                        if sp is not None else None)
+                    # the front end's own time: from the request's
+                    # span opening to the last byte written, but for
+                    # the engine call (_engine_call stops it)
+                    self._front = tracing.phase("frontend",
+                                                front).start()
                     try:
                         self._route_inner(path)
                     finally:
@@ -288,14 +297,28 @@ class _Handler(BaseHTTPRequestHandler):
                         # reads the active trace at observe() time, so
                         # this is what links a latency bucket to its
                         # trace on /metrics
+                        self._front.stop()
                         observed = True
                         instrument.histogram(
                             "m3_http_request_seconds").observe(
                                 time.perf_counter() - t0)
+                        rec = slowlog.take_last_record()
+                        if rec is not None:
+                            rec["phases"]["frontend_s"] = front[
+                                "frontend_s"]
         finally:
             if not observed:  # traceparent/span machinery itself blew up
                 instrument.histogram("m3_http_request_seconds").observe(
                     time.perf_counter() - t0)
+
+    def _engine_call(self, run, *args, **kwargs):
+        """Call into the query engine with the front end's stamp
+        stopped: the engine's time is in its own record."""
+        self._front.stop()
+        try:
+            return run(*args, **kwargs)
+        finally:
+            self._front.start()
 
     # set per-request in _route; the active context echoes back to the
     # caller in the response's traceparent header (see _reply)
@@ -1133,7 +1156,8 @@ class _Handler(BaseHTTPRequestHandler):
         out = []
         try:
             for target in targets:
-                sl = eng.render(target, start, end, step)
+                sl = self._engine_call(eng.render, target, start,
+                                       end, step)
                 for name, row in zip(sl.names, sl.values):
                     out.append({
                         "target": name,
@@ -1572,11 +1596,13 @@ class _Handler(BaseHTTPRequestHandler):
             if with_meta:
                 limits = self._request_limits(p)
                 with serving.batch_scope():
-                    step_times, mat, meta = run(p["query"], start, end,
-                                                step, limits=limits)
+                    step_times, mat, meta = self._engine_call(
+                        run, p["query"], start, end, step,
+                        limits=limits)
             else:
                 with serving.batch_scope():
-                    step_times, mat = run(p["query"], start, end, step)
+                    step_times, mat = self._engine_call(
+                        run, p["query"], start, end, step)
         except QueryLimitExceeded as e:
             self._error(422, str(e), error_type="query-limit-exceeded")
             return
@@ -1624,8 +1650,9 @@ class _Handler(BaseHTTPRequestHandler):
             limits = self._request_limits(p)
             from m3_tpu import serving
             with serving.batch_scope():
-                mat, meta = eng.query_instant_with_meta(
-                    p["query"], t, limits=limits)
+                mat, meta = self._engine_call(
+                    eng.query_instant_with_meta, p["query"], t,
+                    limits=limits)
         except QueryLimitExceeded as e:
             self._error(422, str(e), error_type="query-limit-exceeded")
             return

@@ -70,7 +70,6 @@ from __future__ import annotations
 import math
 import re
 import threading
-import time
 
 import numpy as np
 
@@ -569,11 +568,10 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     leaf_plan = {}     # dedupe key -> (idx, kind, statics, pk)
     params = []        # traced per-node pytrees, by param index
     root_post = []     # host post-ops on the root matrix (sort/...)
-    fetch_s = 0.0
+    cost = engine._cost()
     s_pad = _bucket_pow2(len(step_times), 64)
 
     def build_leaf(sym_leaf, grid):
-        nonlocal fetch_s
         (_, sel, fn, rng_override, keep_name, horizon, hw_sf, hw_tf,
          phi) = sym_leaf
         rng = (sel.range_nanos if rng_override is None
@@ -586,8 +584,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         cached = leaf_plan.get(key)
         if cached is None:
             sp = _bucket_pow2(len(grid), 64)
-            pk = engine._device_gather_pack(sel, grid, rng,
-                                            bucket=_bucket_pow2)
+            pk, _why = engine._device_gather_pack(
+                sel, grid, rng, bucket=_bucket_pow2)
             if pk is not None:
                 kind = "words"
                 # miss = packed compressed words shipped for on-device
@@ -608,8 +606,6 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                     if v is not None))
                 _DEVLED().track("decoded_block_bridge", [
                     v for v in pk.values() if hasattr(v, "nbytes")])
-            fetch_s += getattr(engine._qrange_local, "last_gather_s",
-                               0.0)
             if n_shards > 1:
                 if kind == "words":
                     # equal lanes + stream rows per shard, LOCAL slots
@@ -965,38 +961,43 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         cache_hit = binfo["compile_cache_hit"]
         compiled = binfo["compiled"]
         compile_s = binfo["compile_s"]
-        device_s = binfo["device_s"]
+        # the shared dispatch's own clock (serving/scheduler.py), which
+        # this thread waited through
+        cost.phases["device_s"] = (cost.phases.get("device_s", 0.0)
+                                   + binfo["device_s"])
     else:
         hit = _note_fingerprint(plan_key,
                                 bucket=f"rows{_rows_pad}xsteps{s_pad}")
         ker = kernel_telemetry.kernels().get(kernel_name)
         before = ker.stats() if ker is not None else {}
-        t1 = time.perf_counter()
         # device-ledger borrow: the megabatch is uploaded by jit for
-        # the duration of the call
+        # the duration of the call (numpy leaves: the staging is inside
+        # the call, so this path stamps no h2d of its own)
         try:
-            with observe.device_ledger().borrow(
-                    "query_megabatch", megabatch, count=n_bufs):
-                if n_shards > 1:
-                    out, aux, errs = qp.device_expr_pipeline_sharded(
-                        plan_t, engine.serving_mesh, tuple(leaves),
-                        tuple(params), steps_pad)
-                else:
-                    out, aux, errs = qp.device_expr_pipeline(
-                        plan_t, tuple(leaves), tuple(params), steps_pad)
-            out_np = np.asarray(out)
-            aux_np = tuple(np.asarray(a) for a in aux)
-            errs_np = [np.asarray(e) for e in errs]
+            with cost.phase("device"):
+                with observe.device_ledger().borrow(
+                        "query_megabatch", megabatch, count=n_bufs):
+                    if n_shards > 1:
+                        out, aux, errs = \
+                            qp.device_expr_pipeline_sharded(
+                                plan_t, engine.serving_mesh,
+                                tuple(leaves), tuple(params), steps_pad)
+                    else:
+                        out, aux, errs = qp.device_expr_pipeline(
+                            plan_t, tuple(leaves), tuple(params),
+                            steps_pad)
+                with cost.phase("d2h"):
+                    out_np = np.asarray(out)
+                    aux_np = tuple(np.asarray(a) for a in aux)
+                    errs_np = [np.asarray(e) for e in errs]
         except Exception as exc:  # noqa: BLE001 — a device runtime
             # error must not fail a query the host tier can answer
             engine.last_fetch_stats = {
                 "device_serving": False,
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
             }
-            engine._qrange_local.fused_error = (
-                f"{type(exc).__name__}: {exc}"[:200])
+            cost.fused_error = f"{type(exc).__name__}: {exc}"[:200]
             return None
-        device_s = time.perf_counter() - t1
         after = ker.stats() if ker is not None else {}
         compiled = (after.get("compiles", 0) > before.get("compiles", 0))
         compile_s = (after.get("compile_s", 0.0)
@@ -1014,7 +1015,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         bad = (err[real].any() if real is not None
                else err[:pk["n_streams"]].any())
         if bad:
-            engine._qrange_local.fused_poisoned = True
+            cost.fused_poisoned = True
             return None  # corrupt/unsorted stream: host re-decodes
 
     transfer_bytes = (out_np.nbytes + sum(a.nbytes for a in aux_np)
@@ -1025,21 +1026,17 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     # leaf covers its Call and its Selector), so _record_query_cost's
     # host_nodes = ast_nodes - fused_nodes is exact under splitting.
     fused_nodes = counts["ops"] + len(leaf_plan)
-    ql = engine._qrange_local
-    ql.fused_nodes = getattr(ql, "fused_nodes", 0) + ast_nodes
-    ql.fused_compile_cache = "miss" if compiled else "hit"
-    ql.fused_compile_s = (getattr(ql, "fused_compile_s", 0.0)
-                          + compile_s)
-    ql.fused_transfer_bytes = (getattr(ql, "fused_transfer_bytes", 0)
-                               + transfer_bytes)
-    ql.fused_n_shards = max(getattr(ql, "fused_n_shards", 1), n_shards)
+    cost.fused_nodes += ast_nodes
+    cost.fused_compile_cache = "miss" if compiled else "hit"
+    cost.fused_compile_s += compile_s
+    cost.fused_transfer_bytes += transfer_bytes
+    cost.fused_n_shards = max(cost.fused_n_shards, n_shards)
     if binfo is not None:
-        ql.fused_batched = True
-        ql.fused_batch_size = max(getattr(ql, "fused_batch_size", 0),
-                                  binfo["batch_size"])
-        ql.fused_batch_wait_s = (getattr(ql, "fused_batch_wait_s", 0.0)
-                                 + binfo["waited_s"])
-        task = getattr(ql, "task", None)
+        cost.fused_batched = True
+        cost.fused_batch_size = max(cost.fused_batch_size,
+                                    binfo["batch_size"])
+        cost.fused_batch_wait_s += binfo["waited_s"]
+        task = getattr(engine._qrange_local, "task", None)
         if task is not None:
             # /debug/tasks shows which live queries rode a shared
             # dispatch and what the admission window cost them
@@ -1050,24 +1047,21 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                    counts["fns"][0] if counts["fns"] else None)
     agg_stat = next((a for a in counts["aggs"] if a in LOOSE_AGGS),
                     counts["aggs"][0] if counts["aggs"] else None)
-    engine.last_fetch_stats = {
-        "fetch_s": round(fetch_s, 3),
-        "device_s": round(device_s, 3),
-        "n_streams": sum(ent[3]["n_streams"]
-                         for ent in leaf_plan.values()),
-        "datapoints": sum(ent[3]["datapoints"]
-                          for ent in leaf_plan.values()),
-        "device_serving": True,
-        "device_fused": True,
-        "fused_nodes": fused_nodes,
-        "fn": fn_stat,
-        "agg": agg_stat,
-        "n_shards": n_shards,
-        "compile_cache": "hit" if cache_hit else "miss",
-        "compiled": compiled,
-        "compile_s": round(compile_s, 6),
-        "transfer_bytes": transfer_bytes,
-    }
+    engine._publish_stats(
+        n_streams=sum(ent[3]["n_streams"]
+                      for ent in leaf_plan.values()),
+        datapoints=sum(ent[3]["datapoints"]
+                       for ent in leaf_plan.values()),
+        device_serving=True,
+        device_fused=True,
+        fused_nodes=fused_nodes,
+        fn=fn_stat,
+        agg=agg_stat,
+        n_shards=n_shards,
+        compile_cache="hit" if cache_hit else "miss",
+        compiled=compiled,
+        compile_s=compile_s,
+        transfer_bytes=transfer_bytes)
     if binfo is not None:
         engine.last_fetch_stats["batched"] = True
         engine.last_fetch_stats["batch_size"] = binfo["batch_size"]

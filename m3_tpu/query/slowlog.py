@@ -2,7 +2,9 @@
 
 Monarch-style per-query cost accounting (Adams et al., VLDB 2020):
 every query the engine serves leaves one cost record — expression,
-phase timings (parse / fetch / decode / device / eval), series and
+phase timings (see ``phases`` in docs/observability.md: parse, fetch,
+pack, decode, merge, device with its h2d and d2h, the engine's self
+time, and the HTTP front end's, which sum to the request), series and
 datapoints touched, device-vs-host serving, the limits/warnings its
 ResultMeta accumulated, and its trace_id so a slow entry links
 straight to the distributed trace.  Queries that ran (partly) through
@@ -37,7 +39,9 @@ from m3_tpu.utils import instrument
 
 _log = instrument.logger("query.slowlog")
 
-DEFAULT_CAPACITY = 256
+# a benchmark window is read back from the ring after it closes: 235
+# panels today, and a faster device program only makes more
+DEFAULT_CAPACITY = 2048
 DEFAULT_THRESHOLD_S = 1.0
 DEFAULT_INITIATOR = "http"
 
@@ -67,6 +71,15 @@ def initiator(name: str):
             _tl.initiator = prev
 
 
+def take_last_record() -> dict | None:
+    """The record the calling thread cut last, handed out once: the
+    HTTP front end adds its own phase (``frontend_s``) to the record
+    of the query it just served."""
+    rec = getattr(_tl, "last_record", None)
+    _tl.last_record = None
+    return rec
+
+
 def _threshold_s() -> float:
     """Hot-reloadable via env: operators tune it without a restart."""
     raw = os.environ.get("M3_SLOW_QUERY_SECONDS", "")
@@ -85,6 +98,7 @@ class SlowQueryLog:
         rec.setdefault("ts", time.time())
         with self._lock:
             self._ring.append(rec)
+        _tl.last_record = rec
         total = rec.get("total_s", 0.0)
         if total >= _threshold_s():
             instrument.counter("m3_slow_queries_total").inc()

@@ -12,7 +12,9 @@ the same hot-path seams, parented through a thread-local stack, with:
   - a bounded ring of finished spans exposed via the debug dump
     (`/debug/dump` -> "traces"), the zipkin-lite this image can serve
     with zero egress
-  - span tags + per-span wall duration; errors mark the span
+  - span tags + per-span duration on a monotonic clock (one wall
+    reading places the span, ``perf_counter_ns`` measures it, so a
+    stepped wall clock cannot bend a duration); errors mark the span
   - Dapper-style cross-process propagation (Sigelman et al., 2010):
     a `TraceContext` rides the W3C ``traceparent`` header at the HTTP
     edge and a context field in the node-RPC / remote-query / m3msg
@@ -27,6 +29,13 @@ The tracepoint catalog mirrors the reference's naming scheme
 observability lint (tools/lint_robustness.py) enforces that every
 ``tracing.span("...")`` string literal in the production tree comes
 from this catalog.
+
+``phase(name, sink)`` is the query path's one cost clock: a phase is
+stamped once on ``perf_counter_ns`` and that stamp feeds the query's
+cost record (``sink``), a child span (when the request is sampled or
+carries a ``traceparent``) and a ``jax.profiler.TraceAnnotation``
+named ``m3:<phase>`` that lands in a device trace when a profiler
+session is open.
 """
 
 from __future__ import annotations
@@ -37,20 +46,15 @@ import time
 from collections import deque
 from typing import NamedTuple
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------- catalog
 # Stable tracepoint names (ref: dbnode/tracepoint/tracepoint.go:32 — the
 # catalog exists so span names never drift between emit and analysis).
 
 DB_WRITE_BATCH = "db.WriteBatch"
 DB_FETCH_TAGGED = "db.FetchTagged"
-DB_QUERY_IDS = "db.QueryIDs"
-NS_BOOTSTRAP = "namespace.Bootstrap"
-SHARD_FLUSH = "shard.Flush"
-SHARD_SNAPSHOT = "shard.Snapshot"
 ENGINE_QUERY_RANGE = "engine.QueryRange"
-ENGINE_FETCH_RAW = "engine.FetchRaw"
-AGG_ADD_UNTIMED = "aggregator.AddUntimed"
-AGG_FLUSH = "aggregator.Flush"
 MSG_PUBLISH = "msg.Publish"
 MSG_CONSUME = "msg.Consume"
 REMOTE_FETCH = "remote.Fetch"
@@ -61,6 +65,23 @@ SESSION_FETCH = "session.FetchTagged"
 SESSION_FETCH_HOST = "session.FetchHost"
 HOSTQ_WRITE_BATCH = "client.HostQueueWriteBatch"
 DEVICE_KERNEL = "device.Kernel"
+# the query path's phases (``phase()`` below): one span per stamped
+# phase of a query's cost record, keyed by the phase's short name
+ENGINE_PARSE = "engine.Parse"
+ENGINE_GATHER = "engine.Gather"
+ENGINE_PACK = "engine.Pack"
+ENGINE_DECODE = "engine.Decode"
+ENGINE_MERGE = "engine.Merge"
+ENGINE_DEVICE = "engine.Device"
+DEVICE_H2D = "device.HostToDevice"
+DEVICE_D2H = "device.DeviceToHost"
+HTTP_FRONTEND = "http.Frontend"
+PHASE_SPANS = {
+    "parse": ENGINE_PARSE, "fetch": ENGINE_GATHER, "pack": ENGINE_PACK,
+    "decode": ENGINE_DECODE, "merge": ENGINE_MERGE,
+    "device": ENGINE_DEVICE, "h2d": DEVICE_H2D, "d2h": DEVICE_D2H,
+    "frontend": HTTP_FRONTEND,
+}
 
 
 # --------------------------------------------------------------- context
@@ -119,7 +140,7 @@ def parse_traceparent(value) -> TraceContext | None:
 
 class Span:
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start",
-                 "duration", "tags", "error")
+                 "duration", "tags", "error", "_t0_ns")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
                  parent_id: int | None, tags: dict):
@@ -127,7 +148,8 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.start = time.time()
+        self.start = time.time()  # places the span; never subtracted
+        self._t0_ns = time.perf_counter_ns()
         self.duration = 0.0
         self.tags = tags
         self.error = ""
@@ -274,7 +296,8 @@ class _SpanCtx:
         if st:
             st.pop()
         if self._span is not None:
-            self._span.duration = time.time() - self._span.start
+            self._span.duration = (
+                time.perf_counter_ns() - self._span._t0_ns) / 1e9
             if exc is not None:
                 self._span.error = f"{type(exc).__name__}: {exc}"
             self._tracer.record(self._span)
@@ -429,6 +452,51 @@ def wire_context() -> str | None:
     tp = ctx.to_traceparent()
     tenant = current_tenant()
     return f"{tp};t={tenant}" if tenant else tp
+
+
+class _Phase:
+    """One stamped phase; ``start``/``stop`` for a phase that does not
+    fit a ``with`` block (the HTTP front end's two halves)."""
+
+    __slots__ = ("_name", "_sink", "_span", "_ann", "_t0_ns")
+
+    def __init__(self, name: str, sink: dict):
+        self._name = name
+        self._sink = sink
+        self._t0_ns = None
+
+    def start(self) -> "_Phase":
+        self._span = _GLOBAL.span(PHASE_SPANS[self._name])
+        self._span.__enter__()
+        self._ann = TraceAnnotation("m3:" + self._name)
+        self._ann.__enter__()
+        self._t0_ns = time.perf_counter_ns()
+        return self
+
+    def stop(self, exc_type=None, exc=None, tb=None) -> None:
+        if self._t0_ns is None:
+            return
+        seconds = (time.perf_counter_ns() - self._t0_ns) / 1e9
+        self._t0_ns = None
+        key = self._name + "_s"
+        self._sink[key] = self._sink.get(key, 0.0) + seconds
+        self._ann.__exit__(exc_type, exc, tb)
+        self._span.__exit__(exc_type, exc, tb)
+
+    __enter__ = start
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.stop(exc_type, exc, tb)
+        return False
+
+
+def phase(name: str, sink: dict) -> _Phase:
+    """Stamp one phase of a query: ``with tracing.phase("pack", d):``
+    adds the block's seconds to ``d["pack_s"]``, opens the catalog
+    span ``PHASE_SPANS[name]`` under the active trace and writes an
+    ``m3:<name>`` annotation into an open profiler session.  Phases
+    may nest (``h2d`` inside ``device``); the sink keeps each whole."""
+    return _Phase(name, sink)
 
 
 def set_sampling(sample_1_in: int) -> None:

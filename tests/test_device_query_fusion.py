@@ -152,7 +152,7 @@ def test_fused_bit_identical_exact_family(engines):
     for expr in EXACT_EXPRS:
         mh, md, stats = _run_both(host, dev, expr)
         assert stats.get("device_fused") is True, (
-            expr, getattr(dev._qrange_local, "fused_error", None))
+            expr, dev._cost().fused_error)
         _assert_same_shape(mh, md, expr)
         assert np.array_equal(mh.values, md.values, equal_nan=True), expr
 
@@ -165,7 +165,7 @@ def test_fused_rate_family_strict_close(engines):
     for expr in RATE_EXPRS:
         mh, md, stats = _run_both(host, dev, expr)
         assert stats.get("device_fused") is True, (
-            expr, getattr(dev._qrange_local, "fused_error", None))
+            expr, dev._cost().fused_error)
         _assert_same_shape(mh, md, expr)
         np.testing.assert_allclose(
             np.nan_to_num(md.values), np.nan_to_num(mh.values),
@@ -455,7 +455,7 @@ def test_multi_tier_stitch_matches_host(tmp_path):
     _, md = dev.query_range(expr, start, end, STEP)
     stats = dev.last_fetch_stats or {}
     assert stats.get("device_fused") is True, \
-        getattr(dev._qrange_local, "fused_error", None)
+        dev._cost().fused_error
     assert mh.labels == md.labels
     np.testing.assert_array_equal(np.isnan(mh.values),
                                   np.isnan(md.values))

@@ -446,8 +446,11 @@ def test_serving_mesh_env_end_to_end(tmp_path, monkeypatch):
                 assert t1 == t2, q
                 np.testing.assert_allclose(v1, v2, rtol=1e-12,
                                            atol=1e-12, err_msg=q)
-        # the mesh engine actually served on-device
-        st = mesh_srv.httpd.RequestHandlerClass.engine.last_fetch_stats
+        # the mesh engine actually served on-device (its stats are the
+        # calling thread's own, so ask it from this one)
+        eng = mesh_srv.httpd.RequestHandlerClass.engine
+        eng.query_range("rate(mm[5m])", start, end, 60 * SEC)
+        st = eng.last_fetch_stats
         assert st and st.get("device_serving") is True
         assert st.get("n_shards") == 8
     finally:

@@ -318,7 +318,7 @@ def test_fused_upload_reconciles_with_kernel_bytes(small_fused_db):
         T0 + 10 * 60 * xtime.SECOND, T0 + 50 * 60 * xtime.SECOND,
         60 * xtime.SECOND)
     assert (eng.last_fetch_stats or {}).get("device_fused") is True, (
-        getattr(eng._qrange_local, "fused_error", None))
+        eng._cost().fused_error)
     assert len(mat.labels)
     d_up = up.value - up0
     d_kb = sum(c.value for c in kb) - kb0

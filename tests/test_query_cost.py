@@ -1,0 +1,278 @@
+"""The query path's one cost clock: per-thread cost objects, phases
+that tile a query's time, the device queue counted at dispatch, scope
+names in the device programs, the decline counter, the front end's
+phase, and spans on a clock that cannot step."""
+
+import json
+import threading
+import time
+import urllib.parse
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from m3_tpu.models import query_pipeline as qp
+from m3_tpu.ops import kernel_telemetry
+from m3_tpu.query import slowlog
+from m3_tpu.query.engine import Engine
+from m3_tpu.query.http import CoordinatorServer
+from m3_tpu.storage import (Database, DatabaseOptions, NamespaceOptions,
+                            RetentionOptions)
+from m3_tpu.utils import instrument, tracing, xtime
+
+SEC = xtime.SECOND
+BLOCK = 2 * xtime.HOUR
+T0 = (1_600_000_000 * SEC // BLOCK) * BLOCK
+START, END, STEP = T0 + 10 * 60 * SEC, T0 + 100 * 60 * SEC, 60 * SEC
+# from the sealed block into the mutable buffer
+MIXED_LO, MIXED_HI = T0 + 30 * 60 * SEC, T0 + 2 * BLOCK + 20 * 60 * SEC
+PHASE_KEYS = {"parse_s", "fetch_s", "pack_s", "decode_s", "merge_s",
+              "device_s", "h2d_s", "d2h_s", "self_s", "frontend_s",
+              "total_s"}
+
+
+def _write(db, name: bytes, n_series: int = 12, n: int = 120):
+    for i in range(n_series):
+        tags = {b"__name__": name, b"host": b"h%02d" % i,
+                b"dc": b"dc%d" % (i % 3)}
+        ts = [T0 + (k + 1) * 30 * SEC for k in range(n)]
+        vs = np.cumsum(np.full(n, 1.0 + i)).tolist()
+        db.write_batch("default", [name + b"|h%02d" % i] * n,
+                       [tags] * n, ts, vs)
+
+
+@pytest.fixture
+def db(tmp_path):
+    """`sealed` lives in a flushed block and, for four of its hosts,
+    goes on in the mutable buffer of a later one: a range that reaches
+    both is a mixed payload."""
+    db = Database(DatabaseOptions(path=str(tmp_path), num_shards=4,
+                                  commit_log_enabled=False))
+    db.create_namespace(NamespaceOptions(
+        name="default", retention=RetentionOptions(block_size=BLOCK)))
+    _write(db, b"sealed")
+    db.tick(now_nanos=T0 + 2 * BLOCK)
+    db.flush()
+    n = 60
+    for i in range(4):
+        tags = {b"__name__": b"sealed", b"host": b"h%02d" % i,
+                b"dc": b"dc%d" % (i % 3)}
+        ts = [T0 + 2 * BLOCK + (k + 1) * 30 * SEC for k in range(n)]
+        db.write_batch("default", [b"sealed|h%02d" % i] * n, [tags] * n,
+                       ts, (1e6 + np.arange(n, dtype=float)).tolist())
+    yield db
+    db.close()
+
+
+def _record_of(expr: str) -> dict:
+    return next(r for r in slowlog.log().records() if r["expr"] == expr)
+
+
+def test_two_threads_keep_their_own_stats(db):
+    """One engine, two server threads: a device-served query and a
+    host-served one, both finished before either reads its stats."""
+    eng = Engine(db, "default", device_serving=True)
+    queries = {
+        "device": ("sum by (dc) (rate(sealed[5m]))", START, END),
+        "host": ("rate(sealed[5m])", MIXED_LO, MIXED_HI),
+    }
+    barrier = threading.Barrier(2, timeout=120)
+    seen, errors = {}, []
+
+    def serve(which):
+        expr, lo, hi = queries[which]
+        try:
+            eng.query_range(expr, lo, hi, STEP)
+            barrier.wait()
+            seen[which] = dict(eng.last_fetch_stats or {})
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(f"{which}: {type(e).__name__}: {e}")
+
+    threads = [threading.Thread(target=serve, args=(w,)) for w in queries]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(180)
+        assert not t.is_alive()
+    assert not errors, errors
+    assert seen["device"].get("device_serving") is True
+    assert seen["device"]["device_s"] > 0.0
+    assert not seen["host"].get("device_serving")
+    assert seen["host"].get("device_s", 0.0) == 0.0
+    assert seen["host"]["decode_s"] > 0.0
+    assert _record_of(queries["device"][0])["device_serving"] is True
+    host_rec = _record_of(queries["host"][0])
+    assert host_rec["device_serving"] is False
+    assert host_rec["phases"]["device_s"] == 0.0
+    assert host_rec["device_declines"]["mixed_payloads"] >= 1
+
+
+def test_grouped_device_phases_sum_to_total(db):
+    eng = Engine(db, "default", device_serving=True)
+    expr = "sum by (dc) (rate(sealed[7m]))"
+    eng.query_range(expr, START, END, STEP)
+    assert eng.last_fetch_stats["device_grouped"] is True
+    ph = _record_of(expr)["phases"]
+    assert set(ph) == PHASE_KEYS
+    for key in ("parse_s", "fetch_s", "pack_s", "device_s", "h2d_s",
+                "d2h_s", "self_s"):
+        assert ph[key] > 0.0, key
+    assert ph["decode_s"] == ph["merge_s"] == ph["frontend_s"] == 0.0
+    assert ph["h2d_s"] + ph["d2h_s"] < ph["device_s"]
+    tiled = sum(ph[k] for k in ("parse_s", "fetch_s", "pack_s",
+                                "device_s", "self_s"))
+    assert tiled == pytest.approx(ph["total_s"], rel=1e-9)
+    # the thread's stats carry the same stamps, unrounded
+    assert eng.last_fetch_stats["device_s"] == ph["device_s"]
+    assert eng.last_fetch_stats["fetch_s"] == ph["fetch_s"]
+
+
+def test_host_phases_sum_to_total(db):
+    eng = Engine(db, "default", device_serving=False)
+    expr = "sum by (dc) (rate(sealed[9m]))"
+    eng.query_range(expr, START, END, STEP)
+    ph = _record_of(expr)["phases"]
+    assert set(ph) == PHASE_KEYS
+    assert ph["decode_s"] > 0.0 and ph["device_s"] == 0.0
+    tiled = sum(ph[k] for k in ("parse_s", "fetch_s", "decode_s",
+                                "merge_s", "self_s"))
+    assert tiled == pytest.approx(ph["total_s"], rel=1e-9)
+
+
+def test_queued_ahead_counts_calls_in_flight():
+    entered, release = threading.Event(), threading.Event()
+
+    def slow(x, first):
+        if first:
+            entered.set()
+            assert release.wait(60)
+        return x
+
+    ker = kernel_telemetry.InstrumentedKernel(slow, "test_queue_depth")
+    before = kernel_telemetry._inflight
+    t = threading.Thread(target=ker, args=(np.ones(4), True))
+    t.start()
+    assert entered.wait(60)
+    try:
+        assert kernel_telemetry._inflight == before + 1
+        ker(np.ones(4), False)
+        st = ker.stats()
+        assert st["invocations"] == 1 and st["queued_ahead"] == before + 1
+    finally:
+        release.set()
+        t.join(60)
+    assert not t.is_alive()
+    st = ker.stats()
+    assert st["invocations"] == 2 and st["queued_ahead"] == 2 * before + 1
+    assert st["execute_s"] == pytest.approx(
+        st["dispatch_s"] + st["wait_s"])
+    assert kernel_telemetry._inflight == before
+
+
+def _lowered(which: str) -> str:
+    spec = jax.ShapeDtypeStruct
+    m = w = lanes = steps = 64
+    args = (spec((m, w), jnp.uint64), spec((m,), jnp.int64),
+            spec((m,), jnp.int64), spec((steps,), jnp.int64))
+    rng = np.int64(300 * SEC)
+    if which == "device_rate_pipeline":
+        low = qp.device_rate_pipeline.lower(
+            *args, n_lanes=lanes, n_cap=128, range_nanos=rng, n_dp=128)
+    elif which == "device_grouped_pipeline":
+        low = qp.device_grouped_pipeline.lower(
+            *args, spec((lanes,), jnp.int64), n_lanes=lanes, n_groups=8,
+            n_cap=128, range_nanos=rng, n_dp=128)
+    else:
+        leaf = {"words": args[0], "nbits": args[1], "slots": args[2],
+                "tiers": spec((m,), jnp.int64), "steps": args[3],
+                "rng": spec((), jnp.int64),
+                "valid": spec((lanes,), jnp.bool_)}
+        plan = ("agg", "sum", 8, 1,
+                ("leaf", 0, 0, "words", "rate", lanes, 128, 128, 1, m, w,
+                 steps, 0.5, 0.5))
+        params = ((spec((), jnp.float64), spec((), jnp.float64)),
+                  (spec((lanes,), jnp.int64), spec((8,), jnp.bool_),
+                   spec((), jnp.float64)))
+        low = qp.device_expr_pipeline.lower(plan, (leaf,), params,
+                                            args[3])
+    return low.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which,scopes", [
+    ("device_rate_pipeline", ("m3.decode", "m3.merge", "m3.temporal")),
+    ("device_grouped_pipeline",
+     ("m3.decode", "m3.merge", "m3.temporal", "m3.group")),
+    ("device_expr_pipeline",
+     ("m3.expr", "m3.decode", "m3.merge", "m3.temporal", "m3.group")),
+])
+def test_device_programs_name_their_stages(which, scopes):
+    text = _lowered(which)
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
+
+
+def test_mutable_range_counts_one_decline(db):
+    eng = Engine(db, "default", device_serving=True)
+    fam = instrument.bounded_counter("m3_query_device_decline_total")
+    mixed = fam.labels(reason="mixed_payloads")
+    before = mixed.value
+    expr = "rate(sealed[6m])"
+    _, mat = eng.query_range(expr, MIXED_LO, MIXED_HI, STEP)
+    assert len(mat.labels) == 12
+    assert mixed.value == before + 1
+    rec = _record_of(expr)
+    assert rec["device_serving"] is False
+    assert rec["device_declines"] == {"mixed_payloads": 1}
+
+
+def test_http_query_leaves_frontend_in_its_record(db):
+    srv = CoordinatorServer(db, port=0).start()
+    try:
+        expr = "sum by (dc) (rate(sealed[11m]))"
+        q = urllib.parse.urlencode({
+            "query": expr, "start": START / 1e9, "end": END / 1e9,
+            "step": "60"})
+        t0 = time.perf_counter()
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/api/v1/query_range?{q}") as r:
+            doc = json.loads(r.read())
+        seconds = time.perf_counter() - t0
+        assert doc["status"] == "success" and doc["data"]["result"]
+        # the handler finishes its stamp just after the last byte
+        deadline = time.monotonic() + 10
+        while (_record_of(expr)["phases"]["frontend_s"] == 0.0
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        ph = _record_of(expr)["phases"]
+        assert 0.0 < ph["frontend_s"] < seconds
+        assert ph["frontend_s"] + ph["total_s"] <= seconds
+    finally:
+        srv.stop()
+
+
+def test_span_duration_survives_a_wall_clock_step(monkeypatch):
+    walls = iter([1_000_000.0, 999_000.0, 998_000.0])
+    monkeypatch.setattr(tracing.time, "time", lambda: next(walls))
+    tr = tracing.Tracer(sample_1_in=1)
+    with tr.span(tracing.ENGINE_QUERY_RANGE) as sp:
+        time.sleep(0.002)
+    assert sp.start == 1_000_000.0
+    assert sp.duration >= 0.002
+    assert tr.finished()[-1]["duration_ms"] >= 2.0
+
+
+def test_phase_feeds_record_span_and_annotation():
+    tr = tracing.tracer()
+    sink = {}
+    with tracing.activate(tracing.TraceContext(7, 9, True)):
+        with tracing.phase("pack", sink):
+            time.sleep(0.001)
+        with tracing.phase("pack", sink):
+            pass
+    assert sink["pack_s"] >= 0.001
+    spans = [s for s in tr.finished() if s["name"] == tracing.ENGINE_PACK
+             and s["trace_id"] == f"{7:032x}"]
+    assert len(spans) == 2 and spans[0]["parent_id"] == f"{9:016x}"
