@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from m3_tpu.ops.bitstream import I32, I64
+from m3_tpu.ops.bitstream import I32
 from m3_tpu.ops.histo_quantile import bucket_quantile
 from m3_tpu.ops.kernel_telemetry import instrument_kernel
 from m3_tpu.ops.lane_topk import masked_topk
@@ -41,57 +41,90 @@ from m3_tpu.parallel.mesh import SERIES_AXIS, shard_map
 from m3_tpu.utils import xtime
 
 _INF = jnp.iinfo(jnp.int64).max
+# lanes merged per round: the [lanes, n_cap] temporaries of a round then
+# stay on chip (65,536 lanes on a v5e: 68 ms at 512, 80 at 2,048, 255 in
+# one round, PERF.md PR 26) and off the program's HBM peak
+_MERGE_LANES = 512
 
 
 def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int):
-    """Scatter per-(series, block) decode grids into the packed
-    [n_lanes, n_cap] batch on device.
+    """Compact per-(series, block) decode grids into the packed
+    [n_lanes, n_cap] batch on device, a whole row at a time.
 
     Contract (the engine's emission order, same as the host merge):
-    rows grouped by slot, ascending block time within a slot,
-    timestamps ascending within a row.  Invalid cells scatter with
-    mode='drop'.
+    rows grouped by slot, slots ascending, ascending block time within
+    a slot, timestamps ascending within a row, and a row's valid cells
+    its first `count` cells (decode_batched emits a prefix; _tier_cut
+    keeps a prefix of it).  So a row lands as one contiguous run at
+    (slot, samples of the slot's earlier rows): round k moves the k-th
+    row of every lane at once and rotates it to its offset with a
+    log-step select over the lane width.  The TPU compiler runs an
+    element-indexed scatter one cell at a time (71 ns a cell); here
+    nothing is indexed by cell.  Lanes go _MERGE_LANES at a time (the
+    last chunk overlaps its neighbour rather than pad), so temporaries
+    stay at [_MERGE_LANES, n_cap] whatever the fan-out.  Cells past a
+    lane's n_cap budget DROP, never spill into the next lane (callers
+    surface the overflow via counts).
     """
     M, T = ts.shape
-    flat_mask = valid.reshape(-1)
-    # rank of each valid cell within its slot: global running count of
-    # valid cells minus the slot's base (rows of a slot are contiguous)
-    flat_rank = jnp.cumsum(flat_mask.astype(I64)) - 1  # [M*T]
-    row_counts = valid.sum(axis=1).astype(I64)  # [M]
-    row_base = jnp.cumsum(row_counts) - row_counts  # exclusive per row
-    # base of each SLOT = row_base of the slot's first row; propagate
-    # per-row via a segmented minimum (slots ascending => first row of
-    # a slot has the smallest base)
-    slot_base = jax.ops.segment_min(
-        row_base, slots, num_segments=n_lanes,
-        indices_are_sorted=True)  # [n_lanes]
-    cell_slot = jnp.repeat(slots, T, total_repeat_length=M * T)
-    rank_in_slot = flat_rank - slot_base[cell_slot]
-    # cells past a lane's n_cap budget must DROP, never spill into the
-    # next lane's region (callers surface the overflow via counts)
-    dest = jnp.where(flat_mask & (rank_in_slot < n_cap),
-                     cell_slot * n_cap + rank_in_slot,
-                     jnp.int64(n_lanes) * n_cap)  # OOB => dropped
-    out_t = jnp.full((n_lanes * n_cap,), _INF, dtype=jnp.int64)
-    out_v = jnp.full((n_lanes * n_cap,), jnp.nan, dtype=vs.dtype)
-    out_t = out_t.at[dest].set(ts.reshape(-1), mode="drop")
-    out_v = out_v.at[dest].set(vs.reshape(-1), mode="drop")
-    counts = jax.ops.segment_sum(
-        row_counts, slots, num_segments=n_lanes, indices_are_sorted=True)
-    return (out_t.reshape(n_lanes, n_cap), out_v.reshape(n_lanes, n_cap),
-            counts)
+    B = min(n_lanes, _MERGE_LANES)
+    row_counts = valid.sum(axis=1, dtype=I32)  # [M]
+    first = jnp.searchsorted(slots, jnp.arange(n_lanes + 1), side="left",
+                             method="scan_unrolled")  # lane -> first row
+    # trailing empty rows (jit padding parked on the last lane) must not
+    # lengthen the loop
+    used = jnp.max(jnp.where(row_counts > 0, jnp.arange(1, M + 1), 0))
+    n_rows = jnp.minimum(first[1:], used) - first[:-1]  # [n_lanes]
+    col = jnp.arange(n_cap, dtype=I32)
+    fit = ((0, 0), (0, max(n_cap - T, 0)))
+
+    def place(x, row, off):
+        x = jnp.pad(x[:, :n_cap].at[row].get(mode="promise_in_bounds"), fit)
+        for b in range((n_cap - 1).bit_length()):
+            x = jnp.where((off >> b & 1)[:, None] == 1,
+                          jnp.roll(x, 1 << b, axis=1), x)
+        return x
+
+    def chunk(c, outs):
+        lo = jnp.minimum(c * B, n_lanes - B)
+        first_c = jax.lax.dynamic_slice_in_dim(first, lo, B)
+        n_rows_c = jax.lax.dynamic_slice_in_dim(n_rows, lo, B)
+
+        def body(k, carry):
+            out_t, out_v, counts = carry
+            row = jnp.minimum(first_c + k, M - 1)
+            cnt = jnp.where(k < n_rows_c, row_counts[row], 0)
+            off = jnp.minimum(counts, n_cap)
+            take = (col >= off[:, None]) & (col < (off + cnt)[:, None])
+            return (jnp.where(take, place(ts, row, off), out_t),
+                    jnp.where(take, place(vs, row, off), out_v),
+                    counts + cnt)
+
+        done = jax.lax.fori_loop(0, jnp.max(n_rows_c), body, (
+            jnp.full((B, n_cap), _INF, dtype=jnp.int64),
+            jnp.full((B, n_cap), jnp.nan, dtype=vs.dtype),
+            jnp.zeros((B,), I32)))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, d, lo, 0)
+                     for o, d in zip(outs, done))
+
+    return jax.lax.fori_loop(0, -(-n_lanes // B), chunk, (
+        jnp.empty((n_lanes, n_cap), jnp.int64),
+        jnp.empty((n_lanes, n_cap), vs.dtype),
+        jnp.empty((n_lanes,), I32)))
 
 
 def _window_bounds_device(times, steps, range_nanos):
     """Per-(lane, step) index bounds of the [t - range, t] INCLUSIVE
     window (the -1ns exclusive-start trick mirroring
     consolidate._range_left) — the one definition both the rate and
-    reduce kernels share."""
+    reduce kernels share.  A bound is the count of samples at or
+    before it: one fused compare-and-sum over [L, N, S], where a binary
+    search would be log2(N) dependent element gathers."""
     starts_excl = steps - range_nanos - 1
-    left = jax.vmap(
-        lambda t: jnp.searchsorted(t, starts_excl, side="right"))(times)
-    right = jax.vmap(
-        lambda t: jnp.searchsorted(t, steps, side="right"))(times)
+    left = jax.vmap(lambda t: jnp.searchsorted(
+        t, starts_excl, side="right", method="compare_all"))(times)
+    right = jax.vmap(lambda t: jnp.searchsorted(
+        t, steps, side="right", method="compare_all"))(times)
     return starts_excl, left, right
 
 
@@ -177,7 +210,7 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
                   tiers=None, n_tiers: int = 1):
     """Shared front half of every device serving pipeline: batched
     decode at stream width, the cross-namespace tier cut (multi-tier
-    fan-outs), scatter-merge into lanes, and the full error contract
+    fan-outs), row-wise merge into lanes, and the full error contract
     (per-stream decode errors, truncation at n_dp, lane overflow past
     n_cap, unsorted merged lanes).
 
@@ -193,6 +226,10 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
     with jax.named_scope("m3.merge"):
         if n_tiers > 1 and tiers is not None:
             valid = _tier_cut(ts, valid, slots, tiers, n_lanes, n_tiers)
+            # the merge moves a row's FIRST `count` cells: a cut that
+            # keeps anything but a prefix (a coarse row out of time
+            # order) must fall back, not land the wrong cells
+            error = error | jnp.any(valid[:, 1:] & ~valid[:, :-1], axis=1)
         times, values, counts = _merge_device(ts, vs, valid, slots,
                                               n_lanes, n_cap)
         error = error | (counts > n_cap)[slots]

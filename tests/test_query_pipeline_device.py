@@ -4,6 +4,8 @@ series-sharded variant on the virtual 8-device mesh with its psum
 fleet aggregate (the round-6 device read path, validated the same way
 every device kernel here was before hardware)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -718,3 +720,256 @@ def test_device_pipeline_sharded_psum():
                                rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(np.asarray(fleet),
                                np.nansum(want, axis=0), rtol=1e-12)
+
+
+# -- the merge and the window bounds alone, against numpy ------------------
+
+_INF = np.iinfo(np.int64).max
+
+
+def _merge_reference(ts, vs, valid, slots, n_lanes, n_cap):
+    """The merge's contract, a cell at a time: a slot's valid cells in
+    row order fill its lane from the left, cells past n_cap are dropped
+    and still counted."""
+    out_t = np.full((n_lanes, n_cap), _INF, dtype=np.int64)
+    out_v = np.full((n_lanes, n_cap), np.nan)
+    counts = np.zeros(n_lanes, dtype=np.int64)
+    for r, lane in enumerate(slots):
+        for c in np.flatnonzero(valid[r]):
+            if counts[lane] < n_cap:
+                out_t[lane, counts[lane]] = ts[r, c]
+                out_v[lane, counts[lane]] = vs[r, c]
+            counts[lane] += 1
+    return out_t, out_v, counts
+
+
+def _merge_layout(rows_of, T, m_pad, pad_slot, seed):
+    """rows_of[lane] = the sample counts of the lane's rows, in order.
+    -> ts, vs, valid, slots with `m_pad` rows, the padding rows (count
+    0) parked on `pad_slot`; cells past a row's count hold stale decode
+    state (garbage), never fill values."""
+    rng = np.random.default_rng(seed)
+    slots = [lane for lane, rows in enumerate(rows_of) for _ in rows]
+    counts = [c for rows in rows_of for c in rows]
+    n_real = len(slots)
+    assert n_real <= m_pad
+    slots = np.asarray(slots + [pad_slot] * (m_pad - n_real), np.int64)
+    counts = np.asarray(counts + [0] * (m_pad - n_real), np.int64)
+    ts = rng.integers(1, 10**6, (m_pad, T)).astype(np.int64)
+    vs = rng.random((m_pad, T))
+    t_next: dict[int, int] = {}
+    for r in range(n_real):            # ascending within a lane
+        t0 = t_next.get(int(slots[r]), T0)
+        ts[r, :counts[r]] = t0 + (np.arange(counts[r]) + 1) * SEC
+        t_next[int(slots[r])] = t0 + counts[r] * SEC
+    valid = np.arange(T)[None, :] < counts[:, None]
+    return ts, vs, valid, slots
+
+
+def _random_rows(rng, n_lanes, T, lo, hi, p_empty_lane, p_zero_row):
+    return [[] if rng.random() < p_empty_lane else
+            [0 if rng.random() < p_zero_row else int(rng.integers(1, T + 1))
+             for _ in range(rng.integers(lo, hi + 1))]
+            for _ in range(n_lanes)]
+
+
+def _merge_cases():
+    rng = np.random.default_rng(77)
+    T = 12
+    yield "rows_1_to_5", _random_rows(rng, 9, T, 1, 5, 0.0, 0.0) + [[]], \
+        T, 40, 9, 64
+    yield "zero_count_rows", [[0, 5, 0, 0, 3], [0], [4, 0], [0, 0, 7]] \
+        + [[]], T, 16, 4, 40
+    yield "empty_lanes", _random_rows(rng, 12, T, 1, 3, 0.7, 0.1) + [[]], \
+        T, 32, 12, 40
+    yield "no_rows_at_all", [[], [], []], T, 8, 2, 16
+    # the sharded re-lay parks padding on a lane that holds real rows
+    yield "padding_on_a_real_lane", [[3, 12], [12, 12, 1], [6, 2]], \
+        T, 16, 2, 40
+    yield "overflow", [[12, 12, 12], [12, 5], [12, 12, 12, 12, 12],
+                       [7]] + [[]], T, 16, 4, 30
+    yield "row_wider_than_cap", [[12, 12], [3], [9, 9]] + [[]], T, 8, 3, 8
+    yield "one_full_row_each", [[16]] * 7 + [[]], 16, 8, 7, 16
+
+
+@pytest.mark.parametrize("chunk", [None, 4], ids=["one_chunk", "chunks_of_4"])
+@pytest.mark.parametrize(
+    "rows_of,T,m_pad,pad_slot,n_cap",
+    [pytest.param(*c[1:], id=c[0]) for c in _merge_cases()])
+def test_merge_matches_numpy_reference(rows_of, T, m_pad, pad_slot, n_cap,
+                                       chunk, monkeypatch):
+    """_merge_device moves whole rows; the reference moves cells.  Bit
+    for bit: times, the values' bit patterns, the fill, the counts —
+    also when the lanes go in chunks, the last one overlapping."""
+    from m3_tpu.models import query_pipeline as qp
+
+    if chunk:
+        monkeypatch.setattr(qp, "_MERGE_LANES", chunk)
+    n_lanes = len(rows_of)
+    ts, vs, valid, slots = _merge_layout(rows_of, T, m_pad, pad_slot, 3)
+    got_t, got_v, got_c = jax.jit(      # a new function: a new trace
+        lambda *a: qp._merge_device(*a, n_lanes, n_cap))(
+        jnp.asarray(ts), jnp.asarray(vs), jnp.asarray(valid),
+        jnp.asarray(slots))
+    want_t, want_v, want_c = _merge_reference(ts, vs, valid, slots,
+                                              n_lanes, n_cap)
+    np.testing.assert_array_equal(np.asarray(got_t), want_t)
+    np.testing.assert_array_equal(np.asarray(got_v).view(np.uint64),
+                                  want_v.view(np.uint64))
+    np.testing.assert_array_equal(np.asarray(got_c), want_c)
+
+
+def test_merge_two_tiers_matches_numpy_reference():
+    """Two tiers: the coarse rows come first in a slot and the cut keeps
+    a prefix of each, so the rows still land as contiguous runs and the
+    lane stays ascending."""
+    from m3_tpu.models.query_pipeline import _merge_device, _tier_cut
+
+    n_lanes, T, n_cap = 5, 10, 24
+    slots, tiers, rows_t = [], [], []
+    for lane in range(n_lanes - 1):
+        # coarse block (60 s apart) then two fine ones (10 s) that
+        # start inside the coarse one's span
+        fine0 = T0 + (3 + lane) * 60 * SEC
+        rows_t += [T0 + (np.arange(T) + 1) * 60 * SEC,
+                   fine0 + np.arange(T) * 10 * SEC,
+                   fine0 + (T + np.arange(T)) * 10 * SEC]
+        slots += [lane] * 3
+        tiers += [1, 0, 0]
+    m_pad = 16
+    pad = m_pad - len(slots)
+    ts = np.stack(rows_t + [np.zeros(T, np.int64)] * pad).astype(np.int64)
+    vs = np.random.default_rng(5).random((m_pad, T))
+    valid = np.ones((m_pad, T), bool)
+    valid[len(slots):] = False
+    slots = np.asarray(slots + [n_lanes - 1] * pad, np.int64)
+    tiers = np.asarray(tiers + [0] * pad, np.int64)
+
+    @jax.jit
+    def run(ts, vs, valid, slots, tiers):
+        cut = _tier_cut(ts, valid, slots, tiers, n_lanes, 2)
+        return cut, _merge_device(ts, vs, cut, slots, n_lanes, n_cap)
+
+    cut, (got_t, got_v, got_c) = run(*map(jnp.asarray,
+                                          (ts, vs, valid, slots, tiers)))
+    cut = np.asarray(cut)
+    assert (cut[0::3][:n_lanes - 1].sum(axis=1) < T).all(), "cut is idle"
+    want_t, want_v, want_c = _merge_reference(ts, vs, cut, slots,
+                                              n_lanes, n_cap)
+    np.testing.assert_array_equal(np.asarray(got_t), want_t)
+    np.testing.assert_array_equal(np.asarray(got_v).view(np.uint64),
+                                  want_v.view(np.uint64))
+    np.testing.assert_array_equal(np.asarray(got_c), want_c)
+    assert (np.diff(np.asarray(got_t), axis=1) >= 0).all()
+
+
+@pytest.mark.parametrize("case", ["duplicates", "inf_padding",
+                                  "padded_steps", "steps_on_samples"])
+def test_window_bounds_match_searchsorted(case):
+    """left / right are np.searchsorted(side="right") of the window's
+    exclusive start and its end on each lane."""
+    from m3_tpu.models.query_pipeline import _window_bounds_device
+
+    rng = np.random.default_rng(11)
+    L, N, S = 7, 40, 9
+    range_nanos = 90 * SEC
+    gaps = rng.integers(0 if case == "duplicates" else 1, 4, (L, N))
+    times = T0 + np.cumsum(gaps, axis=1) * 10 * SEC
+    if case == "inf_padding":
+        fill = np.arange(N)[None, :] >= rng.integers(0, N + 1, (L, 1))
+        fill[0] = True                      # an empty lane
+        fill[1] = False                     # a full one
+        times = np.where(fill, _INF, times)
+    steps = T0 + (np.arange(S, dtype=np.int64) + 2) * 120 * SEC
+    if case == "padded_steps":              # the engine repeats the last
+        steps[S - 3:] = steps[S - 4]
+    if case == "steps_on_samples":          # both window edges inclusive
+        steps = times[2, 10:10 + S].copy()
+        range_nanos = int(steps[3] - times[2, 4])
+    starts, left, right = jax.jit(_window_bounds_device)(
+        jnp.asarray(times), jnp.asarray(steps), jnp.int64(range_nanos))
+    np.testing.assert_array_equal(np.asarray(starts),
+                                  steps - range_nanos - 1)
+    for lane in range(L):
+        np.testing.assert_array_equal(
+            np.asarray(left)[lane], np.searchsorted(
+                times[lane], steps - range_nanos - 1, side="right"))
+        np.testing.assert_array_equal(
+            np.asarray(right)[lane],
+            np.searchsorted(times[lane], steps, side="right"))
+
+
+def _walk_jaxpr(jaxpr, scope=""):
+    """(primitive, named-scope path) of every equation, inner jits,
+    loops and branches included."""
+    for eqn in jaxpr.eqns:
+        path = f"{scope}/{eqn.source_info.name_stack}"
+        yield eqn.primitive.name, path
+        stack = list(eqn.params.values())
+        while stack:
+            v = stack.pop()
+            if isinstance(v, (tuple, list)):
+                stack.extend(v)
+            elif hasattr(v, "eqns") or hasattr(v, "jaxpr"):
+                yield from _walk_jaxpr(getattr(v, "jaxpr", v), path)
+
+
+def test_grouped_program_has_no_per_element_addressing():
+    """The TPU compiler runs an element-indexed scatter or gather one
+    element at a time (PERF.md, PR 26: 134 of the program's 213 ms were
+    two scatters, 59 ms two binary searches).  The grouped program
+    keeps its scatters to the [n_groups, S] reduction and its window
+    bounds free of loops; a later edit that brings either back fails
+    here, on the CPU."""
+    from m3_tpu.models.query_pipeline import device_grouped_pipeline
+
+    M, W, L, S = 16, 8, 8, 4
+    sds = jax.ShapeDtypeStruct
+    args = (sds((M, W), np.uint32), sds((M,), np.int32),
+            sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
+    kw = dict(n_lanes=L, n_groups=4, n_cap=32, n_dp=16,
+              range_nanos=jnp.int64(300 * SEC))
+    fn = device_grouped_pipeline.__wrapped__     # the jitted function
+    ops = list(_walk_jaxpr(
+        jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr))
+    scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
+    assert scatters and all("m3.group" in s for _, s in scatters), scatters
+    loops = [(p, s) for p, s in ops
+             if p in ("while", "scan") and "m3.temporal" in s]
+    assert not loops, loops
+    # and the lowered text agrees: no scatter but the reduction's, and
+    # the windowed stage alone lowers without a loop
+    text = fn.lower(*args, **kw).as_text()
+    assert text.count('"stablehlo.scatter"(') == len(scatters)
+    from m3_tpu.models.query_pipeline import _temporal_eval
+    text = jax.jit(functools.partial(_temporal_eval, "rate")).lower(
+        sds((L, 32), np.int64), sds((L, 32), np.float64), args[3],
+        kw["range_nanos"]).as_text()
+    assert "stablehlo.while" not in text and "stablehlo.scatter" not in text
+
+
+def test_tier_cut_that_keeps_no_prefix_is_flagged():
+    """A coarse row out of time order can leave the cut a kept cell
+    behind a dropped one.  The merge moves a row's first `count` cells,
+    so such a row must flag (the engine falls back to the host tier),
+    and a clean two-tier lane beside it must not."""
+    def stream(t):
+        enc = tsz.Encoder(T0)
+        for ti in t:
+            enc.encode(int(ti), 1.0)
+        return enc.finalize()
+
+    fine = T0 + (15 + np.arange(6)) * 10 * SEC           # from 150 s on
+    streams = [stream(T0 + np.asarray([60, 150, 120]) * SEC),   # lane 0
+               stream(fine),
+               stream(T0 + np.asarray([60, 120, 180]) * SEC),   # lane 1
+               stream(fine)]
+    words, nbits = pack_streams(streams)
+    _, _, err = device_rate_pipeline(
+        jnp.asarray(words), jnp.asarray(nbits),
+        jnp.asarray(np.asarray([0, 0, 1, 1], dtype=np.int64)),
+        jnp.asarray(T0 + np.asarray([240], dtype=np.int64) * SEC),
+        n_lanes=2, n_cap=16, range_nanos=300 * SEC, n_dp=8,
+        tiers=jnp.asarray(np.asarray([1, 0, 1, 0], dtype=np.int64)),
+        n_tiers=2)
+    assert np.asarray(err).tolist() == [True, False, False, False]
