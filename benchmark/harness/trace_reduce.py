@@ -1,6 +1,8 @@
 """From a profiler trace (.xplane.pb) to device busy time, program
-time, top operations and labelled idle gaps.  Reads the trace with
-`jax.profiler.ProfileData` and nothing else.
+time, top operations by the program's own scopes and labelled idle
+gaps.  Reads the events with `jax.profiler.ProfileData`; the one thing
+that hides, the operations' metadata (`tf_op`, where the named scope
+is), is read from the file's protobuf wire format by `_op_scopes`.
 
 Device planes are `/device:TPU:<n>`; on them the line `XLA Ops` holds
 one event per executed operation and `XLA Modules` one per program run.
@@ -8,18 +10,27 @@ A trace with no device plane (XLA:CPU, rehearsal only) has its
 operations on host threads, marked by an `hlo_module` stat; they are
 then read as one pseudo-device so that the same code runs end to end.
 
-The harness marks what it is doing with `jax.profiler.TraceAnnotation`
-spans named `bench:<label>`; `bench:window` bounds the traced slice.
+Host spans are `jax.profiler.TraceAnnotation`s: the harness's
+`bench:<label>` (`bench:window` bounds the traced slice) and the
+program's own `m3:<phase>` (utils/tracing.phase), which keep their
+prefix as labels.  A device operation is named by the `m3.*` scope in
+its `tf_op` (`m3.temporal/fusion.81 f32[131072]`); a loop's event has
+no `tf_op` and takes the scope in which its body's operations spend
+most time.  Operations and programs are counted over whole program
+runs only: the profiler cuts the runs in flight at the trace's edges.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
 
 _DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):(\d+)$")
 _OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+_SCOPE = re.compile(r"\bm3\.[A-Za-z0-9_]+")
+_LABELS = ("bench:", "m3:")
 
 
 def find_xplane(log_dir: str) -> str | None:
@@ -58,6 +69,12 @@ def _intersect(a, b):
     return out
 
 
+def _inside(merged, t: float) -> bool:
+    """Whether `t` lies in one of a merged, sorted interval list."""
+    i = bisect.bisect_right(merged, [t, float("inf")]) - 1
+    return i >= 0 and merged[i][0] <= t < merged[i][1]
+
+
 def _events(line):
     return [(ev.name, float(ev.start_ns), float(ev.duration_ns))
             for ev in line.events]
@@ -88,14 +105,120 @@ def _op_name(name: str) -> str:
     return f"{head.lstrip('%')} {_LAYOUT.sub('', rest[:end])}"[:80]
 
 
+def _varint(buf, i: int) -> tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if not b & 0x80:
+            return out, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) of one protobuf message: an int for a
+    varint, the bytes for a length-delimited or fixed field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            val, i = _varint(buf, i)
+        elif kind == 2:
+            size, i = _varint(buf, i)
+            val, i = buf[i:i + size], i + size
+        elif kind in (1, 5):
+            size = 8 if kind == 1 else 4
+            val, i = buf[i:i + size], i + size
+        else:
+            raise ValueError(f"wire type {kind}")
+        yield key >> 3, val
+
+
+def _op_scopes(xplane_path: str) -> dict[str, dict[str, str]]:
+    """{device plane: {operation's event name: its `m3.*` scope}}, from
+    the `tf_op` stat of the plane's event metadata (XSpace.planes = 1;
+    XPlane.name = 2, event_metadata = 4, stat_metadata = 5, each a map
+    entry with the message under 2; XEventMetadata.name = 2, stats = 5;
+    XStatMetadata.id = 1, name = 2; XStat.metadata_id = 1, str_value =
+    5, ref_value = 7, which names a stat metadata whose name is the
+    value)."""
+    out = {}
+    with open(xplane_path, "rb") as f:
+        space = memoryview(f.read())
+    for num, plane in _fields(space):
+        if num != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for num, val in _fields(plane):
+            if num == 2:
+                name = bytes(val).decode()
+            elif num in (4, 5):
+                entry = dict(_fields(val))
+                if 2 not in entry:
+                    continue
+                if num == 4:
+                    events.append(entry[2])
+                else:
+                    meta = dict(_fields(entry[2]))
+                    stat_names[meta.get(1, entry.get(1))] = bytes(
+                        meta.get(2, b"")).decode(errors="replace")
+        if not _DEVICE_PLANE.match(name):
+            continue
+        tf_op = next((k for k, v in stat_names.items() if v == "tf_op"), None)
+        scopes = out.setdefault(name, {})
+        for meta in events:
+            ev_name = found = None
+            for num, val in _fields(meta):
+                if num == 2:
+                    ev_name = bytes(val).decode(errors="replace")
+                elif num == 5 and found is None:
+                    stat = dict(_fields(val))
+                    if stat.get(1) == tf_op:
+                        found = (bytes(stat[5]).decode(errors="replace")
+                                 if 5 in stat else stat_names.get(stat.get(7)))
+            scope = _SCOPE.search(found or "")
+            if ev_name and scope:
+                scopes[ev_name] = scope.group(0)
+    return out
+
+
+def _top_level(ops):
+    """[(name, start, duration, [nested events])] of a line's events:
+    an event that starts inside an earlier one is nested in it."""
+    out = []
+    for name, s, d in sorted(ops, key=lambda ev: (ev[1], -ev[2])):
+        if out and s < out[-1][1] + out[-1][2]:
+            out[-1][3].append((name, s, d))
+        else:
+            out.append((name, s, d, []))
+    return out
+
+
+def _scoped_name(name: str, nested, scopes: dict[str, str]) -> str:
+    scope = scopes.get(name)
+    if scope is None:
+        inside: dict[str, float] = {}
+        for child, _, d in nested:
+            if child in scopes:
+                inside[scopes[child]] = inside.get(scopes[child], 0.0) + d
+        scope = max(inside, key=inside.get) if inside else None
+    short = _op_name(name)
+    return f"{scope}/{short}"[:80] if scope else short
+
+
 def reduce(xplane_path: str) -> dict:
     """-> {window_s, busy_s, n_devices, programs {name: {calls,
-    device_s, min_s, median_s, max_s}}, device_ops [[name, s]], idle_gaps [[label, s]]}.
+    device_s, min_s, median_s, max_s}}, device_ops [[name, s]],
+    idle_gaps [[label, s]]}.
     Seconds are averaged over the device planes found."""
     import jax
 
     data = jax.profiler.ProfileData.from_file(xplane_path)
-    devices, spans = [], []     # per device: (ops, modules); host spans
+    scopes = _op_scopes(xplane_path)
+    # per device: (ops, modules, scopes); host spans
+    devices, spans = [], []
     pseudo_ops, pseudo_modules = [], []
     for plane in data.planes:
         is_dev = _DEVICE_PLANE.match(plane.name)
@@ -108,8 +231,9 @@ def reduce(xplane_path: str) -> dict:
                     modules = _events(line)
                 continue
             for ev in line.events:
-                if ev.name.startswith("bench:"):
-                    spans.append((ev.name[6:], float(ev.start_ns),
+                if ev.name.startswith(_LABELS):
+                    spans.append((ev.name.removeprefix("bench:"),
+                                  float(ev.start_ns),
                                   float(ev.start_ns + ev.duration_ns)))
                 elif ev.duration_ns > 0:
                     module = dict(ev.stats).get("hlo_module")
@@ -120,9 +244,10 @@ def reduce(xplane_path: str) -> dict:
                             (module, float(ev.start_ns),
                              float(ev.duration_ns)))
         if is_dev and (ops or modules):
-            devices.append((ops or modules, modules or []))
+            devices.append((ops or modules, modules or [],
+                            scopes.get(plane.name, {})))
     if not devices and pseudo_ops:
-        devices.append((pseudo_ops, pseudo_modules))
+        devices.append((pseudo_ops, pseudo_modules, {}))
     if not devices:
         return {"window_s": 0.0, "busy_s": 0.0, "n_devices": 0,
                 "programs": {}, "device_ops": [], "idle_gaps": []}
@@ -131,8 +256,8 @@ def reduce(xplane_path: str) -> dict:
     if windows:
         lo, hi = min(s for s, _ in windows), max(e for _, e in windows)
     else:
-        lo = min(s for ops, _ in devices for _, s, _ in ops)
-        hi = max(s + d for ops, _ in devices for _, s, d in ops)
+        lo = min(s for ops, _, _ in devices for _, s, _ in ops)
+        hi = max(s + d for ops, _, _ in devices for _, s, d in ops)
     n = len(devices)
     busy_ns = 0.0
     programs: dict[str, dict] = {}
@@ -143,16 +268,18 @@ def reduce(xplane_path: str) -> dict:
         if name != "window":
             by_label.setdefault(name, []).append((s, e))
     by_label = {name: _union(iv) for name, iv in by_label.items()}
-    for ops, modules in devices:
+    for ops, modules, op_scopes in devices:
         busy = _union(_clip([(s, s + d) for _, s, d in ops], lo, hi))
         busy_ns += sum(e - s for s, e in busy)
-        for name, s, d in ops:
-            if s + d > lo and s < hi:
-                short = _op_name(name)
+        # whole runs only: one cut by the window's edge would count as
+        # a call with part of its time
+        runs = _union([(s, s + d) for _, s, d in modules
+                       if s >= lo and s + d <= hi])
+        for name, s, d, nested in _top_level(ops):
+            if _inside(runs, s + d / 2):
+                short = _scoped_name(name, nested, op_scopes)
                 op_ns[short] = op_ns.get(short, 0.0) + d
         for name, s, d in modules:
-            # whole runs only: one cut by the window's edge would count
-            # as a call with part of its time
             if s >= lo and s + d <= hi:
                 p = programs.setdefault(
                     _module_name(name),

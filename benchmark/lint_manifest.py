@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""Check BENCHMARK.json and benchmark/metrics/*.json against each other
-and against the rules a manifest is refused for.
+"""Check BENCHMARK.json, benchmark/metrics/*.json and the recorded noise
+of each cell (benchmark/noise/<cell>.json) against each other and
+against the rules a manifest is refused for.
 
     python benchmark/lint_manifest.py        # exit 0 = clean
 """
@@ -14,13 +15,18 @@ import sys
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
+sys.path.insert(0, str(HERE))
+
+from harness import noise  # noqa: E402
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 DATA_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
 
 
-def lint(path: pathlib.Path = ROOT / "BENCHMARK.json") -> list[str]:
+def lint(path: pathlib.Path = ROOT / "BENCHMARK.json",
+         noise_dir: pathlib.Path = noise.NOISE) -> list[str]:
     """Problems of the manifest."""
     errs = []
     man = json.loads(path.read_text())
@@ -88,6 +94,12 @@ def lint(path: pathlib.Path = ROOT / "BENCHMARK.json") -> list[str]:
                  for c in e2e[m["name"]] if c not in cells]
     if "setup_s" not in e2e or e2e["setup_s"] != set(cells):
         errs.append("setup_s must be reported by every cell")
+    # a cell brings its recorded runs, and no bound is under twice the
+    # spread they show (harness/noise.py)
+    for c in cells:
+        errs += noise.problems(
+            c, {m["name"]: m["bound"] for m in man["end_to_end"]
+                if c in e2e[m["name"]]}, noise_dir)
 
     seen = set(e2e)
     layered = set()

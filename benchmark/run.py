@@ -3,10 +3,12 @@
 
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-One process: it starts the coordinator service, makes the cell's data
+This process starts the coordinator service, makes the cell's data
 from --seed, sets up and warms, measures for --seconds, checks what the
 window produced against the numpy reference, and prints the result as
-the last line of stdout.  Everything that belongs to one cell is found
+the last line of stdout.  The cell's traffic kind may start a load
+generator as a child (harness/loadgen.py); it stops it before it
+returns.  Everything that belongs to one cell is found
 by name: the configuration and the traffic mix named in BENCHMARK.json,
 the mix's `kind` under traffic_kinds/, each per-layer metric under
 metrics/ and its reader under readers/.  See README.md.
@@ -143,6 +145,9 @@ def main() -> int:
     from harness import service
     from m3_tpu.utils import compile_cache
     cache_dir = compile_cache.configure()
+    # 0 in a checkout's first run, which compiles
+    cached = len(os.listdir(cache_dir)) if cache_dir and os.path.isdir(
+        cache_dir) else 0
     try:
         run.device, run.peaks = service.device_info(args.rehearse,
                                                     cell["chips"])
@@ -152,8 +157,9 @@ def main() -> int:
     run.out_dir.mkdir(parents=True, exist_ok=True)
     run.svc, native_s = service.start(run.out_dir, config["service_config"],
                                       config["service_overlay"])
-    run.emit("start", **run.device, rehearse=args.rehearse,
-             compile_cache_dir=cache_dir, native_build_s=round(native_s, 2),
+    run.emit("start", pid=os.getpid(), **run.device, rehearse=args.rehearse,
+             compile_cache_dir=cache_dir, compile_cache_entries=cached,
+             native_build_s=round(native_s, 2),
              http_port=run.svc.http_port)
     try:
         state = kind.setup(run)
@@ -191,6 +197,14 @@ def main() -> int:
         line["rehearse"] = True
     run.emit("done", correct=correct, seconds=round(
         time.perf_counter() - T_PROCESS, 2))
+    # each number compared beside its limit: the result's last key, and
+    # the last lines of stderr
+    line["checks"] = {c["check"]: {"value": c["value"], "limit": c["limit"],
+                                   "ok": c["ok"]} for c in run.checks}
+    for c in run.checks:
+        print(f"check {c['check']}: {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

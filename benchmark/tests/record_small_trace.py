@@ -1,7 +1,11 @@
 #!/usr/bin/env python3
 """Record the small trace that test_trace_reduce.py reads: five runs of
-one jitted program with sleeps between them, under the harness's
-`bench:` annotations.  Run on the chip; prints what the planes hold.
+one jitted program that has two of the program's named scopes
+(`m3.decode` around a loop, `m3.temporal` around a matrix product),
+each after 2 ms under an `m3:fetch` annotation and inside `m3:device`
+holding `m3:kernel`, with 10 ms of sleep between them, all inside the
+harness's `bench:window`.  Run on the chip; prints what the planes
+hold.
 
     python benchmark/tests/record_small_trace.py <out_dir>
 """
@@ -25,19 +29,29 @@ def main(out_dir: str) -> None:
     from harness import trace_reduce
 
     @jax.jit
-    def small_program(x):
-        return jnp.tanh(x @ x).sum()
+    def small_program(x, rounds):
+        with jax.named_scope("m3.decode"):
+            # a bound read on the device keeps this a loop
+            _, y = jax.lax.while_loop(
+                lambda c: c[0] < rounds,
+                lambda c: (c[0] + 1, jnp.tanh(c[1] * 1.5 + 0.25)), (0, x))
+        with jax.named_scope("m3.temporal"):
+            return (y @ x).sum()
 
     x = jnp.ones((1024, 1024), dtype=jnp.float32)
-    small_program(x).block_until_ready()
+    rounds = jnp.int32(6)
+    small_program(x, rounds).block_until_ready()
     shutil.rmtree(out_dir, ignore_errors=True)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(out_dir, profiler_options=opts)
     with jax.profiler.TraceAnnotation("bench:window"):
         for _ in range(5):
-            with jax.profiler.TraceAnnotation("bench:awaiting_reply"):
-                small_program(x).block_until_ready()
+            with jax.profiler.TraceAnnotation("m3:fetch"):
+                time.sleep(0.002)
+            with jax.profiler.TraceAnnotation("m3:device"):
+                with jax.profiler.TraceAnnotation("m3:kernel"):
+                    small_program(x, rounds).block_until_ready()
             time.sleep(0.01)
     jax.profiler.stop_trace()
     path = trace_reduce.find_xplane(out_dir)
@@ -47,9 +61,9 @@ def main(out_dir: str) -> None:
         for line in plane.lines:
             events = list(line.events)
             print("  LINE", line.name, len(events))
-            for ev in events[:4]:
-                print("     ", ev.name, ev.start_ns, ev.duration_ns,
-                      dict(ev.stats))
+            for ev in events[:16]:
+                print("     ", ev.name[:120], ev.start_ns, ev.duration_ns)
+    print(json.dumps(trace_reduce._op_scopes(path), indent=1))
     print(json.dumps(trace_reduce.reduce(path), indent=1))
     print("xplane", path, pathlib.Path(path).stat().st_size, "bytes")
 

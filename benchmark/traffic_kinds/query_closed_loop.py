@@ -1,15 +1,20 @@
 """Closed-loop dashboard panels over sealed blocks.
 
 Set-up writes the config's fleet block by block at the config's
-backfill pace, seals it with the service's own tick + flush, and sends one panel so that the device
-program is loaded.  `clients` threads, each on its own keep-alive
-connection, each send their next `query_range` when the last reply is
+backfill pace, seals it with the service's own tick + flush, and sends
+one panel so that the device program is loaded.  The clients run in a
+process of their own, as a Grafana or an agent does: harness/loadgen.py,
+started as a child, runs `clients` threads, each on its own keep-alive
+connection, each sending its next `query_range` when the last reply is
 parsed; the panels go round the jobs in an order drawn from the seed.
-The loop runs for `ramp_s` seconds before the window opens, so that
-the window sees the loop's steady state and not four clients starting
-at once; the ramp is set-up.  The window's panels are those sent in
-it; one in flight when it ends is completed and counted.  With --trace 1 a slice of `trace_slice_s`
-seconds in the middle of the window is traced.
+This process, the server's, keeps the slow-query records, the kernel
+telemetry, the trace and every check, and no thread of the harness but
+the main one, which sleeps through the window.  The loop runs for
+`ramp_s` seconds before the window opens, so that the window sees the
+loop's steady state and not four clients starting at once; the ramp is
+set-up.  The window's panels are those sent in it; one in flight when
+it ends is completed and counted.  With --trace 1 a slice of
+`trace_slice_s` seconds in the middle of the window is traced.
 
 The check, after the window: every reply of the window equals the
 first reply for its job, and that one is compared with the numpy
@@ -20,14 +25,13 @@ back (count_over_time per job) and equals the samples acknowledged.
 from __future__ import annotations
 
 import gc
-import itertools
-import threading
+import os
 import time
 
 import numpy as np
 
 from harness import fleet as fleets
-from harness import reference, service, trace_reduce
+from harness import loadgen, reference, service, trace_reduce
 from harness.client import Client
 from harness.fleet import Fleet
 
@@ -48,25 +52,14 @@ def _ingest(fleet: Fleet, client: Client, samples_per_s: float) -> int:
     return acked
 
 
-def _panel(client: Client, mix: dict, fleet: Fleet, job: int):
-    """One panel over HTTP -> (seconds send to parsed reply,
-    {zone: (steps_s, values)})."""
-    expr = mix["query"].replace("<METRIC>", fleet.metric).replace(
+def _query(mix: dict, fleet: Fleet, job: int) -> str:
+    return mix["query"].replace("<METRIC>", fleet.metric).replace(
         "<J>", fleet.job_name(job))
-    t0 = time.perf_counter()
-    doc = client.get_json(
-        "/api/v1/query_range", query=expr,
-        start=fleet.t0 + mix["start_offset_s"],
-        end=fleet.seal_end - mix["step_s"], step=mix["step_s"])
-    rows = {}
-    for s in doc["data"]["result"]:
-        rows[tuple(sorted(s["metric"].items()))] = (
-            np.array([t for t, _ in s["values"]], dtype=np.float64),
-            np.array([float(v) for _, v in s["values"]]))
-    seconds = time.perf_counter() - t0
-    if doc["status"] != "success":
-        raise RuntimeError(f"{expr}: {doc}")
-    return seconds, rows
+
+
+def _range(mix: dict, fleet: Fleet) -> dict:
+    return {"start": fleet.t0 + mix["start_offset_s"],
+            "end": fleet.seal_end - mix["step_s"], "step": mix["step_s"]}
 
 
 def _job_order(seed: int, n_jobs: int) -> np.ndarray:
@@ -96,7 +89,8 @@ def setup(run):
         raise RuntimeError(f"sealed {sealed['block_starts']}, want {want}")
     from m3_tpu.ops import kernel_telemetry
     before = kernel_telemetry.snapshot()
-    seconds, _ = _panel(client, mix, fleet, 0)
+    seconds, _, _ = loadgen.panel(client, _query(mix, fleet, 0),
+                                  **_range(mix, fleet))
     after = kernel_telemetry.snapshot()
     run.emit("warm", seconds=round(seconds, 3), kernels={
         k: {f: round(st[f] - before.get(k, {}).get(f, 0), 3)
@@ -114,12 +108,9 @@ def window(run, state):
     from m3_tpu.query import slowlog
 
     fleet, mix = state["fleet"], run.mix
-    n_clients = mix["clients"]
     order = _job_order(run.seed, fleet.jobs)
-    next_draw = itertools.count()
-    lock = threading.Lock()
-    latencies, sent_at, errors = [], [], []
     gc_pauses = []          # (offset in the window, seconds) of full GCs
+    t_start = float("inf")  # set when the window opens, after the ramp
 
     def on_gc(phase, info, _t=[0.0]):
         if info["generation"] == 2:
@@ -128,70 +119,44 @@ def window(run, state):
             else:
                 gc_pauses.append((round(_t[0] - t_start, 3),
                                   round(time.perf_counter() - _t[0], 4)))
-    first_reply, mismatched = {}, []
-    # set when the window opens, after the ramp
-    t_start = deadline = float("inf")
 
-    def client_loop():
-        client = Client(run.svc.http_port)
-        try:
-            while time.perf_counter() < deadline:
-                with lock:
-                    job = int(order[next(next_draw) % len(order)])
-                try:
-                    with jax.profiler.TraceAnnotation(
-                            "bench:awaiting_reply"):
-                        seconds, rows = _panel(client, mix, fleet, job)
-                except Exception as e:  # noqa: BLE001 - a failed panel
-                    # is counted, the ramp's too, and the loop goes on
-                    with lock:
-                        errors.append(f"{type(e).__name__}: {e}"[:300])
-                    client.close()
-                    client = Client(run.svc.http_port)
-                    continue
-                sent = time.perf_counter() - seconds
-                if sent < t_start:
-                    continue                    # the ramp's
-                with lock:
-                    latencies.append(seconds)
-                    sent_at.append(sent - t_start)
-                    seen = first_reply.setdefault(job, rows)
-                    if seen is not rows and reference.max_rel_gap(
-                            rows, seen) != 0.0:
-                        mismatched.append(job)
-        finally:
-            client.close()
-
-    threads = [threading.Thread(target=client_loop, name=f"client-{i}")
-               for i in range(n_clients)]
-    for t in threads:
-        t.start()
-    time.sleep(mix["ramp_s"])
-    gc.callbacks.append(on_gc)
-    k_before = kernel_telemetry.snapshot()
-    t_wall = time.time()
-    t_start = run.window_opens()
-    deadline = t_start + run.seconds
-    if run.trace:
-        # a steady slice in the middle of the window; the Python
-        # tracer is off, the decode scan alone is thousands of events
-        time.sleep(run.seconds / 3)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        trace_dir = run.trace_dir()
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        with jax.profiler.TraceAnnotation("bench:window"):
-            time.sleep(min(mix["trace_slice_s"], run.seconds / 3))
-        jax.profiler.stop_trace()
-    for t in threads:
-        t.join()
+    child = loadgen.Child()
+    try:
+        run.emit("loadgen", server_pid=os.getpid(), loadgen_pid=child.pid)
+        clock_gap = child.handshake(dict(
+            _range(mix, fleet), port=run.svc.http_port,
+            queries=[_query(mix, fleet, j) for j in range(fleet.jobs)],
+            clients=mix["clients"], order=[int(j) for j in order],
+            seconds=run.seconds))
+        time.sleep(mix["ramp_s"])
+        gc.callbacks.append(on_gc)
+        k_before = kernel_telemetry.snapshot()
+        t_wall = time.time()
+        t_start = run.window_opens()
+        child.window_opens(t_start)
+        if run.trace:
+            # a steady slice in the middle of the window; the Python
+            # tracer is off, the decode scan alone is thousands of events
+            time.sleep(run.seconds / 3)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            trace_dir = run.trace_dir()
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            with jax.profiler.TraceAnnotation("bench:window"):
+                time.sleep(min(mix["trace_slice_s"], run.seconds / 3))
+            jax.profiler.stop_trace()
+        done = child.result()       # blocks until the loop has ended
+    finally:
+        child.stop()
     elapsed = time.perf_counter() - t_start
     gc.callbacks.remove(on_gc)
+    sent_at, ms, jobs = ([p[k] for p in done["panels"]] for k in range(3))
+    errors = done["errors"]
     # for reading a far-off run without a second one: every panel of
     # the window, and the interpreter's full collections
     run.emit("panels", log_only=True, gc_full=gc_pauses,
              sent_at_s=[round(t, 3) for t in sent_at],
-             ms=[round(s * 1000, 2) for s in latencies])
+             ms=[round(x, 2) for x in ms], job=jobs)
     if run.trace:
         path = trace_reduce.find_xplane(trace_dir)
         run.trace_summary = trace_reduce.reduce(path) if path else None
@@ -201,18 +166,27 @@ def window(run, state):
     run.slow_records = [r for r in slowlog.log().records()
                         if r.get("ts", 0) >= t_wall
                         and r["expr"].startswith(expr_head)]
+    # where a stalled panel spent its time: the four slowest records
+    run.emit("slowest", log_only=True, records=[
+        {"at_s": round(r.get("ts", t_wall) - t_wall, 3), "phases": r["phases"]}
+        for r in sorted(run.slow_records,
+                        key=lambda r: -r["phases"]["total_s"])[:4]])
     k_after = kernel_telemetry.snapshot()
     run.kernels = {
         name: {f: st[f] - k_before.get(name, {}).get(f, 0) for f in st}
         for name, st in k_after.items()}
-    run.timers["request_s"] = latencies
-    lat = np.asarray(latencies) * 1000.0
-    n = len(latencies)
-    end_to_end = {}
+    run.timers["request_s"] = [x / 1000.0 for x in ms]
+    lat = np.asarray(ms, dtype=np.float64)
+    n = len(ms)
+    end_to_end, beyond_p95 = {}, 0
     if n:
         end_to_end = {"panel_ms_p50": float(np.percentile(lat, 50)),
                       "panel_ms_p95": float(np.percentile(lat, 95))}
-    state.update(first_reply=first_reply, mismatched=mismatched)
+        beyond_p95 = int((lat > end_to_end["panel_ms_p95"]).sum())
+    first_reply = {job: loadgen.rows_of(doc)
+                   for job, doc in done["first_reply"].items()}
+    state.update(first_reply=first_reply, mismatched=done["differing"],
+                 clock_gap=clock_gap)
     return {"attempted": n + len(errors), "failed": len(errors),
             "end_to_end": end_to_end,
             "summary": {"requests": n, "errors": errors[:3],
@@ -220,6 +194,7 @@ def window(run, state):
                         "panels_per_s": round(n / elapsed, 3),
                         "distinct_jobs": len(first_reply),
                         "max_ms": round(float(lat.max(initial=0)), 1),
+                        "beyond_p95": beyond_p95,
                         "gc_full_s": round(sum(s for _, s in gc_pauses), 3),
                         "compiles_in_window": sum(
                             k.get("compiles", 0)
@@ -246,6 +221,8 @@ def check(run, state, result):
     run.check("replies_differing_from_first_of_job",
               len(state["mismatched"]), 0)
     run.check("failed_requests", result["failed"], 0)
+    run.check("loadgen_clock_gap_s", state["clock_gap"],
+              mix["limits"]["loadgen_clock_gap_s"])
     run.check("compiles_in_window",
               result["summary"]["compiles_in_window"], 0)
     run.check("no_request_completed", 0 if state["first_reply"] else 1, 0)
