@@ -25,12 +25,12 @@ writes are idempotent upserts keyed by (id, timestamp).
 from __future__ import annotations
 
 import threading
-import time
 
 from m3_tpu.aggregator.aggregator import AggregatedMetric, Aggregator
 from m3_tpu.cluster.election import LeaderService
 from m3_tpu.cluster.kv import ErrNotFound, MemStore
 from m3_tpu.utils import instrument
+from m3_tpu.utils.clock import now_nanos
 
 _log = instrument.logger("aggregator.flush")
 
@@ -165,7 +165,7 @@ class FlushManager:
     # -- background loop -----------------------------------------------------
 
     def open(self, interval_seconds: float,
-             clock=lambda: time.time_ns()) -> None:
+             clock=now_nanos) -> None:
         def loop():
             from m3_tpu import observe
             hb = observe.task_ledger().register_daemon(

@@ -10,7 +10,6 @@ aggregated namespaces; queries fan out across namespaces.)
 from __future__ import annotations
 
 import threading
-import time
 
 from m3_tpu.aggregator import (Aggregator, FlushManager,
                                StorageFlushHandler)
@@ -23,6 +22,7 @@ from m3_tpu.metrics.matcher import RuleMatcher, watch_ruleset_updates
 from m3_tpu.metrics.rules import RuleSet
 from m3_tpu.query.http import CoordinatorServer
 from m3_tpu.storage.namespace import NamespaceOptions
+from m3_tpu.utils import clock
 
 
 class Coordinator:
@@ -143,7 +143,7 @@ class Coordinator:
 
     def flush_once(self, now_nanos: int | None = None):
         return self.flush_manager.flush_once(
-            time.time_ns() if now_nanos is None else now_nanos)
+            clock.now_nanos() if now_nanos is None else now_nanos)
 
     def stop(self) -> None:
         self._rules_stop.set()

@@ -13,10 +13,9 @@ import math
 import socket
 import socketserver
 import threading
-import time
 
 from m3_tpu.aggregator import MetricKind
-from m3_tpu.utils import instrument
+from m3_tpu.utils import clock, instrument
 
 SECOND = 1_000_000_000
 
@@ -44,11 +43,11 @@ def parse_line(line: bytes, now_nanos: int | None = None):
         raise ValueError("carbon: empty path")
     value = float(raw_v)
     if raw_t in (b"N", b"n"):
-        t_nanos = now_nanos if now_nanos is not None else time.time_ns()
+        t_nanos = now_nanos if now_nanos is not None else clock.now_nanos()
     else:
         tsec = float(raw_t)
         if tsec == -1.0:
-            t_nanos = now_nanos if now_nanos is not None else time.time_ns()
+            t_nanos = now_nanos if now_nanos is not None else clock.now_nanos()
         else:
             t_nanos = int(tsec * SECOND)
     return (path, graphite_tags(path), MetricKind.GAUGE, value, t_nanos)
@@ -76,7 +75,7 @@ class CarbonIngester:
     def ingest_lines(self, data: bytes) -> None:
         fp = self._fastpath
         if fp is not None and fp.eligible(self._writer):
-            now = time.time_ns()
+            now = clock.now_nanos()
             try:
                 n, fb = fp.write(data, now)
             except Exception:  # noqa: BLE001 - scalar path must serve

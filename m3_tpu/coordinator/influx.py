@@ -20,6 +20,7 @@ samples), `i`/`u` suffixes for integer fields, and booleans mapped to
 from __future__ import annotations
 
 import re
+from m3_tpu.utils import clock
 
 _PRECISION_NANOS = {
     "ns": 1, "n": 1,
@@ -204,9 +205,7 @@ def _parse_one(line: str, mult: int, now_nanos: int | None
     elif now_nanos is not None:
         t_nanos = now_nanos
     else:
-        import time
-
-        t_nanos = time.time_ns()
+        t_nanos = clock.now_nanos()
     out: list[tuple[dict[bytes, bytes], int, float]] = []
     n_fields = 0
     for part in _split_fields(fields):

@@ -47,7 +47,8 @@ _INF = jnp.iinfo(jnp.int64).max
 _MERGE_LANES = 512
 
 
-def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int):
+def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
+                  order=None):
     """Compact per-(series, block) decode grids into the packed
     [n_lanes, n_cap] batch on device, a whole row at a time.
 
@@ -65,10 +66,17 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int):
     stay at [_MERGE_LANES, n_cap] whatever the fan-out.  Cells past a
     lane's n_cap budget DROP, never spill into the next lane (callers
     surface the overflow via counts).
+
+    `order` [M], where rows were laid end to end from two sources (the
+    decoded streams, then the rows that arrived as arrays), is the
+    permutation that puts them into the contract's order; the rows
+    stay where they are and are reached through it.
     """
     M, T = ts.shape
     B = min(n_lanes, _MERGE_LANES)
     row_counts = valid.sum(axis=1, dtype=I32)  # [M]
+    if order is not None:
+        slots, row_counts = slots[order], row_counts[order]
     first = jnp.searchsorted(slots, jnp.arange(n_lanes + 1), side="left",
                              method="scan_unrolled")  # lane -> first row
     # trailing empty rows (jit padding parked on the last lane) must not
@@ -94,6 +102,8 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int):
             out_t, out_v, counts = carry
             row = jnp.minimum(first_c + k, M - 1)
             cnt = jnp.where(k < n_rows_c, row_counts[row], 0)
+            if order is not None:
+                row = order[row]
             off = jnp.minimum(counts, n_cap)
             take = (col >= off[:, None]) & (col < (off + cnt)[:, None])
             return (jnp.where(take, place(ts, row, off), out_t),
@@ -207,12 +217,24 @@ def _tier_cut(ts, valid, slots, tiers, n_lanes: int, n_tiers: int):
 
 def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
                   n_dp: int | None, unit_nanos: int,
-                  tiers=None, n_tiers: int = 1):
+                  tiers=None, n_tiers: int = 1, open_rows=None):
     """Shared front half of every device serving pipeline: batched
     decode at stream width, the cross-namespace tier cut (multi-tier
     fan-outs), row-wise merge into lanes, and the full error contract
     (per-stream decode errors, truncation at n_dp, lane overflow past
     n_cap, unsorted merged lanes).
+
+    `open_rows` = (times i64[R, T], values f64[R, T], counts i32[R],
+    slots i64[R], order i64[M + R]): rows that reach the engine as
+    arrays (open buffers, blocks already decoded), at the decoded rows'
+    width T, a row's samples its first `count` cells.  They are laid
+    after the decoded rows and merged with them by the same
+    _merge_device; `order` puts the M + R rows into its contract's
+    order (grouped by slot, block-ascending: a lane's open rows after
+    its sealed ones), so a merged lane stays time-ascending and the
+    overflow and unsorted flags hold for these rows too.  The error
+    comes back as bool[M + R] then.  No tier cut reads them: the
+    engine declines array rows in a multi-tier fan-out.
 
     Multi-tier merge ordering contract: within a slot, rows arrive
     coarsest tier first (the cut guarantees coarse samples all precede
@@ -230,8 +252,20 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
             # keeps anything but a prefix (a coarse row out of time
             # order) must fall back, not land the wrong cells
             error = error | jnp.any(valid[:, 1:] & ~valid[:, :-1], axis=1)
+        order = None
+        if open_rows is not None:
+            with jax.named_scope("m3.open"):
+                o_ts, o_vs, o_counts, o_slots, order = open_rows
+                o_valid = (jnp.arange(T, dtype=I32)[None, :]
+                           < o_counts[:, None])
+                ts = jnp.concatenate([ts, o_ts])
+                vs = jnp.concatenate([vs, o_vs.astype(vs.dtype)])
+                valid = jnp.concatenate([valid, o_valid])
+                slots = jnp.concatenate([slots, o_slots])
+                error = jnp.concatenate(
+                    [error, jnp.zeros(o_slots.shape, error.dtype)])
         times, values, counts = _merge_device(ts, vs, valid, slots,
-                                              n_lanes, n_cap)
+                                              n_lanes, n_cap, order)
         error = error | (counts > n_cap)[slots]
         unsorted = jnp.any(jnp.diff(times, axis=1) < 0, axis=1)
         error = error | unsorted[slots]
@@ -795,13 +829,14 @@ def device_reduce_pipeline(
     hw_sf: float = 0.5,    # static: holt_winters smoothing factors
     hw_tf: float = 0.5,    # (fixed per dashboard; fold into the program)
     phi=0.5,               # traced: quantile_over_time's parameter
+    open_rows=None,        # rows that arrive as arrays (_decode_merge)
 ):
     """Compressed blocks -> *_over_time matrix, entirely on device.
     Returns (out f64[n_lanes, S], error bool[M]) with the same error
     contract as device_rate_pipeline."""
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
-                                         tiers, n_tiers)
+                                         tiers, n_tiers, open_rows)
     out = _temporal_eval(reducer, times, values, steps, range_nanos,
                          horizon, hw_sf, hw_tf, phi)
     return out, error
@@ -828,10 +863,11 @@ def device_rate_pipeline(
     n_dp: int | None = None,  # static max samples per STREAM (block)
     tiers: jax.Array | None = None,  # [M] dense tier ranks, 0 finest
     n_tiers: int = 1,
+    open_rows=None,        # rows that arrive as arrays (_decode_merge)
 ):
     """Compressed blocks -> per-series windowed rate, entirely on
     device.  Returns (rate f64[n_lanes, S], fleet_sum f64[S],
-    error bool[M]).
+    error bool[M]; bool[M + R] with open_rows).
 
     `n_dp` bounds one stream (one sealed block); `n_cap` bounds one
     output lane (all of a series' blocks).  Decoding at block width and
@@ -840,7 +876,7 @@ def device_rate_pipeline(
     fan-out that is 3x less decode work and HBM traffic."""
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
-                                         tiers, n_tiers)
+                                         tiers, n_tiers, open_rows)
     with jax.named_scope("m3.temporal"):
         rate = _rate_device(times, values, steps, range_nanos,
                             is_counter, is_rate)
@@ -1020,6 +1056,7 @@ def device_grouped_pipeline(
     tiers: jax.Array | None = None,  # [M] dense tier ranks, 0 finest
     n_tiers: int = 1,
     phi=0.5,               # traced: quantile()'s parameter
+    open_rows=None,        # rows that arrive as arrays (_decode_merge)
 ):
     """Compressed blocks -> `agg by (...) (fn(x[range]))` matrix,
     entirely on device: the rate/reduce pipeline fused with the grouped
@@ -1031,7 +1068,7 @@ def device_grouped_pipeline(
     contract (_decode_merge)."""
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
-                                         tiers, n_tiers)
+                                         tiers, n_tiers, open_rows)
     if fn in ("predict_linear", "holt_winters", "quantile_over_time"):
         # parameterized temporals never reach the grouped form (the
         # engine's grouped-child gate is single-arg); keep the trace-time

@@ -34,6 +34,7 @@ from m3_tpu.ops import consolidate as cons
 from m3_tpu.ops.m3tsz_decode import (decode_streams_adaptive,
                                      decode_streams_merged)
 from m3_tpu.query import promql, slowlog
+from m3_tpu.storage.buffer import OpenRow, by_view
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.limits import QueryDeadlineExceeded, ResultMeta
 from m3_tpu.utils import instrument, tracing
@@ -335,15 +336,63 @@ class Engine:
         """Collect the namespace fan-out's raw block payloads without
         decoding: -> (labels, parts, compressed, stream_counts).
 
-        parts[i] = (slot, tier, times, values) mutable-buffer reads;
+        parts[i] = (slot, tier, times, values, kind, after) rows that
+        arrive as arrays: ``open`` a read of an open buffer, ``decoded``
+        a block some cache or replica merge already decoded, ``cold`` a
+        sealed stream merged on the host with a cold write beside it;
+        ``after`` counts the compressed rows emitted before it;
         compressed[i] = (slot, tier, stream_bytes) with stream_counts[i]
-        the v2-fileset dp count (None = unknown).  Streams arrive
+        the v2-fileset dp count (None = unknown).  Both arrive
         slot-grouped ascending, block time ascending within a slot —
         the merge contract shared by the host and device serving tiers.
+
+        The walk is the ``fetch`` phase.  Open buffers are only named
+        during it (``OpenRow``: the buffer's view at that moment, under
+        the database lock); reading them out, all lanes of a view in
+        one call, is the ``open_read`` phase that follows.
         """
+        cost = self._cost()
+        with cost.phase("fetch"):
+            labels, parts, compressed, stream_counts, named, ns_bytes = (
+                self._gather_walk(matchers, start_nanos, end_nanos))
+        if named:
+            with cost.phase("open_read"):
+                self._read_open_rows(parts, named, ns_bytes)
+        cost.gather_bytes = sum(ns_bytes.values())
+        if self.planner is not None and ns_bytes:
+            # per-rung read-bytes accounting (grafana panel 45): label
+            # by declared resolution, "raw" for the unaggregated tier
+            fam = instrument.bounded_counter(
+                "m3_query_rung_read_bytes_total", cap=32)
+            for ns, nb in ns_bytes.items():
+                res = self.db.namespace_options(ns).aggregation_resolution
+                lab = format_duration(res) if res else "raw"
+                fam.labels(resolution=lab).inc(nb)
+        return labels, parts, compressed, stream_counts
+
+    @staticmethod
+    def _read_open_rows(parts: list, named: list, ns_bytes: dict) -> None:
+        """Read the named open rows out of their buffers' views into
+        ``parts``, whose ``None`` placeholders they fill; a lane with
+        nothing in a buffer leaves no row."""
+        for view, mine in by_view(named, lambda item: item[-1]):
+            read = view.read_lanes([item[-1].lane for item in mine])
+            for (at, ns, slot, tier, after, _row), (times, values) in zip(
+                    mine, read):
+                if len(times):
+                    parts[at] = (slot, tier, times, values, "open", after)
+                    ns_bytes[ns] = ns_bytes.get(ns, 0) + 16 * len(times)
+        parts[:] = [p for p in parts if p is not None]
+
+    def _gather_walk(self, matchers, start_nanos: int, end_nanos: int):
+        """-> (labels, parts, compressed, stream_counts, named,
+        ns_bytes): the gather's walk over the fan-out; parts[at] is
+        ``None`` for each (at, ns, slot, tier, after, OpenRow) of
+        `named`."""
         labels: list[dict[bytes, bytes]] = []
         slot_of: dict[bytes, int] = {}
-        parts: list[tuple[int, int, np.ndarray, np.ndarray]] = []
+        parts: list[tuple] = []
+        named: list[tuple] = []
         compressed: list[tuple[int, int, bytes]] = []
         stream_counts: list = []
         limits = getattr(self._qrange_local, "limits", None)
@@ -361,11 +410,12 @@ class Engine:
                 if limits is None and meta is None:
                     series = self.db.fetch_tagged(
                         ns, matchers, lo, hi + 1,
-                        with_counts=True)
+                        with_counts=True, defer_open=True)
                 else:
                     series = self.db.fetch_tagged(
                         ns, matchers, lo, hi + 1,
-                        with_counts=True, limits=limits, meta=meta)
+                        with_counts=True, limits=limits, meta=meta,
+                        defer_open=True)
             except KeyError:
                 continue
             n = self.db._ns(ns)
@@ -379,22 +429,20 @@ class Engine:
                         compressed.append((slot, tier, payload))
                         stream_counts.append(n_dp)
                         nb += len(payload)
+                    elif isinstance(payload, OpenRow):
+                        named.append((len(parts), ns, slot, tier,
+                                      len(compressed), payload))
+                        parts.append(None)
                     else:
-                        parts.append((slot, tier, payload[0], payload[1]))
+                        # arrays without a count: the shard merged a
+                        # sealed stream with the cold write beside it
+                        parts.append((slot, tier, payload[0], payload[1],
+                                      "cold" if n_dp is None else "decoded",
+                                      len(compressed)))
                         nb += payload[0].nbytes + payload[1].nbytes
             if nb:
                 ns_bytes[ns] = ns_bytes.get(ns, 0) + nb
-        self._cost().gather_bytes = sum(ns_bytes.values())
-        if self.planner is not None and ns_bytes:
-            # per-rung read-bytes accounting (grafana panel 45): label
-            # by declared resolution, "raw" for the unaggregated tier
-            fam = instrument.bounded_counter(
-                "m3_query_rung_read_bytes_total", cap=32)
-            for ns, nb in ns_bytes.items():
-                res = self.db.namespace_options(ns).aggregation_resolution
-                lab = format_duration(res) if res else "raw"
-                fam.labels(resolution=lab).inc(nb)
-        return labels, parts, compressed, stream_counts
+        return labels, parts, compressed, stream_counts, named, ns_bytes
 
     def _gather_cached(self, matchers, start_nanos: int, end_nanos: int):
         """Per-query gather memo: when the device tier declines a query
@@ -410,12 +458,11 @@ class Engine:
         stale storage snapshot to a later query — cross-query caching
         belongs to m3_tpu/cache, which sees invalidations.
 
-        The one stamp of the ``fetch`` phase: the walk on a miss, next
-        to nothing on a hit, so a query's ``fetch_s`` is the time it
-        spent gathering, each walk counted once."""
-        with self._cost().phase("fetch"):
-            return self._gather_memoized(matchers, start_nanos,
-                                         end_nanos)
+        ``_gather`` stamps the walk (``fetch``, then ``open_read``) on
+        a miss; a hit costs next to nothing and is stamped nowhere, so
+        a query's ``fetch_s`` is the time it spent gathering, each walk
+        counted once."""
+        return self._gather_memoized(matchers, start_nanos, end_nanos)
 
     def _gather_memoized(self, matchers, start_nanos: int,
                          end_nanos: int):
@@ -627,7 +674,8 @@ class Engine:
                 parts = list(parts)
                 for i, (slot, tier, _) in enumerate(compressed):
                     sel = valid[i]
-                    parts.append((slot, tier, ts[i][sel], vs[i][sel]))
+                    parts.append((slot, tier, ts[i][sel], vs[i][sel],
+                                  "decoded", i))
             raw_parts = self._stitch(parts)
             times, values, _counts = cons.merge_packed(raw_parts,
                                                        len(labels))
@@ -651,9 +699,10 @@ class Engine:
         # single-tier fast path (no aggregated namespaces matched): no
         # cut computation needed, merge_packed handles fragment order
         if parts and all(p[1] == parts[0][1] for p in parts):
-            return [(slot, t, v) for slot, _tier, t, v in parts if len(t)]
+            return [(slot, t, v) for slot, _tier, t, v, *_ in parts
+                    if len(t)]
         by_slot: dict[int, dict[int, list]] = defaultdict(lambda: defaultdict(list))
-        for slot, tier, t, v in parts:
+        for slot, tier, t, v, *_ in parts:
             if len(t):
                 by_slot[slot][tier].append((t, v))
         out = []
@@ -1013,12 +1062,17 @@ class Engine:
         handful of compiled programs; default: linear _bucket).
 
         Returns (pk, None), pk a dict with the packed numpy arrays plus
-        the shape metadata, or (None, reason) where only compressed
-        streams with known counts can be packed: ``mixed_payloads``,
+        the shape metadata, or (None, reason) for what cannot be
+        packed: ``cold_overlay`` (a block the shard merged on the host
+        because a cold write lies beside its sealed stream),
+        ``open_multi_tier`` (array rows in a multi-namespace fan-out),
         ``empty``, ``unknown_counts``.  The per-node tier then falls
         back to the host and counts the reason (``_decline``); the
         fused planner tries its arrays bridge first.  The gather is
-        the ``fetch`` phase; everything after it here is ``pack``."""
+        the ``fetch`` phase, laying the rows that arrive as arrays
+        (open buffers, decoded blocks) out beside the words is
+        ``open_read`` like the reading of them, and everything else
+        here is ``pack``."""
         bucket = self._bucket if bucket is None else bucket
         shifted = self._eval_times(rv, step_times)
         rng = rv.range_nanos if range_nanos is None else range_nanos
@@ -1026,18 +1080,58 @@ class Engine:
         # exact gather (same matchers, same range) for free
         lo, hi = int(shifted[0]) - rng, int(shifted[-1])
         gathered = self._gather_cached(rv.matchers, lo, hi)
-        with self._cost().phase("pack"):
-            return self._pack_gathered(rv, shifted, rng, lo, hi,
-                                       gathered, bucket)
+        cost = self._cost()
+        with cost.phase("pack"):
+            pk, why = self._pack_gathered(rv, shifted, rng, lo, hi,
+                                          gathered, bucket)
+        if pk is not None and gathered[1]:
+            with cost.phase("open_read"):
+                self._pack_array_rows(pk, gathered[1], bucket)
+        return pk, why
+
+    @staticmethod
+    def _pack_array_rows(pk, parts, bucket) -> None:
+        """Lay the rows that arrived as arrays out for the program, in
+        ``pk["open"]``: times, values [R, n_dp] (a row's samples first,
+        the row width of the decoded sealed rows, so that the program's
+        shape does not follow the tail's length), counts and slots [R],
+        and the order [M + R] that puts the decoded rows and these, laid
+        end to end, back into the gather's: grouped by slot, each
+        lane's rows block-ascending."""
+        n_dp, m_pad = pk["n_dp"], len(pk["nbits"])
+        r_pad = bucket(len(parts), 64)
+        times = np.zeros((r_pad, n_dp), dtype=np.int64)
+        values = np.zeros((r_pad, n_dp), dtype=np.float64)
+        counts = np.zeros(r_pad, dtype=np.int32)
+        # padding rows hold nothing and park on the last padding lane
+        slots = np.full(r_pad, pk["lanes_pad"] - 1, dtype=np.int64)
+        # sealed row k sorts at 2k + 1, an array row emitted after k of
+        # them at 2k, padding last
+        key = np.full(m_pad + r_pad, 2 * (m_pad + r_pad), dtype=np.int64)
+        key[:pk["n_streams"]] = 2 * np.arange(pk["n_streams"]) + 1
+        n = len(parts)
+        slots[:n] = [p[0] for p in parts]
+        counts[:n] = [len(p[2]) for p in parts]
+        key[m_pad:m_pad + n] = [2 * p[5] for p in parts]
+        # all rows at once: the cells a row fills, in row-major order,
+        # are the rows' samples end to end
+        cells = np.arange(n_dp)[None, :] < counts[:n, None]
+        times[:n][cells] = np.concatenate([p[2] for p in parts])
+        values[:n][cells] = np.concatenate([p[3] for p in parts])
+        order = np.argsort(key, kind="stable")
+        pk["open"] = (times, values, counts, slots, order)
+        pk["n_rows"] = pk["n_streams"] + len(parts)
+        pk["open_rows"] = sum(p[4] == "open" for p in parts)
+        pk["datapoints"] += int(counts.sum())
 
     def _pack_gathered(self, rv, shifted, rng, lo, hi, gathered,
                        bucket):
         labels, parts, compressed, stream_counts = gathered
-        if parts:
-            # mutable-buffer reads among (or instead of) the sealed
-            # blocks: only compressed streams decode on the device
-            return None, "mixed_payloads"
-        if not compressed or not labels:
+        if any(p[4] == "cold" for p in parts):
+            # the shard already decoded and merged this block on the
+            # host: the host answers
+            return None, "cold_overlay"
+        if not (compressed or parts) or not labels:
             return None, "empty"
         if any(c is None for c in stream_counts):
             return None, "unknown_counts"
@@ -1048,8 +1142,12 @@ class Engine:
         tier_ids = np.asarray([t for _, t, _ in compressed],
                               dtype=np.int64)
         uniq_tiers = np.unique(tier_ids)
-        n_tiers = len(uniq_tiers)
+        n_tiers = max(len(uniq_tiers), 1)
         ranks_np = None
+        if parts and len({t for _, t, _ in compressed}
+                         | {p[1] for p in parts}) > 1:
+            # the device's tier cut reads the decoded rows alone
+            return None, "open_multi_tier"
         if n_tiers > 1:
             # multi-tier fan-out: the device pipelines run the stitch
             # cut themselves (_tier_cut).  Rows must arrive grouped by
@@ -1070,9 +1168,14 @@ class Engine:
         n_lanes = len(labels)
         per_lane = np.zeros(n_lanes, dtype=np.int64)
         np.add.at(per_lane, slots_np, counts_np)
+        # rows that arrive as arrays count towards the same budgets
+        part_counts = np.asarray([len(p[2]) for p in parts], dtype=np.int64)
+        np.add.at(per_lane, np.asarray([p[0] for p in parts],
+                                       dtype=np.int64), part_counts)
         # static shape buckets (jit cache keys): stream count, words
         # width, lanes, per-stream and per-lane sample budgets, steps
-        n_dp = bucket(int(counts_np.max()), 128)
+        n_dp = bucket(int(max(counts_np.max(initial=0),
+                              part_counts.max(initial=0))), 128)
         n_cap = bucket(int(per_lane.max()), 128)
         lanes_pad = bucket(n_lanes, 64)
         m_pad = bucket(len(streams), 64)
@@ -1092,7 +1195,8 @@ class Engine:
         # padding lane; lanes_pad > n_lanes is guaranteed only when
         # padding streams exist, so force one spare lane if needed
         # (re-bucketed so pow2 quantizers stay pow2)
-        if m_pad > len(streams) and lanes_pad == n_lanes:
+        if ((m_pad > len(streams) or bucket(len(parts), 64) > len(parts) > 0)
+                and lanes_pad == n_lanes):
             lanes_pad = bucket(n_lanes + 1, 64)
         slots_p = np.full(m_pad, lanes_pad - 1, dtype=np.int64)
         slots_p[:len(streams)] = slots_np
@@ -1111,7 +1215,23 @@ class Engine:
             "n_streams": len(streams),
             "datapoints": int(counts_np.sum()),
             "tiers": tiers_p, "n_tiers": n_tiers,
+            # the rows handed to the program, and how many of them come
+            # from open buffers (_pack_array_rows)
+            "open": None, "n_rows": len(streams), "open_rows": 0,
         }, None
+
+    @staticmethod
+    def _rows_flagged(pk, err_np) -> bool:
+        """Whether the program flagged a row it was really given: the
+        packed streams, then (past the stream padding) the rows that
+        arrived as arrays."""
+        real = pk.get("real_rows")
+        if real is not None:
+            return bool(err_np[real].any())
+        m_pad = len(pk["nbits"])
+        return bool(err_np[:pk["n_streams"]].any()
+                    or err_np[m_pad:m_pad + pk["n_rows"]
+                              - pk["n_streams"]].any())
 
     def _shard_repack(self, pk, n_shards: int):
         """Re-lay a packed batch for the shard_map'd pipelines: equal
@@ -1202,6 +1322,9 @@ class Engine:
         cost = self._cost()
         n_shards = self._serving_shards()
         if n_shards > 1:
+            if pk["open"] is not None:
+                self._decline("open_rows_sharded")
+                return None
             with cost.phase("pack"):
                 pk = self._shard_repack(pk, n_shards)
         if fn == "quantile_over_time":
@@ -1232,6 +1355,8 @@ class Engine:
                         for k in ("words", "nbits", "slots", "steps"))
                     tiers_d = (None if pk["tiers"] is None
                                else jnp.asarray(pk["tiers"]))
+                    open_d = (None if pk["open"] is None else tuple(
+                        jnp.asarray(a) for a in pk["open"]))
                 if n_shards > 1:
                     rate, err = device_temporal_sharded(
                         self.serving_mesh, words_d, nbits_d, slots_d,
@@ -1246,7 +1371,8 @@ class Engine:
                         n_lanes=lanes_pad, n_cap=n_cap,
                         range_nanos=rng, is_counter=fn != "delta",
                         is_rate=fn == "rate", n_dp=n_dp,
-                        tiers=tiers_d, n_tiers=pk["n_tiers"])
+                        tiers=tiers_d, n_tiers=pk["n_tiers"],
+                        open_rows=open_d)
                 else:
                     rate, err = device_reduce_pipeline(
                         words_d, nbits_d, slots_d, steps_d,
@@ -1254,7 +1380,7 @@ class Engine:
                         range_nanos=rng, reducer=fn, n_dp=n_dp,
                         tiers=tiers_d, n_tiers=pk["n_tiers"],
                         horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
-                        phi=phi)
+                        phi=phi, open_rows=open_d)
                 with cost.phase("d2h"):
                     out = np.asarray(rate)
                     err_np = np.asarray(err)
@@ -1267,15 +1393,13 @@ class Engine:
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
             }
             return None
-        real = pk.get("real_rows")
-        flagged = (err_np[real] if real is not None
-                   else err_np[:pk["n_streams"]])
-        if flagged.any():
+        if self._rows_flagged(pk, err_np):
             self._decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
         self._publish_stats(
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
+            rows=pk["n_rows"], open_rows=pk["open_rows"],
             device_serving=True,
             fn=fn,  # which temporal actually ran on device — the
             # differential suite keys its tolerance on this
@@ -1329,10 +1453,14 @@ class Engine:
             # zero-length, so lanes >= n_lanes can only decode to
             # all-NaN rows and are inert wherever groups_p parks them
             m_real = pk["n_streams"]
-            assert (int(pk["slots"][:m_real].max()) < pk["n_lanes"]
+            assert (int(pk["slots"][:m_real].max(initial=-1))
+                    < pk["n_lanes"]
                     and not pk["nbits"][m_real:].any()), \
                 "device pack violated the padded-lanes-are-NaN invariant"
             if n_shards > 1:
+                if pk["open"] is not None:
+                    self._decline("open_rows_sharded")
+                    return None
                 pk = self._shard_repack(pk, n_shards)
             labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
             n_lanes, lanes_pad = pk["n_lanes"], pk["lanes_pad"]
@@ -1370,6 +1498,8 @@ class Engine:
                     groups_d = jnp.asarray(groups_p)
                     tiers_d = (None if pk["tiers"] is None
                                else jnp.asarray(pk["tiers"]))
+                    open_d = (None if pk["open"] is None else tuple(
+                        jnp.asarray(a) for a in pk["open"]))
                 if n_shards > 1:
                     out_g, err = device_grouped_sharded(
                         self.serving_mesh, words_d, nbits_d, slots_d,
@@ -1384,7 +1514,8 @@ class Engine:
                         n_lanes=lanes_pad, n_groups=g_pad,
                         n_cap=pk["n_cap"], range_nanos=rng,
                         fn=fn, agg=node.op, n_dp=pk["n_dp"],
-                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi)
+                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi,
+                        open_rows=open_d)
                 with cost.phase("d2h"):
                     out = np.asarray(out_g)
                     err_np = np.asarray(err)
@@ -1396,15 +1527,13 @@ class Engine:
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
             }
             return None
-        real = pk.get("real_rows")
-        flagged = (err_np[real] if real is not None
-                   else err_np[:pk["n_streams"]])
-        if flagged.any():
+        if self._rows_flagged(pk, err_np):
             self._decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
         self._publish_stats(
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
+            rows=pk["n_rows"], open_rows=pk["open_rows"],
             n_groups=len(uniq),
             device_serving=True,
             device_grouped=True,
@@ -2020,8 +2149,8 @@ class Engine:
 
     # the stamped phases that tile a query's time; h2d and d2h lie
     # inside device and are recorded beside it
-    _TILING_PHASES = ("parse_s", "fetch_s", "pack_s", "decode_s",
-                      "merge_s", "device_s")
+    _TILING_PHASES = ("parse_s", "fetch_s", "open_read_s", "pack_s",
+                      "decode_s", "merge_s", "device_s")
 
     def _record_query_cost(self, query: str, t0_ns: int, result, meta,
                            error: str | None) -> None:
@@ -2055,6 +2184,10 @@ class Engine:
                 "series": (len(result.labels)
                            if isinstance(result, Matrix) else 0),
                 "datapoints": stats.get("datapoints", 0),
+                # device tiers: rows handed to the program, and how
+                # many of them came from open buffers
+                "rows": stats.get("rows", 0),
+                "open_rows": stats.get("open_rows", 0),
                 "device_serving": bool(stats.get("device_serving")),
                 "fn": stats.get("fn"),
                 "n_shards": stats.get("n_shards", 1),
@@ -2108,6 +2241,9 @@ class Engine:
             if cost.fused_error:
                 rec["device_tier_error"] = cost.fused_error
             slowlog.log().record(rec)
+            if rec["open_rows"]:
+                instrument.counter("m3_query_open_rows_total").inc(
+                    rec["open_rows"])
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
                 # scanned and device execute seconds are accounted at

@@ -59,7 +59,7 @@ from m3_tpu.storage.database import (ColdWriteError, Database,
 from m3_tpu.query import slowlog
 from m3_tpu import attribution
 from m3_tpu.resilience.admission import AdmissionRejected
-from m3_tpu.utils import instrument, snappy, tracing
+from m3_tpu.utils import clock, instrument, snappy, tracing
 
 # accepted remote-write request sizes in samples: the group-commit
 # amortization upstream (m3_commitlog_group_batch_writes) only pays
@@ -1123,7 +1123,6 @@ class _Handler(BaseHTTPRequestHandler):
         return eng
 
     def _graphite_render(self):
-        import time as _time
         p = self._params()
         targets = p.get("target")
         if not targets:
@@ -1131,7 +1130,7 @@ class _Handler(BaseHTTPRequestHandler):
             return
         if isinstance(targets, str):
             targets = [targets]
-        now = _time.time()
+        now = clock.now_s()
         try:
             start = self._graphite_time(p.get("from", "-1h"), now)
             end = self._graphite_time(p.get("until", "now"), now)
@@ -1232,7 +1231,7 @@ class _Handler(BaseHTTPRequestHandler):
             return False
         if not self._admit(nbytes=len(body)):
             return True
-        now = time.time_ns()
+        now = clock.now_nanos()
         n_malformed = 0
         try:
             n_fast, fb = fp.write(body, mult, now)
@@ -1646,7 +1645,7 @@ class _Handler(BaseHTTPRequestHandler):
         if eng is None:
             return
         try:
-            t = _parse_time(p.get("time", str(time.time())))
+            t = _parse_time(p.get("time", str(clock.now_s())))
             limits = self._request_limits(p)
             from m3_tpu import serving
             with serving.batch_scope():

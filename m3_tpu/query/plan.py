@@ -586,6 +586,14 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             sp = _bucket_pow2(len(grid), 64)
             pk, _why = engine._device_gather_pack(
                 sel, grid, rng, bucket=_bucket_pow2)
+            if pk is not None and pk["open"] is not None:
+                if pk["n_streams"]:
+                    # the fused program takes words or arrays, not
+                    # both: the per-node tier serves sealed and open
+                    # rows together
+                    raise Unsupported("open rows beside sealed streams",
+                                      reason="open_rows")
+                pk = None   # arrays alone: the bridge below
             if pk is not None:
                 kind = "words"
                 # miss = packed compressed words shipped for on-device

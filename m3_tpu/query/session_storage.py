@@ -134,7 +134,8 @@ class SessionStorage:
 
     def fetch_tagged(self, ns: str, matchers, start_nanos: int,
                      end_nanos: int, with_counts: bool = False,
-                     limits=None, meta=None):
+                     limits=None, meta=None, defer_open: bool = False):
+        # defer_open: nothing to defer, a session's rows arrive read
         if ns != self.ns:
             raise KeyError(ns)
         deadline = limits.deadline if limits is not None else None

@@ -39,7 +39,7 @@ from m3_tpu.cluster.kv import (ErrAlreadyExists, ErrNotFound,
 from m3_tpu.metrics.policy import format_duration
 from m3_tpu.ops.downsample import AggregationType
 from m3_tpu.storage.tiles import AggregateTilesOptions, TileAggregator
-from m3_tpu.utils import instrument
+from m3_tpu.utils import clock, instrument
 
 from .ladder import RetentionLadder
 
@@ -71,7 +71,7 @@ class TileCompactionDaemon:
                  hot_window_nanos: int = 0,
                  poll_s: float = 30.0,
                  max_blocks_per_pass: int = 64,
-                 now_fn=time.time_ns):
+                 now_fn=clock.now_nanos):
         self._db = db
         self._ladder = ladder
         self._src = source_namespace

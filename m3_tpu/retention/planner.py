@@ -28,12 +28,12 @@ Fetch semantics (load-bearing for correctness):
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 from m3_tpu.metrics.policy import format_duration
 
 from .ladder import RetentionLadder
+from m3_tpu.utils import clock
 
 RAW_RESOLUTION = 0  # sentinel: the unaggregated tier
 
@@ -79,7 +79,7 @@ class QueryPlanner:
 
     def __init__(self, ladder: RetentionLadder, db,
                  raw_namespace: str = "default",
-                 now_fn=time.time_ns):
+                 now_fn=clock.now_nanos):
         self._ladder = ladder
         self._db = db
         self._raw_ns = raw_namespace

@@ -47,7 +47,7 @@ from m3_tpu.cluster.election import LeaderService
 from m3_tpu.cluster.kv import ErrNotFound
 from m3_tpu.query import slowlog
 from m3_tpu.query.engine import Engine
-from m3_tpu.utils import instrument, tracing
+from m3_tpu.utils import clock, instrument, tracing
 
 _log = instrument.logger("rules")
 
@@ -446,7 +446,7 @@ class GroupEvaluator:
         if not self._seen:
             self._alerts = {}
             return
-        now = time.time_ns()
+        now = clock.now_nanos()
         sids = list(self._seen)
         try:
             self._write(self.namespace, sids,

@@ -36,7 +36,7 @@ import queue
 import threading
 import time
 
-from m3_tpu.utils import instrument
+from m3_tpu.utils import clock, instrument
 
 DEFAULT_NAMESPACE = "_m3_internal"
 
@@ -109,7 +109,7 @@ class SelfScraper:
         the sample count enqueued (0 when the cycle was dropped under
         backpressure).  Never blocks on ingest."""
         t0 = time.perf_counter()
-        now = time.time_ns() if now_nanos is None else int(now_nanos)
+        now = clock.now_nanos() if now_nanos is None else int(now_nanos)
         self._m_cycles.inc()
         ids: list[bytes] = []
         tags: list[dict] = []
@@ -213,7 +213,7 @@ class SelfScraper:
         if self._thread is not None:
             self._thread.join(timeout=timeout)
         if staleness and self._seen:
-            now = time.time_ns()
+            now = clock.now_nanos()
             sids = list(self._seen)
             batch = (sids, [self._seen[s] for s in sids],
                      [now] * len(sids), [float("nan")] * len(sids))

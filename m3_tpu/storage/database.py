@@ -22,6 +22,7 @@ import numpy as np
 
 from m3_tpu import attribution
 from m3_tpu.cache import CacheOptions, DecodedBlockCache, SeekManager
+from m3_tpu.storage.buffer import OpenRow, open_rows_samples
 from m3_tpu.storage.commitlog import CommitLog
 from m3_tpu.storage.fileset import (FilesetReader, FilesetWriter,
                                     list_fileset_volumes, list_filesets,
@@ -29,7 +30,7 @@ from m3_tpu.storage.fileset import (FilesetReader, FilesetWriter,
 from m3_tpu.storage.index import IndexOptions, TagIndex
 from m3_tpu.storage.namespace import NamespaceOptions
 from m3_tpu.storage.shard import Shard
-from m3_tpu.utils import faultpoints, instrument, tracing
+from m3_tpu.utils import clock, faultpoints, instrument, tracing
 from m3_tpu.utils.hash import shard_for
 
 _log = instrument.logger("storage")
@@ -347,7 +348,7 @@ class Database:
             # storage/shard.go write-window checks).  Rejection is
             # PER SAMPLE like the reference: in-window samples in the
             # same batch still land, then the caller gets the error.
-            now = time.time_ns()
+            now = clock.now_nanos()
             ok = n.opts.retention.writable_mask(times_nanos, now)
             if not ok.all():
                 n_bad = int((~ok).sum())
@@ -479,7 +480,7 @@ class Database:
             raise KeyError(f"namespace {ns} has no schema")
         n = self._ns(ns)
         if (not n.opts.cold_writes_enabled
-                and not n.opts.retention.writable(t_nanos, time.time_ns())):
+                and not n.opts.retention.writable(t_nanos, clock.now_nanos())):
             instrument.counter("m3_cold_writes_rejected_total").inc()
             raise ValueError(
                 "cold write rejected (cold_writes_enabled=false): "
@@ -599,6 +600,7 @@ class Database:
     def fetch_tagged(
         self, ns: str, matchers, start_nanos: int, end_nanos: int,
         with_counts: bool = False, limits=None, meta=None,
+        defer_open: bool = False,
     ) -> dict[bytes, list[tuple]]:
         """Index query + per-series block fetch — FetchTagged
         (ref: tchannelthrift/node/service.go:614).  The index query is
@@ -609,6 +611,11 @@ class Database:
         carry per-stream datapoint counts, letting the reader size its
         decode grid without a count pass.  Default keeps the public
         2-tuple shape (TCP RPC / session compatibility).
+
+        ``defer_open=True`` hands plain open-buffer reads back as
+        ``OpenRow`` payloads (``Shard.read_series``), consistent with
+        the rest of this fetch, for the caller to read in bulk once
+        the lock is released.
 
         ``limits``/``meta`` (storage.limits) bound the fetch: time
         range clamped at admission, matched series truncated at the
@@ -656,6 +663,8 @@ class Database:
                 return int(entry[2])
             if isinstance(payload, (bytes, bytearray, memoryview)):
                 return max(1, len(payload) // 2)
+            if isinstance(payload, OpenRow):
+                payload = payload.read()
             return len(payload[0])
 
         dp_fetched = 0
@@ -697,7 +706,7 @@ class Database:
                 if lane is not None:
                     out[sid].extend(shard.read_series(
                         sid, lane, start_nanos, end_nanos,
-                        with_counts=with_counts))
+                        with_counts=with_counts, defer_open=defer_open))
                 out[sid].sort(key=lambda p: p[0])
             if limits is not None and limits.max_fetched_datapoints:
                 # sids are partitioned by shard, so summing this
@@ -714,15 +723,23 @@ class Database:
             # the namespace
             dps = 0
             nbytes = 0
+            named = []
             for entries in out.values():
                 for e in entries:
-                    dps += _ndp(e)
                     p = e[1]
+                    if isinstance(p, OpenRow):
+                        named.append(p)
+                        continue
+                    dps += _ndp(e)
                     if isinstance(p, (bytes, bytearray, memoryview)):
                         nbytes += len(p)
                     else:  # decoded (times, values) array pair
                         nbytes += (getattr(p[0], "nbytes", 0)
                                    + getattr(p[1], "nbytes", 0))
+            if named:
+                n = open_rows_samples(named)
+                dps += n
+                nbytes += 16 * n
             attribution.account_read(tracing.current_tenant() or ns,
                                      datapoints=dps,
                                      decoded_bytes=nbytes)
@@ -916,7 +933,7 @@ class Database:
 
     @_locked
     def tick(self, now_nanos: int | None = None) -> dict[str, list[int]]:
-        now_nanos = now_nanos if now_nanos is not None else time.time_ns()
+        now_nanos = now_nanos if now_nanos is not None else clock.now_nanos()
         sealed = defaultdict(list)
         for name, n in self._namespaces.items():
             ids = n.index._ids
@@ -1379,6 +1396,8 @@ class Mediator:
         return self
 
     def _run(self) -> None:
+        from jax.profiler import TraceAnnotation
+
         from m3_tpu import observe
         hb = observe.task_ledger().register_daemon(
             "mediator", interval_hint_s=self.tick_every)
@@ -1386,13 +1405,16 @@ class Mediator:
         while not self._stop.wait(self.tick_every):
             hb.beat()
             try:
-                self.db.tick()
-                self.db.flush()
-                if (self.snapshot_every
-                        and time.monotonic() - last_snapshot
-                        >= self.snapshot_every):
-                    self.db.snapshot()
-                    last_snapshot = time.monotonic()
+                # named in a device trace, so that a reader of one can
+                # tell the device's idle time under a pass from the rest
+                with TraceAnnotation("m3:mediator"):
+                    self.db.tick()
+                    self.db.flush()
+                    if (self.snapshot_every
+                            and time.monotonic() - last_snapshot
+                            >= self.snapshot_every):
+                        self.db.snapshot()
+                        last_snapshot = time.monotonic()
             except Exception as exc:  # noqa: BLE001 - the loop must survive
                 self.last_error = exc
                 instrument.counter("m3_mediator_errors_total").inc()

@@ -10,15 +10,15 @@ the sealed block as an immutable fileset.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable
 
 import numpy as np
 
 from m3_tpu.ops import m3tsz_scalar
-from m3_tpu.storage.buffer import BlockBuffer
+from m3_tpu.storage.buffer import BlockBuffer, OpenRow
 from m3_tpu.storage.fileset import FilesetReader, FilesetWriter, list_filesets
 from m3_tpu.storage.namespace import NamespaceOptions
+from m3_tpu.utils import clock
 
 
 def encode_block_scalar(
@@ -180,7 +180,7 @@ class Shard:
         values = np.asarray(values, dtype=np.float64)
         if len(times_nanos):
             self._m_lag.set(
-                (time.time_ns() - int(times_nanos.max())) / 1e9)
+                (clock.now_nanos() - int(times_nanos.max())) / 1e9)
         starts = times_nanos - (times_nanos % self.opts.retention.block_size)
         uniq = np.unique(starts)
         if len(uniq) == 1:
@@ -359,12 +359,19 @@ class Shard:
 
     def read_series(
         self, series_id: bytes, lane: int, start_nanos: int, end_nanos: int,
-        with_counts: bool = False,
+        with_counts: bool = False, defer_open: bool = False,
     ) -> list[tuple]:
         """In-memory data for [start, end): (block_start, payload) pairs,
         payload either (times, values) arrays from an open buffer or a
         compressed stream from a sealed block.  Flushed filesets are read
         at the Database level (it owns the namespace paths).
+
+        ``defer_open=True`` (the engine's bulk gather) names a plain
+        open-buffer read as an ``OpenRow`` on the buffer's consolidated
+        view instead of cutting it out here: the caller reads all its
+        lanes of a view in one call, after the database lock, and drops
+        the rows that turn out empty.  A block with a sealed stream AND
+        a buffer (a cold write after seal) is merged here either way.
 
         ``with_counts=True`` emits (block_start, payload, n_dp_or_None)
         triples — the count is produced HERE, alongside the payload it
@@ -394,6 +401,11 @@ class Shard:
                     pass
             buf_ts = buf_vs = None
             if bs in self._buffers:
+                if defer_open and sealed_stream is None:
+                    row = OpenRow(self._buffers[bs].view(), lane)
+                    out.append((bs, row, None) if with_counts
+                               else (bs, row))
+                    continue
                 # a cold write after seal lands in a fresh buffer
                 # alongside the sealed block — reads must see both
                 # (ref: buffer bucket versions, buffer.go:221)

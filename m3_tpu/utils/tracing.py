@@ -69,6 +69,7 @@ DEVICE_KERNEL = "device.Kernel"
 # phase of a query's cost record, keyed by the phase's short name
 ENGINE_PARSE = "engine.Parse"
 ENGINE_GATHER = "engine.Gather"
+ENGINE_OPEN_READ = "engine.OpenRead"
 ENGINE_PACK = "engine.Pack"
 ENGINE_DECODE = "engine.Decode"
 ENGINE_MERGE = "engine.Merge"
@@ -77,7 +78,8 @@ DEVICE_H2D = "device.HostToDevice"
 DEVICE_D2H = "device.DeviceToHost"
 HTTP_FRONTEND = "http.Frontend"
 PHASE_SPANS = {
-    "parse": ENGINE_PARSE, "fetch": ENGINE_GATHER, "pack": ENGINE_PACK,
+    "parse": ENGINE_PARSE, "fetch": ENGINE_GATHER,
+    "open_read": ENGINE_OPEN_READ, "pack": ENGINE_PACK,
     "decode": ENGINE_DECODE, "merge": ENGINE_MERGE,
     "device": ENGINE_DEVICE, "h2d": DEVICE_H2D, "d2h": DEVICE_D2H,
     "frontend": HTTP_FRONTEND,

@@ -29,9 +29,9 @@ T0 = (1_600_000_000 * SEC // BLOCK) * BLOCK
 START, END, STEP = T0 + 10 * 60 * SEC, T0 + 100 * 60 * SEC, 60 * SEC
 # from the sealed block into the mutable buffer
 MIXED_LO, MIXED_HI = T0 + 30 * 60 * SEC, T0 + 2 * BLOCK + 20 * 60 * SEC
-PHASE_KEYS = {"parse_s", "fetch_s", "pack_s", "decode_s", "merge_s",
-              "device_s", "h2d_s", "d2h_s", "self_s", "frontend_s",
-              "total_s"}
+PHASE_KEYS = {"parse_s", "fetch_s", "open_read_s", "pack_s", "decode_s",
+              "merge_s", "device_s", "h2d_s", "d2h_s", "self_s",
+              "frontend_s", "total_s"}
 
 
 def _write(db, name: bytes, n_series: int = 12, n: int = 120):
@@ -48,14 +48,20 @@ def _write(db, name: bytes, n_series: int = 12, n: int = 120):
 def db(tmp_path):
     """`sealed` lives in a flushed block and, for four of its hosts,
     goes on in the mutable buffer of a later one: a range that reaches
-    both is a mixed payload."""
+    both holds open rows beside sealed streams.  `cold` lives in the
+    same flushed block with a cold write beside it, which the shard
+    merges on the host: the device tier declines it."""
     db = Database(DatabaseOptions(path=str(tmp_path), num_shards=4,
                                   commit_log_enabled=False))
     db.create_namespace(NamespaceOptions(
         name="default", retention=RetentionOptions(block_size=BLOCK)))
     _write(db, b"sealed")
+    _write(db, b"cold", n_series=3)
     db.tick(now_nanos=T0 + 2 * BLOCK)
     db.flush()
+    db.write_batch("default", [b"cold|h00"],
+                   [{b"__name__": b"cold", b"host": b"h00", b"dc": b"dc0"}],
+                   [T0 + 45 * SEC], [0.5])
     n = 60
     for i in range(4):
         tags = {b"__name__": b"sealed", b"host": b"h%02d" % i,
@@ -77,7 +83,7 @@ def test_two_threads_keep_their_own_stats(db):
     eng = Engine(db, "default", device_serving=True)
     queries = {
         "device": ("sum by (dc) (rate(sealed[5m]))", START, END),
-        "host": ("rate(sealed[5m])", MIXED_LO, MIXED_HI),
+        "host": ("rate(cold[5m])", START, END),
     }
     barrier = threading.Barrier(2, timeout=120)
     seen, errors = {}, []
@@ -107,7 +113,7 @@ def test_two_threads_keep_their_own_stats(db):
     host_rec = _record_of(queries["host"][0])
     assert host_rec["device_serving"] is False
     assert host_rec["phases"]["device_s"] == 0.0
-    assert host_rec["device_declines"]["mixed_payloads"] >= 1
+    assert host_rec["device_declines"]["cold_overlay"] >= 1
 
 
 def test_grouped_device_phases_sum_to_total(db):
@@ -214,18 +220,29 @@ def test_device_programs_name_their_stages(which, scopes):
         assert f"/{scope}/" in text, scope
 
 
-def test_mutable_range_counts_one_decline(db):
+def test_cold_overlay_counts_one_decline(db):
     eng = Engine(db, "default", device_serving=True)
     fam = instrument.bounded_counter("m3_query_device_decline_total")
-    mixed = fam.labels(reason="mixed_payloads")
-    before = mixed.value
+    cold = fam.labels(reason="cold_overlay")
+    before = cold.value
+    expr = "rate(cold[6m])"
+    _, mat = eng.query_range(expr, START, END, STEP)
+    assert len(mat.labels) == 3
+    assert cold.value == before + 1
+    rec = _record_of(expr)
+    assert rec["device_serving"] is False
+    assert rec["device_declines"] == {"cold_overlay": 1}
+
+
+def test_mutable_range_is_served_by_the_device_tier(db):
+    """Open rows beside sealed streams are no longer a decline."""
+    eng = Engine(db, "default", device_serving=True)
     expr = "rate(sealed[6m])"
     _, mat = eng.query_range(expr, MIXED_LO, MIXED_HI, STEP)
     assert len(mat.labels) == 12
-    assert mixed.value == before + 1
     rec = _record_of(expr)
-    assert rec["device_serving"] is False
-    assert rec["device_declines"] == {"mixed_payloads": 1}
+    assert rec["device_serving"] is True and "device_declines" not in rec
+    assert (rec["rows"], rec["open_rows"]) == (16, 4)
 
 
 def test_http_query_leaves_frontend_in_its_record(db):

@@ -948,6 +948,65 @@ def test_grouped_program_has_no_per_element_addressing():
     assert "stablehlo.while" not in text and "stablehlo.scatter" not in text
 
 
+def test_open_rows_keep_the_grouped_programs_structure():
+    """Rows that arrive as arrays (open buffers) go through the same
+    merge: still no scatter outside m3.group and no loop in the
+    windowed stage, the rows are laid under m3.open, and a call without
+    them lowers to the program it was before they existed."""
+    from m3_tpu.models.query_pipeline import device_grouped_pipeline
+
+    M, W, L, S, R, T = 16, 8, 8, 4, 8, 16
+    sds = jax.ShapeDtypeStruct
+    args = (sds((M, W), np.uint32), sds((M,), np.int32),
+            sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
+    kw = dict(n_lanes=L, n_groups=4, n_cap=32, n_dp=T,
+              range_nanos=jnp.int64(300 * SEC))
+    open_rows = (sds((R, T), np.int64), sds((R, T), np.float64),
+                 sds((R,), np.int32), sds((R,), np.int64),
+                 sds((M + R,), np.int64))
+    fn = device_grouped_pipeline.__wrapped__
+    ops = list(_walk_jaxpr(jax.make_jaxpr(functools.partial(
+        fn, **kw))(*args, open_rows=open_rows).jaxpr))
+    scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
+    assert scatters and all("m3.group" in s for _, s in scatters), scatters
+    assert not [(p, s) for p, s in ops
+                if p in ("while", "scan") and "m3.temporal" in s]
+    assert any("m3.open" in s for _, s in ops)
+    with_rows = fn.lower(*args, open_rows=open_rows, **kw).as_text()
+    assert with_rows.count('"stablehlo.scatter"(') == len(scatters)
+    without = fn.lower(*args, **kw).as_text()
+    assert without == fn.lower(*args, open_rows=None, **kw).as_text()
+    assert "m3.open" not in fn.lower(*args, **kw).as_text(debug_info=True)
+    assert "m3.open" in fn.lower(*args, open_rows=open_rows, **kw).as_text(
+        debug_info=True)
+
+
+def test_open_rows_merge_behind_the_decoded_rows_of_their_lane():
+    """_merge_device reaches rows through `order`: decoded rows and
+    open rows laid end to end land in each lane block-ascending."""
+    from m3_tpu.models.query_pipeline import _merge_device
+
+    T, n_lanes, n_cap = 4, 3, 12
+    # decoded rows: lane 0 twice, lane 2 once; open rows: lanes 0 and 1
+    ts = np.array([[1, 2, 3, 0], [4, 5, 0, 0], [1, 2, 0, 0],
+                   [6, 7, 8, 9], [3, 0, 0, 0]], dtype=np.int64)
+    valid = np.array([[1, 1, 1, 0], [1, 1, 0, 0], [1, 1, 0, 0],
+                      [1, 1, 1, 1], [1, 0, 0, 0]], dtype=bool)
+    slots = np.array([0, 0, 2, 0, 1], dtype=np.int64)
+    order = np.argsort(np.array([1, 3, 5, 4, 4]), kind="stable")
+    times, values, counts = jax.jit(
+        _merge_device, static_argnames=("n_lanes", "n_cap"))(
+        jnp.asarray(ts), jnp.asarray(ts * 10.0), jnp.asarray(valid),
+        jnp.asarray(slots), n_lanes=n_lanes, n_cap=n_cap,
+        order=jnp.asarray(order))
+    assert np.asarray(counts).tolist() == [9, 1, 2]
+    assert np.asarray(times)[0, :9].tolist() == [1, 2, 3, 4, 5, 6, 7, 8, 9]
+    assert np.asarray(values)[0, :9].tolist() == [
+        10.0 * t for t in range(1, 10)]
+    assert np.asarray(times)[1, 0] == 3 and np.asarray(times)[2, :2].tolist(
+    ) == [1, 2]
+
+
 def test_tier_cut_that_keeps_no_prefix_is_flagged():
     """A coarse row out of time order can leave the cut a kept cell
     behind a dropped one.  The merge moves a row's first `count` cells,
