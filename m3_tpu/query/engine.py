@@ -37,6 +37,7 @@ from m3_tpu.query import promql, slowlog
 from m3_tpu.storage.buffer import OpenRow, by_view
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.limits import QueryDeadlineExceeded, ResultMeta
+from m3_tpu.storage.shard import ARRAYS, MIXED, OPEN, STREAMS
 from m3_tpu.utils import instrument, tracing
 
 DEFAULT_LOOKBACK = cons.DEFAULT_LOOKBACK
@@ -133,8 +134,9 @@ class QueryCost:
     call, and never another thread's."""
 
     __slots__ = ("phases", "stats", "declines", "gather_bytes",
-                 "ast_nodes", "fused_nodes", "fused_compile_cache",
-                 "fused_compile_s", "fused_transfer_bytes",
+                 "fileset_scans", "ast_nodes", "fused_nodes",
+                 "fused_compile_cache", "fused_compile_s",
+                 "fused_transfer_bytes",
                  "fused_n_shards", "fused_batched", "fused_batch_size",
                  "fused_batch_wait_s", "fused_error", "fused_poisoned",
                  "host_split_reasons", "rung_selections")
@@ -144,6 +146,9 @@ class QueryCost:
         self.stats: dict | None = None
         self.declines: dict[str, int] = {}    # device tier, by reason
         self.gather_bytes = 0
+        # directories the query's gathers had to list (a shard whose
+        # fileset listing was not yet kept): 0 on a served node
+        self.fileset_scans = 0
         # whole-query fusion (query/plan.py): how much of the tree the
         # fused device program served, what it cost to (re)compile,
         # and how many bytes crossed back
@@ -342,14 +347,19 @@ class Engine:
         sealed stream merged on the host with a cold write beside it;
         ``after`` counts the compressed rows emitted before it;
         compressed[i] = (slot, tier, stream_bytes) with stream_counts[i]
-        the v2-fileset dp count (None = unknown).  Both arrive
+        the stored dp count (None = unknown).  Both arrive
         slot-grouped ascending, block time ascending within a slot —
         the merge contract shared by the host and device serving tiers.
 
-        The walk is the ``fetch`` phase.  Open buffers are only named
-        during it (``OpenRow``: the buffer's view at that moment, under
-        the database lock); reading them out, all lanes of a view in
-        one call, is the ``open_read`` phase that follows.
+        The walk is the ``fetch`` phase: ``Database.fetch_tagged``
+        passes each shard once, block by block, under the database
+        lock, over tables that the writers of that state keep (the
+        seal a block's sid -> row table, the flush the shard's fileset
+        listing), and hands the rows over in this order; nothing of it
+        outlives the query.  Open buffers are only named during it
+        (``OpenRow``: the buffer's view at that moment); reading them
+        out, all lanes of a view in one call, is the ``open_read``
+        phase that follows.
         """
         cost = self._cost()
         with cost.phase("fetch"):
@@ -398,6 +408,7 @@ class Engine:
         limits = getattr(self._qrange_local, "limits", None)
         meta = getattr(self._qrange_local, "meta", None)
         ns_bytes: dict[str, int] = {}
+        cost = self._cost()
         for tier, (ns, lo, hi) in enumerate(
                 self._fetch_plan(start_nanos, end_nanos)):
             if limits is not None:
@@ -407,38 +418,45 @@ class Engine:
                 # +1: storage ranges are right-exclusive but a sample at
                 # exactly end_nanos resolves at that instant (an eval at
                 # the first block's very first timestamp must see it)
-                if limits is None and meta is None:
-                    series = self.db.fetch_tagged(
-                        ns, matchers, lo, hi + 1,
-                        with_counts=True, defer_open=True)
-                else:
-                    series = self.db.fetch_tagged(
-                        ns, matchers, lo, hi + 1,
-                        with_counts=True, limits=limits, meta=meta,
-                        defer_open=True)
+                gathered = self.db.fetch_tagged(
+                    ns, matchers, lo, hi + 1, with_counts=True,
+                    limits=limits, meta=meta, defer_open=True)
             except KeyError:
                 continue
-            n = self.db._ns(ns)
-            for sid, blocks in sorted(series.items()):
+            cost.fileset_scans += gathered.fileset_scans
+            tags_of = self.db._ns(ns).index.tags_of
+            for sid, lane, blocks, k in gathered.series:
                 slot = slot_of.get(sid)
                 if slot is None:
                     slot = slot_of[sid] = len(labels)
-                    labels.append(dict(n.index.tags_of(n.index.ordinal(sid))))
-                for _bs, payload, n_dp in blocks:
-                    if isinstance(payload, (bytes, memoryview)):
+                    labels.append(dict(tags_of(lane)))
+                for _bs, kind, payloads, counts in blocks:
+                    payload = payloads[k]
+                    if payload is None:
+                        continue
+                    if kind is MIXED:
+                        # a cold write beside sealed streams: the row
+                        # is whatever the shard made of the two
+                        kind = (STREAMS if isinstance(
+                                    payload, (bytes, memoryview))
+                                else OPEN if isinstance(payload, OpenRow)
+                                else ARRAYS)
+                    if kind is STREAMS:
                         compressed.append((slot, tier, payload))
-                        stream_counts.append(n_dp)
+                        stream_counts.append(
+                            None if counts is None else counts[k])
                         nb += len(payload)
-                    elif isinstance(payload, OpenRow):
+                    elif kind is OPEN:
                         named.append((len(parts), ns, slot, tier,
                                       len(compressed), payload))
                         parts.append(None)
                     else:
                         # arrays without a count: the shard merged a
                         # sealed stream with the cold write beside it
-                        parts.append((slot, tier, payload[0], payload[1],
-                                      "cold" if n_dp is None else "decoded",
-                                      len(compressed)))
+                        parts.append((
+                            slot, tier, payload[0], payload[1],
+                            "cold" if counts is None or counts[k] is None
+                            else "decoded", len(compressed)))
                         nb += payload[0].nbytes + payload[1].nbytes
             if nb:
                 ns_bytes[ns] = ns_bytes.get(ns, 0) + nb
@@ -2188,6 +2206,7 @@ class Engine:
                 # many of them came from open buffers
                 "rows": stats.get("rows", 0),
                 "open_rows": stats.get("open_rows", 0),
+                "fileset_scans": cost.fileset_scans,
                 "device_serving": bool(stats.get("device_serving")),
                 "fn": stats.get("fn"),
                 "n_shards": stats.get("n_shards", 1),

@@ -25,6 +25,9 @@ from __future__ import annotations
 
 import threading
 
+from m3_tpu.storage.database import Gathered
+from m3_tpu.storage.shard import ARRAYS, STREAMS, BlockRows
+
 
 def _labels_of_sid(sid: bytes) -> dict[bytes, bytes]:
     out: dict[bytes, bytes] = {}
@@ -152,14 +155,24 @@ class SessionStorage:
             sids = sids[:keep]
         if meta is not None:
             meta.fetched_series += len(sids)
+        if with_counts and defer_open:
+            # the engine's gather: the rows in its own order (sids
+            # ascending, blocks ascending), one row a block.  Replica-
+            # diverged blocks arrive as (times, values) arrays with an
+            # exact count; identical compressed copies stay opaque
+            # (count unknown -> host decode)
+            return Gathered([
+                (sid, self._index.ordinal(sid),
+                 [BlockRows(bs, STREAMS, [payload], None)
+                  if isinstance(payload, (bytes, memoryview))
+                  else BlockRows(bs, ARRAYS, [payload], [len(payload[0])])
+                  for bs, payload in merged[sid]], 0)
+                for sid in sids])
         out: dict[bytes, list[tuple]] = {}
         for sid in sids:
             self._index.ordinal(sid)  # intern for tags_of
             blocks = merged[sid]
             if with_counts:
-                # replica-diverged blocks arrive as (times, values)
-                # arrays with an exact count; identical compressed
-                # copies stay opaque (count unknown -> host decode)
                 out[sid] = [
                     (bs, payload,
                      None if isinstance(payload, (bytes, memoryview))

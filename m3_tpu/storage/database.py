@@ -17,6 +17,7 @@ import pathlib
 import threading
 import time
 from collections import defaultdict
+from typing import NamedTuple
 
 import numpy as np
 
@@ -29,7 +30,7 @@ from m3_tpu.storage.fileset import (FilesetReader, FilesetWriter,
                                     read_fileset_info, remove_fileset)
 from m3_tpu.storage.index import IndexOptions, TagIndex
 from m3_tpu.storage.namespace import NamespaceOptions
-from m3_tpu.storage.shard import Shard
+from m3_tpu.storage.shard import ARRAYS, OPEN, STREAMS, BlockRows, Shard
 from m3_tpu.utils import clock, faultpoints, instrument, tracing
 from m3_tpu.utils.hash import shard_for
 
@@ -75,6 +76,52 @@ def _locked(fn):
         with self._lock:
             return fn(self, *args, **kwargs)
     return wrapper
+
+
+class Gathered(NamedTuple):
+    """What ``fetch_tagged`` hands the engine's bulk gather
+    (``with_counts=True, defer_open=True``), in the order the gather
+    emits it: ``series`` holds one (sid, index ordinal, blocks, k) per
+    matched series, sids ascending; ``blocks`` are its shard's
+    ``BlockRows``, block starts ascending, and the series' row in each
+    is ``payloads[k]`` / ``counts[k]`` (None: nothing in that block)."""
+
+    series: list[tuple]
+    # directory scans this fetch had to make (a shard not yet listed)
+    fileset_scans: int = 0
+
+
+def _rows_cost(block: BlockRows) -> tuple[int, int, list]:
+    """-> (datapoints, bytes, the rows only named) of one block's rows.
+    A stream without a stored count is estimated at ~2 bytes/sample
+    (m3tsz averages ~1.4B/sample, so this undercounts conservatively
+    rather than rejecting queries early); an ``OpenRow`` is counted by
+    the caller, one search a view (``open_rows_samples``)."""
+    _bs, kind, payloads, counts = block
+    if kind is OPEN:
+        return 0, 0, payloads
+    if (kind is STREAMS and counts is not None
+            and counts.count(None) == payloads.count(None)):
+        # every stream with its stored count (sealed blocks, v2
+        # filesets): two sums, no row looked at in the interpreter
+        return (sum(filter(None, counts)),
+                sum(map(len, filter(None, payloads))), [])
+    dps = nbytes = 0
+    named = []
+    for i, p in enumerate(payloads):
+        if p is None:
+            continue
+        if isinstance(p, (bytes, bytearray, memoryview)):
+            n_dp = counts[i] if counts is not None else None
+            dps += max(1, len(p) // 2) if n_dp is None else int(n_dp)
+            nbytes += len(p)
+        elif isinstance(p, OpenRow):
+            named.append(p)
+        else:  # decoded (times, values) array pair
+            dps += len(p[0])
+            nbytes += (getattr(p[0], "nbytes", 0)
+                       + getattr(p[1], "nbytes", 0))
+    return dps, nbytes, named
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,6 +256,12 @@ class Database:
                                               **db_tag)
         self._m_sealed = instrument.counter("m3_tick_sealed_blocks_total",
                                             **db_tag)
+        # how a shard's data filesets were listed: from the listing
+        # kept on the shard, or by a directory scan (once a shard)
+        self._m_listing_kept = instrument.counter(
+            "m3_fileset_listing_total", source="kept", **db_tag)
+        self._m_listing_scan = instrument.counter(
+            "m3_fileset_listing_total", source="scan", **db_tag)
         # bootstrap/restart observability (warm-restart PR): phase is a
         # numeric code (see _BOOTSTRAP_PHASES) so dashboards can plot
         # the state machine; entries/bytes advance as WAL chunks replay
@@ -517,6 +570,13 @@ class Database:
 
     # --- read path ---
 
+    def _query_ordinals(self, n: _Namespace, matchers, start_nanos,
+                        end_nanos, limits=None, meta=None) -> np.ndarray:
+        return n.index.query_conjunction(
+            matchers, start_nanos, end_nanos, n.opts.retention.block_size,
+            limits=limits, meta=meta,
+        )
+
     @_locked
     def query_ids(
         self,
@@ -528,27 +588,23 @@ class Database:
         meta=None,
     ) -> list[bytes]:
         n = self._ns(ns)
-        ords = n.index.query_conjunction(
-            matchers, start_nanos, end_nanos, n.opts.retention.block_size,
-            limits=limits, meta=meta,
-        )
+        ords = self._query_ordinals(n, matchers, start_nanos, end_nanos,
+                                    limits, meta)
         return [n.index.id_of(o) for o in ords]
 
     @_locked
     def fetch_series(
         self, ns: str, series_id: bytes, start_nanos: int, end_nanos: int,
-        _filesets: list[tuple[int, int]] | None = None,
     ) -> list[tuple[int, object]]:
         """All (block_start, payload) for one series: flushed filesets,
-        sealed in-memory blocks, open buffers.  `_filesets` lets bulk
-        callers (block_metadata) glob the shard directory once."""
+        sealed in-memory blocks, open buffers."""
         n = self._ns(ns)
         lane = n.index.ordinal(series_id)
         shard = n.shard_of(series_id)
         out: list[tuple[int, object]] = []
         # flushed filesets first (oldest data)
         for bs, reader in self._overlapping_filesets(
-                ns, n, shard, start_nanos, end_nanos, _filesets):
+                ns, n, shard, start_nanos, end_nanos):
             blob = reader.read(series_id)
             if blob:
                 out.append((bs, blob))
@@ -556,22 +612,40 @@ class Database:
             out.extend(shard.read_series(series_id, lane, start_nanos, end_nanos))
         return sorted(out, key=lambda p: p[0])
 
+    def _scan_filesets(self, ns: str, shard: Shard) -> dict[int, int]:
+        """List the shard's data directory and keep the listing on the
+        shard: {block_start: latest volume}."""
+        self._m_listing_scan.inc()
+        shard.filesets = dict(list_filesets(self.path / "data", ns,
+                                            shard.shard_id))
+        return shard.filesets
+
+    def _data_filesets(self, ns: str, shard: Shard) -> dict[int, int]:
+        """The shard's data filesets, {block_start: latest volume}, from
+        the listing kept on the shard (``Shard.filesets``).  This
+        database is the only writer of its ``data/`` tree: bootstrap
+        sets the listing from the directory, ``Shard.flush`` renews it
+        with every fileset written, cleanup removes only volumes a
+        newer one supersedes (never a listed one) and a dropped shard
+        starts empty.  A shard not yet listed is scanned once."""
+        if shard.filesets is None:
+            return self._scan_filesets(ns, shard)
+        self._m_listing_kept.inc()
+        return shard.filesets
+
     def _overlapping_filesets(self, ns: str, n, shard, start_nanos: int,
-                              end_nanos: int, filesets=None):
+                              end_nanos: int):
         """Yield (block_start, reader) for flushed filesets overlapping
         [start, end) and not shadowed by an in-memory copy — the ONE
         implementation of the read path's block-selection rules, shared
-        by single-series and fan-out fetches."""
-        mem_blocks = (set(shard.sealed_block_starts())
-                      | set(shard.open_block_starts()))
-        if filesets is None:
-            filesets = list_filesets(self.path / "data", ns,
-                                     shard.shard_id)
+        by single-series and fan-out fetches.  Readers are asked for
+        only where memory does not hold the block; no directory is
+        read (``_data_filesets``)."""
         bsize = n.opts.retention.block_size
-        for bs, vol in filesets:
+        for bs, vol in sorted(self._data_filesets(ns, shard).items()):
             if not (start_nanos < bs + bsize and bs < end_nanos):
                 continue
-            if bs in mem_blocks:
+            if shard.holds_block(bs):
                 continue  # memory copy wins (not yet evicted)
             yield bs, self._cached_reader(ns, shard.shard_id, bs, vol)
 
@@ -601,21 +675,33 @@ class Database:
         self, ns: str, matchers, start_nanos: int, end_nanos: int,
         with_counts: bool = False, limits=None, meta=None,
         defer_open: bool = False,
-    ) -> dict[bytes, list[tuple]]:
-        """Index query + per-series block fetch — FetchTagged
+    ) -> dict[bytes, list[tuple]] | Gathered:
+        """Index query + block fetch of the matched series — FetchTagged
         (ref: tchannelthrift/node/service.go:614).  The index query is
         time-pruned to blocks overlapping [start, end).
 
-        ``with_counts=True`` (the engine's batch-decode path) emits
-        (block_start, payload, n_dp_or_None) triples — v2 filesets
-        carry per-stream datapoint counts, letting the reader size its
-        decode grid without a count pass.  Default keeps the public
-        2-tuple shape (TCP RPC / session compatibility).
+        The walk is block-major: the matched series are grouped by
+        shard, and each shard is passed once — its filesets for block
+        starts memory does not hold (bulk-read through the reader's
+        seek index, listed from ``Shard.filesets``: no directory scan),
+        then ``Shard.read_many`` over what memory holds (sealed rows
+        through the table the seal built, one view an open buffer).
+        Nothing is kept from one fetch to the next; all of it is read
+        under the database lock, so a fetch sees the storage of its
+        own moment.
 
-        ``defer_open=True`` hands plain open-buffer reads back as
-        ``OpenRow`` payloads (``Shard.read_series``), consistent with
-        the rest of this fetch, for the caller to read in bulk once
-        the lock is released.
+        Default: {sid: [(block_start, payload)]}, blocks ascending —
+        the public 2-tuple shape (TCP RPC / session compatibility).
+
+        ``with_counts=True`` carries a datapoint count beside each
+        payload (v2 filesets and sealed blocks store it, letting the
+        reader size its decode grid without a count pass; None =
+        unknown).  ``defer_open=True`` hands plain open-buffer reads
+        back as ``OpenRow`` payloads (``Shard.read_many``), consistent
+        with the rest of this fetch, for the caller to read in bulk
+        once the lock is released.  With both (the engine's gather) the
+        answer is a ``Gathered``: the same rows, handed over in the
+        gather's own order and without per-series containers.
 
         ``limits``/``meta`` (storage.limits) bound the fetch: time
         range clamped at admission, matched series truncated at the
@@ -628,122 +714,105 @@ class Database:
         if limits is not None:
             start_nanos = limits.clamp_time_range(
                 start_nanos, end_nanos, meta)
-        sids = self.query_ids(ns, matchers, start_nanos, end_nanos,
-                              limits=limits, meta=meta)
+        n = self._ns(ns)
+        lanes = self._query_ordinals(n, matchers, start_nanos, end_nanos,
+                                     limits, meta).tolist()
+        id_of = n.index.id_of
+        sids = [id_of(lane) for lane in lanes]
         limit = getattr(self._runtime, "max_fetch_series", 0)
         if limit and len(sids) > limit:
             raise ValueError(
                 f"query matched {len(sids)} series > limit {limit}")
         if meta is not None:
             meta.fetched_series += len(sids)
-        # batch by (shard, fileset): glob each shard's directory once
-        # per query and bulk-read every matched series from a fileset in
-        # one pass (dict-lookup seek index) — at 50k-series fan-outs the
-        # per-series read stack (bloom + bisect + call overhead, ~60k
-        # calls for a 6h query) dominated host-side fetch cost
-        n = self._ns(ns)
-        out: dict[bytes, list[tuple[int, object]]] = {
-            sid: [] for sid in sids}
-        by_shard: dict[int, list[tuple[bytes, int | None]]] = {}
-        for sid in sids:
-            # matched sids are indexed: route via the lane memo instead
-            # of recomputing pure-Python murmur3 per sid; the lane rides
-            # along so the buffer-read loop skips a second lookup
-            lane = n.index.ordinal(sid)
-            shard_id = (n.shard_of_lane(lane) if lane is not None
-                        else n.shard_of(sid).shard_id)
-            by_shard.setdefault(shard_id, []).append((sid, lane))
-        def _ndp(entry) -> int:
-            # (bs, payload[, n_dp]) -> datapoint count; blobs without a
-            # stored count are estimated at ~2 bytes/sample (m3tsz
-            # averages ~1.4B/sample, so this undercounts conservatively
-            # rather than rejecting queries early)
-            payload = entry[1]
-            if len(entry) > 2 and entry[2] is not None:
-                return int(entry[2])
-            if isinstance(payload, (bytes, bytearray, memoryview)):
-                return max(1, len(payload) // 2)
-            if isinstance(payload, OpenRow):
-                payload = payload.read()
-            return len(payload[0])
+        # group by shard (matched sids are indexed: route via the lane
+        # memo instead of recomputing pure-Python murmur3 per sid).
+        # series[i] = (sid, lane, the blocks of its shard, filled
+        # below; its row in each of them)
+        by_shard: dict[int, tuple[list, list, list]] = {}
+        series: list[tuple] = []
+        shard_of_lane = n.shard_of_lane
+        for sid, lane in zip(sids, lanes):
+            group = by_shard.get(shard_id := shard_of_lane(lane))
+            if group is None:
+                group = by_shard[shard_id] = ([], [], [])
+            series.append((sid, lane, group[2], len(group[0])))
+            group[0].append(sid)
+            group[1].append(lane)
 
-        dp_fetched = 0
+        count_cost = attribution.enabled()
+        budget = limits is not None and limits.max_fetched_datapoints
+        dp_fetched = nbytes = scans = 0
         # series cache policy for this namespace: anything but "none"
         # routes v2 fileset reads through the decoded-block cache so a
         # warm repeat serves device-ready (times, values) arrays with
         # zero M3TSZ decode work
         dec_policy = self._decoded_cache.policy_for(ns)
-        for shard_id, shard_sids in by_shard.items():
+        for shard_id, (shard_sids, shard_lanes, blocks) in by_shard.items():
             if limits is not None:
                 limits.check_deadline("block fetch")
-                if limits.datapoints_exceeded(dp_fetched, meta):
+                if budget and limits.datapoints_exceeded(dp_fetched, meta):
                     break  # budget spent: remaining shards truncated
             shard = n.shards[shard_id]
-            only_sids = [sid for sid, _lane in shard_sids]
+            scans += shard.filesets is None
             for bs, reader in self._overlapping_filesets(
                     ns, n, shard, start_nanos, end_nanos):
-                if with_counts:
-                    blobs, dps = reader.read_batch_with_counts(
-                        only_sids, zero_copy=True)
-                    if dec_policy != "none":
-                        decoded = self._decoded_cache.get_or_decode(
-                            ns, shard.shard_id, bs, reader.volume,
-                            dec_policy, only_sids, blobs, dps)
-                        for sid, dec in zip(only_sids, decoded):
-                            if dec is not None:
-                                out[sid].append((bs, dec, len(dec[0])))
-                    else:
-                        for sid, blob, n_dp in zip(only_sids, blobs,
-                                                   dps):
-                            if blob:
-                                out[sid].append((bs, blob, n_dp))
+                if not with_counts:
+                    blocks.append(BlockRows(
+                        bs, STREAMS,
+                        [b or None for b in reader.read_batch(shard_sids)],
+                        None))
+                    continue
+                blobs, counts = reader.read_batch_with_counts(
+                    shard_sids, zero_copy=True)
+                if dec_policy != "none":
+                    decoded = self._decoded_cache.get_or_decode(
+                        ns, shard.shard_id, bs, reader.volume,
+                        dec_policy, shard_sids, blobs, counts)
+                    blocks.append(BlockRows(
+                        bs, ARRAYS, decoded,
+                        [None if d is None else len(d[0])
+                         for d in decoded]))
                 else:
-                    for sid, blob in zip(only_sids,
-                                         reader.read_batch(only_sids)):
-                        if blob:
-                            out[sid].append((bs, blob))
-            for sid, lane in shard_sids:
-                if lane is not None:
-                    out[sid].extend(shard.read_series(
-                        sid, lane, start_nanos, end_nanos,
-                        with_counts=with_counts, defer_open=defer_open))
-                out[sid].sort(key=lambda p: p[0])
-            if limits is not None and limits.max_fetched_datapoints:
-                # sids are partitioned by shard, so summing this
-                # shard's sids counts each entry exactly once
-                dp_fetched += sum(
-                    _ndp(e) for sid, _lane in shard_sids
-                    for e in out[sid])
-        if meta is not None:
-            meta.fetched_datapoints += dp_fetched
-        if attribution.enabled():
-            # per-QUERY attribution (one pass over the result table,
-            # never per sample): datapoints scanned + bytes decoded,
-            # credited to the propagated tenant (fan-out RPC work) or
-            # the namespace
-            dps = 0
-            nbytes = 0
-            named = []
-            for entries in out.values():
-                for e in entries:
-                    p = e[1]
-                    if isinstance(p, OpenRow):
-                        named.append(p)
-                        continue
-                    dps += _ndp(e)
-                    if isinstance(p, (bytes, bytearray, memoryview)):
-                        nbytes += len(p)
-                    else:  # decoded (times, values) array pair
-                        nbytes += (getattr(p[0], "nbytes", 0)
-                                   + getattr(p[1], "nbytes", 0))
-            if named:
-                n = open_rows_samples(named)
-                dps += n
-                nbytes += 16 * n
+                    blocks.append(BlockRows(
+                        bs, STREAMS, [b or None for b in blobs], counts))
+            on_disk = len(blocks)
+            blocks.extend(shard.read_many(
+                shard_sids, shard_lanes, start_nanos, end_nanos,
+                with_counts=with_counts, defer_open=defer_open))
+            if 0 < on_disk < len(blocks):
+                blocks.sort(key=lambda b: b.block_start)
+            if budget or count_cost:
+                # datapoints scanned + bytes decoded, a pass a block
+                # (never per sample); sids are partitioned by shard, so
+                # each row is counted exactly once
+                for block in blocks:
+                    b_dps, b_bytes, b_named = _rows_cost(block)
+                    if b_named:
+                        n_open = open_rows_samples(b_named)
+                        b_dps += n_open
+                        b_bytes += 16 * n_open
+                    dp_fetched += b_dps
+                    nbytes += b_bytes
+        if count_cost:
+            # per-QUERY attribution, credited to the propagated tenant
+            # (fan-out RPC work) or the namespace
             attribution.account_read(tracing.current_tenant() or ns,
-                                     datapoints=dps,
+                                     datapoints=dp_fetched,
                                      decoded_bytes=nbytes)
-        return out
+        if meta is not None and budget:
+            meta.fetched_datapoints += dp_fetched
+        if with_counts and defer_open:
+            series.sort()  # by sid: they are distinct, nothing else is compared
+            return Gathered(series, scans)
+        if with_counts:
+            return {sid: [(b.block_start, b.payloads[k],
+                           b.counts[k] if b.counts else None)
+                          for b in blocks if b.payloads[k] is not None]
+                    for sid, _lane, blocks, k in series}
+        return {sid: [(b.block_start, b.payloads[k])
+                      for b in blocks if b.payloads[k] is not None]
+                for sid, _lane, blocks, k in series}
 
     # --- lifecycle (ref: storage/mediator.go tick+flush loops) ---
 
@@ -798,11 +867,9 @@ class Database:
             return  # already an open buffer: merges naturally
         # flushed-on-disk only (e.g. after a restart): pull the fileset
         # contents into a buffer and supersede it with the next volume
-        on_disk = dict(list_filesets(self.path / "data", ns,
-                                     shard.shard_id))
-        if bs not in on_disk:
+        vol = self._data_filesets(ns, shard).get(bs)
+        if vol is None:
             return
-        vol = on_disk[bs]
         reader = FilesetReader(self.path / "data", ns, shard.shard_id,
                                bs, vol)
         self._load_reader_into_buffers(n, shard, reader, bs)
@@ -850,16 +917,14 @@ class Database:
         sealed/flushed copy of the block — the AggregateTiles input
         gather (ref: shard.go:2659 reads flushed source blocks).  Runs
         under the database lock (the lazy shard-ordinal cache must not
-        race serving writes) and globs each shard directory once."""
+        race serving writes)."""
         n = self._ns(ns)
         out = []
         for shard_id in sorted(n.shards):
-            filesets = list_filesets(self.path / "data", ns, shard_id)
             for ordinal in n.ordinals_for_shard(shard_id):
                 sid = n.index.id_of(ordinal)
                 for b, payload in self.fetch_series(
-                        ns, sid, block_start, block_start + 1,
-                        _filesets=filesets):
+                        ns, sid, block_start, block_start + 1):
                     if b != block_start:
                         continue
                     if isinstance(payload, (bytes, bytearray)):
@@ -876,15 +941,13 @@ class Database:
         from m3_tpu.storage.peers import payload_checksum
 
         n = self._ns(ns)
-        filesets = list_filesets(self.path / "data", ns, shard_id)
         out = {}
         for ordinal in n.ordinals_for_shard(shard_id):
             sid = n.index.id_of(ordinal)
             blocks = [
                 (bs, *payload_checksum(payload))
                 for bs, payload in self.fetch_series(
-                    ns, sid, start_nanos, end_nanos,
-                    _filesets=filesets)]
+                    ns, sid, start_nanos, end_nanos)]
             if blocks:
                 out[sid] = (n.index.tags_of(ordinal), blocks)
         return out
@@ -929,6 +992,7 @@ class Database:
         self._seek.invalidate_where(
             lambda key: key[0] == ns and key[1] == shard_id)
         n.shards[shard_id] = Shard(shard_id, n.opts)
+        n.shards[shard_id].filesets = {}  # every volume was removed above
         return {"blocks": len(blocks), "bytes": freed_bytes}
 
     @_locked
@@ -976,11 +1040,10 @@ class Database:
                 # covers, so restart mmaps segments instead of
                 # re-reading every fileset's metadata
                 covered = [
-                    [shard_id, bs, vol]
-                    for shard_id in n.shards
-                    for bs, vol in list_filesets(
-                        self.path / "data", name, shard_id
-                    )
+                    [shard.shard_id, bs, vol]
+                    for shard in n.shards.values()
+                    for bs, vol in sorted(
+                        self._data_filesets(name, shard).items())
                 ]
                 n.index.persist(self.path / "index" / name, covered)
             store = self._struct_stores.get(name)
@@ -1060,8 +1123,7 @@ class Database:
         supersedes them) — ref: src/dbnode/storage/cleanup.go."""
         for name, n in self._namespaces.items():
             for shard in n.shards.values():
-                flushed = dict(list_filesets(self.path / "data", name,
-                                             shard.shard_id))
+                flushed = self._data_filesets(name, shard)
                 latest = dict(list_filesets(self.path / "snapshot", name,
                                             shard.shard_id))
                 # memory still holds WAL-only data for these blocks
@@ -1150,7 +1212,10 @@ class Database:
             shard_blocks: dict[int, set[int]] = {}
             shard_covers: dict[tuple[int, int], int] = {}
             for shard in n.shards.values():
-                for bs, vol in list_filesets(self.path / "data", name, shard.shard_id):
+                # the directory as the last process left it: from
+                # here on the shard's listing follows what is written
+                for bs, vol in sorted(
+                        self._scan_filesets(name, shard).items()):
                     shard_blocks.setdefault(shard.shard_id, set()).add(bs)
                     info = read_fileset_info(self.path / "data", name,
                                              shard.shard_id, bs, vol) or {}
@@ -1293,8 +1358,7 @@ class Database:
         snap_root = self.path / "snapshot"
         for name, n in self._namespaces.items():
             for shard in n.shards.values():
-                on_disk = dict(list_filesets(self.path / "data", name,
-                                             shard.shard_id))
+                on_disk = self._data_filesets(name, shard)
                 for bs, vol in list_filesets(snap_root, name, shard.shard_id):
                     try:
                         reader = FilesetReader(snap_root, name,

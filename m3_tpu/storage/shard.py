@@ -10,13 +10,13 @@ the sealed block as an immutable fileset.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
 
 from m3_tpu.ops import m3tsz_scalar
 from m3_tpu.storage.buffer import BlockBuffer, OpenRow
-from m3_tpu.storage.fileset import FilesetReader, FilesetWriter, list_filesets
+from m3_tpu.storage.fileset import FilesetWriter
 from m3_tpu.storage.namespace import NamespaceOptions
 from m3_tpu.utils import clock
 
@@ -144,6 +144,31 @@ class SealedBlock:
     # datapoints per stream (known at seal time); rides into the
     # fileset index (v2) so batch readers size decode grids exactly
     counts: list[int] | None = None
+    # sid -> row of ids/streams/counts: built with the block (Shard.seal
+    # makes every SealedBlock) and dropped with it (unseal), so the read
+    # path looks a row up and never searches `ids`
+    row_of: dict[bytes, int] = dataclasses.field(init=False, repr=False)
+
+    def __post_init__(self):
+        self.row_of = {sid: row for row, sid in enumerate(self.ids)}
+
+
+# what the rows of a BlockRows hold (every payload that is not None)
+STREAMS = "streams"  # compressed M3TSZ streams, `counts` beside them
+OPEN = "open"        # OpenRow: an open buffer's lane, named, not yet read
+ARRAYS = "arrays"    # (times, values) arrays, decoded or read from a buffer
+MIXED = "mixed"      # a cold write beside sealed streams: any, row by row
+
+
+class BlockRows(NamedTuple):
+    """One block's rows for the series a bulk read asked for: `payloads`
+    and `counts` are aligned with the series (None where a series has
+    nothing in the block; `counts` None where no row has a count)."""
+
+    block_start: int
+    kind: str
+    payloads: list
+    counts: list | None
 
 
 class Shard:
@@ -165,6 +190,11 @@ class Shard:
         # block is unsealed for a merge (repair / peer loads), so the
         # re-flush writes a NEW volume and readers pick the latest
         self._volume: dict[int, int] = {}
+        # the data filesets of this (namespace, shard) on disk,
+        # {block_start: latest volume}; None until the database, the
+        # only writer of that directory, has listed it once.  `flush`
+        # renews it with every fileset it writes, so reads never scan.
+        self.filesets: dict[int, int] | None = None
         from m3_tpu.utils import instrument
         # wall-clock distance of the newest accepted sample from now:
         # a rising value means writers are falling behind real time
@@ -351,75 +381,101 @@ class Shard:
                 covers_until=blk.sealed_at,
                 counts=blk.counts,
             )
+            if self.filesets is not None:
+                self.filesets[bs] = self._volume.get(bs, 0)
             self._flushed.add(bs)
             flushed.append(bs)
         return flushed
 
     # --- read path ---
 
-    def read_series(
-        self, series_id: bytes, lane: int, start_nanos: int, end_nanos: int,
-        with_counts: bool = False, defer_open: bool = False,
-    ) -> list[tuple]:
-        """In-memory data for [start, end): (block_start, payload) pairs,
-        payload either (times, values) arrays from an open buffer or a
-        compressed stream from a sealed block.  Flushed filesets are read
-        at the Database level (it owns the namespace paths).
+    def read_many(
+        self, sids: list[bytes], lanes: list[int], start_nanos: int,
+        end_nanos: int, with_counts: bool = False,
+        defer_open: bool = False,
+    ) -> list[BlockRows]:
+        """In-memory data of [start, end) for the series `sids` (index
+        ordinals `lanes`), block by block, block starts ascending: one
+        ``BlockRows`` per block in which any of them has data.  Flushed
+        filesets are read at the Database level (it owns the paths).
 
-        ``defer_open=True`` (the engine's bulk gather) names a plain
-        open-buffer read as an ``OpenRow`` on the buffer's consolidated
-        view instead of cutting it out here: the caller reads all its
-        lanes of a view in one call, after the database lock, and drops
-        the rows that turn out empty.  A block with a sealed stream AND
-        a buffer (a cold write after seal) is merged here either way.
+        The ONE implementation of the in-memory block-selection and
+        merge rules; ``read_series`` is its one-series call.  A sealed
+        block's rows come through the table the seal built
+        (``SealedBlock.row_of``), an open buffer's from one ``view()``
+        of it.  ``defer_open=True`` (the engine's bulk gather) names a
+        plain open-buffer read as an ``OpenRow`` on that view instead
+        of cutting it out here: the caller reads all its lanes of a
+        view in one call, after the database lock, and drops the rows
+        that turn out empty.  A series with a sealed stream AND samples
+        in a buffer of the same block (a cold write after the seal) is
+        merged here either way, buffer winning a duplicate timestamp.
 
-        ``with_counts=True`` emits (block_start, payload, n_dp_or_None)
-        triples — the count is produced HERE, alongside the payload it
-        describes (a sealed stream's dp count), never re-derived by a
-        caller from separate state."""
-        ret = self.opts.retention
-        out: list[tuple[int, object]] = []
-        first = start_nanos - (start_nanos % ret.block_size)
-        # iterate only block starts that hold data — walking the whole
-        # [start, end) range block-by-block is O(range/block_size) and
-        # an open-ended query (end = +inf sentinel) would spin through
-        # millions of empty 2h steps
-        candidates = sorted(
-            bs for bs in set(self._sealed) | set(self._buffers)
-            if first <= bs < end_nanos
-        )
-        for bs in candidates:
-            sealed_stream = sealed_count = None
-            if bs in self._sealed:
-                blk = self._sealed[bs]
-                try:
-                    idx = blk.ids.index(series_id)
-                    sealed_stream = blk.streams[idx]
-                    if blk.counts is not None:
-                        sealed_count = blk.counts[idx]
-                except ValueError:
-                    pass
-            buf_ts = buf_vs = None
-            if bs in self._buffers:
-                if defer_open and sealed_stream is None:
-                    row = OpenRow(self._buffers[bs].view(), lane)
-                    out.append((bs, row, None) if with_counts
-                               else (bs, row))
+        ``with_counts=True`` fills ``counts`` with a sealed stream's
+        datapoint count, produced HERE beside the payload it describes,
+        never re-derived by a caller from separate state."""
+        first = start_nanos - start_nanos % self.opts.retention.block_size
+        sealed, buffers = self._sealed, self._buffers
+        out: list[BlockRows] = []
+        # only block starts that hold data: walking [start, end) block
+        # by block is O(range/block_size), and an open-ended query
+        # (end = +inf sentinel) would spin through millions of empty
+        # 2h steps
+        for bs in sorted(bs for bs in sealed.keys() | buffers.keys()
+                         if first <= bs < end_nanos):
+            blk, buf = sealed.get(bs), buffers.get(bs)
+            streams = counts = None
+            if blk is not None:
+                rows = [blk.row_of.get(sid) for sid in sids]
+                streams = [None if r is None else blk.streams[r]
+                           for r in rows]
+                if with_counts and blk.counts is not None:
+                    counts = [None if r is None else blk.counts[r]
+                              for r in rows]
+                if streams.count(None) == len(streams):
+                    streams = counts = None
+            if buf is None:
+                if streams is not None:
+                    out.append(BlockRows(bs, STREAMS, streams, counts))
+            elif streams is None:
+                view = buf.view()
+                if defer_open:
+                    out.append(BlockRows(
+                        bs, OPEN, [OpenRow(view, lane) for lane in lanes],
+                        None))
                     continue
+                read = [tv if len(tv[0]) else None
+                        for tv in view.read_lanes(lanes)]
+                if read.count(None) < len(read):
+                    out.append(BlockRows(bs, ARRAYS, read, None))
+            else:
                 # a cold write after seal lands in a fresh buffer
                 # alongside the sealed block — reads must see both
                 # (ref: buffer bucket versions, buffer.go:221)
-                ts, vs = self._buffers[bs].read_lane(lane)
-                if len(ts):
-                    buf_ts, buf_vs = ts, vs
-            if sealed_stream is not None and buf_ts is not None:
-                # read-time merge: duplicate timestamps resolve to the
-                # buffer (newer write) — the reference's bucket-version
-                # merge; without it a rewrite-after-seal would surface
-                # two values at one timestamp
-                from m3_tpu.ops import m3tsz_scalar as tsz
+                out.append(self._read_cold_overlay(
+                    bs, buf.view(), lanes, streams, counts, defer_open))
+        return out
 
-                st, sv = tsz.decode_series(sealed_stream)
+    @staticmethod
+    def _read_cold_overlay(bs: int, view, lanes, streams, counts,
+                           defer_open: bool) -> BlockRows:
+        """The rows of a block that holds sealed streams and a buffer:
+        a series in both is merged at read time, duplicate timestamps
+        resolving to the buffer (the newer write) — the reference's
+        bucket-version merge; without it a rewrite-after-seal would
+        surface two values at one timestamp."""
+        payloads: list = list(streams)
+        counts = list(counts) if counts is not None else [None] * len(lanes)
+        for i, (lane, stream, (buf_ts, buf_vs)) in enumerate(
+                zip(lanes, streams, view.read_lanes(lanes))):
+            if stream is None and defer_open:
+                payloads[i] = OpenRow(view, lane)
+            elif not len(buf_ts):
+                continue  # the sealed stream alone, or nothing
+            elif stream is None:
+                payloads[i] = (buf_ts, buf_vs)
+            else:
+                st, sv = m3tsz_scalar.decode_series(stream)
                 mt = np.concatenate([np.asarray(st, np.int64), buf_ts])
                 mv = np.concatenate([np.asarray(sv, np.float64), buf_vs])
                 order = np.argsort(mt, kind="stable")
@@ -427,15 +483,32 @@ class Shard:
                 if len(mt) > 1:
                     keep = np.concatenate([mt[:-1] != mt[1:], [True]])
                     mt, mv = mt[keep], mv[keep]
-                out.append((bs, (mt, mv), None) if with_counts
-                           else (bs, (mt, mv)))
-            elif sealed_stream is not None:
-                out.append((bs, sealed_stream, sealed_count)
-                           if with_counts else (bs, sealed_stream))
-            elif buf_ts is not None:
-                out.append((bs, (buf_ts, buf_vs), None) if with_counts
-                           else (bs, (buf_ts, buf_vs)))
+                payloads[i], counts[i] = (mt, mv), None
+        return BlockRows(bs, MIXED, payloads, counts)
+
+    def read_series(
+        self, series_id: bytes, lane: int, start_nanos: int, end_nanos: int,
+        with_counts: bool = False, defer_open: bool = False,
+    ) -> list[tuple]:
+        """``read_many`` for one series: (block_start, payload) pairs,
+        payload a compressed stream from a sealed block, (times, values)
+        arrays from an open buffer (an ``OpenRow`` under ``defer_open``)
+        or the read-time merge of both; with ``with_counts=True``
+        (block_start, payload, n_dp_or_None) triples."""
+        out = []
+        for bs, _kind, payloads, counts in self.read_many(
+                [series_id], [lane], start_nanos, end_nanos,
+                with_counts=with_counts, defer_open=defer_open):
+            if payloads[0] is None:
+                continue
+            out.append((bs, payloads[0], counts[0] if counts else None)
+                       if with_counts else (bs, payloads[0]))
         return out
+
+    def holds_block(self, block_start: int) -> bool:
+        """Memory has a copy of the block (sealed or open): it wins
+        over the fileset of the same block start."""
+        return block_start in self._sealed or block_start in self._buffers
 
     def open_block_starts(self) -> list[int]:
         return sorted(self._buffers)
