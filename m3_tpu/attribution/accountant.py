@@ -22,8 +22,8 @@ and flow to ``_m3_internal`` via the existing self-scrape.
 
 Every hook is request- or batch-scoped (never per-sample) and
 early-returns when attribution is disabled (``M3_ATTRIBUTION=0`` or
-``attribution.enabled: false`` in config), which is what the bench.py
-``attribution`` side leg toggles to assert <= 3% overhead.
+``attribution.enabled: false`` in config);
+``tests/test_attribution.py`` holds the write path to one call a batch.
 """
 
 from __future__ import annotations

@@ -18,10 +18,11 @@ tests/test_query_pipeline_device.py; precision notes follow the decode
 kernel's contract (integer state exact on all backends, f64 emission
 exact on CPU, ~1 ulp on emulated-f64 accelerators).
 
-Sharded entry: `device_rate_sharded` runs the same program under
-`shard_map` over the series axis of a mesh — streams of a slot must be
-placed on one shard (slots are data-parallel), and fleet aggregates
-(`sum(rate(...))`) reduce with one `psum` over ICI.
+Two per-node entry points, `device_temporal_pipeline` and
+`device_grouped_pipeline`; given a `mesh` either runs the same program
+under `shard_map` over the series axis — streams of a slot must be
+placed on one shard (slots are data-parallel), and grouped aggregates
+(`sum by (..)(rate(...))`) reduce with one collective over ICI.
 """
 
 from __future__ import annotations
@@ -807,47 +808,80 @@ def _temporal_eval(fn: str, times, values, steps, range_nanos,
     return _reduce_device(times, values, steps, range_nanos, fn)
 
 
-@instrument_kernel("device_reduce_pipeline")
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_lanes", "n_cap", "reducer", "unit_nanos",
-                     "n_dp", "n_tiers", "hw_sf", "hw_tf"))
-def device_reduce_pipeline(
-    words: jax.Array,
-    nbits: jax.Array,
-    slots: jax.Array,
-    steps: jax.Array,
-    n_lanes: int,
-    n_cap: int,
-    range_nanos,           # traced: not a jit cache key
-    reducer: str = "sum_over_time",
-    unit_nanos: int = xtime.SECOND,
-    n_dp: int | None = None,
-    tiers: jax.Array | None = None,  # [M] dense tier ranks, 0 finest
-    n_tiers: int = 1,
-    horizon=0.0,           # traced: predict_linear's seconds-ahead arg
-    hw_sf: float = 0.5,    # static: holt_winters smoothing factors
-    hw_tf: float = 0.5,    # (fixed per dashboard; fold into the program)
-    phi=0.5,               # traced: quantile_over_time's parameter
-    open_rows=None,        # rows that arrive as arrays (_decode_merge)
-):
-    """Compressed blocks -> *_over_time matrix, entirely on device.
-    Returns (out f64[n_lanes, S], error bool[M]) with the same error
-    contract as device_rate_pipeline."""
+def _per_node(words, nbits, slots, steps, groups, tiers, open_rows,
+              range_nanos, horizon, phi, *, n_lanes, n_cap, n_dp,
+              unit_nanos, n_tiers, fn, hw_sf, hw_tf, n_groups, agg,
+              axis=None):
+    """The per-node program, one body for both entry points and for one
+    chip and a mesh: decode + merge, the windowed temporal function,
+    and with `groups` the lane reduction (over `axis` when the lanes
+    are sharded)."""
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
                                          tiers, n_tiers, open_rows)
-    out = _temporal_eval(reducer, times, values, steps, range_nanos,
-                         horizon, hw_sf, hw_tf, phi)
-    return out, error
+    if groups is None:
+        return _temporal_eval(fn, times, values, steps, range_nanos,
+                              horizon, hw_sf, hw_tf, phi), error
+    if fn in ("predict_linear", "holt_winters", "quantile_over_time"):
+        # parameterized temporals never reach the grouped form (the
+        # engine's grouped-child gate is single-arg); keep the trace-time
+        # error so a future routing bug falls back instead of serving a
+        # default-parameter answer
+        raise ValueError(f"no grouped device form for {fn}")
+    out = _temporal_eval(fn, times, values, steps, range_nanos)
+    return _grouped_reduce(out, groups, n_groups, agg, phi, axis=axis), error
 
 
-@instrument_kernel("device_rate_pipeline")
+def _per_node_on(mesh, words, nbits, slots, steps, groups, tiers,
+                 open_rows, range_nanos, horizon, phi, *, n_lanes,
+                 **static):
+    """`_per_node` as it stands (mesh None: no shard_map, no
+    collective), or series-sharded over `mesh`: each shard decodes and
+    merges its lane range and runs the windowed kernel locally (the
+    multi-tier stitch cut is per slot, so it shards cleanly too), and
+    only the grouped form's lane reduction crosses ICI.  Inputs are
+    then shard-even row blocks: equal stream rows and equal lanes per
+    shard, slots LOCAL per shard, group ids GLOBAL.  The temporal
+    matrix and the error come back sharded by series, the grouped
+    matrix replicated."""
+    if mesh is None:
+        return _per_node(words, nbits, slots, steps, groups, tiers,
+                         open_rows, range_nanos, horizon, phi,
+                         n_lanes=n_lanes, **static)
+    # the open arrays are not sharded by lane yet (ROADMAP Queue 2 A3):
+    # the engine declines them (open_rows_sharded) before it gets here
+    assert open_rows is None, "open rows have no sharded form"
+    n_shards = mesh.shape[SERIES_AXIS]
+    assert n_lanes % n_shards == 0
+    if tiers is None:
+        tiers = jnp.zeros_like(nbits, dtype=jnp.int64)
+    rows = P(SERIES_AXIS)
+
+    @functools.partial(
+        shard_map,
+        mesh=mesh,
+        in_specs=(P(SERIES_AXIS, None), rows, rows, P(),
+                  None if groups is None else rows, rows, P(), P(), P()),
+        out_specs=(P(SERIES_AXIS, None) if groups is None else P(), rows),
+        check_vma=False,
+    )
+    def step(words_l, nbits_l, slots_l, steps_l, groups_l, tiers_l,
+             range_l, horizon_l, phi_l):
+        return _per_node(words_l, nbits_l, slots_l, steps_l, groups_l,
+                         tiers_l, None, range_l, horizon_l, phi_l,
+                         n_lanes=n_lanes // n_shards, axis=SERIES_AXIS,
+                         **static)
+
+    return step(words, nbits, slots, steps, groups, tiers,
+                *(jnp.asarray(x) for x in (range_nanos, horizon, phi)))
+
+
+@instrument_kernel("device_temporal_pipeline")
 @functools.partial(
     jax.jit,
-    static_argnames=("n_lanes", "n_cap", "is_counter",
-                     "is_rate", "unit_nanos", "n_dp", "n_tiers"))
-def device_rate_pipeline(
+    static_argnames=("n_lanes", "n_cap", "fn", "unit_nanos", "n_dp",
+                     "n_tiers", "hw_sf", "hw_tf", "mesh"))
+def device_temporal_pipeline(
     words: jax.Array,      # [M, W] packed compressed block streams
     nbits: jax.Array,      # [M]
     slots: jax.Array,      # [M] output lane per stream (grouped asc)
@@ -857,93 +891,36 @@ def device_rate_pipeline(
     range_nanos,           # TRACED scalar: per-query window duration
     #  must not key the jit cache — arbitrary rate(x[93s]) ranges would
     #  each force a full XLA recompile on the serving path
-    is_counter: bool = True,
-    is_rate: bool = True,
+    fn: str = "rate",
     unit_nanos: int = xtime.SECOND,
     n_dp: int | None = None,  # static max samples per STREAM (block)
     tiers: jax.Array | None = None,  # [M] dense tier ranks, 0 finest
     n_tiers: int = 1,
+    horizon=0.0,           # traced: predict_linear's seconds-ahead arg
+    hw_sf: float = 0.5,    # static: holt_winters smoothing factors
+    hw_tf: float = 0.5,    # (fixed per dashboard; fold into the program)
+    phi=0.5,               # traced: quantile_over_time's parameter
     open_rows=None,        # rows that arrive as arrays (_decode_merge)
+    mesh: Mesh | None = None,  # series-sharded over it (_per_node_on)
 ):
-    """Compressed blocks -> per-series windowed rate, entirely on
-    device.  Returns (rate f64[n_lanes, S], fleet_sum f64[S],
-    error bool[M]; bool[M + R] with open_rows).
+    """Compressed blocks -> the [n_lanes, S] matrix of any windowed
+    temporal function (_temporal_eval), entirely on device.  Returns
+    (out f64[n_lanes, S], error bool[M]; bool[M + R] with open_rows).
 
     `n_dp` bounds one stream (one sealed block); `n_cap` bounds one
     output lane (all of a series' blocks).  Decoding at block width and
     merging into the lane budget keeps the decode grid at
     [streams, n_dp] instead of [streams, n_cap] — on a 6h/2h-block
     fan-out that is 3x less decode work and HBM traffic."""
-    times, values, error = _decode_merge(words, nbits, slots, n_lanes,
-                                         n_cap, n_dp, unit_nanos,
-                                         tiers, n_tiers, open_rows)
-    with jax.named_scope("m3.temporal"):
-        rate = _rate_device(times, values, steps, range_nanos,
-                            is_counter, is_rate)
-        fleet = jnp.nansum(rate, axis=0)
-    return rate, fleet, error
+    return _per_node_on(mesh, words, nbits, slots, steps, None, tiers,
+                        open_rows, range_nanos, horizon, phi,
+                        n_lanes=n_lanes, n_cap=n_cap, n_dp=n_dp,
+                        unit_nanos=unit_nanos, n_tiers=n_tiers, fn=fn,
+                        hw_sf=hw_sf, hw_tf=hw_tf, n_groups=0, agg=None)
 
 
 DEVICE_GROUP_AGGS = ("sum", "avg", "min", "max", "count", "group",
                      "stddev", "stdvar", "quantile")
-
-
-@jax.named_scope("m3.group")
-def _grouped_reduce_sharded(out, groups_l, n_groups: int, agg: str,
-                            phi, axis: str):
-    """Sharded counterpart of _grouped_reduce, shared by the per-node
-    grouped pipeline and the fused expression interpreter: each shard
-    segment-reduces its local lanes and the [n_groups, S] partials
-    combine over ICI with the collective matching the aggregation —
-    psum for the additive moments, pmin/pmax for the order statistics,
-    two psums for stddev/stdvar (global mean first, then the shifted
-    squared deviations).  quantile has no partial-combining form at
-    all, but the matrix being ranked is the REDUCED [lanes, steps]
-    temporal result — small enough to all_gather over ICI — after
-    which the per-step lane sort runs identically on every shard.
-
-    `groups_l` holds GLOBAL group ids for this shard's local lanes;
-    the result is replicated."""
-    if agg == "quantile":
-        out_all = jax.lax.all_gather(out, axis, axis=0,
-                                     tiled=True)  # [n_lanes, S]
-        groups_all = jax.lax.all_gather(groups_l, axis, axis=0,
-                                        tiled=True)
-        return _grouped_quantile(out_all, groups_all, n_groups, phi)
-    m = ~jnp.isnan(out)
-    vz = jnp.where(m, out, 0.0)
-    sums = jax.lax.psum(
-        jax.ops.segment_sum(vz, groups_l, num_segments=n_groups), axis)
-    counts = jax.lax.psum(
-        jax.ops.segment_sum(m.astype(out.dtype), groups_l,
-                            num_segments=n_groups), axis)
-    if agg == "sum":
-        g = sums
-    elif agg == "count":
-        g = counts
-    elif agg == "avg":
-        g = sums / jnp.maximum(counts, 1.0)
-    elif agg == "min":
-        g = jax.lax.pmin(
-            jax.ops.segment_min(jnp.where(m, out, jnp.inf), groups_l,
-                                num_segments=n_groups), axis)
-    elif agg == "max":
-        g = jax.lax.pmax(
-            jax.ops.segment_max(jnp.where(m, out, -jnp.inf), groups_l,
-                                num_segments=n_groups), axis)
-    elif agg == "group":
-        g = jnp.ones_like(sums)
-    elif agg in ("stddev", "stdvar"):
-        mean = sums / jnp.maximum(counts, 1.0)
-        d = jnp.where(m, out - mean[groups_l], 0.0)
-        var = (jax.lax.psum(
-            jax.ops.segment_sum(d * d, groups_l,
-                                num_segments=n_groups),
-            axis) / jnp.maximum(counts, 1.0))
-        g = jnp.sqrt(var) if agg == "stddev" else var
-    else:
-        raise ValueError(f"no device form for aggregation {agg}")
-    return jnp.where(counts == 0, jnp.nan, g)
 
 
 def _grouped_quantile(out, groups, n_groups: int, phi):
@@ -989,9 +966,9 @@ def _grouped_quantile(out, groups, n_groups: int, phi):
     q = v_lo + (v_hi - v_lo) * frac
     return jnp.where(npres > 0, q, jnp.nan)
 
-
 @jax.named_scope("m3.group")
-def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5):
+def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5,
+                    axis: str | None = None):
     """Segment-reduce a served [L, S] temporal matrix over the lane axis
     by group id — the device form of the engine's _eval_agg loop
     (upstream semantics per src/query/functions/aggregation/function.go:
@@ -1001,12 +978,32 @@ def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5):
 
     Lanes whose row is all-NaN (e.g. jit-padding lanes) contribute
     nothing to any group, so callers may park padding lanes on an
-    arbitrary group id."""
+    arbitrary group id.
+
+    With `axis` (the lanes sharded over that mesh axis, inside a
+    shard_map; `groups` the GLOBAL ids of this shard's lanes) each
+    shard segment-reduces its local lanes and the [n_groups, S]
+    partials combine over ICI with the collective matching the
+    aggregation — psum for the additive moments, pmin/pmax for the
+    order statistics, two psums for stddev/stdvar (global mean first,
+    then the shifted squared deviations).  quantile has no
+    partial-combining form at all, but the matrix being ranked is the
+    REDUCED [lanes, steps] temporal result — small enough to all_gather
+    over ICI — after which the per-step lane sort runs identically on
+    every shard.  The result is replicated.  Without it no collective
+    is emitted: the one-chip program."""
+    def across(x, collective=jax.lax.psum):
+        return x if axis is None else collective(x, axis)
+
+    if agg == "quantile" and axis is not None:
+        out, groups = (jax.lax.all_gather(x, axis, axis=0, tiled=True)
+                       for x in (out, groups))
+        axis = None
     m = ~jnp.isnan(out)
     vz = jnp.where(m, out, 0.0)
-    sums = jax.ops.segment_sum(vz, groups, num_segments=n_groups)
-    counts = jax.ops.segment_sum(m.astype(out.dtype), groups,
-                                 num_segments=n_groups)
+    sums = across(jax.ops.segment_sum(vz, groups, num_segments=n_groups))
+    counts = across(jax.ops.segment_sum(m.astype(out.dtype), groups,
+                                        num_segments=n_groups))
     if agg == "sum":
         g = sums
     elif agg == "count":
@@ -1014,17 +1011,20 @@ def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5):
     elif agg == "avg":
         g = sums / jnp.maximum(counts, 1.0)
     elif agg == "min":
-        g = jax.ops.segment_min(jnp.where(m, out, jnp.inf), groups,
-                                num_segments=n_groups)
+        g = across(jax.ops.segment_min(jnp.where(m, out, jnp.inf), groups,
+                                       num_segments=n_groups),
+                   jax.lax.pmin)
     elif agg == "max":
-        g = jax.ops.segment_max(jnp.where(m, out, -jnp.inf), groups,
-                                num_segments=n_groups)
+        g = across(jax.ops.segment_max(jnp.where(m, out, -jnp.inf), groups,
+                                       num_segments=n_groups),
+                   jax.lax.pmax)
     elif agg == "group":
         g = jnp.ones_like(sums)
     elif agg in ("stddev", "stdvar"):
         mean = sums / jnp.maximum(counts, 1.0)
         d = jnp.where(m, out - mean[groups], 0.0)
-        var = (jax.ops.segment_sum(d * d, groups, num_segments=n_groups)
+        var = (across(jax.ops.segment_sum(d * d, groups,
+                                          num_segments=n_groups))
                / jnp.maximum(counts, 1.0))
         g = jnp.sqrt(var) if agg == "stddev" else var
     elif agg == "quantile":
@@ -1038,7 +1038,7 @@ def _grouped_reduce(out, groups, n_groups: int, agg: str, phi=0.5):
 @functools.partial(
     jax.jit,
     static_argnames=("n_lanes", "n_groups", "n_cap", "fn", "agg",
-                     "unit_nanos", "n_dp", "n_tiers"))
+                     "unit_nanos", "n_dp", "n_tiers", "mesh"))
 def device_grouped_pipeline(
     words: jax.Array,
     nbits: jax.Array,
@@ -1057,160 +1057,21 @@ def device_grouped_pipeline(
     n_tiers: int = 1,
     phi=0.5,               # traced: quantile()'s parameter
     open_rows=None,        # rows that arrive as arrays (_decode_merge)
+    mesh: Mesh | None = None,  # series-sharded over it (_per_node_on)
 ):
     """Compressed blocks -> `agg by (...) (fn(x[range]))` matrix,
-    entirely on device: the rate/reduce pipeline fused with the grouped
+    entirely on device: the temporal pipeline fused with the grouped
     lane reduction so only the [n_groups, S] result (not the
     [n_lanes, S] intermediate) ever crosses the PCIe/DCN boundary —
     dashboards aggregate thousands of lanes into a handful of groups,
     making this the transfer-optimal serving form.  Returns
     (out f64[n_groups, S], error bool[M]) with the shared error
     contract (_decode_merge)."""
-    times, values, error = _decode_merge(words, nbits, slots, n_lanes,
-                                         n_cap, n_dp, unit_nanos,
-                                         tiers, n_tiers, open_rows)
-    if fn in ("predict_linear", "holt_winters", "quantile_over_time"):
-        # parameterized temporals never reach the grouped form (the
-        # engine's grouped-child gate is single-arg); keep the trace-time
-        # error so a future routing bug falls back instead of serving a
-        # default-parameter answer
-        raise ValueError(f"no grouped device form for {fn}")
-    out = _temporal_eval(fn, times, values, steps, range_nanos)
-    return _grouped_reduce(out, groups, n_groups, agg, phi), error
-
-
-def device_temporal_sharded(mesh: Mesh, words, nbits, slots, steps,
-                            n_lanes: int, n_cap: int, range_nanos,
-                            fn: str = "rate",
-                            unit_nanos: int = xtime.SECOND,
-                            n_dp: int | None = None,
-                            tiers=None, n_tiers: int = 1,
-                            horizon=0.0,
-                            hw_sf: float = 0.5, hw_tf: float = 0.5,
-                            phi=0.5):
-    """Any device-servable temporal function series-sharded over a
-    mesh: each shard decodes+merges its lane range and runs the
-    windowed kernel locally (no collectives — per-series results are
-    embarrassingly parallel, and the multi-tier stitch cut is per-slot
-    so it shards cleanly too; the grouped/fleet forms add the ICI
-    reduction).  Inputs are shard-even row blocks (equal stream rows
-    and equal lanes per shard; slots LOCAL per shard).
-
-    Returns (out f64[n_lanes, S] sharded by series, error bool[M]
-    sharded by series)."""
-    n_shards = mesh.shape[SERIES_AXIS]
-    assert n_lanes % n_shards == 0
-    local_lanes = n_lanes // n_shards
-    if tiers is None:
-        tiers = jnp.zeros_like(nbits, dtype=jnp.int64)
-
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS), P(SERIES_AXIS),
-                  P(), P(SERIES_AXIS)),
-        out_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS)),
-        check_vma=False,
-    )
-    def step(words_l, nbits_l, slots_l, steps_l, tiers_l):
-        times, values, error = _decode_merge(
-            words_l, nbits_l, slots_l, local_lanes, n_cap, n_dp,
-            unit_nanos, tiers_l, n_tiers)
-        out = _temporal_eval(fn, times, values, steps_l, range_nanos,
-                             horizon, hw_sf, hw_tf, phi)
-        return out, error
-
-    return step(words, nbits, slots, steps, tiers)
-
-
-def device_grouped_sharded(mesh: Mesh, words, nbits, slots, steps,
-                           groups, n_lanes: int, n_groups: int,
-                           n_cap: int, range_nanos,
-                           fn: str = "rate", agg: str = "sum",
-                           unit_nanos: int = xtime.SECOND,
-                           n_dp: int | None = None,
-                           tiers=None, n_tiers: int = 1,
-                           phi=0.5):
-    """Grouped serving over a series-sharded mesh: lanes (and their
-    streams) are split by shard, group ids are GLOBAL, and the
-    [n_groups, S] partials combine over ICI with the collective that
-    matches the aggregation (psum for the additive moments, pmin/pmax
-    for the order statistics).  stddev/stdvar need the global mean
-    before the second pass, so the moment psum runs first and the
-    shifted squared deviations reduce in a second psum — still one
-    program, two small collectives.  quantile has no partial-combining
-    form at all — but the matrix being ranked is the REDUCED
-    [lanes, steps] temporal result, small enough to all_gather over
-    ICI (a dashboard fan-out gathers megabytes, not the raw samples),
-    after which the per-step lane sort runs identically on every
-    shard.
-
-    Returns (out f64[n_groups, S] replicated, error bool[M] sharded)."""
-    n_shards = mesh.shape[SERIES_AXIS]
-    assert n_lanes % n_shards == 0
-    local_lanes = n_lanes // n_shards
-    if tiers is None:
-        tiers = jnp.zeros_like(nbits, dtype=jnp.int64)
-
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS), P(SERIES_AXIS),
-                  P(), P(SERIES_AXIS), P(SERIES_AXIS)),
-        out_specs=(P(), P(SERIES_AXIS)),
-        check_vma=False,
-    )
-    def step(words_l, nbits_l, slots_l, steps_l, groups_l, tiers_l):
-        times, values, error = _decode_merge(
-            words_l, nbits_l, slots_l, local_lanes, n_cap, n_dp,
-            unit_nanos, tiers_l, n_tiers)
-        if fn in ("predict_linear", "holt_winters",
-                  "quantile_over_time"):
-            raise ValueError(f"no grouped device form for {fn}")
-        out = _temporal_eval(fn, times, values, steps_l, range_nanos)
-        return (_grouped_reduce_sharded(out, groups_l, n_groups, agg,
-                                        phi, SERIES_AXIS), error)
-
-    return step(words, nbits, slots, steps, groups, tiers)
-
-
-def device_rate_sharded(mesh: Mesh, words, nbits, slots, steps,
-                        n_lanes: int, n_cap: int, range_nanos,
-                        is_counter: bool = True, is_rate: bool = True,
-                        unit_nanos: int = xtime.SECOND,
-                        n_dp: int | None = None):
-    """The same pipeline series-sharded over a mesh: each shard owns a
-    contiguous lane range (all of a slot's streams live on one shard —
-    the engine's shard routing already guarantees that), and the fleet
-    aggregate reduces with one `psum` over ICI.
-
-    Inputs must be pre-sharded row-blocks: words/nbits/slots split
-    evenly by stream rows, slots LOCAL to each shard (0-based per
-    shard).  Returns (rate [n_lanes, S] sharded by series, fleet [S]
-    replicated, error bool[M] sharded by series — truncation/overflow
-    flags, same contract as the unsharded entry point)."""
-    n_shards = mesh.shape[SERIES_AXIS]
-    assert n_lanes % n_shards == 0
-    local_lanes = n_lanes // n_shards
-
-    @functools.partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(SERIES_AXIS, None), P(SERIES_AXIS), P(SERIES_AXIS),
-                  P()),
-        out_specs=(P(SERIES_AXIS, None), P(), P(SERIES_AXIS)),
-        check_vma=False,
-    )
-    def step(words_l, nbits_l, slots_l, steps_l):
-        rate_l, fleet_l, err_l = device_rate_pipeline(
-            words_l, nbits_l, slots_l, steps_l,
-            n_lanes=local_lanes, n_cap=n_cap, range_nanos=range_nanos,
-            is_counter=is_counter, is_rate=is_rate,
-            unit_nanos=unit_nanos, n_dp=n_dp)
-        fleet = jax.lax.psum(fleet_l, SERIES_AXIS)
-        return rate_l, fleet, err_l
-
-    return step(words, nbits, slots, steps)
+    return _per_node_on(mesh, words, nbits, slots, steps, groups, tiers,
+                        open_rows, range_nanos, 0.0, phi,
+                        n_lanes=n_lanes, n_cap=n_cap, n_dp=n_dp,
+                        unit_nanos=unit_nanos, n_tiers=n_tiers, fn=fn,
+                        hw_sf=0.5, hw_tf=0.5, n_groups=n_groups, agg=agg)
 
 
 # --------------------------------------------------------------------
@@ -1600,11 +1461,9 @@ def _expr_eval(plan, leaves, params, steps, errors,
             _, op, g_pad, pidx, child = node
             cv, _cvalid = ev(child, steps_cur)
             groups, gvalid, phi = params[pidx]
-            if axis is not None and _plan_sharded(child):
-                out = _grouped_reduce_sharded(cv, groups, g_pad, op,
-                                              phi, axis)
-            else:
-                out = _grouped_reduce(cv, groups, g_pad, op, phi)
+            out = _grouped_reduce(
+                cv, groups, g_pad, op, phi,
+                axis=axis if _plan_sharded(child) else None)
             return jnp.where(gvalid[:, None], out, jnp.nan), gvalid
         if tag == "call":
             _, fn, pidx, child = node

@@ -251,8 +251,8 @@ def kernels() -> dict[str, InstrumentedKernel]:
 def snapshot() -> dict[str, dict]:
     """{kernel: stats()} — read before and after a window by
     benchmark/traffic_kinds/query_closed_loop.py (its delta is what
-    benchmark/readers/kernel_telemetry.py reads), by chip_smoke.py's
-    phase lines and by bench.py's kernel table."""
+    benchmark/readers/kernel_telemetry.py reads) and by chip_smoke.py's
+    phase lines."""
     with _KERNELS_LOCK:
         items = list(_KERNELS.items())
     return {name: k.stats() for name, k in items}

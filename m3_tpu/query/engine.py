@@ -1264,7 +1264,7 @@ class Engine:
         words, nbits = pk["words"][:m], pk["nbits"][:m]
         slots = pk["slots"][:m]
         tiers = None if pk["tiers"] is None else pk["tiers"][:m]
-        local_lanes = self._bucket(-(-pk["lanes_pad"] // n_shards), 8)
+        local_lanes = self._shard_lanes(pk["lanes_pad"], n_shards)
         lanes_pad = local_lanes * n_shards
         shard_ids = slots // local_lanes
         counts = np.bincount(shard_ids, minlength=n_shards)
@@ -1293,6 +1293,13 @@ class Engine:
                 "slots": slots_s, "lanes_pad": lanes_pad,
                 "tiers": tiers_s, "real_rows": real}
 
+    def _shard_lanes(self, lanes_pad: int, n_shards: int) -> int:
+        """Lanes a shard holds of a packed batch: all of them on one
+        chip, an even split once _shard_repack has re-laid it."""
+        if n_shards == 1:
+            return lanes_pad
+        return self._bucket(-(-lanes_pad // n_shards), 8)
+
     def _serving_shards(self) -> int:
         from m3_tpu.parallel.mesh import SERIES_AXIS
         mesh = self.serving_mesh
@@ -1312,95 +1319,81 @@ class Engine:
     _QOT_GRID_TEMPORARIES = 5
     _QOT_MAX_ELEMENTS = _QOT_HBM_BUDGET_BYTES // (8 * _QOT_GRID_TEMPORARIES)
 
-    def _device_temporal(self, rv, step_times, fn: str,
-                         range_nanos=None, horizon: float = 0.0,
-                         hw_sf: float = 0.5, hw_tf: float = 0.5,
-                         phi: float = 0.5):
-        """Serve a temporal function entirely on the accelerator: the
-        fused decode -> merge -> windowed kernel pipelines
-        (models/query_pipeline), compressed blocks in,
-        [series, steps] out — the HBM-resident read path.  With a
-        serving_mesh, the shard_map'd variant spreads lanes over the
-        series axis of the mesh.
-
-        Returns (labels, out) or None to fall back to the host tier
-        (mixed/mutable payloads, multi-tier stitch, unknown counts, or
-        any per-stream decode error flagged by the device)."""
+    def _device_pack(self, rv, step_times, range_nanos):
+        """Front half of the per-node device tier's one dispatch:
+        gather and pack `rv`.  Returns (pk, n_shards), the batch as it
+        was packed (before any shard re-lay) and the serving mesh's
+        series shards, or None to fall back to the host tier (what
+        _device_gather_pack cannot pack; open rows with a mesh), the
+        cause counted."""
         pk, why = self._device_gather_pack(rv, step_times, range_nanos)
         if pk is None:
             self._decline(why)
             return None
         self._check_deadline("device decode")
+        n_shards = self._serving_shards()
+        if n_shards > 1 and pk["open"] is not None:
+            self._decline("open_rows_sharded")
+            return None
+        return pk, n_shards
+
+    def _device_run(self, pk, n_shards: int, fn: str, stats: dict,
+                    groups=None, **params):
+        """Back half of the dispatch, the one place that calls the
+        per-node programs (models/query_pipeline): lay the batch over
+        the serving mesh when there is one, stage it, run the entry
+        point (with the mesh, the same program under shard_map over
+        its series axis) and bring the answer back.  With `groups` (the
+        group id of each real lane) the grouped entry point runs, else
+        the temporal one; `params` are the entry point's own keywords
+        and `stats` the caller's own fields of the published stats.
+
+        Returns the program's matrix or None to fall back to the host
+        tier (a device runtime error, or any per-stream decode error
+        flagged by the device)."""
         import jax.numpy as jnp
 
-        from m3_tpu.models.query_pipeline import (
-            device_rate_pipeline, device_reduce_pipeline,
-            device_temporal_sharded)
+        # looked up where it is called: a test may stand another
+        # program in on the module
+        from m3_tpu.models import query_pipeline
 
         cost = self._cost()
-        n_shards = self._serving_shards()
         if n_shards > 1:
-            if pk["open"] is not None:
-                self._decline("open_rows_sharded")
-                return None
             with cost.phase("pack"):
                 pk = self._shard_repack(pk, n_shards)
-        if fn == "quantile_over_time":
-            elements = (pk["lanes_pad"] // max(n_shards, 1)
-                        * len(pk["steps"]) * pk["n_cap"])
-            # pressure = fraction of the per-device HBM window-grid
-            # budget the last QOT demanded; sustained >1.0 means the
-            # device tier is routinely bouncing to host
-            instrument.gauge("m3_device_hbm_gate_pressure").set(
-                elements / self._QOT_MAX_ELEMENTS)
-            if elements > self._QOT_MAX_ELEMENTS:
-                instrument.counter(
-                    "m3_device_hbm_gate_rejections_total").inc()
-                self._decline("hbm_gate")
-                return None  # PER-DEVICE window grid too large: host
-                # native kernel (sharded meshes split the lane axis, so
-                # each device materializes only its shard's slice)
-        labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
-        n_dp, n_cap, lanes_pad = pk["n_dp"], pk["n_cap"], pk["lanes_pad"]
-        n_lanes = pk["n_lanes"]
+        if groups is not None:
+            # padding lanes are all-NaN rows (no streams: the caller
+            # asserts it): they contribute to no group, so parking them
+            # on group 0 is harmless — for the quantile sort layout
+            # this is load-bearing (see _grouped_quantile's
+            # padded-lanes-are-NaN invariant)
+            groups_p = np.zeros(pk["lanes_pad"], dtype=np.int64)
+            groups_p[:len(groups)] = groups
         try:
             with cost.phase("device"):
                 # not fenced: a block_until_ready on the arguments
                 # would change what is measured
                 with cost.phase("h2d"):
-                    words_d, nbits_d, slots_d, steps_d = (
-                        jnp.asarray(pk[k])
-                        for k in ("words", "nbits", "slots", "steps"))
+                    staged = [jnp.asarray(pk[k]) for k in
+                              ("words", "nbits", "slots", "steps")]
+                    if groups is not None:
+                        staged.append(jnp.asarray(groups_p))
                     tiers_d = (None if pk["tiers"] is None
                                else jnp.asarray(pk["tiers"]))
                     open_d = (None if pk["open"] is None else tuple(
                         jnp.asarray(a) for a in pk["open"]))
-                if n_shards > 1:
-                    rate, err = device_temporal_sharded(
-                        self.serving_mesh, words_d, nbits_d, slots_d,
-                        steps_d, n_lanes=lanes_pad,
-                        n_cap=n_cap, range_nanos=rng, fn=fn, n_dp=n_dp,
-                        tiers=tiers_d, n_tiers=pk["n_tiers"],
-                        horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
-                        phi=phi)
-                elif fn in ("rate", "increase", "delta"):
-                    rate, _fleet, err = device_rate_pipeline(
-                        words_d, nbits_d, slots_d, steps_d,
-                        n_lanes=lanes_pad, n_cap=n_cap,
-                        range_nanos=rng, is_counter=fn != "delta",
-                        is_rate=fn == "rate", n_dp=n_dp,
-                        tiers=tiers_d, n_tiers=pk["n_tiers"],
-                        open_rows=open_d)
-                else:
-                    rate, err = device_reduce_pipeline(
-                        words_d, nbits_d, slots_d, steps_d,
-                        n_lanes=lanes_pad, n_cap=n_cap,
-                        range_nanos=rng, reducer=fn, n_dp=n_dp,
-                        tiers=tiers_d, n_tiers=pk["n_tiers"],
-                        horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf,
-                        phi=phi, open_rows=open_d)
+                entry = (query_pipeline.device_temporal_pipeline
+                         if groups is None
+                         else query_pipeline.device_grouped_pipeline)
+                out_d, err = entry(
+                    *staged, n_lanes=pk["lanes_pad"], n_cap=pk["n_cap"],
+                    range_nanos=pk["rng"], fn=fn, n_dp=pk["n_dp"],
+                    tiers=tiers_d, n_tiers=pk["n_tiers"],
+                    open_rows=open_d,
+                    mesh=self.serving_mesh if n_shards > 1 else None,
+                    **params)
                 with cost.phase("d2h"):
-                    out = np.asarray(rate)
+                    out = np.asarray(out_d)
                     err_np = np.asarray(err)
         except Exception as exc:  # noqa: BLE001 - serving must not
             # hard-fail on a device runtime error (HBM OOM on a huge
@@ -1418,11 +1411,49 @@ class Engine:
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
             rows=pk["n_rows"], open_rows=pk["open_rows"],
-            device_serving=True,
-            fn=fn,  # which temporal actually ran on device — the
+            **stats, n_shards=n_shards)
+        return out
+
+    def _device_temporal(self, rv, step_times, fn: str,
+                         range_nanos=None, horizon: float = 0.0,
+                         hw_sf: float = 0.5, hw_tf: float = 0.5,
+                         phi: float = 0.5):
+        """Serve a temporal function entirely on the accelerator: the
+        fused decode -> merge -> windowed kernel pipeline
+        (models/query_pipeline), compressed blocks in,
+        [series, steps] out — the HBM-resident read path.  With a
+        serving_mesh, lanes spread over the series axis of the mesh.
+
+        Returns (labels, out) or None to fall back to the host tier
+        (the dispatch's causes, or the quantile_over_time gate)."""
+        packed = self._device_pack(rv, step_times, range_nanos)
+        if packed is None:
+            return None
+        pk, n_shards = packed
+        if fn == "quantile_over_time":
+            elements = (self._shard_lanes(pk["lanes_pad"], n_shards)
+                        * len(pk["steps"]) * pk["n_cap"])
+            # pressure = fraction of the per-device HBM window-grid
+            # budget the last QOT demanded; sustained >1.0 means the
+            # device tier is routinely bouncing to host
+            instrument.gauge("m3_device_hbm_gate_pressure").set(
+                elements / self._QOT_MAX_ELEMENTS)
+            if elements > self._QOT_MAX_ELEMENTS:
+                instrument.counter(
+                    "m3_device_hbm_gate_rejections_total").inc()
+                self._decline("hbm_gate")
+                return None  # PER-DEVICE window grid too large: host
+                # native kernel (sharded meshes split the lane axis, so
+                # each device materializes only its shard's slice)
+        out = self._device_run(
+            pk, n_shards, fn,
+            # fn: which temporal actually ran on device — the
             # differential suite keys its tolerance on this
-            n_shards=n_shards)
-        return labels, out[:n_lanes, :len(shifted)]
+            {"device_serving": True, "fn": fn},
+            horizon=horizon, hw_sf=hw_sf, hw_tf=hw_tf, phi=phi)
+        if out is None:
+            return None
+        return pk["labels"], out[:pk["n_lanes"], :len(pk["shifted"])]
 
     # aggregations with a device grouped form (topk/bottomk/count_values
     # need the full per-series matrix host-side; quantile joins via the
@@ -1452,36 +1483,23 @@ class Engine:
             # last_over_time over the engine lookback
             rv, fn, rng_override = node.expr, "last_over_time", \
                 self.lookback
-        pk, why = self._device_gather_pack(rv, step_times, rng_override)
-        if pk is None:
-            self._decline(why)
+        packed = self._device_pack(rv, step_times, rng_override)
+        if packed is None:
             return None
-        self._check_deadline("device decode")
-        import jax.numpy as jnp
-
-        from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
-                                                  device_grouped_sharded)
-
-        cost = self._cost()
-        with cost.phase("pack"):
-            n_shards = self._serving_shards()
+        pk, n_shards = packed
+        with self._cost().phase("pack"):
             # padded-lanes-are-NaN invariant (models/query_pipeline
             # _grouped_quantile sort layout depends on it): every real
             # stream row targets a real lane and every padding row is
             # zero-length, so lanes >= n_lanes can only decode to
-            # all-NaN rows and are inert wherever groups_p parks them
+            # all-NaN rows and are inert wherever the dispatch parks
+            # them
             m_real = pk["n_streams"]
             assert (int(pk["slots"][:m_real].max(initial=-1))
                     < pk["n_lanes"]
                     and not pk["nbits"][m_real:].any()), \
                 "device pack violated the padded-lanes-are-NaN invariant"
-            if n_shards > 1:
-                if pk["open"] is not None:
-                    self._decline("open_rows_sharded")
-                    return None
-                pk = self._shard_repack(pk, n_shards)
-            labels, shifted, rng = pk["labels"], pk["shifted"], pk["rng"]
-            n_lanes, lanes_pad = pk["n_lanes"], pk["lanes_pad"]
+            labels = pk["labels"]
             if isinstance(node.expr, promql.Call):
                 # group keys over name-dropped labels: the host path
                 # aggregates the drop_name()'d temporal matrix
@@ -1497,69 +1515,20 @@ class Engine:
             keys = self._group_keys(Matrix(key_labels, None), node)
             uniq = sorted(set(keys))
             group_of = {k: i for i, k in enumerate(uniq)}
-            g_pad = self._bucket(len(uniq), 8)
-            # padding lanes are all-NaN rows (no streams, asserted
-            # above): they contribute to no group, so parking them on
-            # group 0 is harmless — for the quantile sort layout this
-            # is load-bearing (see _grouped_quantile's
-            # padded-lanes-are-NaN invariant)
-            groups_p = np.zeros(lanes_pad, dtype=np.int64)
-            groups_p[:n_lanes] = [group_of[k] for k in keys]
-        try:
-            with cost.phase("device"):
-                # not fenced: a block_until_ready on the arguments
-                # would change what is measured
-                with cost.phase("h2d"):
-                    words_d, nbits_d, slots_d, steps_d = (
-                        jnp.asarray(pk[k])
-                        for k in ("words", "nbits", "slots", "steps"))
-                    groups_d = jnp.asarray(groups_p)
-                    tiers_d = (None if pk["tiers"] is None
-                               else jnp.asarray(pk["tiers"]))
-                    open_d = (None if pk["open"] is None else tuple(
-                        jnp.asarray(a) for a in pk["open"]))
-                if n_shards > 1:
-                    out_g, err = device_grouped_sharded(
-                        self.serving_mesh, words_d, nbits_d, slots_d,
-                        steps_d, groups_d,
-                        n_lanes=lanes_pad, n_groups=g_pad,
-                        n_cap=pk["n_cap"], range_nanos=rng,
-                        fn=fn, agg=node.op, n_dp=pk["n_dp"],
-                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi)
-                else:
-                    out_g, err = device_grouped_pipeline(
-                        words_d, nbits_d, slots_d, steps_d, groups_d,
-                        n_lanes=lanes_pad, n_groups=g_pad,
-                        n_cap=pk["n_cap"], range_nanos=rng,
-                        fn=fn, agg=node.op, n_dp=pk["n_dp"],
-                        tiers=tiers_d, n_tiers=pk["n_tiers"], phi=phi,
-                        open_rows=open_d)
-                with cost.phase("d2h"):
-                    out = np.asarray(out_g)
-                    err_np = np.asarray(err)
-        except Exception as exc:  # noqa: BLE001 - serving must not
-            # hard-fail on a device runtime error: host can still answer
-            self._decline("device_error")
-            self.last_fetch_stats = {
-                "device_serving": False,
-                "device_error": f"{type(exc).__name__}: {exc}"[:200],
-            }
+            groups = np.asarray([group_of[k] for k in keys],
+                                dtype=np.int64)
+        out = self._device_run(
+            pk, n_shards, fn,
+            # fn, agg: device-served temporal + aggregation — the
+            # differential suite keys tolerance on these
+            {"n_groups": len(uniq), "device_serving": True,
+             "device_grouped": True, "fn": fn, "agg": node.op},
+            groups=groups, n_groups=self._bucket(len(uniq), 8),
+            agg=node.op, phi=phi)
+        if out is None:
             return None
-        if self._rows_flagged(pk, err_np):
-            self._decline("decode_error")
-            return None  # corrupt/unsorted stream: host tier re-decodes
-        self._publish_stats(
-            n_streams=pk["n_streams"],
-            datapoints=pk["datapoints"],
-            rows=pk["n_rows"], open_rows=pk["open_rows"],
-            n_groups=len(uniq),
-            device_serving=True,
-            device_grouped=True,
-            fn=fn,  # device-served temporal + aggregation — the
-            agg=node.op,  # differential suite keys tolerance on these
-            n_shards=n_shards)
         return Matrix([dict(k) for k in uniq],
-                      out[:len(uniq), :len(shifted)])
+                      out[:len(uniq), :len(pk["shifted"])])
 
     def _eval_temporal(self, node: promql.Call, step_times):
         fn = node.fn

@@ -1,7 +1,7 @@
 // Scalar M3TSZ decoder + windowed-mean downsample, C++.
 //
 // Two roles:
-//  1. CPU baseline for bench.py: the reference implementation is pure Go
+//  1. CPU baseline: the reference implementation is pure Go
 //     (SURVEY.md §2.4) and no Go toolchain exists in this image, so this
 //     native scalar decoder stands in as the single-core CPU baseline the
 //     TPU path is measured against (same algorithmic shape as

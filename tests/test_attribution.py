@@ -197,6 +197,37 @@ class TestAccountant:
         assert qtop["key"] == "acme|sum(rate(cpu[5m]))"
         assert qtop["count"] == 1000.0
 
+    @pytest.mark.parametrize("n_samples", [8, 512])
+    def test_write_path_accounts_once_a_batch(self, fresh_accounting,
+                                              tmp_path, n_samples):
+        """What keeps attribution cheap on the write path is a count,
+        not a time: a batch is accounted in one call whatever it
+        holds, every sample of it attributed."""
+        acc = fresh_accounting
+        calls = []
+        real = acc.account_write
+        acc.account_write = lambda tenant, **costs: (
+            calls.append(costs), real(tenant, **costs))[1]
+        db = Database(DatabaseOptions(path=str(tmp_path),
+                                      commit_log_enabled=False))
+        db.create_namespace(NamespaceOptions(name="default"))
+        try:
+            tags = [{b"__name__": b"m", b"i": b"%d" % i}
+                    for i in range(n_samples)]
+            now = time.time_ns()
+            with tracing.tenant_scope("acme"):
+                db.write_batch("default", [b"m|%d" % i
+                                           for i in range(n_samples)],
+                               tags, [now] * n_samples,
+                               [1.0] * n_samples)
+        finally:
+            del acc.account_write
+            db.close()
+        assert len(calls) == 1
+        t = acc.tenants_view()["tenants"]["acme"]
+        assert t["samples"] == n_samples
+        assert t["new_series"] == n_samples
+
     def test_tenant_cap_folds_overflow_to_other(self):
         acc = attribution.Accountant(tenant_cap=2)
         acc.account_write("t1", samples=1)

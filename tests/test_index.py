@@ -172,7 +172,7 @@ def test_inline_compaction_when_background_disabled():
 @pytest.mark.slow
 def test_scale_smoke_100k():
     """100k series insert + queries stay fast and memory-bounded enough
-    for CI; the 1M benchmark lives in bench.py's index leg."""
+    for CI; 1M and up is not measured (PERF.md's carried-over table)."""
     idx = TagIndex(seal_threshold=65536)
     for i in range(100_000):
         idx.insert(

@@ -19,7 +19,7 @@ import tempfile
 
 import numpy as np
 import pytest
-from hypothesis import HealthCheck, given, settings
+from hypothesis import HealthCheck, example, given, settings
 from hypothesis import strategies as st
 
 from m3_tpu.ops import m3tsz_scalar as tsz
@@ -203,6 +203,10 @@ def _record_key(sid, t, v, tags):
 
 
 @settings(max_examples=150, **_PROP_SETTINGS)
+@example(  # two batches inside one write-behind window: one chunk, cut
+    ops=[("write", [(b"\x00", 0, 0.0, {})]),
+         ("write", [(b"\x00", 0, 0.0, {})] * 3)],
+    damage=("truncate", 0.5))
 @given(
     ops=st.lists(
         st.one_of(_batch.map(lambda b: ("write", b)),
@@ -215,10 +219,18 @@ def _record_key(sid, t, v, tags):
     ),
 )
 def test_wal_model_prop(ops, damage):
-    """Model: one chunk per write_batch, FIFO.  After flush + crash
-    damage to the live file, replay must yield a per-damage-consistent
-    PREFIX of the acknowledged records: nothing invented, order kept,
-    and every chunk wholly before the damage point intact.  Exact float
+    """Model: FIFO chunks, one per burst the writer drained (group
+    commit: write_batch calls that queue up within the write-behind
+    window share ONE chunk, so the chunk and not the batch is what a
+    torn tail takes or leaves; a batch is never split between chunks).
+    The model used to say "one chunk per write_batch", which the
+    write-behind window made stale: two quick batches are one chunk,
+    and a cut through it rightly loses both (the recorded example
+    below).  Where the chunks ended is read from the undamaged file and
+    held against the ops.  After flush + crash damage to the live file,
+    replay must yield a per-damage-consistent PREFIX of the
+    acknowledged records: nothing invented, order kept, and every chunk
+    wholly before the damage point intact.  Exact float
     bits (incl. NaN) roundtrip.  Tags are stored once per (sid, file)
     (write-side dedup) and rehydrated on replay, so within a file a
     sid's tags are FIRST-WRITER-WINS — the model below mirrors that
@@ -228,20 +240,11 @@ def test_wal_model_prop(ops, damage):
     with tempfile.TemporaryDirectory(prefix="m3_walprop_") as td:
         log = CommitLog(td, rotate_bytes=1 << 30)
         written = []          # acknowledged records w/ EXPECTED tags
-        live_chunks = []      # chunk byte-sizes in the LIVE file
-        model_seen: set = set()   # mirrors the write-side size dedup
         model_first: dict = {}    # per-file: sid -> first tags seen
         for op, arg in ops:
             if op == "write":
-                ids = [r[0] for r in arg]
-                ts = [r[1] for r in arg]
-                vs = [r[2] for r in arg]
-                tg = [r[3] for r in arg]
-                log.write_batch(ids, ts, vs, tg)
-                size_seen = set(model_seen)
-                live_chunks.append(len(log._encode_chunk(
-                    ids, ts, vs, tg, 0, seen=size_seen)))
-                model_seen = size_seen
+                log.write_batch([r[0] for r in arg], [r[1] for r in arg],
+                                [r[2] for r in arg], [r[3] for r in arg])
                 for sid, t, v, tags in arg:
                     if tags and sid not in model_first:
                         model_first[sid] = tags
@@ -249,19 +252,13 @@ def test_wal_model_prop(ops, damage):
                                     model_first.get(sid, {})))
             else:
                 log.rotate()
-                live_chunks = []
-                model_seen = set()
                 model_first = {}
         log.flush()
         log.close()
 
         # index of the first record living in the live file
-        n_live_records = 0
-        for op, arg in reversed(ops):
-            if op == "rotate":
-                break
-            n_live_records += len(arg)
-        first_live = len(written) - n_live_records
+        live_batches = [len(arg) for _, arg in _live_ops(ops)]
+        first_live = len(written) - sum(live_batches)
 
         import pathlib
         # numeric index order, NOT lexicographic: with >= 10 files a
@@ -270,30 +267,39 @@ def test_wal_model_prop(ops, damage):
         from m3_tpu.storage.commitlog import _by_index
         live = max(pathlib.Path(td).glob("commitlog-*.db"), key=_by_index)
         data = bytearray(live.read_bytes())
+        # the live file's chunks as written: (bytes, records) each, the
+        # log's last ones.  They fill the file, and each holds a run of
+        # whole batches
+        live_chunks, n = [], 0
+        for c in reversed(list(CommitLog.replay_chunks(td))):
+            if n == sum(live_batches):
+                break
+            live_chunks.insert(0, (c.nbytes, len(c.times)))
+            n += len(c.times)
+        assert sum(size for size, _ in live_chunks) == len(data)
+        ends = set(np.cumsum(live_batches).tolist())
+        assert set(np.cumsum([k for _, k in live_chunks]).tolist()) <= ends, \
+            "a batch was split between chunks"
+
+        def before(at):
+            """Records of the live chunks that end at or before `at`."""
+            pos = kept = 0
+            for size, k in live_chunks:
+                if pos + size > at:
+                    break
+                pos, kept = pos + size, kept + k
+            return kept
+
         guaranteed = len(written)  # lower bound on surviving records
         if damage[0] == "truncate" and data:
             cut = int(damage[1] * len(data))
             data = data[:cut]
-            guaranteed = first_live
-            pos = 0
-            for size, (op, arg) in zip(live_chunks, _live_ops(ops)):
-                if pos + size <= cut:
-                    guaranteed += len(arg)
-                    pos += size
-                else:
-                    break
+            guaranteed = first_live + before(cut)
             live.write_bytes(bytes(data))
         elif damage[0] == "flip" and data:
             at = int(damage[1] * (len(data) - 1))
             data[at] ^= 1 << damage[2]
-            guaranteed = first_live
-            pos = 0
-            for size, (op, arg) in zip(live_chunks, _live_ops(ops)):
-                if pos + size <= at:
-                    guaranteed += len(arg)
-                    pos += size
-                else:
-                    break
+            guaranteed = first_live + before(at)
             live.write_bytes(bytes(data))
 
         replayed = [(sid, t, v, tg) for sid, t, v, tg, _, _ns in

@@ -184,8 +184,8 @@ def _lowered(which: str) -> str:
     args = (spec((m, w), jnp.uint64), spec((m,), jnp.int64),
             spec((m,), jnp.int64), spec((steps,), jnp.int64))
     rng = np.int64(300 * SEC)
-    if which == "device_rate_pipeline":
-        low = qp.device_rate_pipeline.lower(
+    if which == "device_temporal_pipeline":
+        low = qp.device_temporal_pipeline.lower(
             *args, n_lanes=lanes, n_cap=128, range_nanos=rng, n_dp=128)
     elif which == "device_grouped_pipeline":
         low = qp.device_grouped_pipeline.lower(
@@ -208,7 +208,7 @@ def _lowered(which: str) -> str:
 
 
 @pytest.mark.parametrize("which,scopes", [
-    ("device_rate_pipeline", ("m3.decode", "m3.merge", "m3.temporal")),
+    ("device_temporal_pipeline", ("m3.decode", "m3.merge", "m3.temporal")),
     ("device_grouped_pipeline",
      ("m3.decode", "m3.merge", "m3.temporal", "m3.group")),
     ("device_expr_pipeline",

@@ -12,8 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from m3_tpu.models.query_pipeline import (device_rate_pipeline,
-                                          device_rate_sharded)
+from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
+                                          device_temporal_pipeline)
 from m3_tpu.ops import consolidate as cons
 from m3_tpu.ops import m3tsz_scalar as tsz
 from m3_tpu.ops.bitstream import pack_streams
@@ -53,18 +53,16 @@ def test_device_pipeline_matches_host():
     steps = T0 + np.arange(9, dtype=np.int64) * 120 * SEC + 600 * SEC
     range_nanos = 10 * 60 * SEC
     n_cap = blocks_per * dp
-    rate, fleet, err = device_rate_pipeline(
+    rate, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=n_cap,
-        range_nanos=range_nanos)
+        fn="rate", range_nanos=range_nanos)
     assert not np.asarray(err).any()
     want = _host_reference(frags, n_lanes, steps, range_nanos)
     got = np.asarray(rate)
     np.testing.assert_array_equal(np.isnan(want), np.isnan(got))
     np.testing.assert_allclose(np.nan_to_num(got), np.nan_to_num(want),
                                rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(fleet),
-                               np.nansum(want, axis=0), rtol=1e-12)
 
 
 def test_device_pipeline_block_width_decode():
@@ -77,18 +75,16 @@ def test_device_pipeline_block_width_decode():
     steps = T0 + np.arange(8, dtype=np.int64) * 120 * SEC + 600 * SEC
     range_nanos = 10 * 60 * SEC
     n_cap = blocks_per * dp
-    rate, fleet, err = device_rate_pipeline(
+    rate, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=n_cap,
-        range_nanos=range_nanos, n_dp=dp)
+        fn="rate", range_nanos=range_nanos, n_dp=dp)
     assert not np.asarray(err).any()
     want = _host_reference(frags, n_lanes, steps, range_nanos)
     got = np.asarray(rate)
     np.testing.assert_array_equal(np.isnan(want), np.isnan(got))
     np.testing.assert_allclose(np.nan_to_num(got), np.nan_to_num(want),
                                rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(fleet),
-                               np.nansum(want, axis=0), rtol=1e-12)
 
 
 def test_device_pipeline_truncation_flagged():
@@ -98,16 +94,16 @@ def test_device_pipeline_truncation_flagged():
     streams, slots, _ = _mk_streams(n_lanes, blocks_per, dp, seed=5)
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(4, dtype=np.int64) * 120 * SEC + 600 * SEC
-    _, _, err = device_rate_pipeline(
+    _, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=blocks_per * dp,
-        range_nanos=10 * 60 * SEC, n_dp=dp - 1)  # one short
+        fn="rate", range_nanos=10 * 60 * SEC, n_dp=dp - 1)  # one short
     assert np.asarray(err).all()
     # and at the exact width nothing is flagged
-    _, _, err_ok = device_rate_pipeline(
+    _, err_ok = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=blocks_per * dp,
-        range_nanos=10 * 60 * SEC, n_dp=dp)
+        fn="rate", range_nanos=10 * 60 * SEC, n_dp=dp)
     assert not np.asarray(err_ok).any()
 
 
@@ -123,10 +119,10 @@ def test_device_pipeline_lane_overflow_flagged():
     # budget holds only 2 of the 3 blocks; streams are exactly dp long
     # so per-stream truncation does NOT fire — only the lane overflow
     n_cap = 2 * dp
-    rate, _, err = device_rate_pipeline(
+    rate, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=n_cap,
-        range_nanos=range_nanos, n_dp=dp)
+        fn="rate", range_nanos=range_nanos, n_dp=dp)
     assert np.asarray(err).all()
     # no cross-lane corruption: each lane's merged samples are its own
     # first 2 blocks, so rates equal the host reference on that subset
@@ -153,13 +149,13 @@ def test_device_pipeline_range_is_not_a_compile_key():
     streams, slots, _ = _mk_streams(n_lanes, blocks_per, dp, seed=13)
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(4, dtype=np.int64) * 120 * SEC + 600 * SEC
-    device_rate_pipeline._clear_cache()
+    device_temporal_pipeline._clear_cache()
     for rng_s in (300, 93, 607):
-        device_rate_pipeline(
+        device_temporal_pipeline(
             jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
             jnp.asarray(steps), n_lanes=n_lanes, n_cap=blocks_per * dp,
-            range_nanos=rng_s * SEC, n_dp=dp)
-    assert device_rate_pipeline._cache_size() == 1
+            fn="rate", range_nanos=rng_s * SEC, n_dp=dp)
+    assert device_temporal_pipeline._cache_size() == 1
 
 
 def test_device_pipeline_unsorted_lane_flagged():
@@ -184,21 +180,21 @@ def test_device_pipeline_unsorted_lane_flagged():
             frags.append((lane, t, v))
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(4, dtype=np.int64) * 120 * SEC + 600 * SEC
-    _, _, err = device_rate_pipeline(
+    _, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits),
         jnp.asarray(np.asarray(slots, dtype=np.int64)),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=2 * dp,
-        range_nanos=10 * 60 * SEC, n_dp=dp)
+        fn="rate", range_nanos=10 * 60 * SEC, n_dp=dp)
     err = np.asarray(err)
     assert err[2] and err[3], "overlapping lane's streams must flag"
     assert not err[[0, 1, 4, 5]].any(), "clean lanes must not flag"
 
 
-def test_device_reduce_pipeline_matches_host():
+def test_device_temporal_pipeline_matches_host():
     """*_over_time on device (NaN-masked prefix sums) vs the host
     window_reduce / step_consolidate references — exact on CPU."""
     from m3_tpu.models.query_pipeline import (DEVICE_REDUCERS,
-                                              device_reduce_pipeline)
+                                              device_temporal_pipeline)
 
     n_lanes, blocks_per, dp = 10, 2, 36
     streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp, seed=17)
@@ -207,11 +203,11 @@ def test_device_reduce_pipeline_matches_host():
     range_nanos = 10 * 60 * SEC
     t_ref, v_ref, _ = cons.merge_packed(frags, n_lanes)
     for reducer in DEVICE_REDUCERS:
-        out, err = device_reduce_pipeline(
+        out, err = device_temporal_pipeline(
             jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
             jnp.asarray(steps), n_lanes=n_lanes,
             n_cap=blocks_per * dp, range_nanos=range_nanos,
-            reducer=reducer, n_dp=dp)
+            fn=reducer, n_dp=dp)
         assert not np.asarray(err).any(), reducer
         if reducer == "last_over_time":
             want = cons.step_consolidate(t_ref, v_ref, steps,
@@ -244,7 +240,7 @@ def test_inf_samples_agree_across_tiers():
     semantics), and an Inf + -Inf window must be NaN on both — guards
     the host _masked() clamp regression (nan_to_num turned Inf into
     ±1.8e308 on the host tier only)."""
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
 
     n_lanes, dp = 2, 12
     streams, frags = [], []
@@ -264,11 +260,11 @@ def test_inf_samples_agree_across_tiers():
     rng = dp * 10 * SEC
     t_ref, v_ref, _ = cons.merge_packed(frags, n_lanes)
     host = cons.window_reduce(t_ref, v_ref, steps, rng, "sum_over_time")
-    out, err = device_reduce_pipeline(
+    out, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits),
         jnp.asarray(np.arange(n_lanes, dtype=np.int64)),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=dp,
-        range_nanos=rng, reducer="sum_over_time")
+        range_nanos=rng, fn="sum_over_time")
     assert not np.asarray(err).any()
     dev = np.asarray(out)
     assert host[0, 0] == np.inf and dev[0, 0] == np.inf
@@ -280,7 +276,7 @@ def test_device_minmax_nan_and_wide_windows():
     lanes, an all-NaN window, ±Inf samples, and window widths that
     exercise every decomposition case — same-block, adjacent blocks
     (empty sparse mid-range), and wide multi-block ranges."""
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
 
     rng = np.random.default_rng(71)
     n_lanes, dp = 6, 150  # not a multiple of the 32-sample block
@@ -306,11 +302,11 @@ def test_device_minmax_nan_and_wide_windows():
         range_nanos = range_s * SEC
         steps = T0 + np.arange(12, dtype=np.int64) * 120 * SEC + 60 * SEC
         for reducer in ("min_over_time", "max_over_time"):
-            out, err = device_reduce_pipeline(
+            out, err = device_temporal_pipeline(
                 jnp.asarray(words), jnp.asarray(nbits),
                 jnp.asarray(np.arange(n_lanes, dtype=np.int64)),
                 jnp.asarray(steps), n_lanes=n_lanes, n_cap=dp,
-                range_nanos=range_nanos, reducer=reducer)
+                range_nanos=range_nanos, fn=reducer)
             assert not np.asarray(err).any(), (range_s, reducer)
             want = cons.window_reduce(t_ref, v_ref, steps, range_nanos,
                                       reducer)
@@ -333,7 +329,7 @@ def test_device_stdvar_stability_and_windows():
     1e9-offset samples with unit-scale spread, where the prefix-sum
     E[x^2]-E[x]^2 form would read a wildly wrong (even negative)
     variance."""
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
 
     rng = np.random.default_rng(93)
     n_lanes, dp = 6, 150  # not a multiple of the 32-sample block
@@ -361,11 +357,11 @@ def test_device_stdvar_stability_and_windows():
         range_nanos = range_s * SEC
         steps = T0 + np.arange(12, dtype=np.int64) * 120 * SEC + 60 * SEC
         for reducer in ("stdvar_over_time", "stddev_over_time"):
-            out, err = device_reduce_pipeline(
+            out, err = device_temporal_pipeline(
                 jnp.asarray(words), jnp.asarray(nbits),
                 jnp.asarray(np.arange(n_lanes, dtype=np.int64)),
                 jnp.asarray(steps), n_lanes=n_lanes, n_cap=dp,
-                range_nanos=range_nanos, reducer=reducer)
+                range_nanos=range_nanos, fn=reducer)
             assert not np.asarray(err).any(), (range_s, reducer)
             want = cons.window_reduce(t_ref, v_ref, steps, range_nanos,
                                       reducer)
@@ -394,7 +390,7 @@ def test_device_holt_winters_matches_host():
     sitting at the cnt==2 boundary, several (sf, tf) pairs, and window
     widths covering same-block, adjacent, and wide multi-block
     decompositions — vs the host window_holt_winters reference."""
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
 
     rng = np.random.default_rng(87)
     n_lanes, dp = 6, 150
@@ -419,11 +415,11 @@ def test_device_holt_winters_matches_host():
         range_nanos = range_s * SEC
         steps = T0 + np.arange(12, dtype=np.int64) * 120 * SEC + 60 * SEC
         for sf, tf in ((0.3, 0.1), (0.8, 0.6)):
-            out, err = device_reduce_pipeline(
+            out, err = device_temporal_pipeline(
                 jnp.asarray(words), jnp.asarray(nbits),
                 jnp.asarray(np.arange(n_lanes, dtype=np.int64)),
                 jnp.asarray(steps), n_lanes=n_lanes, n_cap=dp,
-                range_nanos=range_nanos, reducer="holt_winters",
+                range_nanos=range_nanos, fn="holt_winters",
                 hw_sf=sf, hw_tf=tf)
             assert not np.asarray(err).any(), (range_s, sf, tf)
             want = cons.window_holt_winters(t_ref, v_ref, steps,
@@ -443,7 +439,7 @@ def test_device_quantile_over_time_matches_host():
     and all-NaN lanes, every window-width class — vs the host
     window_quantile reference.  phi is traced: the sweep must not grow
     the jit cache."""
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
 
     rng = np.random.default_rng(19)
     n_lanes, dp = 5, 150
@@ -461,16 +457,16 @@ def test_device_quantile_over_time_matches_host():
         frags.append((lane, t, v))
     words, nbits = pack_streams(streams)
     t_ref, v_ref, _ = cons.merge_packed(frags, n_lanes)
-    device_reduce_pipeline._clear_cache()
+    device_temporal_pipeline._clear_cache()
     for range_s in (50, 400, 1490):
         range_nanos = range_s * SEC
         steps = T0 + np.arange(12, dtype=np.int64) * 120 * SEC + 60 * SEC
         for phi in (0.0, 0.25, 0.5, 0.95, 1.0):
-            out, err = device_reduce_pipeline(
+            out, err = device_temporal_pipeline(
                 jnp.asarray(words), jnp.asarray(nbits),
                 jnp.asarray(np.arange(n_lanes, dtype=np.int64)),
                 jnp.asarray(steps), n_lanes=n_lanes, n_cap=dp,
-                range_nanos=range_nanos, reducer="quantile_over_time",
+                range_nanos=range_nanos, fn="quantile_over_time",
                 phi=phi)
             assert not np.asarray(err).any(), (range_s, phi)
             want = cons.window_quantile(t_ref, v_ref, steps,
@@ -482,7 +478,7 @@ def test_device_quantile_over_time_matches_host():
             np.testing.assert_allclose(
                 np.nan_to_num(got), np.nan_to_num(want), rtol=1e-9,
                 atol=1e-12, err_msg=f"{range_s}/{phi}")
-    assert device_reduce_pipeline._cache_size() == 1
+    assert device_temporal_pipeline._cache_size() == 1
 
 
 def _host_grouped(per_lane, groups, n_groups, agg, phi=0.5):
@@ -658,32 +654,64 @@ def test_device_grouped_quantile_phi_sweep():
     assert device_grouped_pipeline._cache_size() == 1
 
 
-def test_device_grouped_sharded_collectives():
-    if jax.device_count() < 8:
-        pytest.skip("needs the virtual 8-device mesh")
-    from m3_tpu.models.query_pipeline import (DEVICE_GROUP_AGGS,
-                                              device_grouped_sharded)
+def _sharded_case(seed):
+    """16 lanes, 2 a shard of the 8-device mesh, the slots local to
+    their shard; -> (mesh, the entry points' positional arguments for
+    the mesh and for one chip, keywords, frags, steps, range)."""
     from m3_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(n_series_shards=8, n_window_shards=1)
-    n_lanes, blocks_per, dp = 16, 2, 30  # 2 lanes per shard
-    streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp, seed=41)
+    n_lanes, blocks_per, dp = 16, 2, 30
+    streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp, seed=seed)
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(7, dtype=np.int64) * 120 * SEC + 600 * SEC
     range_nanos = 10 * 60 * SEC
-    groups = np.arange(n_lanes, dtype=np.int64) % 4  # span shards
-    lanes_per = n_lanes // 8
-    slots_local = slots % lanes_per
-    t_ref, v_ref, _ = cons.merge_packed(frags, n_lanes)
+    head = (jnp.asarray(words), jnp.asarray(nbits))
+    local = head + (jnp.asarray(slots % (n_lanes // 8)), jnp.asarray(steps))
+    whole = head + (jnp.asarray(slots), jnp.asarray(steps))
+    kw = dict(n_lanes=n_lanes, n_cap=blocks_per * dp,
+              range_nanos=range_nanos)
+    return mesh, local, whole, kw, frags, steps, range_nanos
+
+
+@pytest.mark.parametrize("agg", ["sum", "avg", "min", "max", "count",
+                                 "group", "stddev", "stdvar", "quantile"])
+def test_device_grouped_meshed_equals_one_chip(agg):
+    """Each aggregation of the grouped form, given the mesh (the lane
+    reduction through its collective), equals the one-chip program's
+    answer and the host's."""
+    if jax.device_count() < 8:
+        pytest.skip("needs the virtual 8-device mesh")
+    mesh, local, whole, kw, frags, steps, range_nanos = _sharded_case(41)
+    groups = jnp.arange(16, dtype=jnp.int64) % 4  # span shards
+    kw.update(n_groups=4, fn="rate", agg=agg, phi=0.3)
+    out, err = device_grouped_pipeline(*local, groups, mesh=mesh, **kw)
+    one, err_one = device_grouped_pipeline(*whole, groups, **kw)
+    assert not np.asarray(err).any() and not np.asarray(err_one).any()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(one),
+                               rtol=1e-12, atol=1e-12, equal_nan=True)
+    t_ref, v_ref, _ = cons.merge_packed(frags, 16)
+    want = _host_grouped(
+        cons.extrapolated_rate(t_ref, v_ref, steps, range_nanos, True, True),
+        np.asarray(groups), 4, agg, phi=0.3)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-9,
+                               atol=1e-12, equal_nan=True)
+
+
+def test_device_grouped_on_mesh_collectives():
+    if jax.device_count() < 8:
+        pytest.skip("needs the virtual 8-device mesh")
+    from m3_tpu.models.query_pipeline import DEVICE_GROUP_AGGS
+
+    mesh, local, _, kw, frags, steps, range_nanos = _sharded_case(41)
+    groups = np.arange(16, dtype=np.int64) % 4  # span shards
+    t_ref, v_ref, _ = cons.merge_packed(frags, 16)
     want_rate = cons.extrapolated_rate(t_ref, v_ref, steps, range_nanos,
                                        True, True)
     for agg in DEVICE_GROUP_AGGS:
-        out, err = device_grouped_sharded(
-            mesh, jnp.asarray(words), jnp.asarray(nbits),
-            jnp.asarray(slots_local), jnp.asarray(steps),
-            jnp.asarray(groups), n_lanes=n_lanes, n_groups=4,
-            n_cap=blocks_per * dp, range_nanos=range_nanos,
-            fn="rate", agg=agg)
+        out, err = device_grouped_pipeline(
+            *local, jnp.asarray(groups), n_groups=4, fn="rate", agg=agg,
+            mesh=mesh, **kw)
         assert not np.asarray(err).any(), agg
         want = _host_grouped(want_rate, groups, 4, agg)
         got = np.asarray(out)
@@ -692,34 +720,106 @@ def test_device_grouped_sharded_collectives():
         np.testing.assert_allclose(
             np.nan_to_num(got), np.nan_to_num(want), rtol=1e-9,
             atol=1e-12, err_msg=agg)
+    # a parameterized temporal has no grouped form, on a mesh or off it
+    for m in (mesh, None):
+        with pytest.raises(ValueError, match="no grouped device form"):
+            device_grouped_pipeline(
+                *local, jnp.asarray(groups), n_groups=4,
+                fn="holt_winters", agg="sum", mesh=m, **kw)
 
 
 def test_device_pipeline_sharded_psum():
+    """The temporal form given the mesh: per-series results sharded by
+    series, no collective; the fleet sum over ICI is the grouped form
+    with one group (one psum)."""
     if jax.device_count() < 8:
         pytest.skip("needs the virtual 8-device mesh")
-    from m3_tpu.parallel.mesh import make_mesh
-
-    mesh = make_mesh(n_series_shards=8, n_window_shards=1)
-    n_lanes, blocks_per, dp = 16, 2, 30  # 2 lanes per shard
-    streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp)
-    words, nbits = pack_streams(streams)
-    steps = T0 + np.arange(7, dtype=np.int64) * 120 * SEC + 600 * SEC
-    range_nanos = 10 * 60 * SEC
-    # per-shard-local slots (each shard owns a contiguous lane range)
-    lanes_per = n_lanes // 8
-    slots_local = slots % lanes_per
-    rate, fleet, err = device_rate_sharded(
-        mesh, jnp.asarray(words), jnp.asarray(nbits),
-        jnp.asarray(slots_local), jnp.asarray(steps),
-        n_lanes=n_lanes, n_cap=blocks_per * dp,
-        range_nanos=range_nanos)
+    mesh, local, whole, kw, frags, steps, range_nanos = _sharded_case(9)
+    rate, err = device_temporal_pipeline(*local, fn="rate", mesh=mesh, **kw)
     assert not np.asarray(err).any()
-    want = _host_reference(frags, n_lanes, steps, range_nanos)
+    want = _host_reference(frags, 16, steps, range_nanos)
     got = np.asarray(rate)
     np.testing.assert_allclose(np.nan_to_num(got), np.nan_to_num(want),
                                rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(fleet),
+    fleet, err = device_grouped_pipeline(
+        *local, jnp.zeros(16, dtype=jnp.int64), n_groups=1, fn="rate",
+        agg="sum", mesh=mesh, **kw)
+    assert not np.asarray(err).any()
+    # (a step no lane is present at is absent there, 0 in the nansum)
+    np.testing.assert_allclose(np.nan_to_num(np.asarray(fleet)[0]),
                                np.nansum(want, axis=0), rtol=1e-12)
+    # a parameterized temporal through the mesh: its traced parameter
+    # reaches every shard
+    q_mesh, _ = device_temporal_pipeline(
+        *local, fn="quantile_over_time", phi=0.9, mesh=mesh, **kw)
+    q_one, _ = device_temporal_pipeline(
+        *whole, fn="quantile_over_time", phi=0.9, **kw)
+    np.testing.assert_array_equal(np.asarray(q_mesh), np.asarray(q_one))
+
+
+@pytest.mark.parametrize("entry", ["temporal", "grouped"])
+@pytest.mark.parametrize("with_open", [False, True])
+def test_one_chip_program_holds_no_collective(entry, with_open):
+    """Without a mesh either entry point traces to the one-chip
+    program: no shard_map and no collective in its jaxpr, with and
+    without open rows.  (With the mesh the same entry point holds
+    both.)"""
+    n_lanes, dp = 4, 16
+    streams, slots, _ = _mk_streams(n_lanes, 1, dp, seed=3)
+    words, nbits = pack_streams(streams)
+    steps = T0 + np.arange(4, dtype=np.int64) * 60 * SEC + 60 * SEC
+    open_rows = None
+    if with_open:
+        open_rows = (jnp.zeros((2, dp), jnp.int64), jnp.zeros((2, dp)),
+                     jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int64),
+                     jnp.arange(n_lanes + 2))
+    args = [jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
+            jnp.asarray(steps)]
+    kw = dict(n_lanes=n_lanes, n_cap=2 * dp, range_nanos=300 * SEC,
+              n_dp=dp, fn="rate", open_rows=open_rows)
+    if entry == "grouped":
+        fn = functools.partial(device_grouped_pipeline, n_groups=2,
+                               agg="stddev", **kw)
+        args.append(jnp.arange(n_lanes, dtype=jnp.int64) % 2)
+    else:
+        fn = functools.partial(device_temporal_pipeline, **kw)
+    text = str(jax.make_jaxpr(fn)(*args))
+    for word in ("shard_map", "psum", "pmin", "pmax", "all_gather"):
+        assert word not in text, word
+    if jax.device_count() >= 8 and not with_open:
+        from m3_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(n_series_shards=4, n_window_shards=1)
+        meshed = str(jax.make_jaxpr(functools.partial(fn, mesh=mesh))(*args))
+        assert "shard_map" in meshed
+        assert ("psum" in meshed) == (entry == "grouped")
+
+
+@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
+def test_rate_family_through_the_temporal_form_is_rate_device(fn):
+    """rate / increase / delta have no entry point of their own: the
+    temporal form's answer is _rate_device's on the same merged lanes,
+    bit for bit."""
+    from m3_tpu.models.query_pipeline import _decode_merge, _rate_device
+
+    n_lanes, blocks_per, dp = 6, 2, 24
+    streams, slots, _ = _mk_streams(n_lanes, blocks_per, dp, seed=17)
+    words, nbits = pack_streams(streams)
+    steps = jnp.asarray(T0 + np.arange(6, dtype=np.int64) * 90 * SEC
+                        + 300 * SEC)
+    args = (jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots))
+    out, err = device_temporal_pipeline(
+        *args, steps, n_lanes=n_lanes, n_cap=blocks_per * dp, fn=fn,
+        range_nanos=5 * 60 * SEC, n_dp=dp)
+    assert not np.asarray(err).any()
+    times, values, _ = jax.jit(
+        _decode_merge, static_argnums=(3, 4, 5, 6))(
+            *args, n_lanes, blocks_per * dp, dp, SEC)
+    want = jax.jit(_rate_device, static_argnames=("is_counter", "is_rate"))(
+        times, values, steps, 5 * 60 * SEC,
+        is_counter=fn != "delta", is_rate=fn == "rate")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert np.isfinite(np.asarray(out)).any()
 
 
 # -- the merge and the window bounds alone, against numpy ------------------
@@ -1024,11 +1124,11 @@ def test_tier_cut_that_keeps_no_prefix_is_flagged():
                stream(T0 + np.asarray([60, 120, 180]) * SEC),   # lane 1
                stream(fine)]
     words, nbits = pack_streams(streams)
-    _, _, err = device_rate_pipeline(
+    _, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits),
         jnp.asarray(np.asarray([0, 0, 1, 1], dtype=np.int64)),
         jnp.asarray(T0 + np.asarray([240], dtype=np.int64) * SEC),
-        n_lanes=2, n_cap=16, range_nanos=300 * SEC, n_dp=8,
+        n_lanes=2, n_cap=16, fn="rate", range_nanos=300 * SEC, n_dp=8,
         tiers=jnp.asarray(np.asarray([1, 0, 1, 0], dtype=np.int64)),
         n_tiers=2)
     assert np.asarray(err).tolist() == [True, False, False, False]

@@ -244,8 +244,7 @@ def test_seal_pack_encode_compiles(one_chip):
 
 def test_headline_decode_downsample_compiles(one_chip):
     """README's headline shape: 1,000,000 series x 360 dp (1 h at 10 s)
-    -> 1 m means; 57 words is what bench.gen_streams' integer gauges
-    pack to."""
+    -> 1 m means; 57 words is what such integer gauges pack to."""
     from m3_tpu.models.read_pipeline import decode_downsample
     _compile(decode_downsample,
              _sds((1_000_000, 57), np.uint32, one_chip),
@@ -253,8 +252,8 @@ def test_headline_decode_downsample_compiles(one_chip):
 
 
 @pytest.mark.parametrize("kernel,expr", [
-    ("device_rate_pipeline", "rate(http_requests_total[5m])"),
-    pytest.param("device_reduce_pipeline",
+    ("device_temporal_pipeline", "rate(http_requests_total[5m])"),
+    pytest.param("device_temporal_pipeline",
                  "count_over_time(http_requests_total[5m])",
                  marks=pytest.mark.slow),
 ])

@@ -265,7 +265,7 @@ def test_merged_read_batch_on_device_backend():
         assert (np.diff(t_lane) >= 0).all()
 
 
-def test_device_rate_pipeline_on_device():
+def test_device_temporal_pipeline_rate_on_device():
     """Round-5 frontier on hardware: the fused decode->merge->rate
     pipeline (models/query_pipeline.py) — one jit, the
     [streams, samples] intermediate resident in HBM — must lower, run,
@@ -274,7 +274,7 @@ def test_device_rate_pipeline_on_device():
     ~2**-44-relative f64 arithmetic); timestamps and NaN masks are
     exact."""
     dev = _dev()
-    from m3_tpu.models.query_pipeline import device_rate_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
     from m3_tpu.ops import consolidate as cons
 
     n_lanes, blocks_per, dp = 8, 3, 60
@@ -296,13 +296,13 @@ def test_device_rate_pipeline_on_device():
     words_np, nbits_np = pack_streams(streams)
     steps = START + 600 * SEC + np.arange(12, dtype=np.int64) * 120 * SEC
     range_nanos = 10 * 60 * SEC
-    rate, fleet, err = device_rate_pipeline(
+    rate, err = device_temporal_pipeline(
         jax.device_put(jnp.asarray(words_np), dev),
         jax.device_put(jnp.asarray(nbits_np), dev),
         jax.device_put(jnp.asarray(np.asarray(slots, dtype=np.int64)), dev),
         jax.device_put(jnp.asarray(steps), dev),
         n_lanes=n_lanes, n_cap=blocks_per * dp,
-        range_nanos=range_nanos, n_dp=dp)
+        fn="rate", range_nanos=range_nanos, n_dp=dp)
     assert not np.asarray(err).any()
     t_ref, v_ref, _ = cons.merge_packed(frags, n_lanes)
     want = cons.extrapolated_rate(t_ref, v_ref, steps, range_nanos,
@@ -311,18 +311,16 @@ def test_device_rate_pipeline_on_device():
     np.testing.assert_array_equal(np.isnan(want), np.isnan(got))
     np.testing.assert_allclose(np.nan_to_num(got), np.nan_to_num(want),
                                rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(fleet),
-                               np.nansum(want, axis=0), rtol=1e-9)
 
 
-def test_device_reduce_pipeline_on_device():
+def test_device_temporal_pipeline_reducers_on_device():
     """The *_over_time device pipeline (NaN-masked prefix sums over the
     merged batch) must lower and match the host window_reduce on
     hardware within the documented f64-emulation drift; count/present
     are integer-exact."""
     dev = _dev()
     from m3_tpu.models.query_pipeline import (DEVICE_REDUCERS,
-                                              device_reduce_pipeline)
+                                              device_temporal_pipeline)
     from m3_tpu.ops import consolidate as cons
 
     n_lanes, blocks_per, dp = 6, 2, 48
@@ -346,13 +344,13 @@ def test_device_reduce_pipeline_on_device():
     from m3_tpu.ops.consolidate import merge_packed
     t_ref, v_ref, _ = merge_packed(frags, n_lanes)
     for reducer in DEVICE_REDUCERS:
-        out, err = device_reduce_pipeline(
+        out, err = device_temporal_pipeline(
             jax.device_put(jnp.asarray(words_np), dev),
             jax.device_put(jnp.asarray(nbits_np), dev),
             jax.device_put(jnp.asarray(np.asarray(slots, np.int64)), dev),
             jax.device_put(jnp.asarray(steps), dev),
             n_lanes=n_lanes, n_cap=blocks_per * dp,
-            range_nanos=range_nanos, reducer=reducer, n_dp=dp)
+            range_nanos=range_nanos, fn=reducer, n_dp=dp)
         assert not np.asarray(err).any(), reducer
         if reducer == "last_over_time":
             want = cons.step_consolidate(t_ref, v_ref, steps, range_nanos)
@@ -438,7 +436,7 @@ def test_device_multitier_pipeline_on_device():
     risk class as the f64 psum_scatter rewrite gap the lane caught in
     round 5 session 2."""
     dev = _dev()
-    from m3_tpu.models.query_pipeline import device_rate_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
     from m3_tpu.ops import consolidate as cons
 
     n_lanes, dp_fine, dp_coarse = 6, 40, 20
@@ -469,13 +467,13 @@ def test_device_multitier_pipeline_on_device():
     words_np, nbits_np = pack_streams(streams)
     steps = START + 600 * SEC + np.arange(10, dtype=np.int64) * 300 * SEC
     range_nanos = 20 * 60 * SEC
-    rate, _fleet, err = device_rate_pipeline(
+    rate, err = device_temporal_pipeline(
         jax.device_put(jnp.asarray(words_np), dev),
         jax.device_put(jnp.asarray(nbits_np), dev),
         jax.device_put(jnp.asarray(np.asarray(slots, np.int64)), dev),
         jax.device_put(jnp.asarray(steps), dev),
         n_lanes=n_lanes, n_cap=dp_fine + dp_coarse,
-        range_nanos=range_nanos,
+        fn="rate", range_nanos=range_nanos,
         tiers=jax.device_put(
             jnp.asarray(np.asarray(tiers, np.int64)), dev),
         n_tiers=2)
@@ -498,7 +496,7 @@ def test_device_extra_arg_temporals_on_device():
     emulation).  Neither rides the DEVICE_REDUCERS family iteration
     (extra args), so they get their own lane test."""
     dev = _dev()
-    from m3_tpu.models.query_pipeline import device_reduce_pipeline
+    from m3_tpu.models.query_pipeline import device_temporal_pipeline
     from m3_tpu.ops import consolidate as cons
 
     n_lanes, dp = 5, 96
@@ -522,9 +520,9 @@ def test_device_extra_arg_temporals_on_device():
     args = (jax.device_put(jnp.asarray(words_np), dev),
             jax.device_put(jnp.asarray(nbits_np), dev), slots,
             jax.device_put(jnp.asarray(steps), dev))
-    out, err = device_reduce_pipeline(
+    out, err = device_temporal_pipeline(
         *args, n_lanes=n_lanes, n_cap=dp, range_nanos=range_nanos,
-        reducer="holt_winters", hw_sf=0.3, hw_tf=0.1)
+        fn="holt_winters", hw_sf=0.3, hw_tf=0.1)
     assert not np.asarray(err).any()
     want = cons.window_holt_winters(t_ref, v_ref, steps, range_nanos,
                                     0.3, 0.1)
@@ -532,9 +530,9 @@ def test_device_extra_arg_temporals_on_device():
     np.testing.assert_array_equal(np.isnan(want), np.isnan(got))
     np.testing.assert_allclose(np.nan_to_num(got), np.nan_to_num(want),
                                rtol=1e-9, atol=1e-10)
-    out, err = device_reduce_pipeline(
+    out, err = device_temporal_pipeline(
         *args, n_lanes=n_lanes, n_cap=dp, range_nanos=range_nanos,
-        reducer="quantile_over_time", phi=0.9)
+        fn="quantile_over_time", phi=0.9)
     assert not np.asarray(err).any()
     want = cons.window_quantile(t_ref, v_ref, steps, range_nanos, 0.9)
     got = np.asarray(out)
