@@ -139,35 +139,110 @@ def _window_bounds_device(times, steps, range_nanos):
     return starts_excl, left, right
 
 
+# samples a lane up to which _take_at_device selects and above which it
+# gathers: the selection's cost grows with them and the gather's does
+# not (v5e, [512, n] x 256 steps, both ends of three arrays: 2.01 ms at
+# 1,536, 2.33 at 1,920, 7.07 at 6,144, 21.5 at 18,432 against the
+# gathers' 16.3-16.6 at each; they meet near 14,000.  PERF.md PR 33)
+_SELECT_MAX_N = 12288
+
+
+def window_form(n_cap: int) -> str:
+    """How _rate_device reads a window's first and last sample at
+    `n_cap` samples a lane: "select" or "gather" (_take_at_device).  A
+    function of the static bucket alone, so the engine can count which
+    form served a call without asking the program."""
+    return "select" if n_cap <= _SELECT_MAX_N else "gather"
+
+
+def _take_at_device(xs, idxs):
+    """For each [L, S] index of `idxs` (cells in [0, N)), x[l, idx[l, s]]
+    of every [L, N] array of `xs`: `take_along_axis`, the element
+    itself, whatever its bits (NaN, +-inf, _INF padding).
+
+    The TPU compiler runs an element-indexed gather one element at a
+    time, 10 ns each whatever N.  Up to _SELECT_MAX_N samples a lane
+    the read is a selection instead: ONE reduction over the sample
+    axis for all of `idxs` and `xs`, whose combiner only selects, never
+    adds or compares values, under the one-hot masks `arange(N) == idx`.
+    Masks and broadcasts fuse into the reduction; no [L, S, N] array
+    exists.  Exactly one cell a (lane, step) is flagged under each
+    index, so whatever order the partial results meet in, the flagged
+    one survives."""
+    L, N = xs[0].shape
+    if window_form(N) == "gather":
+        return [tuple(jnp.take_along_axis(x, idx, axis=1) for x in xs)
+                for idx in idxs]
+    S, k, n = idxs[0].shape[1], len(xs), len(idxs)
+    cell = jnp.arange(N, dtype=I32)
+    wide = tuple(jnp.broadcast_to(x[:, None, :], (L, S, N)) for x in xs)
+
+    def pick(a, b):
+        # an index's flag, and under it one accumulator an x
+        out = [hit_a | hit_b for hit_a, hit_b in zip(a[:n], b[:n])]
+        for i, hit in enumerate(b[:n]):
+            at = slice(n + i * k, n + (i + 1) * k)
+            out += [jnp.where(hit, y, x) for x, y in zip(a[at], b[at])]
+        return tuple(out)
+
+    got = jax.lax.reduce(
+        tuple(cell == idx[:, :, None] for idx in idxs) + wide * n,
+        (jnp.asarray(False),) * n + tuple(
+            jnp.zeros((), x.dtype) for x in xs) * n,
+        pick, (2,))
+    return [got[n + i * k:n + (i + 1) * k] for i in range(n)]
+
+
 def _rate_device(times, values, steps, range_nanos,
                  is_counter: bool, is_rate: bool):
     """Windowed extrapolated rate on device — the jnp port of
     consolidate.extrapolated_rate (upstream Prometheus semantics:
     >=2 samples, counter-reset prefix sums, 1.1x-avg-spacing
-    extrapolation caps, counter zero floor)."""
+    extrapolation caps, counter zero floor).  Lanes go in even chunks
+    of at most _MERGE_LANES (the last overlaps its neighbour where
+    they do not divide): the stage's [lanes, n_cap] and [lanes, S]
+    temporaries, and what the compiler re-lays of a lane batch for the
+    prefix sums, are a chunk's and not the fan-out's."""
+    L = values.shape[0]
+    n_chunks = -(-L // _MERGE_LANES)
+    B = min(L, -(-L // (8 * n_chunks)) * 8)
+    rate = functools.partial(_rate_lanes, steps=steps,
+                             range_nanos=range_nanos,
+                             is_counter=is_counter, is_rate=is_rate)
+    if n_chunks == 1:
+        return rate(times, values)
+
+    def chunk(c, out):
+        lo = jnp.minimum(c * B, L - B)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, rate(jax.lax.dynamic_slice_in_dim(times, lo, B),
+                      jax.lax.dynamic_slice_in_dim(values, lo, B)), lo, 0)
+
+    return jax.lax.fori_loop(0, n_chunks, chunk, jnp.empty(
+        (L, steps.shape[0]), values.dtype))
+
+
+def _rate_lanes(times, values, steps, range_nanos,
+                is_counter: bool, is_rate: bool):
+    """_rate_device of one chunk of lanes."""
     L, N = values.shape
     starts_excl, left, right = _window_bounds_device(
         times, steps, range_nanos)
     has2 = (right - left) >= 2
     i_first = jnp.clip(left, 0, N - 1)
     i_last = jnp.clip(right - 1, 0, N - 1)
-    t_first = jnp.take_along_axis(times, i_first, axis=1)
-    t_last = jnp.take_along_axis(times, i_last, axis=1)
-    v_first = jnp.take_along_axis(values, i_first, axis=1)
-    v_last = jnp.take_along_axis(values, i_last, axis=1)
-
+    ends = (times, values)
     if is_counter and N > 1:
         prev = values[:, :-1]
         curr = values[:, 1:]
         resets = jnp.where(curr < prev, prev, 0.0)
-        cum = jnp.concatenate(
+        ends += (jnp.concatenate(
             [jnp.zeros((L, 1), values.dtype),
-             jnp.cumsum(resets, axis=1)], axis=1)
-        corr = (jnp.take_along_axis(cum, jnp.clip(right - 1, 0, N - 1),
-                                    axis=1)
-                - jnp.take_along_axis(cum, jnp.clip(left, 0, N - 1),
-                                      axis=1))
-        corr = jnp.where(has2, corr, 0.0)
+             jnp.cumsum(resets, axis=1)], axis=1),)
+    (t_first, v_first, *cum_first), (t_last, v_last, *cum_last) = (
+        _take_at_device(ends, (i_first, i_last)))
+    if cum_first:
+        corr = jnp.where(has2, cum_last[0] - cum_first[0], 0.0)
     else:
         corr = jnp.zeros_like(v_last)
 

@@ -1407,10 +1407,16 @@ class Engine:
         if self._rows_flagged(pk, err_np):
             self._decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
+        window_form = (query_pipeline.window_form(pk["n_cap"])
+                       if fn in ("rate", "increase", "delta") else None)
+        if window_form:
+            instrument.counter("m3_device_window_form_total",
+                               form=window_form).inc()
         self._publish_stats(
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
             rows=pk["n_rows"], open_rows=pk["open_rows"],
+            window_form=window_form,
             **stats, n_shards=n_shards)
         return out
 
@@ -2175,6 +2181,9 @@ class Engine:
                 # many of them came from open buffers
                 "rows": stats.get("rows", 0),
                 "open_rows": stats.get("open_rows", 0),
+                # how the per-node program read its windows' ends
+                # (rate / increase / delta): "select" or "gather"
+                "window_form": stats.get("window_form"),
                 "fileset_scans": cost.fileset_scans,
                 "device_serving": bool(stats.get("device_serving")),
                 "fn": stats.get("fn"),

@@ -245,6 +245,45 @@ def test_mutable_range_is_served_by_the_device_tier(db):
     assert (rec["rows"], rec["open_rows"]) == (16, 4)
 
 
+@pytest.mark.parametrize("expr,form,max_n", [
+    ("sum by (dc) (rate(sealed[8m]))", "select", None),
+    ("increase(sealed[8m])", "select", None),
+    ("delta(sealed[8m])", "gather", 0),
+    ("sum by (dc) (rate(sealed[11m]))", "gather", 0),
+    ("max_over_time(sealed[8m])", None, None),
+])
+def test_window_form_is_counted_and_recorded(db, monkeypatch, expr, form,
+                                             max_n):
+    """A per-node call of the rate family counts the form its n_cap
+    bucket takes (query_pipeline.window_form) and the record says the
+    same word; another temporal function reads no window ends and
+    counts none.  The answer does not depend on the form."""
+    def forget():      # programs traced under another constant
+        qp.device_temporal_pipeline.__wrapped__.clear_cache()
+        qp.device_grouped_pipeline.__wrapped__.clear_cache()
+
+    if max_n is not None:
+        monkeypatch.setattr(qp, "_SELECT_MAX_N", max_n)
+        forget()
+    counters = {f: instrument.counter("m3_device_window_form_total", form=f)
+                for f in ("select", "gather")}
+    before = {f: c.value for f, c in counters.items()}
+    eng = Engine(db, "default", device_serving=True)
+    _, mat = eng.query_range(expr, START, END, STEP)
+    rec = _record_of(expr)
+    assert rec["device_serving"] is True
+    assert rec["window_form"] == form
+    assert {f: c.value - before[f] for f, c in counters.items()} == {
+        f: int(f == form) for f in counters}
+    _, host = Engine(db, "default", device_serving=False).query_range(
+        expr, START, END, STEP)
+    np.testing.assert_allclose(np.asarray(mat.values),
+                               np.asarray(host.values), rtol=1e-9,
+                               equal_nan=True)
+    if max_n is not None:
+        forget()
+
+
 def test_http_query_leaves_frontend_in_its_record(db):
     srv = CoordinatorServer(db, port=0).start()
     try:

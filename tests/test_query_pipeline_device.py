@@ -999,6 +999,148 @@ def test_window_bounds_match_searchsorted(case):
             np.searchsorted(times[lane], steps, side="right"))
 
 
+def _window_case(case, N, seed=23):
+    """(times, values, steps, range_nanos) of one lane batch that holds
+    `case` at N samples a lane: what _rate_device reads at a window's
+    ends, at its worst."""
+    rng = np.random.default_rng(seed)
+    L, S = 6, 9
+    range_nanos = 90 * SEC
+    gaps = rng.integers(0 if case == "duplicates" else 1, 4, (L, N))
+    times = T0 + np.cumsum(gaps, axis=1) * 10 * SEC
+    values = np.cumsum(rng.integers(0, 50, (L, N)), axis=1).astype(float)
+    steps = times[0, -1] - (S - 1 - np.arange(S, dtype=np.int64)) * 70 * SEC
+    if case == "padding":                 # _INF / NaN past a lane's count
+        fill = np.arange(N)[None, :] >= rng.integers(0, N + 1, (L, 1))
+        fill[0], fill[1], fill[2, 1:] = True, False, True
+        times = np.where(fill, _INF, times)
+        values = np.where(fill, np.nan, values)
+    if case == "nan_inf_samples":         # real samples, not padding
+        odd = np.array([np.nan, np.inf, -np.inf, -0.0, 1e300, -1e300])
+        values = np.where(rng.random((L, N)) < 0.5,
+                          odd[rng.integers(0, len(odd), (L, N))], values)
+    if case == "left_is_n":               # windows that open past the end
+        steps = times.max() + (np.arange(S, dtype=np.int64) + 2) * 100 * SEC
+    if case == "right_is_0":              # steps before the first sample
+        steps = T0 - (np.arange(S, dtype=np.int64)[::-1] + 1) * 100 * SEC
+    if case == "empty_and_one_sample":    # 0 or 1 sample a window
+        range_nanos = 5 * SEC
+    if case == "counter_resets":
+        for lane in range(L):
+            cut = rng.integers(1, N)
+            values[lane, cut:] -= values[lane, cut] - 1.0
+    if case == "steps_on_samples":        # both window edges inclusive
+        steps = times[2, N - S:].copy()
+        range_nanos = int(steps[3] - times[2, N - S - 2])
+    return times, values, steps, range_nanos
+
+
+_WINDOW_CASES = ["duplicates", "padding", "nan_inf_samples", "left_is_n",
+                 "right_is_0", "empty_and_one_sample", "counter_resets",
+                 "steps_on_samples"]
+
+
+def _window_sizes():
+    """A lane width on each side of the constant, by the form it takes."""
+    from m3_tpu.models.query_pipeline import _SELECT_MAX_N
+    return [pytest.param(40, id="select"),
+            pytest.param(_SELECT_MAX_N, id="select_at_the_constant"),
+            pytest.param(_SELECT_MAX_N + 8, id="gather")]
+
+
+@pytest.mark.parametrize("N", _window_sizes())
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+def test_take_at_is_take_along_axis(case, N):
+    """_take_at_device hands back the very elements, in either form:
+    nothing added, nothing rounded, no padding leaked, a NaN or an
+    infinity that was picked still one."""
+    from m3_tpu.models.query_pipeline import (_SELECT_MAX_N,
+                                              _take_at_device,
+                                              _window_bounds_device,
+                                              window_form)
+
+    times, values, steps, range_nanos = _window_case(case, N)
+    assert window_form(N) == ("gather" if N > _SELECT_MAX_N else "select")
+    _, left, right = jax.jit(_window_bounds_device)(
+        jnp.asarray(times), jnp.asarray(steps), jnp.int64(range_nanos))
+    cum = np.cumsum(np.nan_to_num(values, posinf=7.0, neginf=-7.0) % 1e3,
+                    axis=1)
+    xs = (times, values, cum)
+    idxs = (np.clip(np.asarray(left), 0, N - 1),
+            np.clip(np.asarray(right) - 1, 0, N - 1))
+    for idx, got in zip(idxs, jax.jit(_take_at_device)(
+            tuple(map(jnp.asarray, xs)), tuple(map(jnp.asarray, idxs)))):
+        assert len(got) == len(xs)
+        for g, x in zip(got, xs):
+            want = np.take_along_axis(x, idx, axis=1)
+            assert np.asarray(g).dtype == x.dtype
+            assert np.array_equal(np.asarray(g), want, equal_nan=True)
+            assert np.array_equal(np.signbit(np.asarray(g)),
+                                  np.signbit(want))
+    if case == "left_is_n":
+        assert (np.asarray(left) == N).all()
+    if case == "right_is_0":
+        assert (np.asarray(right) == 0).all()
+    if case == "empty_and_one_sample":
+        assert set(np.unique(np.asarray(right) - np.asarray(left))) <= {0, 1}
+
+
+@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+def test_rate_family_by_selection_equals_by_gather(case, fn, monkeypatch):
+    """The windowed answer does not depend on the form of the reads:
+    the same inputs through the selection and through the gathers (the
+    program as it was before the selection) agree bit for bit, NaN for
+    NaN."""
+    from m3_tpu.models import query_pipeline as qp
+
+    times, values, steps, range_nanos = _window_case(case, 48)
+    args = (jnp.asarray(times), jnp.asarray(values), jnp.asarray(steps),
+            jnp.int64(range_nanos))
+    assert qp.window_form(48) == "select"
+    selected = np.asarray(jax.jit(functools.partial(
+        qp._temporal_eval, fn))(*args))
+    monkeypatch.setattr(qp, "_SELECT_MAX_N", 0)
+    assert qp.window_form(48) == "gather"
+    gathered = np.asarray(jax.jit(functools.partial(
+        qp._temporal_eval, fn))(*args))
+    assert np.array_equal(selected, gathered, equal_nan=True)
+    real = ~np.isnan(selected)          # a computed NaN's sign is no one's
+    assert np.array_equal(np.signbit(selected[real]),
+                          np.signbit(gathered[real]))
+    if case in ("duplicates", "counter_resets", "steps_on_samples"):
+        assert np.isfinite(selected).any()
+
+
+@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
+@pytest.mark.parametrize("lanes,at_a_time", [(20, 8), (16, 8), (9, 4)])
+def test_rate_lanes_at_a_time_equals_one_chunk(fn, lanes, at_a_time,
+                                               monkeypatch):
+    """Past _MERGE_LANES lanes the windowed rate goes a chunk of lanes
+    at a time (even chunks; the last overlaps its neighbour where they
+    do not divide): the same answer, lane for lane, as all at once."""
+    from m3_tpu.models import query_pipeline as qp
+
+    rng = np.random.default_rng(29)
+    N, S = 48, 9
+    times = T0 + np.cumsum(rng.integers(1, 4, (lanes, N)), axis=1) * 10 * SEC
+    values = np.cumsum(rng.integers(0, 50, (lanes, N)), axis=1).astype(float)
+    values[::3, 20:] -= values[::3, 20:21]        # counter resets
+    fill = np.arange(N)[None, :] >= rng.integers(N // 2, N + 1, (lanes, 1))
+    times, values = np.where(fill, _INF, times), np.where(fill, np.nan, values)
+    steps = T0 + (np.arange(S, dtype=np.int64) + 3) * 100 * SEC
+    args = (jnp.asarray(times), jnp.asarray(values), jnp.asarray(steps),
+            jnp.int64(120 * SEC))
+    whole = np.asarray(jax.jit(functools.partial(
+        qp._temporal_eval, fn))(*args))
+    monkeypatch.setattr(qp, "_MERGE_LANES", at_a_time)
+    lowered = jax.jit(functools.partial(qp._temporal_eval, fn)).lower(*args)
+    assert "stablehlo.while" in lowered.as_text()
+    chunked = np.asarray(lowered.compile()(*args))
+    assert np.array_equal(whole, chunked, equal_nan=True)
+    assert np.isfinite(whole).any(axis=1).all()
+
+
 def _walk_jaxpr(jaxpr, scope=""):
     """(primitive, named-scope path) of every equation, inner jits,
     loops and branches included."""
@@ -1018,9 +1160,10 @@ def test_grouped_program_has_no_per_element_addressing():
     """The TPU compiler runs an element-indexed scatter or gather one
     element at a time (PERF.md, PR 26: 134 of the program's 213 ms were
     two scatters, 59 ms two binary searches).  The grouped program
-    keeps its scatters to the [n_groups, S] reduction and its window
-    bounds free of loops; a later edit that brings either back fails
-    here, on the CPU."""
+    keeps its scatters to the [n_groups, S] reduction and its windowed
+    stage free of loops and of gathers (PR 33: twelve were 16.0 of
+    21.2 ms); a later edit that brings one back fails here, on the
+    CPU."""
     from m3_tpu.models.query_pipeline import device_grouped_pipeline
 
     M, W, L, S = 16, 8, 8, 4
@@ -1034,9 +1177,9 @@ def test_grouped_program_has_no_per_element_addressing():
         jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr))
     scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
     assert scatters and all("m3.group" in s for _, s in scatters), scatters
-    loops = [(p, s) for p, s in ops
-             if p in ("while", "scan") and "m3.temporal" in s]
-    assert not loops, loops
+    per_element = [(p, s) for p, s in ops if p in ("while", "scan", "gather")
+                   and "m3.temporal" in s]
+    assert not per_element, per_element
     # and the lowered text agrees: no scatter but the reduction's, and
     # the windowed stage alone lowers without a loop
     text = fn.lower(*args, **kw).as_text()
@@ -1048,10 +1191,50 @@ def test_grouped_program_has_no_per_element_addressing():
     assert "stablehlo.while" not in text and "stablehlo.scatter" not in text
 
 
+def _rate_at(n_cap, L=8, S=4):
+    """The windowed rate alone and abstract arguments at n_cap samples
+    a lane."""
+    from m3_tpu.models.query_pipeline import _temporal_eval
+    sds = jax.ShapeDtypeStruct
+    return functools.partial(_temporal_eval, "rate"), (
+        sds((L, n_cap), np.int64), sds((L, n_cap), np.float64),
+        sds((S,), np.int64), jnp.int64(300 * SEC))
+
+
+@pytest.mark.parametrize("n_cap", [1536, 1920],
+                         ids=["dash-sealed", "dash-live"])
+def test_rate_at_the_cells_width_has_no_per_element_addressing(n_cap):
+    """The same disease, the gathers (PERF.md, PR 33: twelve of 1.33 ms
+    were 16.0 of the program's 21.2 ms): at the benchmark cells' lane
+    width the windowed rate lowers without a gather, and still without
+    a loop or a scatter."""
+    from m3_tpu.models.query_pipeline import window_form
+
+    assert window_form(n_cap) == "select"
+    rate, args = _rate_at(n_cap)
+    text = jax.jit(rate).lower(*args).as_text()
+    for op in ("stablehlo.gather", "stablehlo.dynamic_gather",
+               "stablehlo.while", "stablehlo.scatter"):
+        assert op not in text, op
+
+
+def test_rate_above_the_constant_keeps_its_six_gathers():
+    """Selection costs L x N x S and the gather L x S: past
+    _SELECT_MAX_N samples a lane the six reads are gathers again."""
+    from m3_tpu.models.query_pipeline import _SELECT_MAX_N, window_form
+
+    n_cap = _SELECT_MAX_N + 128
+    assert window_form(n_cap) == "gather"
+    rate, args = _rate_at(n_cap)
+    ops = [p for p, _ in _walk_jaxpr(jax.make_jaxpr(rate)(*args).jaxpr)]
+    assert ops.count("gather") == 6
+    assert not {"while", "scan", "scatter", "scatter-add"} & set(ops)
+
+
 def test_open_rows_keep_the_grouped_programs_structure():
     """Rows that arrive as arrays (open buffers) go through the same
-    merge: still no scatter outside m3.group and no loop in the
-    windowed stage, the rows are laid under m3.open, and a call without
+    merge: still no scatter outside m3.group and no loop or gather in
+    the windowed stage, the rows are laid under m3.open, and a call without
     them lowers to the program it was before they existed."""
     from m3_tpu.models.query_pipeline import device_grouped_pipeline
 
@@ -1070,7 +1253,7 @@ def test_open_rows_keep_the_grouped_programs_structure():
     scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
     assert scatters and all("m3.group" in s for _, s in scatters), scatters
     assert not [(p, s) for p, s in ops
-                if p in ("while", "scan") and "m3.temporal" in s]
+                if p in ("while", "scan", "gather") and "m3.temporal" in s]
     assert any("m3.open" in s for _, s in ops)
     with_rows = fn.lower(*args, open_rows=open_rows, **kw).as_text()
     assert with_rows.count('"stablehlo.scatter"(') == len(scatters)
