@@ -48,6 +48,14 @@ _INF = jnp.iinfo(jnp.int64).max
 _MERGE_LANES = 512
 
 
+def lane_chunks(n_lanes: int) -> int:
+    """Rounds in which _merge_device and _rate_device go through
+    `n_lanes` lanes, _MERGE_LANES at a time.  A function of the static
+    lane bucket alone, so the engine can put it on a query's record
+    without asking the program."""
+    return -(-n_lanes // _MERGE_LANES)
+
+
 def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
                   order=None):
     """Compact per-(series, block) decode grids into the packed
@@ -118,7 +126,7 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
         return tuple(jax.lax.dynamic_update_slice_in_dim(o, d, lo, 0)
                      for o, d in zip(outs, done))
 
-    return jax.lax.fori_loop(0, -(-n_lanes // B), chunk, (
+    return jax.lax.fori_loop(0, lane_chunks(n_lanes), chunk, (
         jnp.empty((n_lanes, n_cap), jnp.int64),
         jnp.empty((n_lanes, n_cap), vs.dtype),
         jnp.empty((n_lanes,), I32)))
@@ -204,7 +212,7 @@ def _rate_device(times, values, steps, range_nanos,
     temporaries, and what the compiler re-lays of a lane batch for the
     prefix sums, are a chunk's and not the fan-out's."""
     L = values.shape[0]
-    n_chunks = -(-L // _MERGE_LANES)
+    n_chunks = lane_chunks(L)
     B = min(L, -(-L // (8 * n_chunks)) * 8)
     rate = functools.partial(_rate_lanes, steps=steps,
                              range_nanos=range_nanos,
@@ -236,9 +244,17 @@ def _rate_lanes(times, values, steps, range_nanos,
         prev = values[:, :-1]
         curr = values[:, 1:]
         resets = jnp.where(curr < prev, prev, 0.0)
+        # jnp.cumsum's own lowering, spelled out: its rule emits this
+        # reduce_window from a cached function that drops the caller's
+        # scope.  Here the lowered operation is named under m3.temporal;
+        # the TPU compiler still splits so wide a window in two pieces
+        # that carry no name (PERF.md, PR 34), so a chip's trace shows
+        # it scoped only inside a chunk loop
         ends += (jnp.concatenate(
             [jnp.zeros((L, 1), values.dtype),
-             jnp.cumsum(resets, axis=1)], axis=1),)
+             jax.lax.reduce_window(
+                 resets, values.dtype.type(0), jax.lax.add,
+                 (1, N - 1), (1, 1), ((0, 0), (N - 2, 0)))], axis=1),)
     (t_first, v_first, *cum_first), (t_last, v_last, *cum_last) = (
         _take_at_device(ends, (i_first, i_last)))
     if cum_first:

@@ -18,6 +18,12 @@ jitted entry point and records, per kernel:
   ``m3_kernel_bytes_total{kernel}`` — call rate and input volume
 - ``m3_kernel_result_bytes_total{kernel}`` — device->host result
   volume (the transfer the fused path pays to bring answers back)
+- ``m3_kernel_hbm_peak_bytes{kernel}`` (``hbm_peak_bytes`` in the
+  stats) — the compiler's own account of the program's peak in device
+  memory: arguments, result and temporaries of the compiled executable
+  (``memory_analysis()``), the largest of the kernel's compilations.
+  Read once a compile, where the compiling call is detected, from the
+  executable that call left in jit's cache; never on a cache-hit call
 - ``m3_kernel_queued_ahead_total{kernel}`` and the process-wide gauge
   ``m3_device_inflight`` — the device's queue, counted where calls
   are dispatched: one device runs one program at a time, so a call
@@ -81,6 +87,21 @@ def _is_traced(args, kwargs) -> bool:
         isinstance(v, jax.core.Tracer) for v in kwargs.values())
 
 
+def _hbm_peak_bytes(fn, args, kwargs) -> int:
+    """The peak device memory of the executable that `fn(*args,
+    **kwargs)` has just compiled, by the compiler's own account; 0
+    where the backend gives none.  `lower().compile()` of a signature
+    jit has compiled hands back the cached executable (0.6 ms on
+    XLA:CPU), it does not compile again."""
+    try:
+        mem = fn.lower(*args, **kwargs).compile().memory_analysis()
+        return int(getattr(mem, "peak_memory_in_bytes", 0) or (
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes))
+    except Exception:  # noqa: BLE001 - telemetry is best-effort
+        return 0
+
+
 def _arg_volume(args, kwargs):
     """(elements, bytes) across array-like inputs, walking nested
     tuple/list/dict pytrees — the fused whole-query pipeline passes
@@ -119,6 +140,7 @@ class InstrumentedKernel:
             "compile_s": 0.0, "execute_s": 0.0,
             "dispatch_s": 0.0, "wait_s": 0.0, "queued_ahead": 0,
             "elements": 0, "bytes": 0, "result_bytes": 0,
+            "hbm_peak_bytes": 0,
         }
         try:
             self.__dict__["__wrapped__"] = fn
@@ -159,6 +181,7 @@ class InstrumentedKernel:
                 compiled = False
         elements, nbytes = _arg_volume(args, kwargs)
         _, result_bytes = _arg_volume((out,), {})
+        hbm_peak = _hbm_peak_bytes(fn, args, kwargs) if compiled else 0
         st = self.__dict__["_stats"]
         with self.__dict__["_lock"]:
             st["invocations"] += 1
@@ -169,6 +192,8 @@ class InstrumentedKernel:
             if compiled:
                 st["compiles"] += 1
                 st["compile_s"] += elapsed
+                hbm_peak = max(st["hbm_peak_bytes"], hbm_peak)
+                st["hbm_peak_bytes"] = hbm_peak
             else:
                 st["execute_s"] += elapsed
                 st["dispatch_s"] += dispatch_s
@@ -194,6 +219,8 @@ class InstrumentedKernel:
             pass
         if compiled:
             _metrics.counter("m3_kernel_compiles_total", kernel=name).inc()
+            _metrics.gauge("m3_kernel_hbm_peak_bytes",
+                           kernel=name).set(hbm_peak)
             _metrics.histogram("m3_kernel_compile_seconds",
                                kernel=name).observe(elapsed)
         else:
@@ -227,10 +254,13 @@ class InstrumentedKernel:
             return dict(self.__dict__["_stats"])
 
     def reset(self) -> None:
+        """Zero the counts and seconds; `hbm_peak_bytes` describes the
+        executables compiled so far, which a reset does not drop."""
+        st = self.__dict__["_stats"]
         with self.__dict__["_lock"]:
-            for k in self.__dict__["_stats"]:
-                self.__dict__["_stats"][k] = 0 if isinstance(
-                    self.__dict__["_stats"][k], int) else 0.0
+            for k in st:
+                if k != "hbm_peak_bytes":
+                    st[k] = 0 if isinstance(st[k], int) else 0.0
 
 
 def instrument_kernel(name: str):

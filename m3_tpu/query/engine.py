@@ -1416,6 +1416,12 @@ class Engine:
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
             rows=pk["n_rows"], open_rows=pk["open_rows"],
+            # the fan-out: series merged, the lane bucket the program
+            # was compiled for, and the rounds in which a shard's merge
+            # and windowed stage go through its lanes
+            lanes=pk["n_lanes"], lanes_pad=pk["lanes_pad"],
+            lane_chunks=query_pipeline.lane_chunks(
+                pk["lanes_pad"] // n_shards),
             window_form=window_form,
             **stats, n_shards=n_shards)
         return out
@@ -2181,6 +2187,13 @@ class Engine:
                 # many of them came from open buffers
                 "rows": stats.get("rows", 0),
                 "open_rows": stats.get("open_rows", 0),
+                # per-node device tier: the series the program merged,
+                # the lane bucket it ran at, and its lane chunks
+                # (lanes x lane_chunks tells a fleet-wide panel from a
+                # dashboard row)
+                "lanes": stats.get("lanes", 0),
+                "lanes_pad": stats.get("lanes_pad", 0),
+                "lane_chunks": stats.get("lane_chunks", 0),
                 # how the per-node program read its windows' ends
                 # (rate / increase / delta): "select" or "gather"
                 "window_form": stats.get("window_form"),
@@ -2241,6 +2254,9 @@ class Engine:
             if rec["open_rows"]:
                 instrument.counter("m3_query_open_rows_total").inc(
                     rec["open_rows"])
+            if rec["lanes"]:
+                instrument.counter("m3_query_lanes_total").inc(
+                    rec["lanes"])
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
                 # scanned and device execute seconds are accounted at

@@ -99,7 +99,11 @@ def write_points(sess, n_series=10, n_dp=5):
 
 class TestReplicatedWrites:
     def test_writes_reach_all_replicas(self, tmp_path):
-        store, svc, dbs, nodes, topo, sess = make_cluster(tmp_path)
+        # level ALL: a write returns once every replica has it (at
+        # MAJORITY the third may still be in flight when the loop below
+        # reads: it failed one whole run in two under six workers)
+        store, svc, dbs, nodes, topo, sess = make_cluster(
+            tmp_path, write_level=WriteConsistencyLevel.ALL)
         write_points(sess, n_series=6, n_dp=4)
         # RF=3 over 3 nodes: every node holds every series
         for name, db in dbs.items():

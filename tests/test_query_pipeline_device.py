@@ -1156,39 +1156,71 @@ def _walk_jaxpr(jaxpr, scope=""):
                 yield from _walk_jaxpr(getattr(v, "jaxpr", v), path)
 
 
-def test_grouped_program_has_no_per_element_addressing():
+# M, W, L, S, n_cap, n_dp, n_groups of the grouped program: the size the
+# stage tests run at, and the benchmark cells' (lowered, never run)
+_STRUCTURE_SHAPES = {
+    "tiny": (16, 8, 8, 4, 32, 16, 4),
+    "dash-sealed": (1024, 256, 512, 256, 1536, 768, 16),
+    "fanout-fleet": (25024, 256, 12544, 256, 1536, 768, 32),
+}
+
+
+@pytest.mark.parametrize("shape", _STRUCTURE_SHAPES)
+def test_grouped_program_has_no_per_element_addressing(shape):
     """The TPU compiler runs an element-indexed scatter or gather one
     element at a time (PERF.md, PR 26: 134 of the program's 213 ms were
     two scatters, 59 ms two binary searches).  The grouped program
     keeps its scatters to the [n_groups, S] reduction and its windowed
-    stage free of loops and of gathers (PR 33: twelve were 16.0 of
-    21.2 ms); a later edit that brings one back fails here, on the
-    CPU."""
-    from m3_tpu.models.query_pipeline import device_grouped_pipeline
+    stage free of gathers (PR 33: twelve were 16.0 of 21.2 ms) and of
+    loops but the one over lane chunks, which a fan-out past
+    _MERGE_LANES brings; a later edit that brings one back fails here,
+    on the CPU, at a dashboard row's shape and at the whole fleet's."""
+    from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
+                                              lane_chunks)
 
-    M, W, L, S = 16, 8, 8, 4
+    M, W, L, S, n_cap, n_dp, n_groups = _STRUCTURE_SHAPES[shape]
+    chunked = lane_chunks(L) > 1
+    assert chunked == (shape == "fanout-fleet")
     sds = jax.ShapeDtypeStruct
     args = (sds((M, W), np.uint32), sds((M,), np.int32),
             sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
-    kw = dict(n_lanes=L, n_groups=4, n_cap=32, n_dp=16,
+    kw = dict(n_lanes=L, n_groups=n_groups, n_cap=n_cap, n_dp=n_dp,
               range_nanos=jnp.int64(300 * SEC))
     fn = device_grouped_pipeline.__wrapped__     # the jitted function
     ops = list(_walk_jaxpr(
         jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr))
     scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
     assert scatters and all("m3.group" in s for _, s in scatters), scatters
-    per_element = [(p, s) for p, s in ops if p in ("while", "scan", "gather")
-                   and "m3.temporal" in s]
-    assert not per_element, per_element
-    # and the lowered text agrees: no scatter but the reduction's, and
-    # the windowed stage alone lowers without a loop
+    assert not [(p, s) for p, s in ops
+                if p == "gather" and "m3.temporal" in s]
+    # the loops: the decode scan; the merge's lane -> first row search,
+    # its chunks and a chunk's rows; the windowed stage's chunks
+    loops = sorted((s.strip("/").split("/")[0], p) for p, s in ops
+                   if p in ("while", "scan"))
+    assert loops == sorted(
+        [("m3.decode", "scan"), ("m3.merge", "scan"), ("m3.merge", "scan"),
+         ("m3.merge", "while")] + [("m3.temporal", "scan")] * chunked), loops
+    # and the lowered text agrees: no scatter but the reduction's, the
+    # search unrolled, and the windowed stage alone lowers without a
+    # loop until its lanes pass one chunk
     text = fn.lower(*args, **kw).as_text()
     assert text.count('"stablehlo.scatter"(') == len(scatters)
+    assert text.count("stablehlo.while") == 3 + chunked
     from m3_tpu.models.query_pipeline import _temporal_eval
-    text = jax.jit(functools.partial(_temporal_eval, "rate")).lower(
-        sds((L, 32), np.int64), sds((L, 32), np.float64), args[3],
-        kw["range_nanos"]).as_text()
-    assert "stablehlo.while" not in text and "stablehlo.scatter" not in text
+    stage = jax.jit(functools.partial(_temporal_eval, "rate")).lower(
+        sds((L, n_cap), np.int64), sds((L, n_cap), np.float64), args[3],
+        kw["range_nanos"])
+    text = stage.as_text()
+    assert text.count("stablehlo.while") == chunked
+    assert "stablehlo.scatter" not in text and "stablehlo.gather" not in text
+    # the reset prefix sum is the stage's own operation under the
+    # stage's scope (inside a chunk loop's body names are relative to
+    # the loop's), not jnp.cumsum's cached function, which has none
+    assert text.count("stablehlo.reduce_window") == 1
+    assert "@cumsum" not in text
+    named = stage.as_text(debug_info=True)
+    assert ("m3.temporal/reduce_window_sum" in named) != chunked
+    assert "m3.temporal/while/body" in named or not chunked
 
 
 def _rate_at(n_cap, L=8, S=4):

@@ -265,6 +265,24 @@ def test_per_node_pipeline_compiles(one_chip, tiny_db, monkeypatch,
     _compile(getattr(qp, kernel), *new_args, **new_kw)
 
 
+def test_grouped_pipeline_compiles_at_the_fleet_cells_shape(one_chip):
+    """The benchmark cell `fanout-fleet`: 25,000 sealed streams of every
+    series a node holds -> 12,544 lanes x 1,536 in 25 chunks, 256
+    steps, 25 jobs.  At this shape the chip's compiler takes seconds
+    (5.4 s here, PR 34), so it is tier-1; the chunk loops keep the
+    program's temporaries at a chunk's size, 0.57 GB of the 16 (at
+    [lanes, n_cap, steps] the selection alone would be 39 GB)."""
+    M, W, L, S = 25_024, 256, 12_544, 256
+    assert qp.lane_chunks(L) == 25
+    sds = lambda shape, dt: _sds(shape, dt, one_chip)   # noqa: E731
+    compiled = _compile(
+        qp.device_grouped_pipeline, sds((M, W), np.uint32),
+        sds((M,), np.int32), sds((M,), np.int64), sds((S,), np.int64),
+        sds((L,), np.int64), n_lanes=L, n_groups=32, n_cap=1536, n_dp=768,
+        range_nanos=sds((), np.int64))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.slow
 def test_grouped_pipeline_compiles(one_chip, tiny_db, monkeypatch):
     """sum by (job)(rate(...)) as the engine dispatches it: the
