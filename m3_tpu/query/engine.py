@@ -519,22 +519,27 @@ class Engine:
         return g
 
     def _pack_streams_cached(self, matchers, start_nanos: int,
-                             end_nanos: int, streams):
+                             end_nanos: int, streams, bucket):
         """Memoize pack_streams output on the gather memo entry, so a
         query that repeats a selector (or a device path that declines
         after packing) skips the host-side re-pack, not just the
         re-gather.  `streams` must be derived deterministically from
         the memoized gather (same ordering), which every caller
-        guarantees — the pack is keyed by the gather key alone."""
+        guarantees.  The words come laid out in their jit buckets
+        (rows and width rounded up by `bucket`, zero past the
+        streams), so the pack is keyed by the gather key and, under
+        it, by `bucket`: the per-node tier's and the fused planner's
+        differ, and a selector packed for one and asked for by the
+        other is packed again."""
         memo = getattr(self._qrange_local, "gather_cache", None)
         key = (tuple(matchers), start_nanos, end_nanos)
         ent = memo.get(key) if memo is not None else None
-        if ent is not None and "pack" in ent:
-            return ent["pack"]
-        from m3_tpu.ops.bitstream import pack_streams
-        pack = pack_streams(streams)
-        if ent is not None:
-            ent["pack"] = pack
+        packs = {} if ent is None else ent.setdefault("pack", {})
+        pack = packs.get(bucket)
+        if pack is None:
+            from m3_tpu.ops.bitstream import pack_streams
+            pack = packs[bucket] = pack_streams(
+                streams, pad=lambda n: bucket(n, 64))
         return pack
 
     def _arrays_grid_cached(self, matchers, start_nanos: int,
@@ -1199,16 +1204,11 @@ class Engine:
         m_pad = bucket(len(streams), 64)
         s_pad = bucket(len(shifted), 64)
         # pack memo: the multi-tier reorder above is deterministic from
-        # the memoized gather, so the gather key alone identifies the
-        # packed words (satellite of the whole-query fusion PR: a
-        # repeated selector skips the host-side re-pack too)
-        words, nbits = self._pack_streams_cached(rv.matchers, lo, hi,
-                                                 streams)
-        w_pad = bucket(words.shape[1], 64)
-        words_p = np.zeros((m_pad, w_pad), dtype=words.dtype)
-        words_p[:len(streams), :words.shape[1]] = words
-        nbits_p = np.zeros(m_pad, dtype=nbits.dtype)
-        nbits_p[:len(streams)] = nbits
+        # the memoized gather, so the gather key and the bucket identify
+        # the packed words, which come padded to [m_pad, bucketed width]
+        # (a repeated selector skips the host-side re-pack too)
+        words_p, nbits_p = self._pack_streams_cached(
+            rv.matchers, lo, hi, streams, bucket)
         # padding streams (nbits=0, immediately done) park on the last
         # padding lane; lanes_pad > n_lanes is guaranteed only when
         # padding streams exist, so force one spare lane if needed

@@ -346,9 +346,9 @@ def test_pack_streams_memoized_per_query(engines, monkeypatch):
     calls = []
     real = bitstream.pack_streams
 
-    def counting(streams):
+    def counting(streams, **kwargs):
         calls.append(len(streams))
-        return real(streams)
+        return real(streams, **kwargs)
 
     monkeypatch.setattr(bitstream, "pack_streams", counting)
     expr = ("sum by (dc)(rate(http_req[5m]))"
@@ -358,6 +358,35 @@ def test_pack_streams_memoized_per_query(engines, monkeypatch):
     assert np.array_equal(mh.values, md.values, equal_nan=True)
     # one pack for the device engine; the host engine never packs
     assert len(calls) == 1, calls
+
+
+def test_pack_memo_is_keyed_by_the_bucket_too(engines):
+    """The memoized words come padded to their jit buckets, and the
+    per-node tier's linear bucket and the fused planner's power of two
+    differ: one gather packed for both must give each its own shape,
+    and each of them once."""
+    from m3_tpu.ops.bitstream import pack_streams
+    from m3_tpu.query.plan import _bucket_pow2
+
+    _, dev = engines
+    streams = [bytes([i + 1]) * (5 + i % 50) for i in range(130)]
+    matchers, lo, hi = ("m",), 0, 10
+    dev._qrange_local.gather_cache = {(matchers, lo, hi): {}}
+    try:
+        packs = {b: dev._pack_streams_cached(matchers, lo, hi, streams, b)
+                 for b in (Engine._bucket, _bucket_pow2)}
+        for b, (words, nbits) in packs.items():
+            assert dev._pack_streams_cached(
+                matchers, lo, hi, streams, b)[0] is words
+            assert words.shape == (b(130, 64), 64)
+            assert len(nbits) == b(130, 64)
+            want, want_bits = pack_streams(streams)
+            assert np.array_equal(words[:130, :want.shape[1]], want)
+            assert np.array_equal(nbits[:130], want_bits)
+            assert not words[130:].any() and not nbits[130:].any()
+        assert [len(nbits) for _, nbits in packs.values()] == [192, 256]
+    finally:
+        dev._qrange_local.gather_cache = None
 
 
 def test_warm_arrays_bridge_zero_decode(tmp_path):
