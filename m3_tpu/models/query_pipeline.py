@@ -1507,7 +1507,6 @@ def _plan_sharded(node) -> bool:
     return False
 
 
-@jax.named_scope("m3.expr")
 def _expr_eval(plan, leaves, params, steps, errors,
                axis=None, n_shards: int = 1):
     """The fused-query interpreter body, shared by the single-chip and
@@ -1518,7 +1517,13 @@ def _expr_eval(plan, leaves, params, steps, errors,
     histogram_quantile / vector-vector row gathers, whose index maps
     are global).  Returns (out, aux) — aux is (present, rank) when the
     root is a topk node (the host reorders rows by final-step rank
-    after the transfer), else ()."""
+    after the transfer), else ().
+
+    Scopes: a leaf's stages keep their own (`m3.decode`, `m3.merge`,
+    `m3.temporal`), a grouped reduce is `m3.group`, a top-k selection
+    `m3.topk`, every other node's own operations `m3.expr`.  No scope
+    wraps another's stage: a trace is read by the first `m3.*` of an
+    operation's name."""
     aux = ()
 
     def gather(vals, valid, node):
@@ -1528,29 +1533,47 @@ def _expr_eval(plan, leaves, params, steps, errors,
         return vals, valid
 
     def ev(node, steps_cur):
-        nonlocal aux
         tag = node[0]
         if tag == "leaf":
-            (_, i, pidx, kind, fn, lanes_pad, n_cap, n_dp, n_tiers,
-             _m_pad, _w_pad, _s_pad, hw_sf, hw_tf) = node
-            lf = leaves[i]
-            if kind == "words":
-                times, values, err = _decode_merge(
-                    lf["words"], lf["nbits"], lf["slots"],
-                    lanes_pad // n_shards, n_cap, n_dp, xtime.SECOND,
-                    lf["tiers"], n_tiers)
-                errors[i] = err
-            else:
-                times, values = lf["times"], lf["values"]
-            horizon, phi = params[pidx]
-            out = _temporal_eval(fn, times, values, lf["steps"],
-                                 lf["rng"], horizon=horizon,
-                                 hw_sf=hw_sf, hw_tf=hw_tf, phi=phi)
-            return jnp.where(lf["valid"][:, None], out,
-                             jnp.nan), lf["valid"]
+            return leaf(node)
+        if tag == "vv":
+            kids = [ev(node[5], steps_cur), ev(node[6], steps_cur)]
+        elif tag == "subq":     # its child evaluates on the inner grid
+            kids = [ev(node[-1], params[node[5]][0])]
+        else:                   # the child is always the last element
+            kids = [ev(node[-1], steps_cur)]
+        if tag in ("agg", "topk"):      # m3.group, m3.topk
+            return apply(node, kids, steps_cur)
+        with jax.named_scope("m3.expr"):
+            return apply(node, kids, steps_cur)
+
+    def leaf(node):
+        (_, i, pidx, kind, fn, lanes_pad, n_cap, n_dp, n_tiers,
+         _m_pad, _w_pad, _s_pad, hw_sf, hw_tf) = node
+        lf = leaves[i]
+        if kind == "words":
+            times, values, err = _decode_merge(
+                lf["words"], lf["nbits"], lf["slots"],
+                lanes_pad // n_shards, n_cap, n_dp, xtime.SECOND,
+                lf["tiers"], n_tiers)
+            errors[i] = err
+        else:
+            times, values = lf["times"], lf["values"]
+        horizon, phi = params[pidx]
+        out = _temporal_eval(fn, times, values, lf["steps"],
+                             lf["rng"], horizon=horizon,
+                             hw_sf=hw_sf, hw_tf=hw_tf, phi=phi)
+        return jnp.where(lf["valid"][:, None], out,
+                         jnp.nan), lf["valid"]
+
+    def apply(node, kids, steps_cur):
+        """One node's own operations over its children's (values,
+        valid) pairs."""
+        nonlocal aux
+        tag = node[0]
+        cv, cvalid = kids[0]
         if tag == "agg":
             _, op, g_pad, pidx, child = node
-            cv, _cvalid = ev(child, steps_cur)
             groups, gvalid, phi = params[pidx]
             out = _grouped_reduce(
                 cv, groups, g_pad, op, phi,
@@ -1558,12 +1581,10 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(gvalid[:, None], out, jnp.nan), gvalid
         if tag == "call":
             _, fn, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             out = _expr_scalar_fn(fn, cv, params[pidx], steps_cur)
             return jnp.where(cvalid[:, None], out, jnp.nan), cvalid
         if tag == "vs":
             _, op, bool_mod, mat_on_left, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             (s,) = params[pidx]
             a, b = (cv, s) if mat_on_left else (s, cv)
             if op in _EXPR_CMP:
@@ -1582,8 +1603,7 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(cvalid[:, None], out, jnp.nan), cvalid
         if tag == "vv":
             _, op, bool_mod, _out_pad, pidx, lhs, rhs = node
-            lv, lvalid = ev(lhs, steps_cur)
-            rv, rvalid = ev(rhs, steps_cur)
+            (lv, lvalid), (rv, rvalid) = kids
             lv, lvalid = gather(lv, lvalid, lhs)
             rv, rvalid = gather(rv, rvalid, rhs)
             lidx, ridx, valid = params[pidx]
@@ -1603,7 +1623,6 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(valid[:, None], out, jnp.nan), valid
         if tag == "topk":
             _, op, k, g_pad, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             cv, cvalid = gather(cv, cvalid, child)
             (groups,) = params[pidx]
             out, present, rank = masked_topk(cv, groups, g_pad, k,
@@ -1612,7 +1631,6 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(cvalid[:, None], out, jnp.nan), cvalid
         if tag == "hq":
             _, g_pad, b_pad, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             cv, _ = gather(cv, cvalid, child)
             rows_idx, ubs, caps, gvalid, phi = params[pidx]
             counts = cv[rows_idx]  # [g_pad, b_pad, S] bucket gather
@@ -1620,7 +1638,6 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(gvalid[:, None], out, jnp.nan), gvalid
         if tag == "absent":
             _, pidx, child = node
-            cv, _cvalid = ev(child, steps_cur)
             (avalid,) = params[pidx]
             present = jnp.any(~jnp.isnan(cv), axis=0)  # [S]
             if axis is not None and _plan_sharded(child):
@@ -1635,7 +1652,6 @@ def _expr_eval(plan, leaves, params, steps, errors,
         if tag == "subq":
             _, fn, _s_in_pad, hw_sf, hw_tf, pidx, child = node
             sub_times, sub_valid, steps_out, rng, horizon = params[pidx]
-            cv, cvalid = ev(child, sub_times)
             # the host packs the inner grid with pack_valid (absent or
             # NaN samples drop, survivors left-justify ascending): one
             # stable row sort keyed +inf-for-dropped reproduces that
@@ -1652,14 +1668,12 @@ def _expr_eval(plan, leaves, params, steps, errors,
             # indices (depth filter / sort / limit / exclude).  The
             # index map is global, so gather the child first.
             _, _out_pad, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             cv, _ = gather(cv, cvalid, child)
             idx, valid = params[pidx]
             out = cv[idx]
             return jnp.where(valid[:, None], out, jnp.nan), valid
         if tag == "gagg":
             _, op, extra, g_pad, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             cv, _ = gather(cv, cvalid, child)
             groups, gvalid, tval = params[pidx]
             out = _graphite_grouped_reduce(cv, groups, g_pad, op,
@@ -1667,7 +1681,6 @@ def _expr_eval(plan, leaves, params, steps, errors,
             return jnp.where(gvalid[:, None], out, jnp.nan), gvalid
         if tag == "gcall":
             _, fn, statics, pidx, child = node
-            cv, cvalid = ev(child, steps_cur)
             out = _graphite_call(fn, cv, statics, params[pidx],
                                  steps_cur)
             return jnp.where(cvalid[:, None], out, jnp.nan), cvalid

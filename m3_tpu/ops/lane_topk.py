@@ -20,6 +20,9 @@ host tier (query/engine.py:_eval_topk):
   host reorders rows after the root transfer.
 
 Called from inside the jitted fused-query interpreter — no jit here.
+The selection runs under the scope ``m3.topk``, beside the interpreter's
+other stages (``m3.decode`` ... ``m3.group``), so a device trace prices
+it apart.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ import jax.numpy as jnp
 _NO_RANK = jnp.int64(2**62)
 
 
+@jax.named_scope("m3.topk")
 def masked_topk(values, groups, n_groups, k, bottom):
     """Select the top/bottom k lanes per (group, step) cell.
 

@@ -2148,8 +2148,8 @@ class Engine:
 
     # the stamped phases that tile a query's time; h2d and d2h lie
     # inside device and are recorded beside it
-    _TILING_PHASES = ("parse_s", "fetch_s", "open_read_s", "pack_s",
-                      "decode_s", "merge_s", "device_s")
+    _TILING_PHASES = ("parse_s", "plan_s", "fetch_s", "open_read_s",
+                      "pack_s", "decode_s", "merge_s", "device_s")
 
     def _record_query_cost(self, query: str, t0_ns: int, result, meta,
                            error: str | None) -> None:
@@ -2187,15 +2187,23 @@ class Engine:
                 # many of them came from open buffers
                 "rows": stats.get("rows", 0),
                 "open_rows": stats.get("open_rows", 0),
-                # per-node device tier: the series the program merged,
-                # the lane bucket it ran at, and its lane chunks
-                # (lanes x lane_chunks tells a fleet-wide panel from a
+                # device tiers: the series the program merged and the
+                # lane bucket it ran at (over all leaves of a fused
+                # tree); per-node tier: its lane chunks (lanes x
+                # lane_chunks tells a fleet-wide panel from a
                 # dashboard row)
                 "lanes": stats.get("lanes", 0),
                 "lanes_pad": stats.get("lanes_pad", 0),
                 "lane_chunks": stats.get("lane_chunks", 0),
-                # how the per-node program read its windows' ends
-                # (rate / increase / delta): "select" or "gather"
+                # fused tier: the real groups of the tree's grouped
+                # reductions, a root topk / bottomk's k, and the rows
+                # of the answer after the root's host reorder
+                "groups": stats.get("groups", 0),
+                "topk_k": stats.get("topk_k", 0),
+                "rows_out": stats.get("rows_out", 0),
+                # how the program read its windows' ends (rate /
+                # increase / delta): "select" or "gather" ("mixed"
+                # where a fused tree's leaves differ)
                 "window_form": stats.get("window_form"),
                 "fileset_scans": cost.fileset_scans,
                 "device_serving": bool(stats.get("device_serving")),

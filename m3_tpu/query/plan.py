@@ -562,14 +562,30 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     params, dispatches the jitted pipeline, and fixes up the root on
     host.  Returns a Matrix; raises Unsupported to decline; returns
     None on a device runtime error (callers fall back to host)."""
+    from m3_tpu.models import query_pipeline as qp
+    from m3_tpu.ops import kernel_telemetry
+
     step_times = np.asarray(step_times, dtype=np.int64)
     n_shards = engine._serving_shards()
     leaves = []        # traced per-leaf pytrees, by leaf index
     leaf_plan = {}     # dedupe key -> (idx, kind, statics, pk)
     params = []        # traced per-node pytrees, by param index
     root_post = []     # host post-ops on the root matrix (sort/...)
+    shape = {"groups": 0, "topk_k": 0}   # for the query's record
+    forms = set()      # window_form of each rate-family leaf
     cost = engine._cost()
     s_pad = _bucket_pow2(len(step_times), 64)
+    # the plan phase: the build below (leaf plan, group keys, params,
+    # the plan tuple) and the root's host reorder, without the gathers
+    # and packs inside it, which stamp fetch and pack themselves
+    planning = cost.phase("plan")
+
+    def outside_plan(fn, *args, **kwargs):
+        planning.stop()
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            planning.start()
 
     def build_leaf(sym_leaf, grid):
         (_, sel, fn, rng_override, keep_name, horizon, hw_sf, hw_tf,
@@ -584,8 +600,9 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         cached = leaf_plan.get(key)
         if cached is None:
             sp = _bucket_pow2(len(grid), 64)
-            pk, _why = engine._device_gather_pack(
-                sel, grid, rng, bucket=_bucket_pow2)
+            pk, _why = outside_plan(
+                engine._device_gather_pack, sel, grid, rng,
+                bucket=_bucket_pow2)
             if pk is not None and pk["open"] is not None:
                 if pk["n_streams"]:
                     # the fused program takes words or arrays, not
@@ -603,7 +620,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                 _DEVLED().track("decoded_block_bridge", [
                     v for v in pk.values() if hasattr(v, "nbytes")])
             else:
-                pk = _arrays_leaf(engine, sel, grid, rng)
+                pk = outside_plan(_arrays_leaf, engine, sel, grid, rng)
                 if pk is None:
                     raise Unsupported("mixed or unknown payloads",
                                       reason="mixed_payloads")
@@ -670,6 +687,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                 raise Unsupported("quantile_over_time window grid "
                                   "over the HBM budget",
                                   reason="qot_hbm_gate")
+        if fn in ("rate", "increase", "delta"):
+            forms.add(qp.window_form(statics[1]))
         pidx = len(params)
         params.append((np.float64(horizon), np.float64(phi)))
         labels = ([dict(ls) for ls in pk["labels"]] if keep_name
@@ -710,6 +729,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             gvalid = np.arange(g_pad) < len(uniq)
             pidx = len(params)
             params.append((groups_p, gvalid, np.float64(phi)))
+            shape["groups"] += len(uniq)
             return (("agg", agg_node.op, g_pad, pidx, plan_c),
                     [dict(k) for k in uniq], len(uniq), g_pad)
         if tag == "vs":
@@ -759,6 +779,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             groups_p[:n_real] = [group_of[kk] for kk in keys]
             pidx = len(params)
             params.append((groups_p,))
+            shape["topk_k"] = k
             # topk keeps child labels verbatim; row order is fixed up
             # on host from the (present, rank) aux after the transfer
             return (("topk", agg_node.op, k, g_pad, pidx, plan_c),
@@ -913,6 +934,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             gvalid = np.arange(g_pad) < n_groups
             pidx = len(params)
             params.append((groups_p, gvalid, np.float64(tval)))
+            shape["groups"] += n_groups
             return (("gagg", op, extra, g_pad, pidx, plan_c),
                     out_labels, n_groups, g_pad)
         if tag == "gcall":
@@ -928,15 +950,13 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         raise Unsupported(f"unknown symbolic node {tag!r}",
                           reason="unknown_node")
 
-    plan_t, root_labels, n_real, _rows_pad = build(sym, step_times)
+    with planning:
+        plan_t, root_labels, n_real, _rows_pad = build(sym, step_times)
     kernel_name = ("device_expr_pipeline_sharded" if n_shards > 1
                    else "device_expr_pipeline")
     plan_key = (plan_t if n_shards == 1
                 else (plan_t, ("mesh", n_shards)))
     engine._check_deadline("device fused")
-
-    from m3_tpu.models import query_pipeline as qp
-    from m3_tpu.ops import kernel_telemetry
 
     steps_pad = np.full(s_pad, step_times[-1], dtype=np.int64)
     steps_pad[:len(step_times)] = step_times
@@ -979,8 +999,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         ker = kernel_telemetry.kernels().get(kernel_name)
         before = ker.stats() if ker is not None else {}
         # device-ledger borrow: the megabatch is uploaded by jit for
-        # the duration of the call (numpy leaves: the staging is inside
-        # the call, so this path stamps no h2d of its own)
+        # the duration of the call (numpy leaves: the call stages them,
+        # so h2d_s is 0 on this path)
         try:
             with cost.phase("device"):
                 with observe.device_ledger().borrow(
@@ -1051,6 +1071,36 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             task.batch = {"size": binfo["batch_size"],
                           "wait_s": round(binfo["waited_s"], 6)}
 
+    with planning:      # the root's host reorder
+        values = out_np[:n_real, :len(step_times)]
+        labels = root_labels[:n_real]
+        if plan_t[0] == "topk":
+            # eval_ordered semantics: rows ordered by final-step rank,
+            # unselected-at-every-step rows dropped (host _eval_topk)
+            present_np = aux_np[0][:n_real]
+            rank_np = aux_np[1][:n_real]
+            order = [i for i in np.argsort(rank_np, kind="stable")
+                     if present_np[i]]
+            labels = [labels[i] for i in order]
+            values = values[order]
+        for _tag, desc in root_post:
+            # prometheus sorts instant vectors by value; for a range
+            # result the last step's value is the sort key (host parity)
+            last = np.where(np.isnan(values[:, -1]),
+                            -np.inf if desc else np.inf,
+                            values[:, -1])
+            order = np.argsort(last, kind="stable")
+            if desc:
+                order = order[::-1]
+            labels = [labels[i] for i in order]
+            values = values[order]
+    # what the program ran at, for the query's record: how the rate
+    # family read its windows' ends (a function of a leaf's bucket)
+    for form in sorted(forms):
+        instrument.counter("m3_device_window_form_total",
+                           form=form).inc()
+    window_form = (min(forms) if len(forms) == 1
+                   else "mixed" if forms else None)
     fn_stat = next((f for f in counts["fns"] if f in LOOSE_FNS),
                    counts["fns"][0] if counts["fns"] else None)
     agg_stat = next((a for a in counts["aggs"] if a in LOOSE_AGGS),
@@ -1069,31 +1119,19 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         compile_cache="hit" if cache_hit else "miss",
         compiled=compiled,
         compile_s=compile_s,
-        transfer_bytes=transfer_bytes)
+        transfer_bytes=transfer_bytes,
+        # the fan-out, as the per-node tier records it: rows handed to
+        # the program, series merged and their lane bucket over all
+        # leaves; then the tree's real groups, a root top-k's k and
+        # the rows of the answer
+        rows=sum(ent[3].get("n_rows", 0) for ent in leaf_plan.values()),
+        lanes=sum(ent[3]["n_lanes"] for ent in leaf_plan.values()),
+        lanes_pad=sum(ent[3]["lanes_pad"]
+                      for ent in leaf_plan.values()),
+        groups=shape["groups"], topk_k=shape["topk_k"],
+        rows_out=len(labels), window_form=window_form)
     if binfo is not None:
         engine.last_fetch_stats["batched"] = True
         engine.last_fetch_stats["batch_size"] = binfo["batch_size"]
     from m3_tpu.query.engine import Matrix
-    values = out_np[:n_real, :len(step_times)]
-    labels = root_labels[:n_real]
-    if plan_t[0] == "topk":
-        # eval_ordered semantics: rows ordered by final-step rank,
-        # unselected-at-every-step rows dropped (host _eval_topk)
-        present_np = aux_np[0][:n_real]
-        rank_np = aux_np[1][:n_real]
-        order = [i for i in np.argsort(rank_np, kind="stable")
-                 if present_np[i]]
-        labels = [labels[i] for i in order]
-        values = values[order]
-    for _tag, desc in root_post:
-        # prometheus sorts instant vectors by value; for a range
-        # result the last step's value is the sort key (host parity)
-        last = np.where(np.isnan(values[:, -1]),
-                        -np.inf if desc else np.inf,
-                        values[:, -1])
-        order = np.argsort(last, kind="stable")
-        if desc:
-            order = order[::-1]
-        labels = [labels[i] for i in order]
-        values = values[order]
     return Matrix(labels, values)

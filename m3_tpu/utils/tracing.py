@@ -71,6 +71,7 @@ ENGINE_PARSE = "engine.Parse"
 ENGINE_GATHER = "engine.Gather"
 ENGINE_OPEN_READ = "engine.OpenRead"
 ENGINE_PACK = "engine.Pack"
+ENGINE_PLAN = "engine.Plan"
 ENGINE_DECODE = "engine.Decode"
 ENGINE_MERGE = "engine.Merge"
 ENGINE_DEVICE = "engine.Device"
@@ -80,6 +81,7 @@ HTTP_FRONTEND = "http.Frontend"
 PHASE_SPANS = {
     "parse": ENGINE_PARSE, "fetch": ENGINE_GATHER,
     "open_read": ENGINE_OPEN_READ, "pack": ENGINE_PACK,
+    "plan": ENGINE_PLAN,
     "decode": ENGINE_DECODE, "merge": ENGINE_MERGE,
     "device": ENGINE_DEVICE, "h2d": DEVICE_H2D, "d2h": DEVICE_D2H,
     "frontend": HTTP_FRONTEND,

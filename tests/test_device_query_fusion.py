@@ -496,3 +496,43 @@ def test_multi_tier_stitch_matches_host(tmp_path):
         np.nan_to_num(md.values), np.nan_to_num(mh.values),
         rtol=1e-12, atol=1e-12)
     db.close()
+
+
+def test_fused_topk_record_carries_its_phases_and_shape(engines):
+    """A top-k panel is one fused program: its record has the planning
+    stamped apart from the call, the staging inside it (numpy leaves:
+    h2d_s 0.0), and the shape the program ran at."""
+    host, dev = engines
+    expr = "topk(2, sum by (job)(rate(http_req[5m])))"
+    slowlog.log().clear()
+    mh, md, stats = _run_both(host, dev, expr)
+    assert stats["device_fused"] is True
+    _assert_same_shape(mh, md, expr)
+    np.testing.assert_allclose(np.nan_to_num(mh.values),
+                               np.nan_to_num(md.values), rtol=1e-12)
+    rec = next(r for r in slowlog.log().records()
+               if r["device_serving"])
+    assert rec["expr"] == expr
+    ph = rec["phases"]
+    assert ph["plan_s"] > 0.0 and ph["h2d_s"] == 0.0
+    assert 0.0 < ph["d2h_s"] < ph["device_s"]
+    tiling = ("parse_s", "plan_s", "fetch_s", "open_read_s", "pack_s",
+              "decode_s", "merge_s", "device_s", "self_s")
+    assert sum(ph[k] for k in tiling) == pytest.approx(ph["total_s"],
+                                                       rel=1e-9)
+    # 6 series of http_req in one 64-lane bucket, 3 jobs, k = 2; every
+    # job is in the top two at some step of these 41
+    assert (rec["lanes"], rec["lanes_pad"], rec["groups"],
+            rec["topk_k"]) == (6, 64, 3, 2)
+    assert rec["rows_out"] == rec["series"] == len(md.labels)
+    assert 2 <= rec["rows_out"] <= 3
+    assert rec["window_form"] == "select" and rec["rows"] >= 6
+    assert rec["device_tier"]["host_nodes"] == 0
+    assert "device_declines" not in rec
+    # a tree without a top-k or a rate: no k, no window form
+    slowlog.log().clear()
+    _run_both(host, dev, EXACT_EXPRS[3])
+    rec = next(r for r in slowlog.log().records()
+               if r["device_serving"])
+    assert rec["topk_k"] == 0 and rec["window_form"] is None
+    assert rec["groups"] == 3 and rec["rows_out"] == 3

@@ -29,8 +29,8 @@ T0 = (1_600_000_000 * SEC // BLOCK) * BLOCK
 START, END, STEP = T0 + 10 * 60 * SEC, T0 + 100 * 60 * SEC, 60 * SEC
 # from the sealed block into the mutable buffer
 MIXED_LO, MIXED_HI = T0 + 30 * 60 * SEC, T0 + 2 * BLOCK + 20 * 60 * SEC
-PHASE_KEYS = {"parse_s", "fetch_s", "open_read_s", "pack_s", "decode_s",
-              "merge_s", "device_s", "h2d_s", "d2h_s", "self_s",
+PHASE_KEYS = {"parse_s", "plan_s", "fetch_s", "open_read_s", "pack_s",
+              "decode_s", "merge_s", "device_s", "h2d_s", "d2h_s", "self_s",
               "frontend_s", "total_s"}
 
 
@@ -196,12 +196,15 @@ def _lowered(which: str) -> str:
                 "tiers": spec((m,), jnp.int64), "steps": args[3],
                 "rng": spec((), jnp.int64),
                 "valid": spec((lanes,), jnp.bool_)}
-        plan = ("agg", "sum", 8, 1,
-                ("leaf", 0, 0, "words", "rate", lanes, 128, 128, 1, m, w,
-                 steps, 0.5, 0.5))
+        # abs(sum by (..)(rate(..))): the op-tree's own scope is the
+        # call's, the stages under it keep theirs
+        plan = ("call", "abs", 2,
+                ("agg", "sum", 8, 1,
+                 ("leaf", 0, 0, "words", "rate", lanes, 128, 128, 1, m, w,
+                  steps, 0.5, 0.5)))
         params = ((spec((), jnp.float64), spec((), jnp.float64)),
                   (spec((lanes,), jnp.int64), spec((8,), jnp.bool_),
-                   spec((), jnp.float64)))
+                   spec((), jnp.float64)), ())
         low = qp.device_expr_pipeline.lower(plan, (leaf,), params,
                                             args[3])
     return low.as_text(debug_info=True)

@@ -1347,3 +1347,45 @@ def test_tier_cut_that_keeps_no_prefix_is_flagged():
         tiers=jnp.asarray(np.asarray([1, 0, 1, 0], dtype=np.int64)),
         n_tiers=2)
     assert np.asarray(err).tolist() == [True, False, False, False]
+
+
+def test_fused_topk_program_names_its_stages_at_the_cells_shape():
+    """`topk(5, sum by (instance)(rate(..[5m])))` of one job as
+    `dash-topk` runs it (lowered, never run): 1,024 streams x 512 words
+    at the fused planner's pow2 buckets, 512 lanes x 2,048 samples, 256
+    steps, 100 groups in 128 rows.  Each stage lowers under its own
+    scope and the op-tree's `m3.expr` wraps none of them: a device
+    trace is read by the first `m3.*` of an operation's name, so a scope
+    around the whole interpreter would hide the others."""
+    from m3_tpu.models.query_pipeline import device_expr_pipeline
+
+    M, W, L, S, n_cap, n_dp, g_pad = 1024, 512, 512, 256, 2048, 1024, 128
+    sds = jax.ShapeDtypeStruct
+    leaf = {"words": sds((M, W), np.uint32), "nbits": sds((M,), np.int32),
+            "slots": sds((M,), np.int64), "tiers": sds((M,), np.int64),
+            "steps": sds((S,), np.int64), "rng": sds((), np.int64),
+            "valid": sds((L,), np.bool_)}
+    plan = ("topk", "topk", 5, 8, 2,
+            ("agg", "sum", g_pad, 1,
+             ("leaf", 0, 0, "words", "rate", L, n_cap, n_dp, 1, M, W, S,
+              0.5, 0.5)))
+    params = ((sds((), np.float64), sds((), np.float64)),
+              (sds((L,), np.int64), sds((g_pad,), np.bool_),
+               sds((), np.float64)),
+              (sds((g_pad,), np.int64),))
+    low = device_expr_pipeline.lower(plan, (leaf,), params,
+                                     sds((S,), np.int64))
+    text = low.as_text(debug_info=True)
+    for scope in ("m3.decode", "m3.merge", "m3.temporal", "m3.group",
+                  "m3.topk"):
+        assert f"/{scope}/" in text, scope
+    assert "m3.expr" not in text        # no node of this tree is its own
+    for outer in ("m3.expr", "m3.topk", "m3.group"):
+        for inner in ("m3.decode", "m3.merge", "m3.temporal"):
+            assert f"{outer}/{inner}" not in text, (outer, inner)
+    # the selection is two sorts over the group axis, under its scope
+    ops = list(_walk_jaxpr(jax.make_jaxpr(functools.partial(
+        device_expr_pipeline.__wrapped__, plan))((leaf,), params,
+                                                 sds((S,), np.int64)).jaxpr))
+    sorts = [s for p, s in ops if p == "sort"]
+    assert len(sorts) == 2 and all("m3.topk" in s for s in sorts), sorts
