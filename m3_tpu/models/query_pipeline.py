@@ -33,11 +33,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from m3_tpu.ops import m3tsz_decode
 from m3_tpu.ops.bitstream import I32
 from m3_tpu.ops.histo_quantile import bucket_quantile
 from m3_tpu.ops.kernel_telemetry import instrument_kernel
 from m3_tpu.ops.lane_topk import masked_topk
-from m3_tpu.ops.m3tsz_decode import decode_batched
 from m3_tpu.parallel.mesh import SERIES_AXIS, shard_map
 from m3_tpu.utils import xtime
 
@@ -54,6 +54,15 @@ def lane_chunks(n_lanes: int) -> int:
     lane bucket alone, so the engine can put it on a query's record
     without asking the program."""
     return -(-n_lanes // _MERGE_LANES)
+
+
+def decode_refills(n_dp: int, n_words: int) -> int:
+    """Refills of the decode scan's per-row word window in one
+    _decode_merge of rows `n_words` wide and `n_dp` samples deep (and
+    the step that tells a truncated stream); 0 where a row is no longer
+    than the window.  Like lane_chunks a function of the static buckets
+    alone."""
+    return m3tsz_decode.decode_refills(n_dp + 1, n_words)
 
 
 def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
@@ -334,7 +343,7 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
     time-ascending — violations trip the unsorted flag)."""
     T = n_cap if n_dp is None else n_dp
     with jax.named_scope("m3.decode"):
-        ts, vs, valid, _count, error = decode_batched(
+        ts, vs, valid, _count, error = m3tsz_decode.decode_batched(
             words, nbits, T, int_optimized=True, unit_nanos=unit_nanos,
             flag_truncation=True)
     with jax.named_scope("m3.merge"):

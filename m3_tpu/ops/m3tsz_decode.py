@@ -7,15 +7,26 @@ nextParallel) with one data-parallel kernel: L series decode in lockstep,
 one datapoint per scan step, every control-flow branch of the bit grammar
 turned into arithmetic selects.
 
-TPU-first design notes:
-- Per-lane variable-position bitstream access is expressed as a one-hot
-  masked row-sum over the ``[L, W]`` word tensor (TPU has no fast gather;
-  the masked-sum runs on the VPU at memory bandwidth and is ~36x faster
-  than an XLA gather here).  One fused pass per step yields a 160-bit
-  window per lane, from which the timestamp record (<=36 bits), value
-  control bits (<=16) and value payload (<=64) are all carved with
-  shifts — datapoint records are at most 31+116 bits from the window
-  base, so one window per datapoint suffices.
+TPU-first design notes (the numbers: one TPU v5e, PERF.md PR 39):
+- Per-lane variable-position bitstream access is a one-hot masked
+  OR-reduce over the word tensor (the TPU compiler runs an
+  element-indexed gather an element at a time).  The scan reads the
+  tensor laid ``[W, L]``, a lane's words down the MAJOR axis: the
+  compiler lays an array by the axis it is reduced over, and with the
+  lanes across a register the reduce is an OR from register to
+  register, its cost in proportion to the words read.  One fused pass
+  per step yields a 160-bit window per lane, from which the timestamp
+  record (<=36 bits), value control bits (<=16) and value payload
+  (<=64) are all carved with shifts: a record is at most
+  MAX_RECORD_BITS from the window base, so one window per datapoint
+  suffices.
+- A step reads only what the record at a lane's cursor can need: past
+  WIN_WORDS words a row, the windows come from a per-lane word window
+  of WIN_WORDS words, refilled for every lane at once, each from the
+  block that holds its cursor, every WIN_STEPS steps (_refill: one
+  masked OR-reduce at block grain); and the first record, whose layout
+  is its own and whose place is the same in every row, is decoded once,
+  before the scan, so the scan's body plans a later record alone.
 - Per-lane decode state is the same ~10 scalars the reference iterator
   keeps (SURVEY.md §8.1), all integer registers, exact on every backend.
   The final f64 emission is bit-exact on CPU; on TPU float64 is emulated
@@ -53,10 +64,47 @@ from m3_tpu.utils import xtime
 
 MULT_DIVISORS = np.array([10.0**i for i in range(m3tsz_scalar.MAX_MULT + 1)])
 
+# the longest record the grammar can write in the units this kernel
+# decodes: a timestamp in the catch-all bucket (its opcode is as long as
+# the last bucket's), the longest value control (the update, repeat and
+# float bits, a sig block with its 6-bit field, a mult block, the sign)
+# and a whole 64-bit payload
+MAX_RECORD_BITS = (
+    m3tsz_scalar.TIME_BUCKETS[-1][1]
+    + m3tsz_scalar.DEFAULT_VALUE_BITS[xtime.Unit.SECOND]
+    + 3 + (2 + m3tsz_scalar.NUM_SIG_BITS_FIELD)
+    + (1 + m3tsz_scalar.NUM_MULT_BITS) + 1
+    + 64)
+_READ_WORDS = 5  # _window128 reads five words from the cursor's
+
+# The per-lane word window of the scan (decode_batched): WIN_WORDS words a
+# lane, whole blocks of WIN_BLOCK words from the one that holds the lane's
+# cursor, refilled every WIN_STEPS steps.  WIN_STEPS is the most the
+# grammar allows: the cursor's word lies at most WIN_BLOCK - 1 words into
+# a fresh window, WIN_STEPS - 1 records of MAX_RECORD_BITS move it on, and
+# the last read still ends inside (tests/test_m3tsz_decode_batched.py).
+# Chosen on the chip (TPU v5e, `decode_batched` alone, PERF.md PR 39): a
+# step costs in proportion to WIN_WORDS, a refill one sweep of the rows.
+WIN_BLOCK = 8
+WIN_WORDS = 40
+WIN_STEPS = 1 + ((WIN_WORDS - WIN_BLOCK - _READ_WORDS) * 32
+                 // MAX_RECORD_BITS)
+
+
+def decode_refills(scan_len: int, n_words: int) -> int:
+    """Refills of the per-lane word window in one decode_batched call of
+    `scan_len` steps over rows `n_words` wide: the first record is read
+    in place, the others WIN_STEPS to a refill; 0 where a row is no
+    longer than the window and every step reads the row.  The schedule
+    is the grammar's worst case, so a function of the static shapes
+    alone."""
+    if n_words <= WIN_WORDS:
+        return 0
+    return -(-(scan_len - 1) // WIN_STEPS)
+
 
 class DecodeState(NamedTuple):
     cursor: jax.Array  # i32[L] bit position
-    started: jax.Array  # bool[L] first datapoint consumed
     done: jax.Array  # bool[L] saw end-of-stream
     error: jax.Array  # bool[L] unsupported construct / corrupt
     prev_time: jax.Array  # i64[L] unix nanos
@@ -105,16 +153,19 @@ def _sext(win: jax.Array, skip: int, nbits: int) -> jax.Array:
 def _window128(words: jax.Array, cursor: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(hi, lo) u64 pair: 128 stream bits starting at each lane's cursor.
 
-    Five consecutive words from the cursor's base word are extracted in
-    ONE variadic-reduce pass over [L, W] — no gather, and no repeated
-    HBM sweeps: packing the five u32s into three u64 operands of a
-    single `lax.reduce` makes XLA read the word tensor once per step
-    instead of once per window word (the step scan is HBM-bound; this
-    is a ~2.6x end-to-end win on the 1M-series decode bench).
+    `words` is u32[W, L], a lane's words down the MAJOR axis: the whole
+    row, or the lane's word window (decode_batched).  Five consecutive
+    words from the cursor's base word are picked by ONE variadic masked
+    OR-reduce over that axis: no gather, and the five u32s ride three
+    u64 operands of a single `lax.reduce`, so the W words of a lane are
+    read once a step; words past W read zero.  Reduced over the major
+    axis the lanes lie across a register and the reduce is an OR from
+    register to register; over the minor axis (the words across the
+    register) a step cost the same at 128 words as at 256.
     """
     base = cursor >> 5
     off = (cursor & 31).astype(U64)
-    diff = jnp.arange(words.shape[1], dtype=I32)[None, :] - base[:, None]
+    diff = jnp.arange(words.shape[0], dtype=I32)[:, None] - base[None, :]
     w64 = words.astype(U64)
     z = jnp.zeros((), U64)
     a = jnp.where(diff == 0, w64 << U64(32), z) | jnp.where(diff == 1, w64, z)
@@ -124,7 +175,7 @@ def _window128(words: jax.Array, cursor: jax.Array) -> tuple[jax.Array, jax.Arra
     def _or3(acc, x):
         return (acc[0] | x[0], acc[1] | x[1], acc[2] | x[2])
 
-    w01, w23, w45 = jax.lax.reduce((a, b, c), (z, z, z), _or3, (1,))
+    w01, w23, w45 = jax.lax.reduce((a, b, c), (z, z, z), _or3, (0,))
     aligned = off == 0
     inv = U64(64) - jnp.where(aligned, U64(1), off)  # dodge shift-by-64
     hi = jnp.where(aligned, w01, (w01 << off) | (w23 >> inv))
@@ -370,7 +421,10 @@ def _apply_value(st: DecodeState, plan: ValuePlan, payload: jax.Array) -> Decode
 def _emit_value(st: DecodeState) -> jax.Array:
     """Current datapoint value as float64 (ref: iterator.go:183-197)."""
     float_val = jax.lax.bitcast_convert_type(st.prev_float, jnp.float64)
-    divisor = jnp.asarray(MULT_DIVISORS)[jnp.clip(st.mult, 0, m3tsz_scalar.MAX_MULT)]
+    # 10 ** clip(mult, 0, MAX_MULT), a select a power: no gather in the step
+    divisor = jnp.full(st.mult.shape, MULT_DIVISORS[0])
+    for i in range(1, m3tsz_scalar.MAX_MULT + 1):
+        divisor = jnp.where(st.mult >= i, MULT_DIVISORS[i], divisor)
     int_val = st.int_val.astype(jnp.float64) / divisor
     return jnp.where(st.is_float, float_val, int_val)
 
@@ -382,12 +436,12 @@ def _merge(st: DecodeState, new_st: DecodeState, emit) -> DecodeState:
 
 def _init_state(words: jax.Array, nbits: jax.Array) -> DecodeState:
     """State before any datapoint: cursor past the raw 64-bit stream start
-    (a static two-word slice — uniform position, no window pass needed)."""
-    L = words.shape[0]
-    start = (words[:, 0].astype(U64) << U64(32)) | words[:, 1].astype(U64)
+    (a static two-word slice of `words` u32[W, L]: uniform position, no
+    window pass needed)."""
+    L = words.shape[1]
+    start = (words[0].astype(U64) << U64(32)) | words[1].astype(U64)
     return DecodeState(
         cursor=jnp.full((L,), 64, I32),
-        started=jnp.zeros((L,), jnp.bool_),
         # Streams too small for start + EOS marker are immediately done.
         done=nbits < 64 + 11,
         error=jnp.zeros((L,), jnp.bool_),
@@ -402,13 +456,16 @@ def _init_state(words: jax.Array, nbits: jax.Array) -> DecodeState:
     )
 
 
-def _decode_step(words, nbits, st: DecodeState, int_optimized: bool, unit_nanos: int):
-    """Decode one datapoint on every lane.
+def _decode_step(words, nbits, st: DecodeState, int_optimized: bool,
+                 unit_nanos: int, first: bool = False):
+    """Decode one datapoint on every lane, from `words` u32[W, L].
 
-    Returns (state', time i64[L], value f64[L], valid bool[L]).  The
-    first-record layout (mode bit instead of update structure) is selected
-    per lane by the `started` flag — both plans are register arithmetic on
-    the same window, so the select costs no extra memory pass.
+    Returns (state', (time i64[L], value f64[L], valid bool[L])).
+    `first` is static: a stream's first record has a layout of its own
+    (a mode bit instead of the update structure) and is the record of
+    step 0 on every lane at once.  A lane that emits nothing at step 0
+    met its end marker or an error and is never active again, so no
+    later step needs that plan.
     """
     hi, lo = _window128(words, st.cursor)  # the ONE window pass
     t, d, t_len, eos, bad = _parse_timestamp(hi, st, unit_nanos)
@@ -421,20 +478,96 @@ def _decode_step(words, nbits, st: DecodeState, int_optimized: bool, unit_nanos:
         prev_delta=jnp.where(emit, d, st.prev_delta),
     )
     cwin = hi << jnp.minimum(t_len, 63).astype(U64)
-    plan_next = _plan_value(cwin, st2, int_optimized, first=False)
-    plan_first = _plan_value(cwin, st2, int_optimized, first=True)
-    plan = jax.tree.map(
-        lambda n, f: jnp.where(st.started, n, f), plan_next, plan_first
-    )
+    plan = _plan_value(cwin, st2, int_optimized, first=first)
     payload = take_top(_mid_window(hi, lo, t_len + plan.ctrl), plan.payload_len)
     st3 = _merge(st2, _apply_value(st2, plan, payload), emit)
     st3 = st3._replace(
         cursor=st2.cursor + jnp.where(emit, t_len + plan.ctrl + plan.payload_len, 0),
-        started=st.started | emit,
     )
     st3 = st3._replace(error=st3.error | ((st3.cursor > nbits) & ~st3.done))
     valid = emit & ~st3.error
-    return st3, st3.prev_time, _emit_value(st3), valid
+    return st3, (st3.prev_time, _emit_value(st3), valid)
+
+
+def _refill(blocks: jax.Array, block: jax.Array) -> jax.Array:
+    """u32[WIN_WORDS, L]: each lane's WIN_WORDS // WIN_BLOCK consecutive
+    blocks of WIN_BLOCK words from block `block` i32[L], out of `blocks`
+    u32[W / WIN_BLOCK, WIN_BLOCK, L], the lanes' words seen block by
+    block.
+
+    _window128's trick at block grain: the blocks are picked by ONE
+    variadic masked OR-reduce over the block axis, so a refill costs one
+    sweep of the rows: no gather, no per-lane dynamic slice, no rotation
+    of the row.  Blocks past a lane's end read zero, as words past W do
+    in _window128."""
+    n = WIN_WORDS // WIN_BLOCK
+    diff = (jnp.arange(blocks.shape[0], dtype=I32)[:, None]
+            - block[None, :])[:, None, :]
+    z = jnp.zeros((), jnp.uint32)
+    picked = jax.lax.reduce(
+        tuple(jnp.where(diff == j, blocks, z) for j in range(n)),
+        (z,) * n, lambda acc, x: tuple(a | b for a, b in zip(acc, x)), (0,))
+    return jnp.concatenate(picked, axis=0)
+
+
+def _windowed_steps(blocks, nbits, st: DecodeState, n_steps: int,
+                    int_optimized: bool, unit_nanos: int):
+    """One refill, then `n_steps` (at most WIN_STEPS) later records on
+    every lane, their reads taken from the lane's word window: a step
+    then costs WIN_WORDS words a lane and not the row.  The window is an
+    invariant of the steps' loop.  _decode_step runs on it as it does on
+    the row, the cursor and the stream's length rebased to the window's
+    first bit; the state between refills carries the true cursor.
+
+    A lane whose read would leave its window sets `error`, like any
+    construct the kernel does not handle: WIN_STEPS is the most steps
+    after which no record the grammar allows can, so only a cursor that
+    a corrupt stream moved backwards (a contained XOR after a zero one,
+    which no encoder writes) does."""
+    with jax.named_scope("refill"):
+        block = (st.cursor >> 5) // WIN_BLOCK
+        win = _refill(blocks, block)
+    origin = block * (32 * WIN_BLOCK)
+    rel_bits = nbits - origin
+
+    def step(st: DecodeState, _):
+        word = st.cursor >> 5
+        outside = ((word < 0) | (word + _READ_WORDS > WIN_WORDS)) & ~st.done
+        return _decode_step(win, rel_bits, st._replace(error=st.error | outside),
+                            int_optimized, unit_nanos)
+
+    st, outs = jax.lax.scan(
+        step, st._replace(cursor=st.cursor - origin), None, length=n_steps)
+    return st._replace(cursor=st.cursor + origin), outs
+
+
+def _later_steps(words, nbits, st: DecodeState, n_steps: int,
+                 int_optimized: bool, unit_nanos: int):
+    """The `n_steps` records after a stream's first, on every lane of
+    `words` u32[W, L] -> (state', (times, values, valid) [n_steps, L])."""
+    W, L = words.shape
+    if W <= WIN_WORDS:  # a row no longer than the window is its own
+        return jax.lax.scan(
+            lambda st, _: _decode_step(words, nbits, st, int_optimized,
+                                       unit_nanos),
+            st, None, length=n_steps)
+    blocks = jnp.pad(words, ((0, -W % WIN_BLOCK), (0, 0))).reshape(
+        -1, WIN_BLOCK, L)
+    run = functools.partial(_windowed_steps, blocks, nbits,
+                            int_optimized=int_optimized,
+                            unit_nanos=unit_nanos)
+    # whole windows (their WIN_STEPS results land as one block: eight
+    # rows are a register tile's), then one of the steps left over: a
+    # step past n_steps could reach an end of stream that the scan must
+    # not see (flag_truncation)
+    whole, left = divmod(n_steps, WIN_STEPS)
+    st, outs = jax.lax.scan(
+        lambda st, _: run(st, n_steps=WIN_STEPS), st, None, length=whole)
+    outs = tuple(x.reshape((-1,) + x.shape[2:]) for x in outs)
+    if left:
+        st, last = run(st, n_steps=left)
+        outs = tuple(jnp.concatenate(pair) for pair in zip(outs, last))
+    return st, outs
 
 
 @functools.partial(
@@ -463,18 +596,22 @@ def decode_batched(
     """
     if unit_nanos not in (xtime.SECOND, 1_000_000):
         raise ValueError("fast path supports second/millisecond units")
-    words = words.astype(jnp.uint32)
+    if n_steps < 1:
+        raise ValueError("n_steps must be at least 1")
+    words = words.astype(jnp.uint32).T  # [W, L]: see _window128
     st = _init_state(words, nbits)
-
-    def step(st: DecodeState, _):
-        st, t, v, valid = _decode_step(words, nbits, st, int_optimized, unit_nanos)
-        return st, (t, v, valid)
-
     # the EOS marker is consumed by the step AFTER the last datapoint,
     # so truncation detection needs one extra (discarded) scan step for
     # a stream holding exactly n_steps records to reach done=True
     scan_len = n_steps + 1 if flag_truncation else n_steps
-    st, (ts, vs, valid) = jax.lax.scan(step, st, None, length=scan_len)
+    # the first record: its own layout, and at bit 64 of every row, so
+    # its read is a static slice and its plan stays out of the scan
+    st, first = _decode_step(words[:2 + _READ_WORDS], nbits, st,
+                             int_optimized, unit_nanos, first=True)
+    st, later = _later_steps(words, nbits, st, scan_len - 1, int_optimized,
+                             unit_nanos)
+    ts, vs, valid = (jnp.concatenate([f[None], x])
+                     for f, x in zip(first, later))
     ts = jnp.moveaxis(ts, 0, 1)[:, :n_steps]
     vs = jnp.moveaxis(vs, 0, 1)[:, :n_steps]
     valid = jnp.moveaxis(valid, 0, 1)[:, :n_steps]
@@ -513,13 +650,14 @@ def decode_downsample_fused(
 
     if n_steps % window:
         raise ValueError(f"n_steps {n_steps} not divisible by window {window}")
-    words = words.astype(jnp.uint32)
-    L = words.shape[0]
+    words = words.astype(jnp.uint32).T  # [W, L]: see _window128
+    L = words.shape[1]
     st = _init_state(words, nbits)
 
-    def dp_step(carry, _=None):
+    def dp_step(carry, _=None, first=False):
         st, s, ssq, cnt, vmin, vmax, last, has_last = carry
-        st, _t, v, valid = _decode_step(words, nbits, st, int_optimized, unit_nanos)
+        st, (_t, v, valid) = _decode_step(words, nbits, st, int_optimized,
+                                          unit_nanos, first=first)
         contrib = valid & ~jnp.isnan(v)
         vz = jnp.where(contrib, v, 0.0)
         s = s + vz
@@ -532,7 +670,7 @@ def decode_downsample_fused(
             has_last = has_last | valid
         return (st, s, ssq, cnt, vmin, vmax, last, has_last), None
 
-    def win_step(st: DecodeState, _):
+    def win_step(st: DecodeState, _=None, first=False):
         carry = (
             st,
             jnp.zeros((L,), jnp.float64),
@@ -543,11 +681,14 @@ def decode_downsample_fused(
             jnp.full((L,), jnp.nan, jnp.float64),
             jnp.zeros((L,), jnp.bool_),
         )
+        if first:  # a stream's first record opens the first window
+            carry, _n = dp_step(carry, first=True)
         if window <= 8:  # unroll small windows; nest a scan for large ones
-            for _ in range(window):
+            for _ in range(window - first):
                 carry, _n = dp_step(carry)
         else:
-            carry, _n = jax.lax.scan(dp_step, carry, None, length=window)
+            carry, _n = jax.lax.scan(dp_step, carry, None,
+                                     length=window - first)
         st, s, ssq, cnt, vmin, vmax, last, has_last = carry
         if full_agg:
             any_c = vmin != jnp.inf
@@ -563,7 +704,9 @@ def decode_downsample_fused(
             out = (s, cnt)
         return st, out
 
-    st, outs = jax.lax.scan(win_step, st, None, length=n_steps // window)
+    st, head = win_step(st, first=True)
+    st, outs = jax.lax.scan(win_step, st, None, length=n_steps // window - 1)
+    outs = tuple(jnp.concatenate([h[None], x]) for h, x in zip(head, outs))
     tr = lambda x: jnp.moveaxis(x, 0, 1)  # noqa: E731
     if full_agg:
         agg = WindowedAgg(

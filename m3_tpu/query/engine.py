@@ -1422,6 +1422,9 @@ class Engine:
             lanes=pk["n_lanes"], lanes_pad=pk["lanes_pad"],
             lane_chunks=query_pipeline.lane_chunks(
                 pk["lanes_pad"] // n_shards),
+            # the decode scan's refills of its per-row word window
+            decode_refills=query_pipeline.decode_refills(
+                pk["n_dp"], pk["words"].shape[1]),
             window_form=window_form,
             **stats, n_shards=n_shards)
         return out
@@ -2195,6 +2198,11 @@ class Engine:
                 "lanes": stats.get("lanes", 0),
                 "lanes_pad": stats.get("lanes_pad", 0),
                 "lane_chunks": stats.get("lane_chunks", 0),
+                # device tiers: how often the decode scan refilled its
+                # per-row word window (0: rows no longer than the
+                # window, every step reads the row), over all leaves
+                # of a fused tree
+                "decode_refills": stats.get("decode_refills", 0),
                 # fused tier: the real groups of the tree's grouped
                 # reductions, a root topk / bottomk's k, and the rows
                 # of the answer after the root's host reorder
@@ -2265,6 +2273,9 @@ class Engine:
             if rec["lanes"]:
                 instrument.counter("m3_query_lanes_total").inc(
                     rec["lanes"])
+            if rec["decode_refills"]:
+                instrument.counter("m3_decode_window_refills_total").inc(
+                    rec["decode_refills"])
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
                 # scanned and device execute seconds are accounted at

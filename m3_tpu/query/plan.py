@@ -1129,7 +1129,14 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         lanes_pad=sum(ent[3]["lanes_pad"]
                       for ent in leaf_plan.values()),
         groups=shape["groups"], topk_k=shape["topk_k"],
-        rows_out=len(labels), window_form=window_form)
+        rows_out=len(labels), window_form=window_form,
+        # the decode scans of the leaves that arrive as words: their
+        # refills of the per-row word window, a function of a leaf's
+        # buckets (statics: n_dp, the words of a row)
+        decode_refills=sum(
+            qp.decode_refills(statics[2], statics[5])
+            for _, kind, statics, _ in leaf_plan.values()
+            if kind == "words"))
     if binfo is not None:
         engine.last_fetch_stats["batched"] = True
         engine.last_fetch_stats["batch_size"] = binfo["batch_size"]

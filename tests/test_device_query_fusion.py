@@ -527,6 +527,10 @@ def test_fused_topk_record_carries_its_phases_and_shape(engines):
     assert rec["rows_out"] == rec["series"] == len(md.labels)
     assert 2 <= rec["rows_out"] <= 3
     assert rec["window_form"] == "select" and rec["rows"] >= 6
+    # the decode scan of the one leaf, from its buckets alone (the fused
+    # planner's: 256 samples a row, 128 words): its window's refills
+    from m3_tpu.models import query_pipeline as qp
+    assert rec["decode_refills"] == qp.decode_refills(256, 128) > 0
     assert rec["device_tier"]["host_nodes"] == 0
     assert "device_declines" not in rec
     # a tree without a top-k or a rate: no k, no window form

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from m3_tpu.models import query_pipeline
-from m3_tpu.ops import kernel_telemetry
+from m3_tpu.ops import kernel_telemetry, m3tsz_decode
 from m3_tpu.query import slowlog
 from m3_tpu.query.engine import Engine
 from m3_tpu.query.http import CoordinatorServer
@@ -130,6 +130,8 @@ def test_fleet_wide_panel_over_http_equals_host_tier_and_reference(served,
     compiles = kernel_telemetry.snapshot()[
         "device_grouped_pipeline"]["compiles"]
     lanes_total = instrument.counter("m3_query_lanes_total").value
+    refills_total = instrument.counter(
+        "m3_decode_window_refills_total").value
     got = loadgen.rows_of(_get(served["port"], query=query, start=start,
                                end=end, step=STEP_S))
 
@@ -144,6 +146,13 @@ def test_fleet_wide_panel_over_http_equals_host_tier_and_reference(served,
     assert rec["window_form"] == "select"
     assert (instrument.counter("m3_query_lanes_total").value
             - lanes_total) == n
+    # the decode scan, from the buckets alone: 60 samples a block pack
+    # into 64 words a row and are decoded in the bucket of 128, so the
+    # per-row word window is refilled 128 / WIN_STEPS times a call
+    assert rec["decode_refills"] == query_pipeline.decode_refills(
+        128, 64) == 128 // m3tsz_decode.WIN_STEPS > 0
+    assert (instrument.counter("m3_decode_window_refills_total").value
+            - refills_total) == rec["decode_refills"]
     # the program this call compiled gave the compiler's account of its
     # peak: no less than its arguments and result
     st = kernel_telemetry.snapshot()["device_grouped_pipeline"]
@@ -178,3 +187,54 @@ def test_fleet_wide_panel_over_http_equals_host_tier_and_reference(served,
         for g, row in reference.sum_by(groups, rates).items()})
     assert len(want) == (n // per_job if by == "job" else ZONES)
     assert reference.max_rel_gap(got, want) <= 1e-11
+
+
+def test_record_says_how_often_the_decode_scan_refilled_its_window(tmp_path):
+    """At the benchmark cells' buckets (a 2 h block at 10 s: 720 samples,
+    some 910 bytes, 256 words a row, 768 samples decoded) the decode scan
+    reads a per-row word window, refilled every WIN_STEPS steps after the
+    first record, and the query's record and the counter say how often,
+    as a function of the buckets alone; a row no wider than the window is
+    its own (0).  The answer is the host tier's."""
+    K, C = m3tsz_decode.WIN_STEPS, m3tsz_decode.WIN_WORDS
+    assert query_pipeline.decode_refills(768, 256) == -(-768 // K) > 0
+    assert query_pipeline.decode_refills(1024, 512) == -(-1024 // K)
+    assert query_pipeline.decode_refills(768, C) == 0
+
+    n, per_block = 8, BLOCK // (10 * SEC)
+    db = Database(DatabaseOptions(path=str(tmp_path), num_shards=2,
+                                  commit_log_enabled=False))
+    db.create_namespace(NamespaceOptions(
+        name="default", retention=RetentionOptions(block_size=BLOCK)))
+    rng = np.random.default_rng(3)
+    ts = (T0 // SEC + 10 * np.arange(per_block)) * SEC
+    for i in range(n):
+        values = np.cumsum(rng.integers(0, 100, per_block)).astype(float)
+        db.write_batch(
+            "default", [b"s%02d" % i] * per_block,
+            [{b"__name__": b"http_requests_total", b"job": b"job-%d" % (i % 2),
+              b"instance": b"inst-%d" % i}] * per_block,
+            ts.tolist(), values.tolist())
+    db.tick(now_nanos=T0 + BLOCK + 11 * 60 * SEC)
+    db.flush()
+    try:
+        query = "sum by (job)(rate(http_requests_total[5m]))"
+        span = ((T0 // SEC + 600) * SEC, (T0 // SEC + 7000) * SEC, 300 * SEC)
+        before = instrument.counter("m3_decode_window_refills_total").value
+        _, got = Engine(db, "default", device_serving=True).query_range(
+            query, *span)
+        rec = next(r for r in slowlog.log().records() if r["expr"] == query)
+        assert rec["device_serving"] and rec["rows"] == n
+        assert rec["decode_refills"] == -(-768 // K)
+        assert (instrument.counter("m3_decode_window_refills_total").value
+                - before) == rec["decode_refills"]
+        _, want = Engine(db, "default", device_serving=False).query_range(
+            query, *span)
+        # a host-tier record carries the field and counts nothing
+        host = next(r for r in slowlog.log().records() if r["expr"] == query)
+        assert not host["device_serving"] and host["decode_refills"] == 0
+        np.testing.assert_allclose(np.asarray(got.values),
+                                   np.asarray(want.values), rtol=1e-12)
+        assert np.isfinite(np.asarray(got.values)).all()
+    finally:
+        db.close()

@@ -1165,6 +1165,30 @@ _STRUCTURE_SHAPES = {
 }
 
 
+def _decode_loops(ops, n_dp, n_words):
+    """The loops the decode stage holds at this bucket, having checked
+    what is under `m3.decode`: the first record is decoded before any
+    loop; a row no longer than the word window goes through ONE loop of
+    steps; a longer one through the loop over refills, a window's steps
+    inside it, and the steps left over where n_dp does not divide.  No
+    gather and no scatter: the words a step reads are picked by a
+    masked reduce, a window's blocks by ONE more under `refill`."""
+    from m3_tpu.ops.m3tsz_decode import WIN_STEPS, WIN_WORDS, decode_refills
+
+    decode = [(p, s) for p, s in ops if "m3.decode" in s]
+    assert not [(p, s) for p, s in decode
+                if p == "gather" or p.startswith("scatter")]
+    refills = decode_refills(n_dp + 1, n_words)
+    assert (refills > 0) == (n_words > WIN_WORDS)
+    left = n_dp % WIN_STEPS > 0
+    want = 2 + left if refills else 1
+    assert [p for p, _ in decode if p in ("while", "scan")] == ["scan"] * want
+    refill = [p for p, s in decode if "refill" in s]
+    assert refill.count("reduce") == (1 + left if refills else 0)
+    assert not set(refill) & {"gather", "while", "scan", "dynamic_slice"}
+    return want
+
+
 @pytest.mark.parametrize("shape", _STRUCTURE_SHAPES)
 def test_grouped_program_has_no_per_element_addressing(shape):
     """The TPU compiler runs an element-indexed scatter or gather one
@@ -1173,8 +1197,10 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     keeps its scatters to the [n_groups, S] reduction and its windowed
     stage free of gathers (PR 33: twelve were 16.0 of 21.2 ms) and of
     loops but the one over lane chunks, which a fan-out past
-    _MERGE_LANES brings; a later edit that brings one back fails here,
-    on the CPU, at a dashboard row's shape and at the whole fleet's."""
+    _MERGE_LANES brings; the decode scan reads a per-row word window
+    past WIN_WORDS words a row (PR 39) and fills it without an indexed
+    access a row; a later edit that brings one back fails here, on the
+    CPU, at a dashboard row's shape and at the whole fleet's."""
     from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
                                               lane_chunks)
 
@@ -1193,19 +1219,24 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     assert scatters and all("m3.group" in s for _, s in scatters), scatters
     assert not [(p, s) for p, s in ops
                 if p == "gather" and "m3.temporal" in s]
-    # the loops: the decode scan; the merge's lane -> first row search,
-    # its chunks and a chunk's rows; the windowed stage's chunks
+    # the loops: the decode scan's (the refills and a window's steps at
+    # the cells' 256 words a row, the steps alone at the tiny shape's 8);
+    # the merge's lane -> first row search, its chunks and a chunk's
+    # rows; the windowed stage's chunks
+    decode_loops = _decode_loops(ops, n_dp, W)
+    assert decode_loops == (1 if shape == "tiny" else 2)
     loops = sorted((s.strip("/").split("/")[0], p) for p, s in ops
                    if p in ("while", "scan"))
     assert loops == sorted(
-        [("m3.decode", "scan"), ("m3.merge", "scan"), ("m3.merge", "scan"),
-         ("m3.merge", "while")] + [("m3.temporal", "scan")] * chunked), loops
+        [("m3.decode", "scan")] * decode_loops
+        + [("m3.merge", "scan"), ("m3.merge", "scan"), ("m3.merge", "while")]
+        + [("m3.temporal", "scan")] * chunked), loops
     # and the lowered text agrees: no scatter but the reduction's, the
     # search unrolled, and the windowed stage alone lowers without a
     # loop until its lanes pass one chunk
     text = fn.lower(*args, **kw).as_text()
     assert text.count('"stablehlo.scatter"(') == len(scatters)
-    assert text.count("stablehlo.while") == 3 + chunked
+    assert text.count("stablehlo.while") == 2 + decode_loops + chunked
     from m3_tpu.models.query_pipeline import _temporal_eval
     stage = jax.jit(functools.partial(_temporal_eval, "rate")).lower(
         sds((L, n_cap), np.int64), sds((L, n_cap), np.float64), args[3],
@@ -1389,3 +1420,6 @@ def test_fused_topk_program_names_its_stages_at_the_cells_shape():
                                                  sds((S,), np.int64)).jaxpr))
     sorts = [s for p, s in ops if p == "sort"]
     assert len(sorts) == 2 and all("m3.topk" in s for s in sorts), sorts
+    # 512 words a row: the decode scan reads the per-row word window,
+    # 1,024 steps after the first record in 128 whole windows
+    assert _decode_loops(ops, n_dp, W) == 2
