@@ -9,12 +9,33 @@ at data time T is due at node time T + j * cadence / jobs.  A request
 is sent when it is due, whatever became of the ones before it, as an
 agent's remote-write shards do: `connections` keep-alive connections,
 each with one request in flight at most.  A request that finds none of
-them free waits for one, and one that starts more than `late_after_s`
-behind its due time is counted late.  The ticks between `first_tick`
-and the moment this process starts are due already: they are sent at
-once, in order, as the end of the catch-up replay (`catch_up`, not
-counted late), and the schedule runs from there.  It imports numpy,
+them free waits for one.  The ticks between `first_tick` and the moment
+this process starts are due already: they are sent at once, in order,
+as the end of the catch-up replay (`catch_up`, never counted late or
+held), and the schedule runs from there.  It imports numpy,
 harness/fleet.py, wire.py and client.py; nothing of the program.
+
+A request that starts more than `late_after_s` behind its due time is
+`late`, and the generator records what it can tell apart about why:
+
+    woke   the schedule thread's clock straight after its sleep, before
+           the request is handed to a connection.  `woke - due` is how
+           late this process ran: the host's doing.
+    held   whether every one of the `connections` had a request in
+           flight when this one was handed over, by a count kept under
+           the generator's lock and by no timing (a slow hand-off on a
+           machine that stood still must not read as the node's doing).
+           `sent - woke` of a held request is its wait for a
+           connection: the node's doing.
+    busy   that count: the requests in flight ahead of this one.
+
+A witness of the host that does not depend on when a request happens
+to be due: one more thread (`HostWitness`) sleeps `NAP_S` at a time and
+keeps every sleep that overran by more than `STALL_S` as `[at,
+seconds]`.  This process is idle but for a few sends a second, so an
+overrun is the machine's.  `account` turns the rows into the window's
+numbers, the ones the kind judges and the ones it only reports; it is
+here so that one arithmetic serves the run and its tests.
 
 The conversation is loadgen.py's (so loadgen_live.Child drives it):
 
@@ -25,12 +46,17 @@ The conversation is loadgen.py's (so loadgen_live.Child drives it):
     child   {"clock": <its time.perf_counter()>} and the loop starts
     parent  {"window_opens_at": <the parent's perf_counter reading>}
     child   {"requests": [[job, tick, due, sent, acked, late,
-             catch_up], ...], "samples_acked", "errors": [...]}
+             catch_up, woke, held, busy], ...], "samples_acked",
+             "errors": [...], "to_perf", "host_stalls": [[at,
+             seconds], ...]}
 
-`due`, `sent` and `acked` are `time.perf_counter()` readings; the
-requests come in the order of their acknowledgement.  The loop ends
-with the first request due after the window's end, which it learns
-when the parent opens the window, some time after the loop's start.
+`due`, `woke`, `sent`, `acked` and a stall's `at` (the moment its sleep
+should have ended) are `time.perf_counter()` readings; `to_perf` is
+the reading at which the node's clock reads 0, which the schedule is
+laid by; the requests come in the order of their acknowledgement.  The
+loop ends with the first request due after the window's end, which it
+learns when the parent opens the window, some time after the loop's
+start.
 """
 
 from __future__ import annotations
@@ -73,6 +99,90 @@ class Scrape:
             self._values[job][:, tick:tick + 1])
 
 
+# a request's row, by index
+JOB, TICK, DUE, SENT, ACKED, LATE, CATCH_UP, WOKE, HELD, BUSY = range(10)
+
+NAP_S = 0.01        # the witness's sleep
+STALL_S = 0.05      # an overrun it keeps
+
+
+def schedule_of(ts_s, first_tick: int, to_perf: float, cadence_s: float,
+                jobs: int):
+    """The schedule's law -> (job, tick, due) in the order they fall
+    due: job j's request for the tick at data time T is due when the
+    node's clock reads T + j * cadence / jobs.  The generator sends by
+    it and the kind counts by it what was due in a window."""
+    for tick in range(first_tick, len(ts_s)):
+        for job in range(jobs):
+            yield job, tick, (float(ts_s[tick]) + to_perf
+                              + job * cadence_s / jobs)
+
+
+class HostWitness(threading.Thread):
+    """Sleeps NAP_S at a time until `end()`; `stalls` holds `[at,
+    seconds]` of every sleep that overran by more than STALL_S, `at`
+    the moment it should have ended."""
+
+    def __init__(self):
+        super().__init__(name="host-witness", daemon=True)
+        self.stalls: list[list[float]] = []
+        self._ended = False
+
+    def run(self) -> None:
+        while not self._ended:
+            wake_at = time.perf_counter() + NAP_S
+            time.sleep(NAP_S)
+            over = time.perf_counter() - wake_at
+            if over > STALL_S:
+                self.stalls.append([wake_at, over])
+
+    def end(self) -> list[list[float]]:
+        self._ended = True
+        self.join()
+        return self.stalls
+
+
+def account(requests: list, due_pairs: list, host_stalls: list,
+            t_start: float, seconds: float, late_after_s: float,
+            cadence_s: float, connections: int) -> dict:
+    """The scrape's numbers of one window, from the generator's rows,
+    its witness's stalls and `due_pairs`, the (job, tick) the schedule
+    has due in the window.  A request is the window's when it was sent
+    after the window opened; the catch-up's are neither late nor held.
+
+    Judged by the kind: `scrapes_held_share` (requests that found every
+    connection taken and then waited more than `late_after_s` for one,
+    over the window's requests: the node held the loop closed),
+    `scrapes_missing` (pairs due in the window that no request was sent
+    for), `scrapes_a_tick_behind` (requests sent a cadence or more
+    after they were due: over some period the rate offered was not the
+    mix's, whoever's doing).  Reported only: the rest.
+    """
+    rows = [r for r in requests if r[SENT] >= t_start]
+    live = [r for r in rows if not r[CATCH_UP]]
+    late = [r for r in live if r[LATE]]
+    held = [r for r in live if r[HELD]]
+    sent_for = {(r[JOB], r[TICK]) for r in requests}
+    inside = [s for at, s in host_stalls
+              if t_start <= at + s and at <= t_start + seconds]
+    return {
+        "scrapes": len(rows),
+        "scrapes_late": len(late),
+        "scrapes_woke_late": sum(not r[HELD] for r in late),
+        "scrapes_held": len(held),
+        "write_connections_busy_max": max(
+            (min(r[BUSY] + 1, connections) for r in rows), default=0),
+        "host_stall_max_ms": max(inside, default=0.0) * 1000,
+        "host_stalled_ms": sum(inside, 0.0) * 1000,
+        "scrapes_held_share": sum(
+            r[SENT] - r[WOKE] > late_after_s for r in held)
+        / max(len(rows), 1),
+        "scrapes_missing": sum(p not in sent_for for p in due_pairs),
+        "scrapes_a_tick_behind": sum(
+            r[SENT] - r[DUE] >= cadence_s for r in live),
+    }
+
+
 def open_loop(spec: dict, window_end: list) -> dict:
     f = spec["fleet"]
     fleet = Fleet(f["cfg"], f["seed"], f["now_s"], f["n_blocks"])
@@ -85,43 +195,61 @@ def open_loop(spec: dict, window_end: list) -> dict:
     local = threading.local()
     lock = threading.Lock()
     requests, errors = [], []
+    in_flight = [0]         # handed to the pool and not yet ended
     started = time.perf_counter()
 
-    def send(job: int, tick: int, due: float, body: bytes) -> None:
-        if not hasattr(local, "client"):
-            local.client = Client(spec["port"])
-        sent = time.perf_counter()
+    def send(job: int, tick: int, due: float, woke: float, busy: int,
+             body: bytes) -> None:
         try:
-            local.client.remote_write(body)
-        except Exception as e:  # noqa: BLE001 - counted; the schedule
-            # goes on
+            if not hasattr(local, "client"):
+                local.client = Client(spec["port"])
+            sent = time.perf_counter()
+            try:
+                local.client.remote_write(body)
+            except Exception as e:  # noqa: BLE001 - counted; the
+                # schedule goes on
+                with lock:
+                    errors.append(f"{type(e).__name__}: {e}"[:300])
+                local.client.close()
+                local.client = Client(spec["port"])
+                return
+            acked = time.perf_counter()
+            catch_up = due < started
+            late = not catch_up and sent - due > spec["late_after_s"]
+            held = not catch_up and busy >= spec["connections"]
             with lock:
-                errors.append(f"{type(e).__name__}: {e}"[:300])
-            local.client.close()
-            local.client = Client(spec["port"])
-            return
-        acked = time.perf_counter()
-        catch_up = due < started
-        late = not catch_up and sent - due > spec["late_after_s"]
-        with lock:
-            requests.append([job, tick, due, sent, acked, late, catch_up])
+                requests.append([job, tick, due, sent, acked, late,
+                                 catch_up, woke, held, busy])
+        finally:
+            with lock:
+                in_flight[0] -= 1
 
     def schedule(pool) -> None:
-        for tick in range(spec["first_tick"], fleet.per_block):
-            for job in range(fleet.jobs):
-                due = (float(scrape.ts_s[tick]) + to_perf
-                       + job * fleet.cadence_s / fleet.jobs)
-                if due > window_end[0]:
-                    return
-                body = scrape.body(job, tick)
-                time.sleep(max(0.0, due - time.perf_counter()))
-                pool.submit(send, job, tick, due, body)
+        for job, tick, due in schedule_of(
+                scrape.ts_s, spec["first_tick"], to_perf, fleet.cadence_s,
+                fleet.jobs):
+            if due > window_end[0]:
+                return
+            body = scrape.body(job, tick)
+            time.sleep(max(0.0, due - time.perf_counter()))
+            woke = time.perf_counter()
+            with lock:
+                busy = in_flight[0]
+                in_flight[0] += 1
+            pool.submit(send, job, tick, due, woke, busy, body)
         errors.append("the open block ran out of ticks")
 
-    with concurrent.futures.ThreadPoolExecutor(spec["connections"]) as pool:
-        schedule(pool)
+    witness = HostWitness()
+    witness.start()
+    try:
+        with concurrent.futures.ThreadPoolExecutor(
+                spec["connections"]) as pool:
+            schedule(pool)
+    finally:
+        host_stalls = witness.end()
     return {"requests": requests, "errors": errors,
-            "samples_acked": len(requests) * fleet.instances}
+            "samples_acked": len(requests) * fleet.instances,
+            "to_perf": to_perf, "host_stalls": host_stalls}
 
 
 def main() -> int:

@@ -13,10 +13,12 @@ buffers.  From there on the fleet is scraped: harness/scrapegen.py, a
 child process, sends the scrape as remote-write requests, open loop on
 the node's clock, first the ticks that fell due during the backfill,
 at once (the end of the catch-up), then each when it is due; it logs
-every request's due, send and acknowledgement time and whether it was
-late.  One snapshot is taken, so that what the mediator's snapshot runs
-is loaded, and the node's own `Mediator` is started with the config's
-periods.  One panel is sent, so that the device program is loaded.
+every request's due, wake, send and acknowledgement time, whether it
+started late and whether it found every connection taken, and beside
+them every stall of its own process (its witness thread).  One
+snapshot is taken, so that what the mediator's snapshot runs is loaded,
+and the node's own `Mediator` is started with the config's periods.
+One panel is sent, so that the device program is loaded.
 The second child is harness/loadgen_live.py: `clients` closed-loop
 readers; it logs every panel's send and completion time, its range's
 end, the first and last reply of each job and, for every other reply,
@@ -53,12 +55,24 @@ scrape ticks the writer's log shows acknowledged before the reader's
 log shows the panel sent (a step whose range may hold a sample in
 flight is compared with both answers); every other reply equal to the
 first of its job, bit for bit, on the steps they share up to that
-first reply's last acknowledged tick; no failed panel or write, late
-scrapes within the mix's share, no compile in the window, every
-record served by the device tier, both children on the parent's
-clock, the mediator without an error; and the fleet read back by the
-host tier (count_over_time per job over sealed blocks and open
-buffers) equal to the samples acknowledged.
+first reply's last acknowledged tick; no failed panel or write, no
+compile in the window, every record served by the device tier, both
+children on the parent's clock, the mediator without an error; and the
+fleet read back by the host tier (count_over_time per job over sealed
+blocks and open buffers) equal to the samples acknowledged.
+
+That the traffic offered was the mix's is three checks, each on what
+the node or the generator did and none on the host's scheduler
+(harness/scrapegen.account): `scrapes_held_share`, the requests that
+found all `write_connections` taken and then waited more than
+`late_after_s` for one (the node held the loop closed);
+`scrapes_missing`, (job, tick) pairs the schedule has due in the
+window that no request was sent for; `scrapes_a_tick_behind`, requests
+sent a whole cadence or more after they were due.  How late the
+generator's own thread woke, and every stall of its process, is the
+host's: reported in every run (`scrapes_late`, `scrapes_woke_late`,
+`host_stall_max_ms`, `host_stalled_ms`; the log's `scrapes` and
+`host_stalls` lines) and judged in none.
 """
 
 from __future__ import annotations
@@ -75,7 +89,7 @@ import time
 
 import numpy as np
 
-from harness import (loadgen, loadgen_live, reference, service,
+from harness import (loadgen, loadgen_live, reference, scrapegen, service,
                      trace_reduce, wire)
 from harness.client import Client
 from harness.fleet import Fleet
@@ -168,7 +182,7 @@ def _warm(run, state) -> None:
                "now_s": state["anchor"], "n_blocks": fleet.n_blocks},
         first_tick=state["tail_cols"], clock_offset_s=state["offset_s"],
         late_after_s=mix["late_after_s"],
-        connections=mix["write_connections"]))
+        connections=run.param(mix, "write_connections")))
     # what the mediator's snapshot runs is loaded before it starts
     t0 = time.perf_counter()
     run.svc.db.snapshot()
@@ -266,9 +280,14 @@ def window(run, state):
     errors = done["errors"]
     requests = wrote["requests"]
     in_window = [r for r in requests if r[3] >= t_start]
+    scrapes = scrapegen.account(
+        requests, _due_in_window(fleet, state["tail_cols"],
+                                 wrote["to_perf"], t_start, run.seconds),
+        wrote["host_stalls"], t_start, run.seconds, mix["late_after_s"],
+        fleet.cadence_s, run.param(mix, "write_connections"))
     # for reading a far-off run without a second one: every panel and
     # every scrape request of the window, the interpreter's full
-    # collections
+    # collections, the stalls of the generator's process
     run.emit("panels", log_only=True, gc_full=gc_pauses,
              sent_at_s=[round(p[0], 3) for p in panels],
              ms=[round(x, 2) for x in ms], job=[p[2] for p in panels],
@@ -277,7 +296,14 @@ def window(run, state):
              sent_at_s=[round(r[3] - t_start, 3) for r in requests],
              ack_ms=[round((r[4] - r[3]) * 1000, 2) for r in requests],
              lag_ms=[round((r[3] - r[2]) * 1000, 2) for r in requests],
+             wake_lag_ms=[round((r[7] - r[2]) * 1000, 2) for r in requests],
+             conn_wait_ms=[round((r[3] - r[7]) * 1000, 2) for r in requests],
+             held=[int(r[8]) for r in requests],
+             busy=[r[9] for r in requests],
              job=[r[0] for r in requests], tick=[r[1] for r in requests])
+    run.emit("host_stalls", log_only=True, stalls=[
+        [round(at - t_start, 3), round(s * 1000, 1)]
+        for at, s in wrote["host_stalls"]])
     if run.trace:
         path = trace_reduce.find_xplane(trace_dir)
         run.trace_summary = trace_reduce.reduce(path) if path else None
@@ -316,7 +342,7 @@ def window(run, state):
         # panel_median_ms.live
         end_to_end = {"panel_ms_p95": float(np.percentile(lat, 95))}
         beyond_p95 = int((lat > end_to_end["panel_ms_p95"]).sum())
-    state.update(done=done, wrote=wrote, t_start=t_start,
+    state.update(done=done, wrote=wrote, t_start=t_start, scrapes=scrapes,
                  clock_gaps=(gap_r, state["clock_gap_writer"]))
     acks = np.asarray(run.timers["write_ack_s"]) * 1000
     return {"attempted": n + len(errors) + len(in_window)
@@ -331,9 +357,10 @@ def window(run, state):
                         "panel_ms_p50": float(np.median(lat)) if n else None,
                         "max_ms": round(float(lat.max(initial=0)), 1),
                         "beyond_p95": beyond_p95,
-                        "scrapes": len(in_window),
                         "scrapes_caught_up": sum(r[6] for r in requests),
-                        "scrapes_late": sum(r[5] for r in in_window),
+                        **{k: (round(v, 1) if k.endswith("_ms") else v)
+                           for k, v in scrapes.items()
+                           if k not in _SCRAPE_CHECKS},
                         "write_ack_ms_p50": round(float(
                             np.median(acks)) if len(acks) else 0.0, 2),
                         "write_ack_ms_max": round(float(
@@ -342,6 +369,26 @@ def window(run, state):
                         "compiles_in_window": sum(
                             k.get("compiles", 0)
                             for k in run.kernels.values())}}
+
+
+# of scrapegen.account's numbers, the ones check() judges
+_SCRAPE_CHECKS = ("scrapes_held_share", "scrapes_missing",
+                  "scrapes_a_tick_behind")
+
+
+def _due_in_window(fleet, first_tick: int, to_perf: float, t_start: float,
+                   seconds: float):
+    """(job, tick) of the scrape requests the schedule has due in the
+    window, by the law the generator sends by."""
+    out = []
+    for job, tick, due in scrapegen.schedule_of(
+            fleet.block_ts(fleet.n_blocks), first_tick, to_perf,
+            fleet.cadence_s, fleet.jobs):
+        if due > t_start + seconds:
+            break
+        if due >= t_start:
+            out.append((job, tick))
+    return out
 
 
 def _acked_ticks(requests, job: int, before: float) -> tuple[int, int]:
@@ -414,11 +461,12 @@ def check(run, state, result):
     run.check("replies_differing_from_first_of_job", len(differing), 0)
     run.check("failed_requests", len(done["errors"]), 0)
     run.check("failed_writes", len(wrote["errors"]), 0)
-    in_window = [r for r in requests if r[3] >= state["t_start"]]
-    run.check("scrapes_late_share",
-              sum(r[5] for r in in_window) / max(len(in_window), 1),
-              mix["limits"]["scrapes_late_share"])
-    run.check("no_scrape_in_window", 0 if in_window else 1, 0)
+    # the traffic offered was the mix's: what the node and the
+    # generator did to it; what the host did is in the summary
+    for name in _SCRAPE_CHECKS:
+        run.check(name, state["scrapes"][name], mix["limits"][name])
+    run.check("no_scrape_in_window",
+              0 if state["scrapes"]["scrapes"] else 1, 0)
     for name, gap in zip(("readers", "writer"), state["clock_gaps"]):
         run.check(f"loadgen_clock_gap_s.{name}", gap,
                   mix["limits"]["loadgen_clock_gap_s"])
