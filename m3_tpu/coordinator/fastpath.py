@@ -131,7 +131,7 @@ class ColumnarFastPath:
         db = self._db
         wal_seq = None
         new_labels = None
-        with db._lock:
+        with db.hold("write_columnar"):
             n_new = int(self._lib.prom_router_resolve(
                 self._router, ls, off_flat, blob, n_series, slots,
                 new_idx))

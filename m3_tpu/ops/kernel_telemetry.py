@@ -31,7 +31,9 @@ jitted entry point and records, per kernel:
   its own.  ``execute_s`` cannot tell that wait from running;
   ``queued_ahead / invocations`` is the mean depth a call met, and
   ``dispatch_s`` (the jitted call's return) against ``wait_s`` (from
-  there to ready) splits the host's part from the device's.
+  there to ready) splits the host's part from the device's; a
+  cache-hit call's wait is also charged to the calling query
+  (``tracing.charge``: its record's ``device_wait_s``).
 
 and opens a ``device.Kernel`` span (tagged ``queued_ahead``) so device
 time shows up inside distributed query traces (the Monarch-style cost
@@ -234,13 +236,17 @@ class InstrumentedKernel:
 
                 tenant = attribution.current_tenant()
                 # a cross-query batched dispatch runs under the
-                # reserved batch scope: the scheduler splits its
-                # device seconds per entry, so billing the whole call
-                # to the token holder's tenant here would double-count
-                if (attribution.enabled()
-                        and tenant != attribution.BATCH_TENANT):
-                    attribution.account_read(
-                        tenant, device_seconds=elapsed)
+                # reserved batch scope: the scheduler hands each entry
+                # the call's device seconds and its wait, so charging
+                # the token holder here would double-count
+                if tenant != attribution.BATCH_TENANT:
+                    # blocked for the chip, by the two stamps above:
+                    # the calling query's device_wait_s
+                    tracing.charge("device_wait_s",
+                                   elapsed - dispatch_s)
+                    if attribution.enabled():
+                        attribution.account_read(
+                            tenant, device_seconds=elapsed)
             except Exception:  # noqa: BLE001 - telemetry is best-effort
                 pass
         return out

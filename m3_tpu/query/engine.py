@@ -133,7 +133,8 @@ class QueryCost:
     query begins, so a caller reads ``last_fetch_stats`` after the
     call, and never another thread's."""
 
-    __slots__ = ("phases", "stats", "declines", "gather_bytes",
+    __slots__ = ("phases", "cpu", "cpu_t0_ns", "stats", "declines",
+                 "gather_bytes",
                  "fileset_scans", "ast_nodes", "fused_nodes",
                  "fused_compile_cache", "fused_compile_s",
                  "fused_transfer_bytes",
@@ -142,7 +143,16 @@ class QueryCost:
                  "host_split_reasons", "rung_selections")
 
     def __init__(self):
-        self.phases: dict[str, float] = {}
+        # the waits' keys are there from the start: a collection's
+        # callback may charge its pause at any point of the thread's
+        # code, and must not change the size of a dict being copied
+        self.phases: dict[str, float] = dict.fromkeys(
+            tracing.WAIT_KEYS, 0.0)
+        # a clocked query's (one in tracing.COST_CLOCK_1_IN): the
+        # thread's CPU seconds by phase, and the CPU clock's reading
+        # where the engine call began; None: no phase reads that clock
+        self.cpu: dict[str, float] | None = None
+        self.cpu_t0_ns = 0
         self.stats: dict | None = None
         self.declines: dict[str, int] = {}    # device tier, by reason
         self.gather_bytes = 0
@@ -169,7 +179,7 @@ class QueryCost:
     def phase(self, name: str):
         """``with cost.phase("pack"):`` — the one stamp of a phase
         (utils/tracing.phase): record, span and trace annotation."""
-        return tracing.phase(name, self.phases)
+        return tracing.phase(name, self.phases, self.cpu)
 
     def split(self, reason: str) -> None:
         """A subtree the fused planner left to the host, by cause (the
@@ -204,6 +214,9 @@ class Engine:
         # recorded; None keeps the plain full-range namespace fan-out
         self.planner = planner
         self._qrange_local = threading.local()
+        # queries since the last one that read the CPU clock
+        self._unclocked = 0
+        self._unclocked_lock = threading.Lock()
         # None = auto, resolved lazily per query from the backend JAX
         # reports (see _device_serving_active)
         self.device_serving = device_serving
@@ -307,8 +320,21 @@ class Engine:
             cost = self._qrange_local.cost = QueryCost()
         return cost
 
-    def _begin_cost(self) -> QueryCost:
+    def _begin_cost(self, live: bool = False) -> QueryCost:
+        """Arm the calling thread's cost object for one query, and
+        decide, once, whether the query reads the CPU clock: if its
+        span is `live` (sampled by the tracer, or forced by the
+        request's ``traceparent``) or it is this engine's
+        ``tracing.COST_CLOCK_1_IN``-th query since the last that did."""
         cost = self._qrange_local.cost = QueryCost()
+        with self._unclocked_lock:
+            self._unclocked += 1
+            clocked = live or self._unclocked >= tracing.COST_CLOCK_1_IN
+            if clocked:
+                self._unclocked = 0
+        if clocked:
+            cost.cpu = {}
+            cost.cpu_t0_ns = time.thread_time_ns()
         return cost
 
     @property
@@ -2116,7 +2142,7 @@ class Engine:
             self._qrange_local.task = task
             self._qrange_local.limits = limits
             self._qrange_local.meta = meta
-            self._begin_cost()
+            cost = self._begin_cost(live=ctx is not None)
             # the gather memo exists ONLY between here and the finally
             # below; _gather_cached bypasses memoization when it is None
             self._qrange_local.gather_cache = {}
@@ -2125,8 +2151,11 @@ class Engine:
             error = None
             cache_stats.begin()  # per-query cache hit/miss scoreboard
             try:
-                step_times, result = self._query_range(
-                    query, start_nanos, end_nanos, step_nanos)
+                # what the thread waits for between two phases (the
+                # engine's self time) is charged to the query too
+                with tracing.sink_scope(cost.phases):
+                    step_times, result = self._query_range(
+                        query, start_nanos, end_nanos, step_nanos)
                 return step_times, result, meta
             except Exception as e:
                 error = f"{type(e).__name__}: {e}"[:300]
@@ -2150,9 +2179,14 @@ class Engine:
                 self._qrange_local.task = None
 
     # the stamped phases that tile a query's time; h2d and d2h lie
-    # inside device and are recorded beside it
+    # inside device and are recorded beside it, as the waits
+    # (tracing.WAIT_KEYS) are beside the phases they interrupted
     _TILING_PHASES = ("parse_s", "plan_s", "fetch_s", "open_read_s",
                       "pack_s", "decode_s", "merge_s", "device_s")
+    # the phases, of those that tile it, that block on nothing but
+    # locks: where wall - CPU - the database lock's wait is the wait
+    # for the interpreter lock
+    _LOCK_ONLY_PHASES = _TILING_PHASES[:-1] + ("self_s",)
 
     def _record_query_cost(self, query: str, t0_ns: int, result, meta,
                            error: str | None) -> None:
@@ -2164,17 +2198,34 @@ class Engine:
         ``total_s`` minus the phases that tile it, so those and
         ``self_s`` sum to ``total_s``.  ``frontend_s`` is the HTTP
         front end's, added by query/http.py once the reply is
-        written; it lies outside ``total_s``."""
+        written; it lies outside ``total_s``.
+
+        A clocked query's record (``QueryCost.cpu``) also carries
+        ``cpu``, the thread's CPU seconds under the same keys
+        (``total_s`` from two readings around the engine call, not a
+        sum), and ``interp_wait_s``: over ``_LOCK_ONLY_PHASES``, wall
+        minus CPU, minus ``db_lock_wait_s``: the interpreter lock and
+        whatever the host's scheduler took.  Any other record has
+        neither key."""
         try:
-            total_s = (time.perf_counter_ns() - t0_ns) / 1e9
             cost = self._cost()
+            cpu_total_s = (None if cost.cpu is None else (
+                time.thread_time_ns() - cost.cpu_t0_ns) / 1e9)
+            total_s = (time.perf_counter_ns() - t0_ns) / 1e9
             stats = cost.stats or {}
-            phases = {k: cost.phases.get(k, 0.0)
-                      for k in self._TILING_PHASES + ("h2d_s", "d2h_s")}
-            phases["self_s"] = total_s - sum(
-                phases[k] for k in self._TILING_PHASES)
-            phases["frontend_s"] = 0.0
-            phases["total_s"] = total_s
+
+            def tiled(stamps: dict, total: float) -> dict:
+                out = {k: stamps.get(k, 0.0)
+                       for k in self._TILING_PHASES + ("h2d_s", "d2h_s")}
+                out["self_s"] = total - sum(
+                    out[k] for k in self._TILING_PHASES)
+                out["frontend_s"] = 0.0
+                out["total_s"] = total
+                return out
+
+            phases = tiled(cost.phases, total_s)
+            for k in tracing.WAIT_KEYS:
+                phases[k] = cost.phases.get(k, 0.0)
             ctx = tracing.current_context()
             tenant = tracing.current_tenant() or self.ns
             rec = {
@@ -2229,6 +2280,11 @@ class Engine:
                 # thread-local scoreboard armed in query_range_with_meta
                 "cache": cache_stats.snapshot(),
             }
+            if cpu_total_s is not None:
+                cpu = rec["cpu"] = tiled(cost.cpu, cpu_total_s)
+                rec["interp_wait_s"] = sum(
+                    phases[k] - cpu[k] for k in self._LOCK_ONLY_PHASES
+                ) - phases["db_lock_wait_s"]
             if cost.declines:
                 # where the per-node device tier handed a selector to
                 # the host: {reason: n}, the slugs of
@@ -2273,9 +2329,6 @@ class Engine:
             if rec["lanes"]:
                 instrument.counter("m3_query_lanes_total").inc(
                     rec["lanes"])
-            if rec["decode_refills"]:
-                instrument.counter("m3_decode_window_refills_total").inc(
-                    rec["decode_refills"])
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
                 # scanned and device execute seconds are accounted at

@@ -26,6 +26,7 @@ import numpy as np
 from m3_tpu.cache import stats as cache_stats
 from m3_tpu.ops import consolidate as cons
 from m3_tpu.query.engine import Engine
+from m3_tpu.utils import tracing
 
 SECOND = 1_000_000_000
 
@@ -261,7 +262,8 @@ class GraphiteEngine:
         # arm the same per-query thread-local state the PromQL path
         # sets up in query_range_with_meta/_query_range, so the fused
         # lowerer's accounting and the gather memo work under render()
-        cost = eng._begin_cost()
+        cost = eng._begin_cost(
+            live=tracing.current_context() is not None)
         with cost.phase("parse"):
             ast = parse(target)
         cost.ast_nodes = gdev.ast_size(ast)
@@ -271,7 +273,8 @@ class GraphiteEngine:
         error = None
         cache_stats.begin()
         try:
-            return self._eval(ast, steps, step_nanos)
+            with tracing.sink_scope(cost.phases):
+                return self._eval(ast, steps, step_nanos)
         except Exception as e:
             error = f"{type(e).__name__}: {e}"[:300]
             raise

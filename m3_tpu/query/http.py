@@ -304,8 +304,13 @@ class _Handler(BaseHTTPRequestHandler):
                                 time.perf_counter() - t0)
                         rec = slowlog.take_last_record()
                         if rec is not None:
-                            rec["phases"]["frontend_s"] = front[
-                                "frontend_s"]
+                            phases = rec["phases"]
+                            phases["frontend_s"] = front["frontend_s"]
+                            # what the front end waited for (a full
+                            # collection under the render) beside
+                            # what the engine did
+                            for k in tracing.WAIT_KEYS:
+                                phases[k] += front.get(k, 0.0)
         finally:
             if not observed:  # traceparent/span machinery itself blew up
                 instrument.histogram("m3_http_request_seconds").observe(
@@ -318,7 +323,12 @@ class _Handler(BaseHTTPRequestHandler):
         try:
             return run(*args, **kwargs)
         finally:
-            self._front.start()
+            # a clocked query's front end is clocked from here on,
+            # into the record's own `cpu` (its `frontend_s`: the
+            # reply's render and write; the request's parse ran
+            # before the engine decided)
+            rec = slowlog.last_record()
+            self._front.start(None if rec is None else rec.get("cpu"))
 
     # set per-request in _route; the active context echoes back to the
     # caller in the response's traceparent header (see _reply)

@@ -991,8 +991,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         compile_s = binfo["compile_s"]
         # the shared dispatch's own clock (serving/scheduler.py), which
         # this thread waited through
-        cost.phases["device_s"] = (cost.phases.get("device_s", 0.0)
-                                   + binfo["device_s"])
+        for key in ("device_s", "device_wait_s"):
+            cost.phases[key] = cost.phases.get(key, 0.0) + binfo[key]
     else:
         hit = _note_fingerprint(plan_key,
                                 bucket=f"rows{_rows_pad}xsteps{s_pad}")

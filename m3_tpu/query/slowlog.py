@@ -71,11 +71,16 @@ def initiator(name: str):
             _tl.initiator = prev
 
 
+def last_record() -> dict | None:
+    """The record the calling thread cut last and has not taken."""
+    return getattr(_tl, "last_record", None)
+
+
 def take_last_record() -> dict | None:
     """The record the calling thread cut last, handed out once: the
     HTTP front end adds its own phase (``frontend_s``) to the record
     of the query it just served."""
-    rec = getattr(_tl, "last_record", None)
+    rec = last_record()
     _tl.last_record = None
     return rec
 

@@ -29,7 +29,7 @@ from m3_tpu.services.config import (AggregatorConfig, CoordinatorConfig,
 from m3_tpu.storage.cluster_node import ClusterStorageNode
 from m3_tpu.storage.database import Database, DatabaseOptions
 from m3_tpu.storage.namespace import NamespaceOptions, RetentionOptions
-from m3_tpu.utils import instrument
+from m3_tpu.utils import instrument, tracing
 
 
 def _apply_attribution(ac) -> None:
@@ -46,8 +46,10 @@ def _apply_observe(oc) -> None:
     """Bring up the flight recorder (continuous profiler + stall
     watchdog) per config.  Refcounted process-global: an in-process
     coordinator + db node pair shares one recorder, one watchdog, one
-    task ledger."""
+    task ledger.  The interpreter's full collections are clocked from
+    here on (one ``gc.callbacks`` entry a process)."""
     observe.start(oc)
+    tracing.watch_collector()
 
 
 def _build_self_scraper(ss, db, write_fn, instance: str, role: str):

@@ -457,6 +457,10 @@ class BatchScheduler:
             "compile_s": compile_s,
             "device_s": elapsed,
             "device_s_share": share,
+            # blocked for the chip inside it, by kernel telemetry's
+            # stamps (a compiling call counts none)
+            "device_wait_s": (after.get("wait_s", 0.0)
+                              - before.get("wait_s", 0.0)),
         }
         with self._cv:
             for qi, e in enumerate(entries):

@@ -69,11 +69,69 @@ class ResourceExhaustedError(ValueError):
     the reference returns 429 for limit errors, x/net/http errors.go)."""
 
 
+class _Hold:
+    """One acquisition of the database lock (``Database.hold``).
+
+    The acquisition is tried without blocking first: an uncontended or
+    re-entrant one reads no clock.  Else the wait is clocked where it
+    happens (``tracing.wait("db_lock")``: the calling query's
+    ``db_lock_wait_s``, ``m3_wait_seconds_total{on="db_lock"}``, an
+    ``m3:wait:db_lock`` annotation, the live span's ``lock_wait_ms``).
+
+    A thread's outermost acquisition also says what it held, under its
+    entry's name (``m3_db_lock_held_seconds_total{entry}``), wherever
+    that costs one clock reading and no more: from its own acquisition
+    if it had waited for the lock (it stamped then), else from the
+    moment the first thread came to wait for it.  A hold that nobody
+    waited for and that had not queued itself counts nothing."""
+
+    __slots__ = ("_db", "_entry", "_outermost", "_since_ns")
+
+    def __init__(self, db: "Database", entry: str):
+        self._db = db
+        self._entry = entry
+
+    def __enter__(self) -> None:
+        db = self._db
+        self._since_ns = 0
+        if not db._lock.acquire(blocking=False):
+            with tracing.wait("db_lock") as w:
+                if not db._lock_wanted_ns:
+                    db._lock_wanted_ns = w.t0_ns
+                db._lock.acquire()
+            self._since_ns = w.t1_ns
+            tracing.tag_current(
+                lock_wait_ms=round((w.t1_ns - w.t0_ns) / 1e6, 3))
+        # read and written under the lock alone
+        self._outermost = db._lock_entry is None
+        if self._outermost:
+            db._lock_entry = self._entry
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        db = self._db
+        since_ns = 0
+        if self._outermost:
+            since_ns = self._since_ns or db._lock_wanted_ns
+            if since_ns:
+                held_ns = time.perf_counter_ns() - since_ns
+                db._lock_wanted_ns = 0
+            db._lock_entry = None
+        db._lock.release()
+        if since_ns:    # counted once the lock is free again
+            instrument.counter("m3_db_lock_held_seconds_total",
+                               entry=self._entry).inc(held_ns / 1e9)
+        return False
+
+
 def _locked(fn):
-    """Serialize a Database entry point on the instance lock."""
+    """Serialize a Database entry point on the instance lock, held
+    under the method's name (``_write_columns_locked`` as
+    ``write_columns``)."""
+    entry = fn.__name__.strip("_").removesuffix("_locked")
+
     @functools.wraps(fn)
     def wrapper(self, *args, **kwargs):
-        with self._lock:
+        with _Hold(self, entry):
             return fn(self, *args, **kwargs)
     return wrapper
 
@@ -226,6 +284,10 @@ class Database:
         # (the reference uses fine-grained per-shard locks; one RLock
         # is the honest equivalent for this structure)
         self._lock = threading.RLock()
+        # what _Hold keeps: the entry of the thread that holds the
+        # lock (outermost), and since when some thread has waited for it
+        self._lock_entry: str | None = None
+        self._lock_wanted_ns = 0
         # read-path caches (m3_tpu.cache): the seek manager pools open
         # fileset readers; the decoded-block cache serves warm reads
         # without M3TSZ decode under per-namespace series cache
@@ -329,6 +391,12 @@ class Database:
 
     def namespace_options(self, ns: str) -> NamespaceOptions:
         return self._ns(ns).opts
+
+    def hold(self, entry: str) -> _Hold:
+        """``with db.hold("write_columnar"):`` the database lock for a
+        caller that is no method of this class (the columnar ingest
+        fast path), clocked and named as ``_locked`` entries are."""
+        return _Hold(self, entry)
 
     def _ns(self, name: str) -> _Namespace:
         if name not in self._namespaces:

@@ -35,11 +35,18 @@ stamped once on ``perf_counter_ns`` and that stamp feeds the query's
 cost record (``sink``), a child span (when the request is sampled or
 carries a ``traceparent``) and a ``jax.profiler.TraceAnnotation``
 named ``m3:<phase>`` that lands in a device trace when a profiler
-session is open.
+session is open.  What a phase waited for is clocked where the wait
+happens (``wait(name)``: the database lock; ``charge``: the chip, by
+kernel telemetry's own stamps; ``watch_collector``: the interpreter's
+full collections) and added to the sink of the phase it interrupted;
+what it worked is read from the thread's CPU clock on one query in
+``COST_CLOCK_1_IN`` (``phase(.., cpu=)``).
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import os
 import threading
 import time
@@ -47,6 +54,8 @@ from collections import deque
 from typing import NamedTuple
 
 from jax.profiler import TraceAnnotation
+
+from m3_tpu.utils import instrument
 
 # ---------------------------------------------------------------- catalog
 # Stable tracepoint names (ref: dbnode/tracepoint/tracepoint.go:32 — the
@@ -458,32 +467,101 @@ def wire_context() -> str | None:
     return f"{tp};t={tenant}" if tenant else tp
 
 
+# One query in sixteen reads the thread's CPU clock beside the wall
+# clock (``Engine.query_range_with_meta`` decides; a live span forces
+# it).  A constant, not an option: ``trace_sample_1_in`` stays the one
+# knob.  Why sixteen (the builder of the refused PR 40, on the chip's
+# machine, gVisor): ``time.thread_time_ns`` cost 6.2 us a read alone and
+# 19.4 us beside three busy threads, is read under the interpreter
+# lock, so every client pays for every client's reads, and moves in
+# steps of 10 ms; read at every stamp of every query it put
+# dash-sealed's median panel +3.7 to +4.6%, by one query in eight +0.6
+# to +1.6%.  So the clock gives sums and means over a window's clocked
+# records, never one record's value.
+COST_CLOCK_1_IN = 16
+
+# what a phase may have waited for, by the key its sink is charged
+# under; every slow-query record carries each, 0.0 where nothing waited
+WAIT_KEYS = ("db_lock_wait_s", "device_wait_s", "gc_pause_s")
+
+
+class _CostState(threading.local):
+    # the sink of the innermost open phase of this thread: where its
+    # waits are charged; None outside any query
+    sink = None
+
+
+_STATE = _CostState()
+
+
+def charge(key: str, seconds: float) -> None:
+    """Add `seconds` under `key` (one of ``WAIT_KEYS``) to the sink of
+    the calling thread's innermost open phase; a thread outside any
+    query has none and nothing is kept."""
+    sink = _STATE.sink
+    if sink is not None:
+        sink[key] = sink.get(key, 0.0) + seconds
+
+
+@contextlib.contextmanager
+def sink_scope(sink: dict):
+    """``with sink_scope(d):`` waits on this thread are charged to `d`
+    where no phase is open (the engine's self time)."""
+    outer, _STATE.sink = _STATE.sink, sink
+    try:
+        yield
+    finally:
+        _STATE.sink = outer
+
+
 class _Phase:
     """One stamped phase; ``start``/``stop`` for a phase that does not
     fit a ``with`` block (the HTTP front end's two halves)."""
 
-    __slots__ = ("_name", "_sink", "_span", "_ann", "_t0_ns")
+    __slots__ = ("_name", "_sink", "_cpu", "_span", "_live", "_ann",
+                 "_t0_ns", "_c0_ns", "_outer")
 
-    def __init__(self, name: str, sink: dict):
+    def __init__(self, name: str, sink: dict, cpu: dict | None):
         self._name = name
         self._sink = sink
+        self._cpu = cpu
         self._t0_ns = None
 
-    def start(self) -> "_Phase":
+    def start(self, cpu: dict | None = None) -> "_Phase":
+        """`cpu`: clock the phase into it from this start on (the
+        front end learns only from the engine call whether its query
+        is a clocked one)."""
+        if cpu is not None:
+            self._cpu = cpu
         self._span = _GLOBAL.span(PHASE_SPANS[self._name])
-        self._span.__enter__()
+        self._live = self._span.__enter__()
         self._ann = TraceAnnotation("m3:" + self._name)
         self._ann.__enter__()
+        self._outer = _STATE.sink
+        _STATE.sink = self._sink
         self._t0_ns = time.perf_counter_ns()
+        if self._cpu is not None:
+            # inside the wall stamps, so that wall - CPU is no less
+            # than nought by more than the clock's step
+            self._c0_ns = time.thread_time_ns()
         return self
 
     def stop(self, exc_type=None, exc=None, tb=None) -> None:
         if self._t0_ns is None:
             return
+        key = self._name + "_s"
+        cpu = self._cpu
+        if cpu is not None:
+            cpu_s = (time.thread_time_ns() - self._c0_ns) / 1e9
+            cpu[key] = cpu.get(key, 0.0) + cpu_s
         seconds = (time.perf_counter_ns() - self._t0_ns) / 1e9
         self._t0_ns = None
-        key = self._name + "_s"
         self._sink[key] = self._sink.get(key, 0.0) + seconds
+        _STATE.sink = self._outer
+        if cpu is not None and self._live is not None:
+            self._live.tags["cpu_ms"] = round(cpu_s * 1e3, 3)
+            self._live.tags["wait_ms"] = round(
+                (seconds - cpu_s) * 1e3, 3)
         self._ann.__exit__(exc_type, exc, tb)
         self._span.__exit__(exc_type, exc, tb)
 
@@ -494,13 +572,107 @@ class _Phase:
         return False
 
 
-def phase(name: str, sink: dict) -> _Phase:
+def phase(name: str, sink: dict, cpu: dict | None = None) -> _Phase:
     """Stamp one phase of a query: ``with tracing.phase("pack", d):``
     adds the block's seconds to ``d["pack_s"]``, opens the catalog
     span ``PHASE_SPANS[name]`` under the active trace and writes an
     ``m3:<name>`` annotation into an open profiler session.  Phases
-    may nest (``h2d`` inside ``device``); the sink keeps each whole."""
-    return _Phase(name, sink)
+    may nest (``h2d`` inside ``device``); the sink keeps each whole.
+    While the phase is open, what its thread waits for (``wait``,
+    ``charge``) is added to ``d`` under ``WAIT_KEYS``.  With `cpu` (a
+    clocked query's, one in ``COST_CLOCK_1_IN``) the thread's CPU
+    seconds of the block are added to ``cpu["pack_s"]`` and a live
+    span is tagged ``cpu_ms`` / ``wait_ms``; without it the phase
+    reads no CPU clock."""
+    return _Phase(name, sink, cpu)
+
+
+class _Wait:
+    """One blocking acquisition, on the wall clock."""
+
+    __slots__ = ("_name", "_ann", "t0_ns", "t1_ns")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self) -> "_Wait":
+        self._ann = TraceAnnotation("m3:wait:" + self._name)
+        self._ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1_ns = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        seconds = (self.t1_ns - self.t0_ns) / 1e9
+        charge(self._name + "_wait_s", seconds)
+        instrument.counter("m3_wait_seconds_total",
+                           on=self._name).inc(seconds)
+        instrument.counter("m3_waits_total", on=self._name).inc()
+        return False
+
+
+def wait(name: str) -> _Wait:
+    """Clock a wait where it happens: ``with tracing.wait("db_lock"):``
+    around a blocking acquisition stamps ``perf_counter_ns`` on both
+    sides (``t0_ns``, ``t1_ns``), charges the seconds as
+    ``<name>_wait_s`` to the phase the calling thread is in, counts
+    them in ``m3_wait_seconds_total{on=<name>}`` /
+    ``m3_waits_total{on=<name>}`` whoever the thread is, and writes an
+    ``m3:wait:<name>`` annotation, so that the wait lies on a device
+    trace's clock.  For the contended path only: try the acquisition
+    without blocking first, and an uncontended one reads no clock."""
+    return _Wait(name)
+
+
+def tag_current(**tags) -> None:
+    """Tag the calling thread's innermost span, if it is live."""
+    st = _GLOBAL._stack()
+    if st and isinstance(st[-1], Span):
+        st[-1].tags.update(tags)
+
+
+class _CollectorWatch:
+    """The ``gc.callbacks`` entry.  The interpreter runs one collection
+    at a time, start and stop on the thread whose allocation set it
+    off, wherever that thread was: the callback takes no lock the
+    interrupted code may hold, so its counters are made before it is
+    installed and not looked up in the registry from inside it."""
+
+    def __init__(self):
+        self._seconds = instrument.counter("m3_gc_pause_seconds_total")
+        self._count = instrument.counter("m3_gc_collections_total")
+        self._ann = None
+        self._t0_ns = 0
+
+    def __call__(self, when: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if when == "start":
+            self._ann = TraceAnnotation("m3:gc")
+            self._ann.__enter__()
+            self._t0_ns = time.perf_counter_ns()
+        elif self._ann is not None:
+            seconds = (time.perf_counter_ns() - self._t0_ns) / 1e9
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+            charge("gc_pause_s", seconds)
+            self._seconds.inc(seconds)
+            self._count.inc()
+
+
+_COLLECTOR_WATCH = _CollectorWatch()
+
+
+def watch_collector() -> None:
+    """Clock the interpreter's full collections (generation 2: every
+    thread stands still for one): an ``m3:gc`` annotation from start
+    to stop, ``m3_gc_pause_seconds_total`` / ``m3_gc_collections_total``
+    and the pause charged as ``gc_pause_s`` to the phase it
+    interrupted on the thread it ran on.  One ``gc.callbacks`` entry a
+    process, installed where a service starts."""
+    if _COLLECTOR_WATCH not in gc.callbacks:
+        gc.callbacks.append(_COLLECTOR_WATCH)
 
 
 def set_sampling(sample_1_in: int) -> None:

@@ -130,8 +130,6 @@ def test_fleet_wide_panel_over_http_equals_host_tier_and_reference(served,
     compiles = kernel_telemetry.snapshot()[
         "device_grouped_pipeline"]["compiles"]
     lanes_total = instrument.counter("m3_query_lanes_total").value
-    refills_total = instrument.counter(
-        "m3_decode_window_refills_total").value
     got = loadgen.rows_of(_get(served["port"], query=query, start=start,
                                end=end, step=STEP_S))
 
@@ -151,8 +149,6 @@ def test_fleet_wide_panel_over_http_equals_host_tier_and_reference(served,
     # per-row word window is refilled 128 / WIN_STEPS times a call
     assert rec["decode_refills"] == query_pipeline.decode_refills(
         128, 64) == 128 // m3tsz_decode.WIN_STEPS > 0
-    assert (instrument.counter("m3_decode_window_refills_total").value
-            - refills_total) == rec["decode_refills"]
     # the program this call compiled gave the compiler's account of its
     # peak: no less than its arguments and result
     st = kernel_telemetry.snapshot()["device_grouped_pipeline"]
@@ -220,14 +216,11 @@ def test_record_says_how_often_the_decode_scan_refilled_its_window(tmp_path):
     try:
         query = "sum by (job)(rate(http_requests_total[5m]))"
         span = ((T0 // SEC + 600) * SEC, (T0 // SEC + 7000) * SEC, 300 * SEC)
-        before = instrument.counter("m3_decode_window_refills_total").value
         _, got = Engine(db, "default", device_serving=True).query_range(
             query, *span)
         rec = next(r for r in slowlog.log().records() if r["expr"] == query)
         assert rec["device_serving"] and rec["rows"] == n
         assert rec["decode_refills"] == -(-768 // K)
-        assert (instrument.counter("m3_decode_window_refills_total").value
-                - before) == rec["decode_refills"]
         _, want = Engine(db, "default", device_serving=False).query_range(
             query, *span)
         # a host-tier record carries the field and counts nothing

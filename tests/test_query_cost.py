@@ -32,6 +32,7 @@ MIXED_LO, MIXED_HI = T0 + 30 * 60 * SEC, T0 + 2 * BLOCK + 20 * 60 * SEC
 PHASE_KEYS = {"parse_s", "plan_s", "fetch_s", "open_read_s", "pack_s",
               "decode_s", "merge_s", "device_s", "h2d_s", "d2h_s", "self_s",
               "frontend_s", "total_s"}
+WAIT_KEYS = {"db_lock_wait_s", "device_wait_s", "gc_pause_s"}
 
 
 def _write(db, name: bytes, n_series: int = 12, n: int = 120):
@@ -122,7 +123,7 @@ def test_grouped_device_phases_sum_to_total(db):
     eng.query_range(expr, START, END, STEP)
     assert eng.last_fetch_stats["device_grouped"] is True
     ph = _record_of(expr)["phases"]
-    assert set(ph) == PHASE_KEYS
+    assert set(ph) == PHASE_KEYS | WAIT_KEYS
     for key in ("parse_s", "fetch_s", "pack_s", "device_s", "h2d_s",
                 "d2h_s", "self_s"):
         assert ph[key] > 0.0, key
@@ -141,7 +142,7 @@ def test_host_phases_sum_to_total(db):
     expr = "sum by (dc) (rate(sealed[9m]))"
     eng.query_range(expr, START, END, STEP)
     ph = _record_of(expr)["phases"]
-    assert set(ph) == PHASE_KEYS
+    assert set(ph) == PHASE_KEYS | WAIT_KEYS
     assert ph["decode_s"] > 0.0 and ph["device_s"] == 0.0
     tiled = sum(ph[k] for k in ("parse_s", "fetch_s", "decode_s",
                                 "merge_s", "self_s"))
@@ -335,3 +336,309 @@ def test_phase_feeds_record_span_and_annotation():
     spans = [s for s in tr.finished() if s["name"] == tracing.ENGINE_PACK
              and s["trace_id"] == f"{7:032x}"]
     assert len(spans) == 2 and spans[0]["parent_id"] == f"{9:016x}"
+
+
+# --- a phase's work apart from its waiting (PR 42) ---
+
+LOCK_ONLY = ("parse_s", "plan_s", "fetch_s", "open_read_s", "pack_s",
+             "decode_s", "merge_s", "self_s")
+
+
+def _counted(monkeypatch, name: str) -> list:
+    """Count the process's readings of `time.<name>` from here on."""
+    real, calls = getattr(time, name), []
+
+    def reading():
+        calls.append(1)
+        return real()
+
+    monkeypatch.setattr(time, name, reading)
+    return calls
+
+
+def _unsampled(monkeypatch):
+    """No root span is sampled: a query is clocked by the engine's
+    count alone, or by a context the test activates."""
+    monkeypatch.setattr(tracing.tracer(), "sample_1_in", 10 ** 9)
+    with tracing.span(tracing.ENGINE_QUERY_RANGE):
+        pass        # a name's first root span is its sampled one
+
+
+def _held(db, entry: str, seconds: float) -> threading.Thread:
+    """A thread that holds the database lock under `entry`; returns
+    once it has it."""
+    has_it = threading.Event()
+
+    def hold():
+        with db.hold(entry):
+            has_it.set()
+            time.sleep(seconds)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert has_it.wait(30)
+    return t
+
+
+def test_db_lock_wait_is_the_waiting_querys_and_the_holders_entry(
+        db, monkeypatch):
+    _unsampled(monkeypatch)
+    eng = Engine(db, "default", device_serving=False)
+    eng.query_range("rate(sealed[3m])", START, END, STEP)      # warm
+    held = instrument.counter("m3_db_lock_held_seconds_total",
+                              entry="tick")
+    waited = instrument.counter("m3_wait_seconds_total", on="db_lock")
+    waits = instrument.counter("m3_waits_total", on="db_lock")
+    before = held.value, waited.value, waits.value
+    expr = "rate(sealed[4m])"
+    holder = _held(db, "tick", 0.05)
+    with tracing.activate(tracing.TraceContext(11, 13, True)):
+        eng.query_range(expr, START, END, STEP)
+    holder.join(30)
+    ph = _record_of(expr)["phases"]
+    assert 0.04 <= ph["db_lock_wait_s"] <= 0.07
+    assert ph["db_lock_wait_s"] <= ph["fetch_s"]
+    assert waits.value == before[2] + 1
+    assert waited.value - before[1] == pytest.approx(ph["db_lock_wait_s"])
+    # the holder had not queued: it is charged from the moment the
+    # query came to wait
+    assert held.value - before[0] == pytest.approx(
+        ph["db_lock_wait_s"], abs=0.005)
+    # the span that covers the wait says the same
+    span = next(s for s in tracing.tracer().finished()
+                if s["name"] == tracing.DB_FETCH_TAGGED
+                and s["trace_id"] == f"{11:032x}")
+    assert float(span["tags"]["lock_wait_ms"]) == pytest.approx(
+        ph["db_lock_wait_s"] * 1e3, abs=0.001)
+    # another thread's query, once the lock is free, waited for nothing
+    other = "rate(sealed[2m])"
+    t = threading.Thread(target=eng.query_range,
+                         args=(other, START, END, STEP))
+    t.start()
+    t.join(60)
+    assert _record_of(other)["phases"]["db_lock_wait_s"] == 0.0
+    assert waits.value == before[2] + 1
+
+
+def test_a_waiter_that_queued_is_charged_its_whole_hold(db):
+    """The second holder stamped when it got the lock: its hold counts
+    from there, whether or not anyone waits for it."""
+    held = instrument.counter("m3_db_lock_held_seconds_total",
+                              entry="second")
+    before = held.value
+    first = _held(db, "first", 0.03)
+    with db.hold("second"):
+        time.sleep(0.02)
+    first.join(30)
+    assert 0.02 <= held.value - before <= 0.05
+
+
+@pytest.mark.parametrize("how", ["uncontended", "reentrant", "entry"])
+def test_a_free_database_lock_reads_no_clock(db, monkeypatch, how):
+    from m3_tpu.storage import database
+
+    seen = []
+
+    @database._locked
+    def _probe_locked(self):
+        seen.append(self._lock_entry)
+
+    readings = _counted(monkeypatch, "perf_counter_ns")
+    if how == "uncontended":
+        with db.hold("outer"):
+            pass
+    elif how == "reentrant":
+        with db.hold("outer"):
+            with db.hold("inner"):
+                seen.append(db._lock_entry)
+        assert seen == ["outer"]
+    else:
+        _probe_locked(db)
+        assert seen == ["probe"]
+    assert readings == []
+    assert db._lock_entry is None
+
+
+def test_a_wait_outside_any_query_moves_the_counters_alone():
+    waits = instrument.counter("m3_waits_total", on="test_wait")
+    seconds = instrument.counter("m3_wait_seconds_total", on="test_wait")
+    with tracing.wait("test_wait") as w:
+        time.sleep(0.002)
+    assert waits.value == 1
+    assert seconds.value == pytest.approx((w.t1_ns - w.t0_ns) / 1e9)
+    assert seconds.value >= 0.002
+    sink = {}
+    with tracing.phase("fetch", sink):
+        with tracing.wait("test_wait"):
+            pass
+    assert 0.0 < sink["test_wait_wait_s"] <= sink["fetch_s"]
+
+
+def test_one_query_in_sixteen_reads_the_cpu_clock(db, monkeypatch):
+    _unsampled(monkeypatch)
+    eng = Engine(db, "default", device_serving=False)
+    readings = _counted(monkeypatch, "thread_time_ns")
+    exprs = [f"rate(sealed[{200 + i}s])" for i in range(17)]
+    for expr in exprs[:15]:
+        eng.query_range(expr, START, END, STEP)
+    assert readings == []
+    eng.query_range(exprs[15], START, END, STEP)
+    assert len(readings) >= 4      # the engine call and a phase or more
+    clocked = [e for e in exprs[:16] if "cpu" in _record_of(e)]
+    assert clocked == [exprs[15]]
+    assert [e for e in exprs[:16] if "interp_wait_s" in _record_of(e)
+            ] == clocked
+    # a live span forces one, and the count starts again from it
+    del readings[:]
+    with tracing.activate(tracing.TraceContext(17, 19, True)):
+        eng.query_range(exprs[16], START, END, STEP)
+    assert "cpu" in _record_of(exprs[16]) and readings
+    del readings[:]
+    for i in range(15):
+        eng.query_range(f"rate(sealed[{300 + i}s])", START, END, STEP)
+    assert readings == []
+
+
+@pytest.mark.parametrize("device", [False, True])
+def test_a_clocked_record_splits_work_from_waiting(db, monkeypatch,
+                                                   device):
+    _unsampled(monkeypatch)
+    eng = Engine(db, "default", device_serving=device)
+    expr = f"sum by (dc) (rate(sealed[{13 + device}m]))"
+    with tracing.activate(tracing.TraceContext(23, 29 + device, True)):
+        eng.query_range(expr, START, END, STEP)
+    rec = _record_of(expr)
+    ph, cpu = rec["phases"], rec["cpu"]
+    assert set(cpu) == PHASE_KEYS and set(ph) == PHASE_KEYS | WAIT_KEYS
+    assert all(v >= 0.0 for k, v in cpu.items() if k != "self_s")
+    assert 0.0 < cpu["total_s"] <= ph["total_s"] + 0.011
+    tiled = sum(cpu[k] for k in PHASE_KEYS - {
+        "h2d_s", "d2h_s", "frontend_s", "total_s"})
+    assert tiled == pytest.approx(cpu["total_s"], rel=1e-9)
+    assert rec["interp_wait_s"] == pytest.approx(
+        sum(ph[k] - cpu[k] for k in LOCK_ONLY) - ph["db_lock_wait_s"])
+    assert (cpu["device_s"] > 0.0) == device
+    # the live span of a clocked phase carries both
+    span = next(s for s in tracing.tracer().finished()
+                if s["name"] == tracing.ENGINE_GATHER
+                and s["trace_id"] == f"{23:032x}"
+                and s["parent_id"] is not None)
+    assert float(span["tags"]["cpu_ms"]) >= 0.0
+    assert "wait_ms" in span["tags"]
+
+
+def test_device_wait_lies_inside_the_device_phase(db, monkeypatch):
+    _unsampled(monkeypatch)
+    eng = Engine(db, "default", device_serving=True)
+    expr = "sum by (dc) (rate(sealed[17m]))"
+    eng.query_range(expr, START, END, STEP)        # compiles: no wait
+    ker = kernel_telemetry.kernels()["device_grouped_pipeline"]
+    before = ker.stats()["wait_s"]
+    eng.query_range(expr, START, END, STEP)
+    ph = slowlog.log().records(limit=1)[0]["phases"]
+    assert 0.0 < ph["device_wait_s"] <= ph["device_s"]
+    assert ph["device_wait_s"] == pytest.approx(
+        ker.stats()["wait_s"] - before)
+    host = "sum by (dc) (rate(cold[17m]))"
+    eng.query_range(host, START, END, STEP)
+    assert _record_of(host)["phases"]["device_wait_s"] == 0.0
+
+
+def test_a_full_collection_is_charged_to_the_phase_it_interrupted():
+    import gc
+
+    pauses = instrument.counter("m3_gc_pause_seconds_total")
+    count = instrument.counter("m3_gc_collections_total")
+    before = pauses.value, count.value
+    tracing.watch_collector()
+    tracing.watch_collector()        # one entry, however often asked
+    assert gc.callbacks.count(tracing._COLLECTOR_WATCH) == 1
+    try:
+        sink = {}
+        with tracing.phase("pack", sink):
+            gc.collect()
+            gc.collect(0)            # a young collection is not clocked
+        gc.collect()                 # no phase open: the counters alone
+    finally:
+        gc.callbacks.remove(tracing._COLLECTOR_WATCH)
+    assert 0.0 < sink["gc_pause_s"] <= sink["pack_s"]
+    assert count.value == before[1] + 2
+    assert pauses.value - before[0] > sink["gc_pause_s"]
+
+
+def test_http_front_end_is_clocked_with_its_query(db, monkeypatch):
+    _unsampled(monkeypatch)
+    srv = CoordinatorServer(db, port=0).start()
+    try:
+        expr = "sum by (dc) (rate(sealed[19m]))"
+        q = urllib.parse.urlencode({
+            "query": expr, "start": START / 1e9, "end": END / 1e9,
+            "step": "60"})
+        url = f"http://127.0.0.1:{srv.port}/api/v1/query_range?{q}"
+        with urllib.request.urlopen(url) as r:
+            r.read()
+        req = urllib.request.Request(url, headers={
+            "traceparent": f"00-{31:032x}-{37:016x}-01"})
+        with urllib.request.urlopen(req) as r:
+            r.read()
+        deadline = time.monotonic() + 10
+        while (slowlog.log().records(limit=1)[0]["phases"]["frontend_s"]
+               == 0.0 and time.monotonic() < deadline):
+            time.sleep(0.01)
+        forced, plain = slowlog.log().records(limit=2)
+        assert "cpu" not in plain and "interp_wait_s" not in plain
+        assert forced["trace_id"] == f"{31:032x}"
+        assert 0.0 <= forced["cpu"]["frontend_s"] <= (
+            forced["phases"]["frontend_s"] + 0.011)
+        assert set(forced["phases"]) == PHASE_KEYS | WAIT_KEYS
+    finally:
+        srv.stop()
+
+
+def test_database_lock_bookkeeping_under_many_threads(db):
+    """More threads than cores on a short switch interval: the lock
+    still excludes, no hold is left named, and the holds that were
+    clocked, being exclusive, sum to no more than the time that passed."""
+    import sys
+
+    threads, rounds = 16, 150
+    shared = {"n": 0}
+    held = [instrument.counter("m3_db_lock_held_seconds_total",
+                               entry=f"stress{k}") for k in range(3)]
+    waited = instrument.counter("m3_wait_seconds_total", on="db_lock")
+    before = sum(c.value for c in held), waited.value
+    errors = []
+
+    def work(i):
+        try:
+            for r in range(rounds):
+                with db.hold(f"stress{i % 3}"):
+                    n = shared["n"]
+                    if r % 7 == 0:
+                        with db.hold("inner"):      # re-entrant
+                            assert db._lock_entry == f"stress{i % 3}"
+                    shared["n"] = n + 1
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(f"{type(e).__name__}: {e}")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t0 = time.perf_counter()
+    try:
+        pool = [threading.Thread(target=work, args=(i,))
+                for i in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    elapsed = time.perf_counter() - t0
+    assert not errors, errors[:3]
+    assert shared["n"] == threads * rounds
+    assert db._lock_entry is None and db._lock_wanted_ns == 0
+    assert db._lock.acquire(blocking=False)
+    db._lock.release()
+    assert 0.0 < sum(c.value for c in held) - before[0] <= elapsed
+    assert waited.value > before[1]
