@@ -241,12 +241,14 @@ class ColumnarFastPath:
                 self._sid_of_slot = np.resize(self._sid_of_slot, grow)
                 self._tags_of_slot = np.resize(self._tags_of_slot, grow)
             self._lane_of_slot[slot] = lane
-            self._shard_of_slot[slot] = n.shard_of_lane(lane)
             self._idlen_of_slot[slot] = len(sid)
             self._sid_of_slot[slot] = sid
             self._tags_of_slot[slot] = labels
             self._n_slots = slot + 1
             slot_ids[j] = slot
+        # the new series' shards, asked for once
+        self._shard_of_slot[slot_ids] = n.shards_of_lanes(
+            self._lane_of_slot[slot_ids].tolist())
         return slot_ids
 
 

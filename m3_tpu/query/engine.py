@@ -19,11 +19,13 @@ wherever it exists (the reference's aggregated-namespace read path).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
+import operator
 import re
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 
 import numpy as np
 
@@ -124,6 +126,38 @@ def _ast_size(node) -> int:
     return 1
 
 
+# what a row of the walk is, by the code the walk's `kind` column holds
+_ROW_STREAM, _ROW_OPEN, _ROW_ARRAYS = 0, 1, 2
+_ROW_OF_KIND = {STREAMS: _ROW_STREAM, OPEN: _ROW_OPEN, ARRAYS: _ROW_ARRAYS}
+
+
+@dataclasses.dataclass(frozen=True, slots=True)
+class StreamRows:
+    """The gather's compressed rows, as columns: row i is
+    ``streams[i]`` (the stream's bytes) of the series at ``slots[i]``,
+    fetched from tier ``tiers[i]``, holding ``counts[i]`` datapoints
+    (-1: no stored count).  Slot-grouped within a tier, block time
+    ascending within a slot: the merge contract's order.  The arrays
+    belong to the query's gather memo: read, never written."""
+
+    streams: list
+    slots: np.ndarray
+    tiers: np.ndarray
+    counts: np.ndarray
+
+    def __len__(self) -> int:
+        return len(self.streams)
+
+    def known_counts(self) -> np.ndarray | None:
+        """``counts``, or None where any row's count is unknown."""
+        return None if (self.counts < 0).any() else self.counts
+
+    def triples(self):
+        """The rows one by one, (slot, tier, stream): for the one
+        consumer that still goes round them."""
+        return zip(self.slots.tolist(), self.tiers.tolist(), self.streams)
+
+
 class QueryCost:
     """What one query cost, written where it is paid: the phase stamps
     (``phase``, seconds by ``<phase>_s``), the serving path's stats
@@ -134,7 +168,7 @@ class QueryCost:
     call, and never another thread's."""
 
     __slots__ = ("phases", "cpu", "cpu_t0_ns", "stats", "declines",
-                 "gather_bytes",
+                 "gather_bytes", "walk_rows",
                  "fileset_scans", "ast_nodes", "fused_nodes",
                  "fused_compile_cache", "fused_compile_s",
                  "fused_transfer_bytes",
@@ -159,6 +193,10 @@ class QueryCost:
         # directories the query's gathers had to list (a shard whose
         # fileset listing was not yet kept): 0 on a served node
         self.fileset_scans = 0
+        # rows the query's walks were handed as columns, and rows they
+        # had to classify one by one (a cold write beside a sealed
+        # stream: a MIXED block)
+        self.walk_rows = {"columns": 0, "by_row": 0}
         # whole-query fusion (query/plan.py): how much of the tree the
         # fused device program served, what it cost to (re)compile,
         # and how many bytes crossed back
@@ -365,23 +403,24 @@ class Engine:
 
     def _gather(self, matchers, start_nanos: int, end_nanos: int):
         """Collect the namespace fan-out's raw block payloads without
-        decoding: -> (labels, parts, compressed, stream_counts).
+        decoding: -> (labels, parts, rows).
 
         parts[i] = (slot, tier, times, values, kind, after) rows that
         arrive as arrays: ``open`` a read of an open buffer, ``decoded``
         a block some cache or replica merge already decoded, ``cold`` a
         sealed stream merged on the host with a cold write beside it;
         ``after`` counts the compressed rows emitted before it;
-        compressed[i] = (slot, tier, stream_bytes) with stream_counts[i]
-        the stored dp count (None = unknown).  Both arrive
-        slot-grouped ascending, block time ascending within a slot —
-        the merge contract shared by the host and device serving tiers.
+        `rows` the compressed ones, as columns (``StreamRows``: stream,
+        slot, tier, stored dp count).  Both arrive slot-grouped within
+        a tier, block time ascending within a slot — the merge
+        contract shared by the host and device serving tiers.
 
         The walk is the ``fetch`` phase: ``Database.fetch_tagged``
         passes each shard once, block by block, under the database
         lock, over tables that the writers of that state keep (the
         seal a block's sid -> row table, the flush the shard's fileset
-        listing), and hands the rows over in this order; nothing of it
+        listing), and hands the rows over as columns, which the walk
+        puts into this order with one sort a tier; nothing of it
         outlives the query.  Open buffers are only named during it
         (``OpenRow``: the buffer's view at that moment); reading them
         out, all lanes of a view in one call, is the ``open_read``
@@ -389,8 +428,8 @@ class Engine:
         """
         cost = self._cost()
         with cost.phase("fetch"):
-            labels, parts, compressed, stream_counts, named, ns_bytes = (
-                self._gather_walk(matchers, start_nanos, end_nanos))
+            labels, parts, rows, named, ns_bytes = self._gather_walk(
+                matchers, start_nanos, end_nanos)
         if named:
             with cost.phase("open_read"):
                 self._read_open_rows(parts, named, ns_bytes)
@@ -404,7 +443,7 @@ class Engine:
                 res = self.db.namespace_options(ns).aggregation_resolution
                 lab = format_duration(res) if res else "raw"
                 fam.labels(resolution=lab).inc(nb)
-        return labels, parts, compressed, stream_counts
+        return labels, parts, rows
 
     @staticmethod
     def _read_open_rows(parts: list, named: list, ns_bytes: dict) -> None:
@@ -421,16 +460,28 @@ class Engine:
         parts[:] = [p for p in parts if p is not None]
 
     def _gather_walk(self, matchers, start_nanos: int, end_nanos: int):
-        """-> (labels, parts, compressed, stream_counts, named,
-        ns_bytes): the gather's walk over the fan-out; parts[at] is
-        ``None`` for each (at, ns, slot, tier, after, OpenRow) of
-        `named`."""
+        """-> (labels, parts, rows, named, ns_bytes): the gather's walk
+        over the fan-out; parts[at] is ``None`` for each (at, ns, slot,
+        tier, after, OpenRow) of `named`.
+
+        A tier's rows come as columns (``Gathered``) and are laid into
+        a grid, a series' place in the tier by block start
+        (``_lay_tier``); read row by row, the grid is the order a loop
+        over the tier's series, sids ascending, and over each one's
+        blocks would emit.  Slots are numbered by first sight across
+        tiers.  Nothing runs once a series or once a row in the
+        interpreter, but the rows of a ``MIXED`` block (a cold write
+        beside a sealed stream), which are told apart one by one and
+        counted as ``by_row``; and everything a row is done by
+        builtins that keep the interpreter lock: an array call on a
+        tier's rows would let go of it, and under other queries' load
+        wait a switch interval to have it back, once a call."""
         labels: list[dict[bytes, bytes]] = []
-        slot_of: dict[bytes, int] = {}
-        parts: list[tuple] = []
+        seen: list[bytes] = []      # the sid of each slot
+        parts: list = []
         named: list[tuple] = []
-        compressed: list[tuple[int, int, bytes]] = []
-        stream_counts: list = []
+        # the compressed rows' columns
+        streams, slots, tiers, counts = [], [], [], []
         limits = getattr(self._qrange_local, "limits", None)
         meta = getattr(self._qrange_local, "meta", None)
         ns_bytes: dict[str, int] = {}
@@ -439,7 +490,6 @@ class Engine:
                 self._fetch_plan(start_nanos, end_nanos)):
             if limits is not None:
                 limits.check_deadline("gather")
-            nb = 0
             try:
                 # +1: storage ranges are right-exclusive but a sample at
                 # exactly end_nanos resolves at that instant (an eval at
@@ -450,43 +500,158 @@ class Engine:
             except KeyError:
                 continue
             cost.fileset_scans += gathered.fileset_scans
-            tags_of = self.db._ns(ns).index.tags_of
-            for sid, lane, blocks, k in gathered.series:
-                slot = slot_of.get(sid)
-                if slot is None:
-                    slot = slot_of[sid] = len(labels)
-                    labels.append(dict(tags_of(lane)))
-                for _bs, kind, payloads, counts in blocks:
-                    payload = payloads[k]
-                    if payload is None:
-                        continue
-                    if kind is MIXED:
-                        # a cold write beside sealed streams: the row
-                        # is whatever the shard made of the two
-                        kind = (STREAMS if isinstance(
-                                    payload, (bytes, memoryview))
-                                else OPEN if isinstance(payload, OpenRow)
-                                else ARRAYS)
-                    if kind is STREAMS:
-                        compressed.append((slot, tier, payload))
-                        stream_counts.append(
-                            None if counts is None else counts[k])
-                        nb += len(payload)
-                    elif kind is OPEN:
-                        named.append((len(parts), ns, slot, tier,
-                                      len(compressed), payload))
-                        parts.append(None)
-                    else:
-                        # arrays without a count: the shard merged a
-                        # sealed stream with the cold write beside it
-                        parts.append((
-                            slot, tier, payload[0], payload[1],
-                            "cold" if counts is None or counts[k] is None
-                            else "decoded", len(compressed)))
-                        nb += payload[0].nbytes + payload[1].nbytes
+            # the slot of each of the tier's series: those an earlier
+            # tier saw keep theirs, the others take the next ones
+            slot_of = dict(zip(seen, itertools.count()))
+            tier_slots = list(map(slot_of.get, gathered.sids))
+            is_new = list(map(operator.is_, tier_slots,
+                              itertools.repeat(None)))
+            deque(map(tier_slots.__setitem__,
+                      itertools.compress(itertools.count(), is_new),
+                      range(len(seen), len(seen) + sum(is_new))), maxlen=0)
+            seen.extend(itertools.compress(gathered.sids, is_new))
+            # a copy each: the index's memo is shared
+            labels.extend(map(dict, self.db._ns(ns).index.tags_of_many(
+                list(itertools.compress(gathered.lanes, is_new)))))
+            emitted = len(streams)
+            nb = self._lay_tier(gathered.shards, tier_slots, tier, ns,
+                                parts, named, streams, slots, counts)
+            tiers.extend(itertools.repeat(tier, len(streams) - emitted))
             if nb:
                 ns_bytes[ns] = ns_bytes.get(ns, 0) + nb
-        return labels, parts, compressed, stream_counts, named, ns_bytes
+        try:
+            counts = np.fromiter(counts, dtype=np.int64, count=len(counts))
+        except TypeError:
+            # a None among them (a stream without a stored count)
+            # reads nan
+            counts = np.asarray(counts, dtype=np.float64)
+            counts = np.where(np.isnan(counts), -1, counts).astype(np.int64)
+        return (labels, parts, StreamRows(
+            streams, np.fromiter(slots, dtype=np.int64, count=len(slots)),
+            np.fromiter(tiers, dtype=np.int64, count=len(tiers)), counts),
+            named, ns_bytes)
+
+    def _lay_tier(self, shards, tier_slots: list, tier: int, ns: str,
+                  parts: list, named: list, streams: list, slots: list,
+                  counts: list) -> int:
+        """One tier's rows (``Gathered.shards``; `tier_slots` the slot
+        of each of the tier's series) appended, in the gather's order,
+        to `streams` / `slots` / `counts` (the compressed ones),
+        `parts` (arrays) and `named` (open rows, ``None`` standing in
+        `parts`).  -> the bytes the rows hold, the open rows' left out.
+
+        The rows are laid into a grid, a line a series of the tier (its
+        place among the tier's sids) and a column a block start: every
+        block's rows end to end, one scatter a column of rows into the
+        grid's cells; the grid read line by line, the cells that hold
+        nothing dropped, is the gather's order.  The scatter is an
+        object array's (it keeps the interpreter lock); the cells'
+        numbers are made a shard at a time, on arrays too short for
+        the array library to let go of the lock."""
+        starts = sorted({block.block_start for _places, blocks in shards
+                         for block in blocks})
+        if not starts:
+            return 0
+        column_of = {bs: k for k, bs in enumerate(starts)}
+        laid: list = []             # the blocks' rows end to end
+        laid_counts: list = []
+        kinds = None    # made by the first block that is not STREAMS
+        cells = []
+        by_row = 0
+        for places, blocks in shards:
+            line = np.asarray(places, dtype=np.int64) * len(starts)
+            for bs, kind, rows, row_counts in blocks:
+                cells.append(line + column_of[bs])
+                if kind is not STREAMS:
+                    if kinds is None:
+                        kinds = [_ROW_STREAM] * len(laid)
+                    if kind is MIXED:
+                        # a cold write beside sealed streams: a row is
+                        # whatever the shard made of the two
+                        kinds.extend(
+                            _ROW_STREAM if isinstance(p, (bytes, memoryview))
+                            else _ROW_OPEN if isinstance(p, OpenRow)
+                            else _ROW_ARRAYS for p in rows)
+                        by_row += len(rows) - rows.count(None)
+                    else:
+                        kinds.extend(itertools.repeat(_ROW_OF_KIND[kind],
+                                                      len(rows)))
+                elif kinds is not None:
+                    kinds.extend(itertools.repeat(_ROW_STREAM, len(rows)))
+                laid.extend(rows)
+                laid_counts.extend(itertools.repeat(None, len(rows))
+                              if row_counts is None else row_counts)
+        cell = np.concatenate(cells)
+
+        def lines(column: list) -> list:
+            """The grid's cells line by line, `column` scattered in."""
+            grid = np.full(len(tier_slots) * len(starts), None, dtype=object)
+            grid[cell] = np.fromiter(column, dtype=object, count=len(column))
+            return grid.tolist()
+
+        columns = [lines(laid),
+                   list(itertools.chain.from_iterable(
+                       zip(*[tier_slots] * len(starts)))),
+                   lines(laid_counts)]
+        if kinds is not None:
+            columns.append(lines(kinds))
+        if None in columns[0]:      # a series absent from a block
+            held = list(map(operator.is_not, columns[0],
+                            itertools.repeat(None)))
+            columns = [list(itertools.compress(c, held)) for c in columns]
+        payloads, slot, count = columns[:3]
+        self._count_walk_rows(len(payloads) - by_row, by_row)
+        if kinds is None:           # compressed rows alone
+            streams.extend(payloads)
+            slots.extend(slot)
+            counts.extend(count)
+            return sum(map(len, payloads))
+        is_stream = list(map(operator.eq, columns[3],
+                             itertools.repeat(_ROW_STREAM)))
+        # a row that is no stream comes after the streams before it
+        after = list(map(operator.add, itertools.accumulate(is_stream),
+                         itertools.repeat(len(streams))))
+        emitted = len(streams)
+        streams.extend(itertools.compress(payloads, is_stream))
+        slots.extend(itertools.compress(slot, is_stream))
+        counts.extend(itertools.compress(count, is_stream))
+        nb = sum(map(len, itertools.islice(streams, emitted, None)))
+        # the rows that arrive as arrays or (open rows) will
+        others = list(map(operator.not_, is_stream))
+        payloads, slot, count, kind, after = (
+            list(itertools.compress(c, others))
+            for c in (payloads, slot, count, columns[3], after))
+        is_open = list(map(operator.eq, kind, itertools.repeat(_ROW_OPEN)))
+        named.extend(itertools.compress(
+            zip(itertools.count(len(parts)), itertools.repeat(ns), slot,
+                itertools.repeat(tier), after, payloads), is_open))
+        rows: list = [None] * len(payloads)
+        arrays = list(map(operator.not_, is_open))
+        pairs = list(itertools.compress(payloads, arrays))
+        times = list(map(operator.itemgetter(0), pairs))
+        values = list(map(operator.itemgetter(1), pairs))
+        # arrays without a count: the shard merged a sealed stream
+        # with the cold write beside it
+        names = map(("decoded", "cold").__getitem__,
+                    map(operator.is_, itertools.compress(count, arrays),
+                        itertools.repeat(None)))
+        deque(map(rows.__setitem__,
+                  itertools.compress(itertools.count(), arrays),
+                  zip(itertools.compress(slot, arrays),
+                      itertools.repeat(tier), times, values, names,
+                      itertools.compress(after, arrays))), maxlen=0)
+        parts.extend(rows)
+        nbytes = operator.attrgetter("nbytes")
+        return nb + sum(map(nbytes, times)) + sum(map(nbytes, values))
+
+    def _count_walk_rows(self, columns: int, by_row: int) -> None:
+        """Rows a walk was handed as columns, and rows it had to tell
+        apart one by one: in the query's record and the registry."""
+        walked = self._cost().walk_rows
+        fam = instrument.bounded_counter("m3_query_walk_rows_total")
+        for form, n in (("columns", columns), ("by_row", by_row)):
+            walked[form] += n
+            fam.labels(form=form).inc(n)
 
     def _gather_cached(self, matchers, start_nanos: int, end_nanos: int):
         """Per-query gather memo: when the device tier declines a query
@@ -632,12 +797,12 @@ class Engine:
     def _fetch_raw(self, matchers, start_nanos: int, end_nanos: int):
         """-> (labels, times [L, N], values [L, N]) batched, decoded,
         stitched across the namespace fan-out."""
-        labels, parts, compressed, stream_counts = self._gather_cached(
+        labels, parts, rows = self._gather_cached(
             matchers, start_nanos, end_nanos)
         self._check_deadline("host decode")
         cost = self._cost()
-        if compressed and not parts and all(
-                tier == compressed[0][1] for _, tier, _ in compressed):
+        streams, slots, tiers = rows.streams, rows.slots, rows.tiers
+        if rows and not parts and (tiers == tiers[0]).all():
             # hot path (warm node, single namespace, everything served
             # from compressed blocks): fused decode+merge writes every
             # block stream directly into the packed batch — no
@@ -647,13 +812,9 @@ class Engine:
             # temporal windows) selects samples by time, so they are
             # simply never picked.
             with cost.phase("decode"):
-                streams = [p for _, _, p in compressed]
-                slots = np.asarray([slot for slot, _, _ in compressed],
-                                   dtype=np.int64)
-                known = (None if any(c is None for c in stream_counts)
-                         else np.asarray(stream_counts, dtype=np.int64))
                 fused = decode_streams_merged(
-                    streams, slots, len(labels), counts=known)
+                    streams, slots, len(labels),
+                    counts=rows.known_counts())
                 if fused is None:
                     # out-of-order data / no toolchain: general decode
                     # + merge
@@ -674,34 +835,27 @@ class Engine:
                 datapoints=int(np.asarray(valid).sum()),
                 read_bytes=int(cost.gather_bytes))
             return labels, times2, values2
-        if compressed and not parts and _VECTORIZED_STITCH:
+        if rows and not parts and _VECTORIZED_STITCH:
             # multi-tier, all-compressed (raw + aggregated namespaces
             # both serving from blocks): vectorized stitch over the
             # decoded grids — per-slot tier cuts computed with
             # minimum-scatters, then one merge — instead of the
             # per-(series, block) fragment slicing below
             with cost.phase("decode"):
-                streams = [p for _, _, p in compressed]
-                known = (None if any(c is None for c in stream_counts)
-                         else np.asarray(stream_counts, dtype=np.int64))
-                ts, vs, valid = decode_streams_adaptive(streams,
-                                                        counts=known)
+                ts, vs, valid = decode_streams_adaptive(
+                    streams, counts=rows.known_counts())
             with cost.phase("merge"):
-                slots = np.asarray([s for s, _, _ in compressed],
-                                   dtype=np.int64)
-                tiers = np.asarray([t for _, t, _ in compressed],
-                                   dtype=np.int64)
                 valid = np.array(valid)  # writable: cuts mask rows below
                 n_lanes = len(labels)
                 cut = np.full(n_lanes, cons._INF, dtype=np.int64)
                 for tier in np.unique(tiers):  # ascending = finest first
-                    rows = np.nonzero(tiers == tier)[0]
-                    keep = valid[rows] & (
-                        ts[rows] < cut[slots[rows]][:, None])
-                    valid[rows] = keep
-                    row_min = np.where(keep, ts[rows],
+                    at = np.nonzero(tiers == tier)[0]
+                    keep = valid[at] & (
+                        ts[at] < cut[slots[at]][:, None])
+                    valid[at] = keep
+                    row_min = np.where(keep, ts[at],
                                        cons._INF).min(axis=1)
-                    np.minimum.at(cut, slots[rows], row_min)
+                    np.minimum.at(cut, slots[at], row_min)
                 times2, values2, _ = cons.merge_grids(
                     slots, ts, vs, valid, n_lanes,
                     t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
@@ -714,14 +868,13 @@ class Engine:
         # mutable buffers in the mix: decode, stitch, merge and clamp
         # as one step (the fragments interleave), filed under decode
         with cost.phase("decode"):
-            if compressed:
-                streams = [p for _, _, p in compressed]
+            if rows:
                 ts, vs, valid = decode_streams_adaptive(streams)
                 # copy: `parts` may be the list held by the gather
                 # cache — appending in place would poison a later cache
                 # hit with doubled (raw + decoded) fragments
                 parts = list(parts)
-                for i, (slot, tier, _) in enumerate(compressed):
+                for i, (slot, tier, _) in enumerate(rows.triples()):
                     sel = valid[i]
                     parts.append((slot, tier, ts[i][sel], vs[i][sel],
                                   "decoded", i))
@@ -1175,25 +1328,22 @@ class Engine:
 
     def _pack_gathered(self, rv, shifted, rng, lo, hi, gathered,
                        bucket):
-        labels, parts, compressed, stream_counts = gathered
+        labels, parts, rows = gathered
         if any(p[4] == "cold" for p in parts):
             # the shard already decoded and merged this block on the
             # host: the host answers
             return None, "cold_overlay"
-        if not (compressed or parts) or not labels:
+        if not (rows or parts) or not labels:
             return None, "empty"
-        if any(c is None for c in stream_counts):
+        counts_np = rows.known_counts()
+        if counts_np is None:
             return None, "unknown_counts"
-        streams = [p for _, _, p in compressed]
-        slots_np = np.asarray([s for s, _, _ in compressed],
-                              dtype=np.int64)
-        counts_np = np.asarray(stream_counts, dtype=np.int64)
-        tier_ids = np.asarray([t for _, t, _ in compressed],
-                              dtype=np.int64)
-        uniq_tiers = np.unique(tier_ids)
+        # the walk's own columns: nothing is unpicked a row
+        streams, slots_np = rows.streams, rows.slots
+        uniq_tiers = np.unique(rows.tiers)
         n_tiers = max(len(uniq_tiers), 1)
         ranks_np = None
-        if parts and len({t for _, t, _ in compressed}
+        if parts and len(set(uniq_tiers.tolist())
                          | {p[1] for p in parts}) > 1:
             # the device's tier cut reads the decoded rows alone
             return None, "open_multi_tier"
@@ -1205,22 +1355,20 @@ class Engine:
             # earliest sample, keeping merged lanes time-ascending) and
             # block-ascending within (slot, tier) — the gather's
             # original order, preserved by the stable lexsort
-            rank_of = {int(t): r for r, t in enumerate(uniq_tiers)}
-            ranks_np = np.asarray([rank_of[int(t)] for t in tier_ids],
-                                  dtype=np.int64)
+            ranks_np = np.searchsorted(uniq_tiers, rows.tiers)
             order = np.lexsort(
                 (np.arange(len(streams)), -ranks_np, slots_np))
-            streams = [streams[i] for i in order]
+            streams = list(map(streams.__getitem__, order.tolist()))
             slots_np = slots_np[order]
             counts_np = counts_np[order]
             ranks_np = ranks_np[order]
         n_lanes = len(labels)
-        per_lane = np.zeros(n_lanes, dtype=np.int64)
-        np.add.at(per_lane, slots_np, counts_np)
         # rows that arrive as arrays count towards the same budgets
         part_counts = np.asarray([len(p[2]) for p in parts], dtype=np.int64)
-        np.add.at(per_lane, np.asarray([p[0] for p in parts],
-                                       dtype=np.int64), part_counts)
+        per_lane = (
+            np.bincount(slots_np, counts_np, n_lanes)
+            + np.bincount(np.asarray([p[0] for p in parts], dtype=np.int64),
+                          part_counts, n_lanes)).astype(np.int64)
         # static shape buckets (jit cache keys): stream count, words
         # width, lanes, per-stream and per-lane sample budgets, steps
         n_dp = bucket(int(max(counts_np.max(initial=0),
@@ -2265,6 +2413,7 @@ class Engine:
                 # where a fused tree's leaves differ)
                 "window_form": stats.get("window_form"),
                 "fileset_scans": cost.fileset_scans,
+                "walk_rows": dict(cost.walk_rows),
                 "device_serving": bool(stats.get("device_serving")),
                 "fn": stats.get("fn"),
                 "n_shards": stats.get("n_shards", 1),

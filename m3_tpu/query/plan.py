@@ -484,7 +484,7 @@ def _arrays_leaf(engine, sel, step_times, rng):
     to the host tier)."""
     shifted = engine._eval_times(sel, step_times)
     lo, hi = int(shifted[0]) - rng, int(shifted[-1])
-    labels, parts, compressed, _counts = engine._gather_cached(
+    labels, parts, compressed = engine._gather_cached(
         sel.matchers, lo, hi)
     if compressed or not parts or not labels:
         return None
@@ -543,7 +543,7 @@ def serve_fused(engine, node, step_times):
             rng = (sel.range_nanos if rng_override is None
                    else rng_override) or engine.lookback
             shifted = engine._eval_times(sel, step_times)
-            labels, parts, compressed, _c = engine._gather_cached(
+            labels, parts, compressed = engine._gather_cached(
                 sel.matchers, int(shifted[0]) - rng, int(shifted[-1]))
             if parts and not compressed and labels:
                 any_arrays = True

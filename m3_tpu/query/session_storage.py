@@ -26,7 +26,7 @@ from __future__ import annotations
 import threading
 
 from m3_tpu.storage.database import Gathered
-from m3_tpu.storage.shard import ARRAYS, STREAMS, BlockRows
+from m3_tpu.storage.shard import ARRAYS, MIXED, STREAMS, BlockRows
 
 
 def _labels_of_sid(sid: bytes) -> dict[bytes, bytes]:
@@ -64,6 +64,9 @@ class _SidIndex:
         with self._lock:
             sid = self._sids[ordinal]
         return tuple(_labels_of_sid(sid).items())
+
+    def tags_of_many(self, ordinals: list[int]) -> list:
+        return list(map(self.tags_of, ordinals))
 
     def __len__(self) -> int:
         with self._lock:
@@ -135,6 +138,27 @@ class SessionStorage:
             limits=limits, meta=meta)
         return sorted(fetched)
 
+    @staticmethod
+    def _block_rows(sids, merged) -> list[BlockRows]:
+        """The merged rows of `sids` block by block, block starts
+        ascending, each block's rows aligned with `sids` (None: the
+        series has nothing there)."""
+        rows_of: dict[int, list] = {}
+        for k, sid in enumerate(sids):
+            for bs, payload in merged[sid]:
+                rows_of.setdefault(bs, [None] * len(sids))[k] = payload
+        blocks = []
+        for bs, rows in sorted(rows_of.items()):
+            is_stream = [isinstance(p, (bytes, memoryview)) for p in rows]
+            n_streams = sum(is_stream)
+            kind = (STREAMS if n_streams == len(rows) - rows.count(None)
+                    else MIXED if n_streams else ARRAYS)
+            blocks.append(BlockRows(
+                bs, kind, rows, None if kind is STREAMS else
+                [None if p is None or s else len(p[0])
+                 for p, s in zip(rows, is_stream)]))
+        return blocks
+
     def fetch_tagged(self, ns: str, matchers, start_nanos: int,
                      end_nanos: int, with_counts: bool = False,
                      limits=None, meta=None, defer_open: bool = False):
@@ -156,18 +180,14 @@ class SessionStorage:
         if meta is not None:
             meta.fetched_series += len(sids)
         if with_counts and defer_open:
-            # the engine's gather: the rows in its own order (sids
-            # ascending, blocks ascending), one row a block.  Replica-
-            # diverged blocks arrive as (times, values) arrays with an
-            # exact count; identical compressed copies stay opaque
-            # (count unknown -> host decode)
-            return Gathered([
-                (sid, self._index.ordinal(sid),
-                 [BlockRows(bs, STREAMS, [payload], None)
-                  if isinstance(payload, (bytes, memoryview))
-                  else BlockRows(bs, ARRAYS, [payload], [len(payload[0])])
-                  for bs, payload in merged[sid]], 0)
-                for sid in sids])
+            # the engine's gather: the series by sid, the rows as one
+            # shard's blocks.  Replica-diverged blocks arrive as
+            # (times, values) arrays with an exact count; identical
+            # compressed copies stay opaque (count unknown -> host
+            # decode)
+            return Gathered(
+                sids, list(map(self._index.ordinal, sids)),
+                [(list(range(len(sids))), self._block_rows(sids, merged))])
         out: dict[bytes, list[tuple]] = {}
         for sid in sids:
             self._index.ordinal(sid)  # intern for tags_of

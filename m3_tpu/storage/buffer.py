@@ -19,6 +19,7 @@ a set of lanes one call.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import NamedTuple
 
 import numpy as np
@@ -148,6 +149,13 @@ class OpenRow(NamedTuple):
 
     def read(self) -> tuple[np.ndarray, np.ndarray]:
         return self.view.read_lanes([self.lane])[0]
+
+
+def open_rows(view: BufferView, lanes) -> list[OpenRow]:
+    """An ``OpenRow`` for each of `lanes` on one view, named in one
+    C-level pass (a named tuple's own constructor is a Python call)."""
+    return list(map(tuple.__new__, itertools.repeat(OpenRow),
+                    zip(itertools.repeat(view), lanes)))
 
 
 def by_view(items, row_of=lambda item: item):

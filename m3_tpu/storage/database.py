@@ -11,12 +11,15 @@ bootstrapper/base.go:78).
 
 from __future__ import annotations
 
+import array
 import dataclasses
 import functools
+import itertools
+import operator
 import pathlib
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from typing import NamedTuple
 
 import numpy as np
@@ -138,32 +141,56 @@ def _locked(fn):
 
 class Gathered(NamedTuple):
     """What ``fetch_tagged`` hands the engine's bulk gather
-    (``with_counts=True, defer_open=True``), in the order the gather
-    emits it: ``series`` holds one (sid, index ordinal, blocks, k) per
-    matched series, sids ascending; ``blocks`` are its shard's
-    ``BlockRows``, block starts ascending, and the series' row in each
-    is ``payloads[k]`` / ``counts[k]`` (None: nothing in that block)."""
+    (``with_counts=True, defer_open=True``): columns, no container a
+    series.  ``sids`` / ``lanes`` are the matched series' ids and index
+    ordinals, sids ascending: a series' place in them is its slot.
+    ``shards`` holds one (slots, blocks) per shard the fetch passed, in
+    the order it passed them: ``blocks`` are the shard's ``BlockRows``,
+    block starts ascending, and row k of each belongs to the series
+    at ``slots[k]`` (a payload of None: nothing in that block).  A
+    series whose shard the datapoint budget cut off has a slot and no
+    row."""
 
-    series: list[tuple]
+    sids: list[bytes]
+    lanes: list[int]
+    shards: list[tuple[list[int], list[BlockRows]]]
     # directory scans this fetch had to make (a shard not yet listed)
     fileset_scans: int = 0
+
+
+def _take(seq, places: list[int]) -> list:
+    """``[seq[i] for i in places]`` by one C-level call."""
+    if not places:
+        return []
+    # a -1 behind the places: an itemgetter of one index alone would
+    # hand back the item, not a tuple of it
+    return list(operator.itemgetter(*places, -1)(seq)[:-1])
 
 
 def _rows_cost(block: BlockRows) -> tuple[int, int, list]:
     """-> (datapoints, bytes, the rows only named) of one block's rows.
     A stream without a stored count is estimated at ~2 bytes/sample
     (m3tsz averages ~1.4B/sample, so this undercounts conservatively
-    rather than rejecting queries early); an ``OpenRow`` is counted by
-    the caller, one search a view (``open_rows_samples``)."""
+    rather than rejecting queries early); an ``OpenRow`` among other
+    rows (a ``MIXED`` block) is counted by the caller, one search a
+    view (``open_rows_samples``)."""
     _bs, kind, payloads, counts = block
     if kind is OPEN:
-        return 0, 0, payloads
-    if (kind is STREAMS and counts is not None
-            and counts.count(None) == payloads.count(None)):
+        # the rows of one view (Shard.read_many names a buffer's lanes
+        # on one): one search for all of them
+        n = int(payloads[0].view.counts(
+            list(map(operator.itemgetter(1), payloads))).sum())
+        return n, 16 * n, []
+    if kind is STREAMS and counts is not None:
         # every stream with its stored count (sealed blocks, v2
         # filesets): two sums, no row looked at in the interpreter
-        return (sum(filter(None, counts)),
-                sum(map(len, filter(None, payloads))), [])
+        try:
+            return sum(counts), sum(map(len, payloads)), []
+        except TypeError:
+            # a None among them: a series the block lacks
+            if counts.count(None) == payloads.count(None):
+                return (sum(filter(None, counts)),
+                        sum(map(len, filter(None, payloads))), [])
     dps = nbytes = 0
     named = []
     for i, p in enumerate(payloads):
@@ -224,32 +251,44 @@ class _Namespace:
         # grows (avoids full-index scans per per-shard metadata call)
         self._shard_ordinals: dict[int, list[int]] = {}
         self._shard_ordinals_upto = 0
-        # ordinal -> shard id memo, SPARSE: a dense list would force an
-        # O(total-series) catch-up hash storm on the first write after
-        # bootstrapping a large recovered index
-        self._lane_shards: dict[int, int] = {}
+        # ordinal -> shard id memo, an array as long as the index with
+        # -1 where nobody has asked yet: it grows with the index but is
+        # FILLED only where asked (a dense fill would force an
+        # O(total-series) hash storm on the first write after
+        # bootstrapping a large recovered index)
+        self._lane_shards = array.array("i")
 
     def shard_of(self, series_id: bytes) -> Shard:
         return self.shards[shard_for(series_id, len(self.shards))]
 
-    def shard_of_lane(self, lane: int) -> int:
-        """Shard id for an index ordinal, memoized — shard placement is
-        a pure function of the series id, and the pure-Python murmur3
-        dominates steady-state ingest when recomputed per sample."""
-        s = self._lane_shards.get(lane)
-        if s is None:
-            s = self._lane_shards[lane] = shard_for(
-                self.index.id_of(lane), len(self.shards))
-        return s
+    def shards_of_lanes(self, lanes: list[int]) -> list[int]:
+        """Shard id of each index ordinal, memoized: shard placement
+        is a pure function of the series id, and the pure-Python
+        murmur3 dominates both steady-state ingest and a fan-out's
+        grouping when recomputed.  One C-level pass over the memo;
+        the interpreter goes round only the ordinals asked for the
+        first time."""
+        memo = self._lane_shards
+        if len(memo) < len(self.index):
+            memo.extend(itertools.repeat(
+                -1, max(len(self.index), 2 * len(memo)) - len(memo)))
+        shards = list(map(memo.__getitem__, lanes))
+        if -1 in shards:
+            new = list(itertools.compress(
+                lanes, map(operator.eq, shards, itertools.repeat(-1))))
+            for lane, sid in zip(new, self.index.ids_of(new)):
+                memo[lane] = shard_for(sid, len(self.shards))
+            shards = list(map(memo.__getitem__, lanes))
+        return shards
 
     def ordinals_for_shard(self, shard_id: int) -> list[int]:
         n = len(self.index)
         while self._shard_ordinals_upto < n:
             o = self._shard_ordinals_upto
-            # computed inline, NOT via shard_of_lane: this scan walks
+            # computed inline, NOT via shards_of_lanes: this scan walks
             # every ordinal, and routing it through the memo would
-            # densely materialize the dict the memo's sparseness exists
-            # to avoid (its result already lives in _shard_ordinals)
+            # fill all of it, the hash storm its filling only where
+            # asked exists to avoid (the result lives in _shard_ordinals)
             self._shard_ordinals.setdefault(
                 shard_for(self.index.id_of(o), len(self.shards)),
                 []).append(o)
@@ -507,20 +546,16 @@ class Database:
         # dict-backed and irreducibly per-object); everything per-sample
         # below this loop is numpy
         lanes_u = np.empty(u, dtype=np.int64)
-        shards_u = np.empty(u, dtype=np.int64)
         insert = n.index.insert
-        shard_of_lane = n.shard_of_lane
         idx_before = len(n.index)  # new-series delta for attribution
         if uniq_tags is None:
             for i, sid in enumerate(uniq_ids):
-                lane = insert(sid, {})
-                lanes_u[i] = lane
-                shards_u[i] = shard_of_lane(lane)
+                lanes_u[i] = insert(sid, {})
         else:
             for i, (sid, tg) in enumerate(zip(uniq_ids, uniq_tags)):
-                lane = insert(sid, tg)
-                lanes_u[i] = lane
-                shards_u[i] = shard_of_lane(lane)
+                lanes_u[i] = insert(sid, tg)
+        shards_u = np.fromiter(n.shards_of_lanes(lanes_u.tolist()),
+                               dtype=np.int64, count=u)
         if uniq_idx is None:
             lanes, shard_ids = lanes_u, shards_u
         else:
@@ -658,7 +693,7 @@ class Database:
         n = self._ns(ns)
         ords = self._query_ordinals(n, matchers, start_nanos, end_nanos,
                                     limits, meta)
-        return [n.index.id_of(o) for o in ords]
+        return n.index.ids_of(ords)
 
     @_locked
     def fetch_series(
@@ -785,28 +820,28 @@ class Database:
         n = self._ns(ns)
         lanes = self._query_ordinals(n, matchers, start_nanos, end_nanos,
                                      limits, meta).tolist()
-        id_of = n.index.id_of
-        sids = [id_of(lane) for lane in lanes]
+        sids = n.index.ids_of(lanes)
         limit = getattr(self._runtime, "max_fetch_series", 0)
         if limit and len(sids) > limit:
             raise ValueError(
                 f"query matched {len(sids)} series > limit {limit}")
         if meta is not None:
             meta.fetched_series += len(sids)
-        # group by shard (matched sids are indexed: route via the lane
-        # memo instead of recomputing pure-Python murmur3 per sid).
-        # series[i] = (sid, lane, the blocks of its shard, filled
-        # below; its row in each of them)
-        by_shard: dict[int, tuple[list, list, list]] = {}
-        series: list[tuple] = []
-        shard_of_lane = n.shard_of_lane
-        for sid, lane in zip(sids, lanes):
-            group = by_shard.get(shard_id := shard_of_lane(lane))
-            if group is None:
-                group = by_shard[shard_id] = ([], [], [])
-            series.append((sid, lane, group[2], len(group[0])))
-            group[0].append(sid)
-            group[1].append(lane)
+        # group by shard: a series' place in `lanes` appended to its
+        # shard's list (the shard from the lane memo: no pure-Python
+        # murmur3 per sid), by C-level passes; a shard's series stay
+        # in the index's order, and the shards are passed in the order
+        # of their first match.  Builtins throughout, no array call:
+        # under other threads' load every array call on more than a
+        # few hundred elements lets go of the interpreter lock and
+        # waits a switch interval to get it back
+        shard_ids = n.shards_of_lanes(lanes)
+        places_of: dict[int, list[int]] = {
+            shard_id: [] for shard_id in dict.fromkeys(shard_ids)}
+        deque(map(list.append, map(places_of.__getitem__, shard_ids),
+                  range(len(lanes))), maxlen=0)
+        # (the places of a shard's series in `lanes`, its blocks)
+        passed: list[tuple[list[int], list[BlockRows]]] = []
 
         count_cost = attribution.enabled()
         budget = limits is not None and limits.max_fetched_datapoints
@@ -816,20 +851,20 @@ class Database:
         # warm repeat serves device-ready (times, values) arrays with
         # zero M3TSZ decode work
         dec_policy = self._decoded_cache.policy_for(ns)
-        for shard_id, (shard_sids, shard_lanes, blocks) in by_shard.items():
+        for shard_id, places in places_of.items():
             if limits is not None:
                 limits.check_deadline("block fetch")
                 if budget and limits.datapoints_exceeded(dp_fetched, meta):
                     break  # budget spent: remaining shards truncated
             shard = n.shards[shard_id]
+            shard_sids = _take(sids, places)
+            blocks: list[BlockRows] = []
             scans += shard.filesets is None
             for bs, reader in self._overlapping_filesets(
                     ns, n, shard, start_nanos, end_nanos):
                 if not with_counts:
                     blocks.append(BlockRows(
-                        bs, STREAMS,
-                        [b or None for b in reader.read_batch(shard_sids)],
-                        None))
+                        bs, STREAMS, reader.read_batch(shard_sids), None))
                     continue
                 blobs, counts = reader.read_batch_with_counts(
                     shard_sids, zero_copy=True)
@@ -842,14 +877,15 @@ class Database:
                         [None if d is None else len(d[0])
                          for d in decoded]))
                 else:
-                    blocks.append(BlockRows(
-                        bs, STREAMS, [b or None for b in blobs], counts))
+                    blocks.append(BlockRows(bs, STREAMS, blobs, counts))
             on_disk = len(blocks)
             blocks.extend(shard.read_many(
-                shard_sids, shard_lanes, start_nanos, end_nanos,
+                shard_sids, _take(lanes, places),
+                start_nanos, end_nanos,
                 with_counts=with_counts, defer_open=defer_open))
             if 0 < on_disk < len(blocks):
                 blocks.sort(key=lambda b: b.block_start)
+            passed.append((places, blocks))
             if budget or count_cost:
                 # datapoints scanned + bytes decoded, a pass a block
                 # (never per sample); sids are partitioned by shard, so
@@ -871,16 +907,29 @@ class Database:
         if meta is not None and budget:
             meta.fetched_datapoints += dp_fetched
         if with_counts and defer_open:
-            series.sort()  # by sid: they are distinct, nothing else is compared
-            return Gathered(series, scans)
+            # slots: the series by sid (they are distinct)
+            by_sid = sorted(range(len(sids)), key=sids.__getitem__)
+            slot_of = [0] * len(sids)
+            deque(map(slot_of.__setitem__, by_sid, range(len(sids))),
+                  maxlen=0)
+            return Gathered(
+                _take(sids, by_sid), _take(lanes, by_sid),
+                [(_take(slot_of, places), blocks)
+                 for places, blocks in passed], scans)
+        # the public shapes, a container a series: its shard's blocks
+        # and its row in them (none: the budget cut its shard off)
+        rows_of: list[tuple] = [((), 0)] * len(sids)
+        for places, blocks in passed:
+            for k, i in enumerate(places):
+                rows_of[i] = (blocks, k)
         if with_counts:
             return {sid: [(b.block_start, b.payloads[k],
                            b.counts[k] if b.counts else None)
                           for b in blocks if b.payloads[k] is not None]
-                    for sid, _lane, blocks, k in series}
+                    for sid, (blocks, k) in zip(sids, rows_of)}
         return {sid: [(b.block_start, b.payloads[k])
                       for b in blocks if b.payloads[k] is not None]
-                for sid, _lane, blocks, k in series}
+                for sid, (blocks, k) in zip(sids, rows_of)}
 
     # --- lifecycle (ref: storage/mediator.go tick+flush loops) ---
 

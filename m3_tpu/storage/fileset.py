@@ -18,6 +18,7 @@ same crash-atomicity rule the reference's TLA+ flush spec encodes
 
 from __future__ import annotations
 
+import itertools
 import json
 import pathlib
 import struct
@@ -263,46 +264,51 @@ class FilesetReader:
         ``zero_copy=True`` returns memoryview slices of the mmap
         instead of bytes copies (engine batch path: tens of thousands
         of small copies per fan-out otherwise)."""
-        blobs = self.read_batch(series_ids, zero_copy=zero_copy)
+        blobs, rows = self._read_rows(series_ids, zero_copy)
         if self._counts is None:
             return blobs, [None] * len(blobs)
-        pos_of = self._pos_of  # built by read_batch
-        counts = [None if b is None else self._counts[pos_of[sid]]
-                  for sid, b in zip(series_ids, blobs)]
-        return blobs, counts
-
-    _mv: memoryview | None = None
+        return blobs, list(map(self._counts_or_none.__getitem__,
+                               rows.tolist()))
 
     def read_batch(self, series_ids,
                    zero_copy: bool = False) -> list[bytes | None]:
-        """Bulk read: one dict lookup per id instead of bloom + bisect.
-        The id->position map is built lazily on first bulk read and
-        amortized across every query hitting this (cached) reader —
+        """Bulk read: one dict lookup per id instead of bloom + bisect,
+        None for an id the fileset lacks (or holds an empty stream
+        of).  The id->position map is built lazily on first bulk read
+        and amortized across every query hitting this (cached) reader —
         fan-out reads spend their time here, not in per-call setup
         (ref: the seek-index byte ranges reused across a batch,
         persist/fs/retriever.go seekerManager)."""
+        return self._read_rows(series_ids, zero_copy)[0]
+
+    def _read_rows(self, series_ids, zero_copy: bool
+                   ) -> tuple[list, np.ndarray]:
+        """-> (blobs, the index row of each id, -1 where the blob is
+        None), by C-level passes over the ids: the look-ups, the
+        slices' bounds taken from two arrays, the cuts."""
         pos_of = self._pos_of
         if pos_of is None:
             pos_of = self._pos_of = {
                 sid: i for i, sid in enumerate(self._ids)}
-        offsets = self._offsets
-        if zero_copy:
-            mv = self._mv
-            if mv is None:
-                mv = self._mv = memoryview(self._data)
-        else:
-            mv = None
-        data = self._data
-        out: list = []
-        for sid in series_ids:
-            i = pos_of.get(sid)
-            if i is None:
-                out.append(None)
-            else:
-                off, length = offsets[i]
-                out.append(mv[off:off + length] if zero_copy
-                           else data[off:off + length].tobytes())
-        return out
+            off = np.asarray(self._offsets, dtype=np.int64).reshape(-1, 2)
+            # one row behind the last, which is what -1 takes: an
+            # empty slice, no count
+            self._starts = np.append(off[:, 0], 0)
+            self._ends = np.append(off[:, 0] + off[:, 1], 0)
+            self._counts_or_none = (None if self._counts is None
+                                    else (*self._counts, None))
+            self._mv = memoryview(self._data)
+        rows = np.fromiter(map(pos_of.get, series_ids, itertools.repeat(-1)),
+                           dtype=np.int64, count=len(series_ids))
+        starts, ends = self._starts[rows], self._ends[rows]
+        rows[starts == ends] = -1
+        blobs = map(self._mv.__getitem__,
+                    map(slice, starts.tolist(), ends.tolist()))
+        if not zero_copy:
+            blobs = map(bytes, blobs)
+        out = np.fromiter(blobs, dtype=object, count=len(rows))
+        out[rows < 0] = None
+        return out.tolist(), rows
 
     def read_all(self) -> tuple[list[bytes], list[bytes]]:
         return self._ids, [

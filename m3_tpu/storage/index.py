@@ -54,9 +54,12 @@ Restart = mmap segments + replay only the WAL tail; no full rebuild.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import hashlib
+import itertools
 import json
+import operator
 import pathlib
 import re
 import shutil
@@ -64,7 +67,7 @@ import struct
 import threading
 import time
 import weakref
-from collections import OrderedDict, defaultdict
+from collections import OrderedDict, defaultdict, deque
 
 import numpy as np
 
@@ -373,6 +376,33 @@ class _FrozenRegistry:
     def id_of(self, ordinal: int) -> bytes:
         return _blob_item(self.ids_blob, self.ids_off, ordinal - self.base)
 
+    def ids_of(self, ordinals: list[int]) -> list[bytes]:
+        """``id_of`` for a list of this segment's ordinals, ascending:
+        one pass over the blob by one compiled ``struct``
+        (``<gap>x<length>s`` an id: the bytes between two ids asked
+        for are stepped over, never copied, whatever the ordinals'
+        spread).  Nothing is done once an id in the interpreter; the
+        offsets' arithmetic is five array calls, each of which may
+        let go of the interpreter lock."""
+        if not all(map(operator.lt, ordinals, ordinals[1:])):
+            once = sorted(set(ordinals))    # one asked twice
+            place = dict(zip(once, itertools.count()))
+            ids = self.ids_of(once)
+            return list(map(ids.__getitem__, map(place.get, ordinals)))
+        if not ordinals:
+            return []
+        n = len(ordinals)
+        rel = np.fromiter(ordinals, dtype=np.int64, count=n) - self.base
+        bounds = self.ids_off[np.concatenate((rel, rel + 1))]
+        starts, ends = bounds[:n], bounds[n:]
+        spans = itertools.chain.from_iterable(zip(
+            itertools.chain((0,), (starts[1:] - ends[:-1]).tolist()),
+            (ends - starts).tolist()))
+        # not struct.unpack_from: the module would keep a format this
+        # long in its cache
+        return list(struct.Struct("%dx%ds" * n % tuple(spans))
+                    .unpack_from(self.ids_blob, int(starts[0])))
+
     def tags_raw(self, ordinal: int) -> bytes:
         return _blob_item(self.tags_blob, self.tags_off, ordinal - self.base)
 
@@ -451,6 +481,37 @@ class SeriesRegistry:
             if seg.base <= ordinal < seg.base + seg.n:
                 return seg.id_of(ordinal)
         raise IndexError(ordinal)
+
+    def ids_of(self, ordinals) -> list[bytes]:
+        """``id_of`` for ordinals (a list or an array), in their order:
+        each frozen segment answers for its range in one pass, the
+        mutable tail by its list; nothing is done once an ordinal in
+        the interpreter."""
+        ordinals = (ordinals.tolist() if isinstance(ordinals, np.ndarray)
+                    else list(ordinals))
+        back = None
+        if not all(map(operator.le, ordinals, ordinals[1:])):
+            back = sorted(range(len(ordinals)), key=ordinals.__getitem__)
+            ordinals = list(map(ordinals.__getitem__, back))
+        base = self._mut_base
+        ids: list[bytes] = []
+        if ordinals and ordinals[0] < 0:
+            raise IndexError(ordinals[0])
+        for seg in self._frozen:
+            mine = ordinals[bisect.bisect_left(ordinals, seg.base):
+                            bisect.bisect_left(ordinals, seg.base + seg.n)]
+            if mine:
+                ids.extend(seg.ids_of(mine))
+        tail = ordinals[bisect.bisect_left(ordinals, base):]
+        ids.extend(map(self._mut_ids.__getitem__,
+                       map(operator.sub, tail, itertools.repeat(base))))
+        if len(ids) != len(ordinals):
+            raise IndexError("ordinal outside the registry")
+        if back is None:
+            return ids
+        out: list = [None] * len(ids)
+        deque(map(out.__setitem__, back, ids), maxlen=0)
+        return out
 
     def tags_raw(self, ordinal: int) -> bytes:
         if ordinal >= self._mut_base:
@@ -1177,6 +1238,10 @@ class TagIndex:
     def id_of(self, ordinal: int) -> bytes:
         return self._registry.id_of(ordinal)
 
+    def ids_of(self, ordinals) -> list[bytes]:
+        """``id_of`` for an array of ordinals, in one call."""
+        return self._registry.ids_of(ordinals)
+
     TAGS_MEMO_CAPACITY = 262144
 
     def tags_of(self, ordinal: int) -> dict[bytes, bytes]:
@@ -1195,6 +1260,19 @@ class TagIndex:
         else:
             memo.move_to_end(ordinal)
         return d
+
+    def tags_of_many(self, ordinals) -> list[dict[bytes, bytes]]:
+        """``tags_of`` for an array of ordinals, in their order: the
+        memo asked and touched by C-level calls, the interpreter going
+        round only the ordinals it does not hold yet.  The dicts are
+        the memo's own, as ``tags_of``'s are."""
+        if isinstance(ordinals, np.ndarray):
+            ordinals = ordinals.tolist()
+        tags = list(map(self._tags_memo.get, ordinals))
+        if None in tags:
+            return list(map(self.tags_of, ordinals))
+        deque(map(self._tags_memo.move_to_end, ordinals), maxlen=0)
+        return tags
 
     # --- queries (ref: src/m3ninx/search/searcher/) ---
 

@@ -10,12 +10,14 @@ the sealed block as an immutable fileset.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import operator
 from typing import Callable, NamedTuple
 
 import numpy as np
 
 from m3_tpu.ops import m3tsz_scalar
-from m3_tpu.storage.buffer import BlockBuffer, OpenRow
+from m3_tpu.storage.buffer import BlockBuffer, OpenRow, open_rows
 from m3_tpu.storage.fileset import FilesetWriter
 from m3_tpu.storage.namespace import NamespaceOptions
 from m3_tpu.utils import clock
@@ -148,9 +150,32 @@ class SealedBlock:
     # makes every SealedBlock) and dropped with it (unseal), so the read
     # path looks a row up and never searches `ids`
     row_of: dict[bytes, int] = dataclasses.field(init=False, repr=False)
+    # streams and counts with a None behind the last row: what a bulk
+    # read takes rows from, -1 standing for a series the block lacks
+    _streams_or_none: tuple = dataclasses.field(init=False, repr=False)
+    _counts_or_none: tuple | None = dataclasses.field(init=False,
+                                                      repr=False)
 
     def __post_init__(self):
         self.row_of = {sid: row for row, sid in enumerate(self.ids)}
+        self._streams_or_none = (*self.streams, None)
+        self._counts_or_none = (None if self.counts is None
+                                else (*self.counts, None))
+
+    def take(self, sids: list[bytes], with_counts: bool
+             ) -> tuple[list, list | None]:
+        """-> (streams, counts) aligned with `sids`, None where the
+        block lacks a series (`counts` None: not asked for, or not
+        stored).  Three C-level passes: nothing is done once a row in
+        the interpreter."""
+        # a -1 behind the rows: an itemgetter of one index alone would
+        # hand back the item, not a tuple of it
+        pick = operator.itemgetter(
+            *map(self.row_of.get, sids, itertools.repeat(-1)), -1)
+        streams = list(pick(self._streams_or_none)[:-1])
+        if not with_counts or self._counts_or_none is None:
+            return streams, None
+        return streams, list(pick(self._counts_or_none)[:-1])
 
 
 # what the rows of a BlockRows hold (every payload that is not None)
@@ -426,13 +451,8 @@ class Shard:
             blk, buf = sealed.get(bs), buffers.get(bs)
             streams = counts = None
             if blk is not None:
-                rows = [blk.row_of.get(sid) for sid in sids]
-                streams = [None if r is None else blk.streams[r]
-                           for r in rows]
-                if with_counts and blk.counts is not None:
-                    counts = [None if r is None else blk.counts[r]
-                              for r in rows]
-                if streams.count(None) == len(streams):
+                streams, counts = blk.take(sids, with_counts)
+                if not any(streams):    # a sealed stream is never empty
                     streams = counts = None
             if buf is None:
                 if streams is not None:
@@ -440,9 +460,8 @@ class Shard:
             elif streams is None:
                 view = buf.view()
                 if defer_open:
-                    out.append(BlockRows(
-                        bs, OPEN, [OpenRow(view, lane) for lane in lanes],
-                        None))
+                    out.append(BlockRows(bs, OPEN, open_rows(view, lanes),
+                                         None))
                     continue
                 read = [tv if len(tv[0]) else None
                         for tv in view.read_lanes(lanes)]

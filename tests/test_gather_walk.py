@@ -36,8 +36,8 @@ NS = "default"
 MATCH = [("eq", b"__name__", b"m")]
 
 
-def _open_db(path, cache=None):
-    db = Database(DatabaseOptions(path=str(path), num_shards=SHARDS,
+def _open_db(path, cache=None, shards=SHARDS):
+    db = Database(DatabaseOptions(path=str(path), num_shards=shards,
                                   commit_log_enabled=False, cache=cache))
     db.create_namespace(NamespaceOptions(
         name=NS, retention=RetentionOptions(block_size=BLOCK)))
@@ -56,13 +56,23 @@ def _value(i: int, col: int, bump: float = 0.0) -> float:
     return float((col % 200) * (1 + i)) + bump
 
 
-def _write(db, cols, series=range(SERIES), bump: float = 0.0, load=False):
+def _write(db, cols, series=range(SERIES), bump: float = 0.0, load=False,
+           ns=NS):
     cols = list(cols)
     for i in series:
         ts = [T0 + c * CADENCE for c in cols]
         vs = [_value(i, c, bump) for c in cols]
         (db.load_batch if load else db.write_batch)(
-            NS, [_sid(i)] * len(ts), [_tags(i)] * len(ts), ts, vs)
+            ns, [_sid(i)] * len(ts), [_tags(i)] * len(ts), ts, vs)
+
+
+def _write_fleet(db, cols, series):
+    """Many series at once: one request a column."""
+    series = list(series)
+    sids, tags = [_sid(i) for i in series], [_tags(i) for i in series]
+    for c in cols:
+        db.write_batch(NS, sids, tags, [T0 + c * CADENCE] * len(series),
+                       [_value(i, c) for i in series])
 
 
 def _tick(db, n_blocks: int):
@@ -87,22 +97,22 @@ def _lane_of_buffer(buf: BlockBuffer, lane: int):
 
 
 def _oracle_series(db, sid: bytes, lo: int, hi: int, defer_open: bool,
-                   counted: bool = True):
+                   counted: bool = True, ns: str = NS):
     """[(block_start, kind, payload, n_dp)] of one series in [lo, hi):
     flushed filesets not shadowed by memory (read from the directory
     itself), then what memory holds, block starts ascending."""
-    n = db._ns(NS)
+    n = db._ns(ns)
     lane = n.index.ordinal(sid)
     shard = n.shard_of(sid)
     in_memory = set(shard._sealed) | set(shard._buffers)
     rows = []
-    for bs, vol in list_filesets(db.path / "data", NS, shard.shard_id):
+    for bs, vol in list_filesets(db.path / "data", ns, shard.shard_id):
         if not (lo < bs + BLOCK and bs < hi) or bs in in_memory:
             continue
-        reader = FilesetReader(db.path / "data", NS, shard.shard_id, bs, vol)
+        reader = FilesetReader(db.path / "data", ns, shard.shard_id, bs, vol)
         if sid not in reader.ids:
             continue
-        if counted and db._decoded_cache.policy_for(NS) != "none":
+        if counted and db._decoded_cache.policy_for(ns) != "none":
             # a series cache policy: the fileset's rows arrive decoded
             ts, vs = tsz.decode_series(reader.read(sid))
             rows.append((bs, "decoded", (np.asarray(ts, np.int64),
@@ -153,16 +163,16 @@ def _ndp(row) -> int:
 
 
 def _oracle_fetch(db, lo, hi, defer_open, limits=None, meta=None,
-                  counted=True):
+                  counted=True, ns=NS):
     """{sid: rows}, sids in index order: the fetch's own rules (series
     truncated at the index, shards in order of first match, the
     datapoint budget checked between shards)."""
     if limits is not None:
         lo = limits.clamp_time_range(lo, hi, meta)
-    sids = db.query_ids(NS, MATCH, lo, hi, limits=limits, meta=meta)
+    sids = db.query_ids(ns, MATCH, lo, hi, limits=limits, meta=meta)
     if meta is not None:
         meta.fetched_series += len(sids)
-    n = db._ns(NS)
+    n = db._ns(ns)
     by_shard: dict[int, list[bytes]] = {}
     for sid in sids:
         by_shard.setdefault(n.shard_of(sid).shard_id, []).append(sid)
@@ -172,7 +182,8 @@ def _oracle_fetch(db, lo, hi, defer_open, limits=None, meta=None,
         if limits is not None and limits.datapoints_exceeded(fetched, meta):
             break
         for sid in group:
-            out[sid] = _oracle_series(db, sid, lo, hi, defer_open, counted)
+            out[sid] = _oracle_series(db, sid, lo, hi, defer_open, counted,
+                                      ns)
             if limits is not None and limits.max_fetched_datapoints:
                 fetched += sum(_ndp(r) for r in out[sid])
     if meta is not None:
@@ -181,22 +192,31 @@ def _oracle_fetch(db, lo, hi, defer_open, limits=None, meta=None,
 
 
 def _oracle_walk(db, lo, hi, limits=None, meta=None):
-    """-> (labels, [(slot, kind, payload, n_dp)] in the gather's order,
-    ns_bytes)."""
-    fetched = _oracle_fetch(db, lo, hi + 1, True, limits, meta)
-    n = db._ns(NS)
-    labels, rows, nbytes = [], [], 0
-    for slot, sid in enumerate(sorted(fetched)):
-        labels.append(dict(n.index.tags_of(n.index.ordinal(sid))))
-        for _bs, kind, payload, count in fetched[sid]:
-            if kind == "stream":
-                nbytes += len(payload)
-            elif len(payload[0]):
-                nbytes += 16 * len(payload[0])
-            else:
-                continue        # an open row that turned out empty
-            rows.append((slot, kind, payload, count))
-    return labels, rows, ({NS: nbytes} if nbytes else {})
+    """-> (labels, [(slot, kind, payload, n_dp, tier)] in the gather's
+    order, ns_bytes): the per-series loop the engine's walk was until
+    PR 44, a tier after the other in the fetch plan's order, slots
+    numbered by first sight."""
+    labels, rows, ns_bytes, slot_of = [], [], {}, {}
+    for tier, ns in enumerate(Engine(db, NS)._resolve_namespaces()):
+        fetched = _oracle_fetch(db, lo, hi + 1, True, limits, meta, ns=ns)
+        n = db._ns(ns)
+        nbytes = 0
+        for sid in sorted(fetched):
+            slot = slot_of.get(sid)
+            if slot is None:
+                slot = slot_of[sid] = len(labels)
+                labels.append(dict(n.index.tags_of(n.index.ordinal(sid))))
+            for _bs, kind, payload, count in fetched[sid]:
+                if kind == "stream":
+                    nbytes += len(payload)
+                elif len(payload[0]):
+                    nbytes += 16 * len(payload[0])
+                else:
+                    continue        # an open row that turned out empty
+                rows.append((slot, kind, payload, count, tier))
+        if nbytes:
+            ns_bytes[ns] = nbytes
+    return labels, rows, ns_bytes
 
 
 # --- the walk under test, laid out the same way -------------------------
@@ -204,10 +224,14 @@ def _oracle_walk(db, lo, hi, limits=None, meta=None):
 def _walk(engine, lo, hi, limits=None, meta=None):
     engine._qrange_local.limits, engine._qrange_local.meta = limits, meta
     try:
-        labels, parts, compressed, counts, named, ns_bytes = (
+        labels, parts, stream_rows, named, ns_bytes = (
             engine._gather_walk(MATCH, lo, hi))
     finally:
         engine._qrange_local.limits = engine._qrange_local.meta = None
+    # the columns, read back a row at a time (the old triple view)
+    compressed = list(stream_rows.triples())
+    counts = [None if c < 0 else c for c in stream_rows.counts.tolist()]
+    assert len(compressed) == len(stream_rows) == len(counts)
     for _at, _ns, _slot, _tier, _after, row in named:
         assert isinstance(row, OpenRow)
     Engine._read_open_rows(parts, named, ns_bytes)
@@ -216,15 +240,15 @@ def _walk(engine, lo, hi, limits=None, meta=None):
     def streams_up_to(n):
         nonlocal ci
         while ci < n:
-            slot, _tier, payload = compressed[ci]
+            slot, tier, payload = compressed[ci]
             assert isinstance(payload, (bytes, memoryview))
-            rows.append((slot, "stream", payload, counts[ci]))
+            rows.append((slot, "stream", payload, counts[ci], tier))
             ci += 1
 
-    for slot, _tier, ts, vs, kind, after in parts:
+    for slot, tier, ts, vs, kind, after in parts:
         streams_up_to(after)
         rows.append((slot, kind, (ts, vs),
-                     len(ts) if kind == "decoded" else None))
+                     len(ts) if kind == "decoded" else None, tier))
     streams_up_to(len(compressed))
     return labels, rows, ns_bytes
 
@@ -239,9 +263,9 @@ def _assert_same_payload(kind, a, b):
 
 
 def _assert_same_rows(got, want):
-    assert [(r[0], r[1], r[3]) for r in got] == [
-        (r[0], r[1], r[3]) for r in want]
-    for (_, kind, a, _), (_, _, b, _) in zip(got, want):
+    assert [(r[0], r[1], r[3], r[4]) for r in got] == [
+        (r[0], r[1], r[3], r[4]) for r in want]
+    for (_, kind, a, *_), (_, _, b, *_) in zip(got, want):
         _assert_same_payload(kind, a, b)
 
 
@@ -379,11 +403,53 @@ def _series_cache_policy(db, tmp):
     return db, lo, hi
 
 
+FLEET = 600
+FLEET_SHARDS = 8
+FLEET_COLS = 16         # a block's samples: every 15th column
+
+
+def _fleet_with_gaps_a_cold_write_and_a_tail(db, tmp):
+    """600 series over 8 shards (sids that do not sort as the index's
+    ordinals do: h100 < h11): every third series absent from the first
+    block, the second block MIXED in the shards two cold writes land
+    in, an open tail for a fifth of the fleet."""
+    db.close()
+    db = _open_db(tmp, shards=FLEET_SHARDS)
+    _write_fleet(db, range(0, PER_BLOCK, PER_BLOCK // FLEET_COLS),
+                 (i for i in range(FLEET) if i % 3))
+    _write_fleet(db, range(PER_BLOCK, 2 * PER_BLOCK,
+                           PER_BLOCK // FLEET_COLS), range(FLEET))
+    _tick(db, 2)
+    db.flush()
+    _write(db, range(PER_BLOCK + 30, PER_BLOCK + 34), series=(7, 301),
+           bump=0.25)
+    _write_fleet(db, range(2 * PER_BLOCK, 2 * PER_BLOCK + 3),
+                 range(0, FLEET, 5))
+    return db, 0, 2 * PER_BLOCK + 3
+
+
+def _two_namespaces(db, tmp):
+    """A second tier: an aggregated namespace that holds every series,
+    the raw one half of them, so the second tier's series take slots
+    between and behind the first's."""
+    db.create_namespace(NamespaceOptions(
+        name="agg", retention=RetentionOptions(block_size=BLOCK),
+        aggregated=True, aggregation_resolution=60 * SEC))
+    raw = [i for i in range(SERIES) if i % 4 >= 2]
+    _write(db, range(PER_BLOCK, 2 * PER_BLOCK), series=raw)
+    _write(db, range(0, 2 * PER_BLOCK, 2), ns="agg")
+    _write(db, range(2 * PER_BLOCK, 2 * PER_BLOCK + 20, 2), series=(1, 2),
+           ns="agg")
+    _tick(db, 2)
+    return db, 0, 2 * PER_BLOCK + 20
+
+
 STATES = [_sealed_only, _sealed_and_flushed, _open_only,
           _sealed_and_open_tail, _cold_write_after_seal,
           _series_absent_from_a_block, _only_on_disk_after_restart,
           _memory_copy_gone_and_open_tail, _unseal_and_reflush,
-          _after_cleanup, _series_cache_policy]
+          _after_cleanup, _series_cache_policy,
+          _fleet_with_gaps_a_cold_write_and_a_tail, _two_namespaces]
 
 
 @pytest.mark.parametrize("state", STATES, ids=lambda f: f.__name__[1:])
@@ -421,14 +487,18 @@ def test_cold_write_rows_are_merged_and_the_buffer_wins(tmp_path):
         db.close()
 
 
-@pytest.mark.parametrize("limits", [
-    QueryLimits(max_fetched_series=5),
-    QueryLimits(max_fetched_datapoints=900),
-    QueryLimits(max_fetched_series=7, max_fetched_datapoints=1500),
-    QueryLimits(max_time_range_nanos=BLOCK),
-], ids=["series", "datapoints", "both", "time_range"])
-def test_walk_with_limits_truncates_as_the_oracle(limits, tmp_path):
-    db, lo, hi = _sealed_and_open_tail(_open_db(tmp_path), tmp_path)
+@pytest.mark.parametrize("limits,state", [
+    (QueryLimits(max_fetched_series=5), _sealed_and_open_tail),
+    (QueryLimits(max_fetched_datapoints=900), _sealed_and_open_tail),
+    (QueryLimits(max_fetched_series=7, max_fetched_datapoints=1500),
+     _sealed_and_open_tail),
+    (QueryLimits(max_time_range_nanos=BLOCK), _sealed_and_open_tail),
+    (QueryLimits(max_fetched_datapoints=6000),
+     _fleet_with_gaps_a_cold_write_and_a_tail),
+], ids=["series", "datapoints", "both", "time_range",
+        "fleet_between_shards"])
+def test_walk_with_limits_truncates_as_the_oracle(limits, state, tmp_path):
+    db, lo, hi = state(_open_db(tmp_path), tmp_path)
     try:
         lo, hi = T0 + lo * CADENCE, T0 + hi * CADENCE
         meta, want_meta = ResultMeta(), ResultMeta()
@@ -444,6 +514,14 @@ def test_walk_with_limits_truncates_as_the_oracle(limits, tmp_path):
             want_meta.fetched_series, want_meta.fetched_datapoints)
         full = _oracle_walk(db, lo, hi)
         assert len(rows) < len(full[1])
+        if state is _fleet_with_gaps_a_cold_write_and_a_tail:
+            # cut between two shards: some have every row, some none
+            full_of, got_of = ({}, {})
+            for out, all_rows in ((full_of, full[1]), (got_of, rows)):
+                for slot, *_ in all_rows:
+                    out[slot] = out.get(slot, 0) + 1
+            assert 0 < len(got_of) < len(full_of) == len(labels)
+            assert all(full_of[slot] == k for slot, k in got_of.items())
     finally:
         db.close()
 
@@ -456,6 +534,122 @@ def test_require_exhaustive_aborts(tmp_path):
             _walk(Engine(db, NS), T0, T0 + 2 * BLOCK,
                   QueryLimits(max_fetched_datapoints=10,
                               require_exhaustive=True), ResultMeta())
+    finally:
+        db.close()
+
+
+# --- the counter that says the columns engaged ---------------------------
+
+def _walk_rows_total(form: str) -> float:
+    from m3_tpu.utils import instrument
+    return instrument.bounded_counter(
+        "m3_query_walk_rows_total").labels(form=form).value
+
+
+@pytest.mark.parametrize("state,cold", [
+    (_sealed_only, False), (_sealed_and_flushed, False), (_open_only, False),
+    (_sealed_and_open_tail, False), (_only_on_disk_after_restart, False),
+    (_series_absent_from_a_block, False), (_two_namespaces, False),
+    (_cold_write_after_seal, True),
+    (_fleet_with_gaps_a_cold_write_and_a_tail, True),
+], ids=lambda v: v.__name__[1:] if callable(v) else "")
+def test_walk_rows_by_row_only_beside_a_cold_write(state, cold, tmp_path):
+    """Sealed and open rows travel as columns; only the rows of a MIXED
+    block (a cold write beside a sealed stream) are told apart one by
+    one, and the record and the registry say how many."""
+    from m3_tpu.query import slowlog
+    db, lo, hi = state(_open_db(tmp_path), tmp_path)
+    try:
+        engine = Engine(db, NS)
+        lo, hi = T0 + lo * CADENCE, T0 + hi * CADENCE
+        before = {f: _walk_rows_total(f) for f in ("columns", "by_row")}
+        cost = engine._begin_cost()
+        _labels, _parts, rows, named, _ = engine._gather_walk(MATCH, lo, hi)
+        walked = dict(cost.walk_rows)
+        # every row the walk was handed: streams, arrays, named open rows
+        assert walked["columns"] + walked["by_row"] == len(rows) + len(
+            _parts)
+        assert (walked["by_row"] > 0) == cold
+        if cold:
+            mixed = sum(
+                len(b.payloads) - b.payloads.count(None)
+                for _slots, blocks in db.fetch_tagged(
+                    NS, MATCH, lo, hi + 1, with_counts=True,
+                    defer_open=True).shards
+                for b in blocks if b.kind == "mixed")
+            assert walked["by_row"] == mixed < walked["columns"]
+        for form, n in walked.items():
+            assert _walk_rows_total(form) - before[form] == n
+        engine.query_range("sum(m)", lo + 600 * SEC, lo + 1200 * SEC,
+                           60 * SEC)
+        rec = slowlog.log().records(limit=1)[0]
+        assert set(rec["walk_rows"]) == {"columns", "by_row"}
+        assert rec["walk_rows"]["columns"] > 0
+    finally:
+        db.close()
+
+
+def _rows_by_series(gathered):
+    """{sid: [(block_start, payload bytes or arrays)]} of a Gathered."""
+    out = {sid: [] for sid in gathered.sids}
+    assert gathered.sids == sorted(gathered.sids)
+    assert len(gathered.lanes) == len(gathered.sids)
+    for slots, blocks in gathered.shards:
+        assert [b.block_start for b in blocks] == sorted(
+            b.block_start for b in blocks)
+        for b in blocks:
+            assert len(b.payloads) == len(slots)
+            for slot, payload in zip(slots, b.payloads):
+                if payload is not None:
+                    out[gathered.sids[slot]].append((b.block_start, payload))
+    return {sid: sorted(rows, key=lambda r: r[0])
+            for sid, rows in out.items()}
+
+
+@pytest.mark.parametrize("state", [_sealed_and_flushed,
+                                   _series_absent_from_a_block,
+                                   _cold_write_after_seal],
+                         ids=lambda f: f.__name__[1:])
+def test_session_storage_gathers_the_same_rows(state, tmp_path):
+    """``SessionStorage.fetch_tagged`` builds the database's ``Gathered``
+    (one shard's worth) from the rows a session hands it: the same
+    series in the same order, the same rows, and the engine's walk over
+    it emits them as it emits the database's."""
+    from m3_tpu.query.session_storage import SessionStorage
+
+    db, lo, hi = state(_open_db(tmp_path), tmp_path)
+
+    class _Session:
+        def fetch_tagged_with_meta(self, ns, matchers, start, end,
+                                   deadline=None):
+            return db.fetch_tagged(ns, matchers, start, end), ResultMeta()
+
+    try:
+        lo, hi = T0 + lo * CADENCE, T0 + hi * CADENCE
+        storage = SessionStorage(_Session(), NS,
+                                 db.namespace_options(NS))
+        mine = storage.fetch_tagged(NS, MATCH, lo, hi + 1, with_counts=True,
+                                    defer_open=True)
+        # the database's own, its open rows read as the session reads them
+        theirs = db.fetch_tagged(NS, MATCH, lo, hi + 1, with_counts=True)
+        got = _rows_by_series(mine)
+        assert list(got) == sorted(theirs)
+        for sid, rows in got.items():
+            assert [bs for bs, _p in rows] == [e[0] for e in theirs[sid]]
+            for (_bs, a), (_bs2, b, _c) in zip(rows, theirs[sid]):
+                _assert_same_payload(
+                    "stream" if isinstance(b, (bytes, memoryview))
+                    else "arrays", a, b)
+        # and through the two engines' walks: the same slots and rows
+        labels, rows, _ = _walk(Engine(storage, NS), lo, hi)
+        want_labels, want_rows, _ = _walk(Engine(db, NS), lo, hi)
+        assert len(labels) == len(want_labels)
+        assert [(r[0], r[4]) for r in rows] == [
+            (r[0], r[4]) for r in want_rows]
+        for (_, kind, a, *_), (_, want_kind, b, *_) in zip(rows, want_rows):
+            assert (kind == "stream") == (want_kind == "stream")
+            _assert_same_payload("stream" if kind == "stream" else "arrays",
+                                 a, b)
     finally:
         db.close()
 
@@ -589,6 +783,64 @@ def test_sealed_rows_come_through_the_table(tmp_path):
         assert bs not in shard._sealed
     finally:
         db.close()
+
+
+def _python_calls(fn) -> int:
+    """Python-level function calls (C calls are not counted) of fn()."""
+    import sys
+    calls = [0]
+
+    def count(_frame, event, _arg):
+        if event == "call":
+            calls[0] += 1
+
+    sys.setprofile(count)
+    try:
+        fn()
+    finally:
+        sys.setprofile(None)
+    return calls[0]
+
+
+def test_a_gather_runs_no_python_a_series(tmp_path, monkeypatch):
+    """The interpreter goes round shards and blocks, never series or
+    rows: four times the series, same shards and blocks, costs under a
+    tenth more Python calls; the index's one-at-a-time answers are not
+    asked for at all."""
+    from m3_tpu.storage import index as index_mod
+
+    def fleet(path, n):
+        db = _open_db(path, shards=FLEET_SHARDS)
+        _write_fleet(db, range(0, 2 * PER_BLOCK, PER_BLOCK // 4), range(n))
+        _tick(db, 2)
+        db.flush()
+        return db
+
+    calls = {}
+    for n in (500, 2000):
+        db = fleet(tmp_path / str(n), n)
+        try:
+            engine = Engine(db, NS)
+            walk = lambda: engine._gather_walk(     # noqa: E731
+                MATCH, T0, T0 + 2 * BLOCK - 1)
+            _labels, _parts, rows, _named, _ = walk()   # the memos fill
+            assert len(rows) == 2 * n
+            one_at_a_time = []
+            monkeypatch.setattr(
+                index_mod.TagIndex, "id_of",
+                lambda *a: one_at_a_time.append("id_of"))
+            monkeypatch.setattr(
+                index_mod.SeriesRegistry, "id_of",
+                lambda *a: one_at_a_time.append("registry.id_of"))
+            calls[n] = _python_calls(walk)
+            monkeypatch.undo()
+            assert one_at_a_time == []
+        finally:
+            db.close()
+    # the shard of a lane comes from the array memo alone
+    assert not hasattr(database_mod._Namespace, "shard_of_lane")
+    assert calls[2000] < 1.1 * calls[500], calls
+    assert calls[500] < 2000, calls      # some dozens a shard-block
 
 
 def test_one_view_an_open_buffer_and_walk(tmp_path, monkeypatch):
