@@ -147,20 +147,29 @@ def _window_bounds_device(times, steps, range_nanos):
     consolidate._range_left) — the one definition both the rate and
     reduce kernels share.  A bound is the count of samples at or
     before it: one fused compare-and-sum over [L, N, S], where a binary
-    search would be log2(N) dependent element gathers."""
+    search would be log2(N) dependent element gathers.  Its operations
+    stand under `bounds` in the caller's scope (`m3.temporal/bounds` in
+    a trace), apart from the reads of the windows' ends (`take`)."""
     starts_excl = steps - range_nanos - 1
-    left = jax.vmap(lambda t: jnp.searchsorted(
-        t, starts_excl, side="right", method="compare_all"))(times)
-    right = jax.vmap(lambda t: jnp.searchsorted(
-        t, steps, side="right", method="compare_all"))(times)
+    with jax.named_scope("bounds"):
+        left = jax.vmap(lambda t: jnp.searchsorted(
+            t, starts_excl, side="right", method="compare_all"))(times)
+        right = jax.vmap(lambda t: jnp.searchsorted(
+            t, steps, side="right", method="compare_all"))(times)
     return starts_excl, left, right
 
 
 # samples a lane up to which _take_at_device selects and above which it
 # gathers: the selection's cost grows with them and the gather's does
-# not (v5e, [512, n] x 256 steps, both ends of three arrays: 2.01 ms at
-# 1,536, 2.33 at 1,920, 7.07 at 6,144, 21.5 at 18,432 against the
-# gathers' 16.3-16.6 at each; they meet near 14,000.  PERF.md PR 33)
+# not.  Set from a sweep at 256 steps (v5e, [512, n] x 256, both ends
+# of three arrays: 2.01 ms at 1,536, 2.33 at 1,920, 7.07 at 6,144, 21.5
+# at 18,432 against the gathers' 16.3-16.6 at each; they meet near
+# 14,000.  PERF.md PR 33).  Both grow with the steps alike, so the
+# crossing moves little: at the two-day panel's 1,344 steps the
+# selection takes 9.4 ms at 1,536, 50.4 at 6,144, 101.0 at 12,288, 140.5
+# at 15,872 and 150.6 at 18,432 against the gathers' 85.7-92.0 at each;
+# they meet near 11,000, and at the constant the selection is 10%
+# dearer (PERF.md PR 45, the cell dash-2d runs the gathers' side)
 _SELECT_MAX_N = 12288
 
 
@@ -172,10 +181,12 @@ def window_form(n_cap: int) -> str:
     return "select" if n_cap <= _SELECT_MAX_N else "gather"
 
 
+@jax.named_scope("take")
 def _take_at_device(xs, idxs):
     """For each [L, S] index of `idxs` (cells in [0, N)), x[l, idx[l, s]]
     of every [L, N] array of `xs`: `take_along_axis`, the element
-    itself, whatever its bits (NaN, +-inf, _INF padding).
+    itself, whatever its bits (NaN, +-inf, _INF padding).  In a trace
+    its operations stand under `m3.temporal/take`, in either form.
 
     The TPU compiler runs an element-indexed gather one element at a
     time, 10 ns each whatever N.  Up to _SELECT_MAX_N samples a lane
@@ -208,6 +219,36 @@ def _take_at_device(xs, idxs):
             jnp.zeros((), x.dtype) for x in xs) * n,
         pick, (2,))
     return [got[n + i * k:n + (i + 1) * k] for i in range(n)]
+
+
+# samples a lane up to which _prefix_sum_device is ONE reduce_window and
+# above which it is a log-step scan: the TPU compiler splits a window
+# into pieces of 128 and its time to compile grows steeply with their
+# count past 32 (compiled for a described v5e, [512, n] float64: 1.4 s
+# at 4,095, 38.9 at 8,191, 114.8 at 12,287, 168.5 at 15,871; on the
+# chip's machine the two-day panel's first request gave up after 60 s).
+# At [512, 15,871] on a v5e the scan compiles in 4.9 s and runs in
+# 2.85 ms; the same window in blocks of 1,024 in 10.7 (PERF.md PR 45)
+_PREFIX_MAX_N = 4096
+
+
+def _prefix_sum_device(x):
+    """Inclusive prefix sum of x [L, n] along a lane.
+
+    Up to _PREFIX_MAX_N it is jnp.cumsum's own lowering, spelled out:
+    its rule emits this reduce_window from a cached function that drops
+    the caller's scope.  Here the lowered operation is named under
+    m3.temporal; the TPU compiler still splits so wide a window in two
+    pieces that carry no name (PERF.md, PR 34), so a chip's trace shows
+    it scoped only inside a chunk loop.  A longer lane is scanned in
+    log2(n) steps of shifted adds (the sums meet in another order than
+    a running sum's: exact on counters' integer-valued resets)."""
+    n = x.shape[1]
+    if n > _PREFIX_MAX_N:
+        return jax.lax.associative_scan(jnp.add, x, axis=1)
+    return jax.lax.reduce_window(
+        x, x.dtype.type(0), jax.lax.add, (1, n), (1, 1),
+        ((0, 0), (n - 1, 0)))
 
 
 def _rate_device(times, values, steps, range_nanos,
@@ -253,17 +294,9 @@ def _rate_lanes(times, values, steps, range_nanos,
         prev = values[:, :-1]
         curr = values[:, 1:]
         resets = jnp.where(curr < prev, prev, 0.0)
-        # jnp.cumsum's own lowering, spelled out: its rule emits this
-        # reduce_window from a cached function that drops the caller's
-        # scope.  Here the lowered operation is named under m3.temporal;
-        # the TPU compiler still splits so wide a window in two pieces
-        # that carry no name (PERF.md, PR 34), so a chip's trace shows
-        # it scoped only inside a chunk loop
         ends += (jnp.concatenate(
-            [jnp.zeros((L, 1), values.dtype),
-             jax.lax.reduce_window(
-                 resets, values.dtype.type(0), jax.lax.add,
-                 (1, N - 1), (1, 1), ((0, 0), (N - 2, 0)))], axis=1),)
+            [jnp.zeros((L, 1), values.dtype), _prefix_sum_device(resets)],
+            axis=1),)
     (t_first, v_first, *cum_first), (t_last, v_last, *cum_last) = (
         _take_at_device(ends, (i_first, i_last)))
     if cum_first:

@@ -1365,10 +1365,14 @@ class Engine:
         n_lanes = len(labels)
         # rows that arrive as arrays count towards the same budgets
         part_counts = np.asarray([len(p[2]) for p in parts], dtype=np.int64)
+        part_slots = np.asarray([p[0] for p in parts], dtype=np.int64)
         per_lane = (
             np.bincount(slots_np, counts_np, n_lanes)
-            + np.bincount(np.asarray([p[0] for p in parts], dtype=np.int64),
-                          part_counts, n_lanes)).astype(np.int64)
+            + np.bincount(part_slots, part_counts, n_lanes)).astype(np.int64)
+        # the widest lane's rows: the rounds of the merge's inner loop
+        rows_per_lane = int((np.bincount(slots_np, minlength=n_lanes)
+                             + np.bincount(part_slots, minlength=n_lanes)
+                             ).max(initial=0))
         # static shape buckets (jit cache keys): stream count, words
         # width, lanes, per-stream and per-lane sample budgets, steps
         n_dp = bucket(int(max(counts_np.max(initial=0),
@@ -1404,6 +1408,7 @@ class Engine:
             "words": words_p, "nbits": nbits_p, "slots": slots_p,
             "steps": steps_p, "n_dp": n_dp, "n_cap": n_cap,
             "lanes_pad": lanes_pad, "n_lanes": n_lanes,
+            "rows_per_lane": rows_per_lane,
             "n_streams": len(streams),
             "datapoints": int(counts_np.sum()),
             "tiers": tiers_p, "n_tiers": n_tiers,
@@ -1596,6 +1601,11 @@ class Engine:
             lanes=pk["n_lanes"], lanes_pad=pk["lanes_pad"],
             lane_chunks=query_pipeline.lane_chunks(
                 pk["lanes_pad"] // n_shards),
+            # the other buckets the windowed stage's cost is the
+            # product of (samples a lane, steps), and the widest lane's
+            # rows (a long range: 22 blocks a series; a dashboard row: 2)
+            n_cap=pk["n_cap"], steps_pad=len(pk["steps"]),
+            rows_per_lane=pk["rows_per_lane"],
             # the decode scan's refills of its per-row word window
             decode_refills=query_pipeline.decode_refills(
                 pk["n_dp"], pk["words"].shape[1]),
@@ -2397,6 +2407,13 @@ class Engine:
                 "lanes": stats.get("lanes", 0),
                 "lanes_pad": stats.get("lanes_pad", 0),
                 "lane_chunks": stats.get("lane_chunks", 0),
+                # device tiers: the samples-a-lane and step buckets the
+                # program ran at and the widest lane's rows (the
+                # largest of a fused tree's leaves): n_cap x steps_pad
+                # is what the windowed stage's bounds compare a lane
+                "n_cap": stats.get("n_cap", 0),
+                "steps_pad": stats.get("steps_pad", 0),
+                "rows_per_lane": stats.get("rows_per_lane", 0),
                 # device tiers: how often the decode scan refilled its
                 # per-row word window (0: rows no longer than the
                 # window, every step reads the row), over all leaves

@@ -1130,6 +1130,13 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                       for ent in leaf_plan.values()),
         groups=shape["groups"], topk_k=shape["topk_k"],
         rows_out=len(labels), window_form=window_form,
+        # the widest leaf's samples a lane and rows a lane, and the
+        # steps' bucket
+        n_cap=max((ent[3]["n_cap"] for ent in leaf_plan.values()),
+                  default=0),
+        steps_pad=len(steps_pad),
+        rows_per_lane=max((ent[3].get("rows_per_lane", 0)
+                           for ent in leaf_plan.values()), default=0),
         # the decode scans of the leaves that arrive as words: their
         # refills of the per-row word window, a function of a leaf's
         # buckets (statics: n_dp, the words of a row)

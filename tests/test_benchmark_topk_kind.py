@@ -240,7 +240,7 @@ def test_manifest_holds_the_cell_and_lints():
     cfg = next(c for c in man["configs"] if c["name"] == "m3query-topk")
     assert cfg["reduced"] == ["hours", "jobs", "query_fanout_series"]
     assert len(cfg["source"]) <= 200
-    cell = man["workloads"][-1]
+    cell = next(w for w in man["workloads"] if w["name"] == "dash-topk")
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "dash-topk", "m3query-topk", "panels-topk-4c", 1)
     judged = [m["name"] for m in man["end_to_end"]

@@ -1162,6 +1162,8 @@ _STRUCTURE_SHAPES = {
     "tiny": (16, 8, 8, 4, 32, 16, 4),
     "dash-sealed": (1024, 256, 512, 256, 1536, 768, 16),
     "fanout-fleet": (25024, 256, 12544, 256, 1536, 768, 32),
+    # 22 rows a lane and the windowed stage's gather form (PR 45)
+    "dash-2d": (11008, 256, 512, 1344, 15872, 768, 16),
 }
 
 
@@ -1202,11 +1204,13 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     access a row; a later edit that brings one back fails here, on the
     CPU, at a dashboard row's shape and at the whole fleet's."""
     from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
-                                              lane_chunks)
+                                              lane_chunks, window_form)
 
     M, W, L, S, n_cap, n_dp, n_groups = _STRUCTURE_SHAPES[shape]
     chunked = lane_chunks(L) > 1
     assert chunked == (shape == "fanout-fleet")
+    gathers = window_form(n_cap) == "gather"
+    assert gathers == (shape == "dash-2d")
     sds = jax.ShapeDtypeStruct
     args = (sds((M, W), np.uint32), sds((M,), np.int32),
             sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
@@ -1217,8 +1221,15 @@ def test_grouped_program_has_no_per_element_addressing(shape):
         jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr))
     scatters = [(p, s) for p, s in ops if p.startswith("scatter")]
     assert scatters and all("m3.group" in s for _, s in scatters), scatters
-    assert not [(p, s) for p, s in ops
-                if p == "gather" and "m3.temporal" in s]
+    # the windowed stage reads its windows' ends by a selection up to
+    # _SELECT_MAX_N samples a lane; past it (the two-day panel) by six
+    # gathers, all of them under m3.temporal/take, and the bounds under
+    # m3.temporal/bounds hold none
+    in_stage = [s for p, s in ops if p == "gather" and "m3.temporal" in s]
+    assert len(in_stage) == (6 if gathers else 0)
+    assert all("m3.temporal/take" in s for s in in_stage), in_stage
+    bounds = [p for p, s in ops if "m3.temporal/bounds" in s]
+    assert "reduce_sum" in bounds and "gather" not in bounds
     # the loops: the decode scan's (the refills and a window's steps at
     # the cells' 256 words a row, the steps alone at the tiny shape's 8);
     # the merge's lane -> first row search, its chunks and a chunk's
@@ -1243,14 +1254,22 @@ def test_grouped_program_has_no_per_element_addressing(shape):
         kw["range_nanos"])
     text = stage.as_text()
     assert text.count("stablehlo.while") == chunked
-    assert "stablehlo.scatter" not in text and "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
+    # (take_along_axis lowers to one function a dtype, called six times)
+    assert ("stablehlo.gather" in text) == gathers
     # the reset prefix sum is the stage's own operation under the
     # stage's scope (inside a chunk loop's body names are relative to
     # the loop's), not jnp.cumsum's cached function, which has none
-    assert text.count("stablehlo.reduce_window") == 1
+    # (past _PREFIX_MAX_N samples a lane it is a log-step scan, PR 45:
+    # the TPU compiler takes minutes over one window that wide)
+    from m3_tpu.models.query_pipeline import _PREFIX_MAX_N
+    scanned = n_cap - 1 > _PREFIX_MAX_N
+    assert scanned == (shape == "dash-2d")
+    assert text.count("stablehlo.reduce_window") == (not scanned)
     assert "@cumsum" not in text
     named = stage.as_text(debug_info=True)
-    assert ("m3.temporal/reduce_window_sum" in named) != chunked
+    assert ("m3.temporal/reduce_window_sum" in named) == (
+        not chunked and not scanned)
     assert "m3.temporal/while/body" in named or not chunked
 
 
@@ -1423,3 +1442,25 @@ def test_fused_topk_program_names_its_stages_at_the_cells_shape():
     # 512 words a row: the decode scan reads the per-row word window,
     # 1,024 steps after the first record in 128 whole windows
     assert _decode_loops(ops, n_dp, W) == 2
+
+
+@pytest.mark.parametrize("n", [1, 2, 40, 1535, 4096, 4097, 5000, 15871])
+def test_prefix_sum_by_scan_equals_the_one_window(n):
+    """The reset prefix sum is one reduce_window up to _PREFIX_MAX_N
+    samples a lane (what every 4 h cell lowers to) and a log-step scan
+    above (the TPU compiler takes minutes over one window that wide,
+    PR 45): the same sums either way, exactly, on counters'
+    integer-valued resets."""
+    from m3_tpu.models.query_pipeline import (_PREFIX_MAX_N,
+                                              _prefix_sum_device)
+
+    rng = np.random.default_rng(n)
+    x = rng.integers(1, 10**9, (5, n)).astype(np.float64)
+    x[rng.random((5, n)) < 0.9] = 0.0           # a reset now and then
+    x[3] = 0.0                                  # a counter that never resets
+    fn = jax.jit(_prefix_sum_device)
+    got = np.asarray(fn(jnp.asarray(x)))
+    assert got.dtype == x.dtype and got.shape == x.shape
+    assert np.array_equal(got, np.cumsum(x, axis=1))
+    text = fn.lower(jnp.asarray(x)).as_text()
+    assert ("stablehlo.reduce_window" in text) == (n <= _PREFIX_MAX_N)

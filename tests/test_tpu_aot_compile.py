@@ -283,6 +283,31 @@ def test_grouped_pipeline_compiles_at_the_fleet_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_grouped_pipeline_compiles_at_the_two_day_cells_shape(one_chip):
+    """The benchmark cell `dash-2d`: 11,000 sealed streams of one job's
+    500 series over the 22 blocks a 48 h retention holds -> 512 lanes x
+    15,872 samples, 1,344 steps: the windowed stage's gather form.  Its
+    first request has 60 s (the mix's `request_timeout_s`), and the
+    rate family's reset prefix sum as ONE reduce_window a lane wide took
+    the compiler 168.5 s of the program's 149 at this shape (PR 45: the
+    first panel gave up on the chip); as a scan past _PREFIX_MAX_N the
+    whole program takes 6-9 s here.  The two bounds fuse: at
+    [lanes, n_cap, steps] one of them would be 10.9 GB."""
+    import time
+
+    M, W, L, S, n_cap = 11_008, 256, 512, 1_344, 15_872
+    assert qp.window_form(n_cap) == "gather" and n_cap - 1 > qp._PREFIX_MAX_N
+    sds = lambda shape, dt: _sds(shape, dt, one_chip)   # noqa: E731
+    t0 = time.perf_counter()
+    compiled = _compile(
+        qp.device_grouped_pipeline, sds((M, W), np.uint32),
+        sds((M,), np.int32), sds((M,), np.int64), sds((S,), np.int64),
+        sds((L,), np.int64), n_lanes=L, n_groups=16, n_cap=n_cap, n_dp=768,
+        range_nanos=sds((), np.int64))
+    assert time.perf_counter() - t0 < 60.0
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.slow
 def test_grouped_pipeline_compiles(one_chip, tiny_db, monkeypatch):
     """sum by (job)(rate(...)) as the engine dispatches it: the
