@@ -42,10 +42,27 @@ from m3_tpu.parallel.mesh import SERIES_AXIS, shard_map
 from m3_tpu.utils import xtime
 
 _INF = jnp.iinfo(jnp.int64).max
-# lanes merged per round: the [lanes, n_cap] temporaries of a round then
-# stay on chip (65,536 lanes on a v5e: 68 ms at 512, 80 at 2,048, 255 in
-# one round, PERF.md PR 26) and off the program's HBM peak
+# lanes merged per round: the round's temporaries then stay on chip
+# (65,536 lanes on a v5e: 68 ms at 512, 80 at 2,048, 255 in one round,
+# PERF.md PR 26) and off the program's HBM peak.  In the "rotate" form
+# (merge_form) they are the row padded to [lanes, n_cap] and its rotated
+# copies; in the "window" form the row's window [lanes, 2 x n_dp] and
+# its rotated copies, and the lane itself is only the loop's carry
 _MERGE_LANES = 512
+
+# rows' widths a lane from which _merge_device rotates a row inside a
+# window of two rows' width ("window") and under which it rotates it
+# over the lane's ("rotate"): merge_form.  Set from a sweep of the merge
+# alone (v5e, [512, n_cap] from rows of 768 cells filled to 713-720,
+# host clock around a call, some 1.3 ms of it the call's own; rotate
+# against window): 1.55 / 1.52 ms at 1,536 (2 rows a lane), 1.59 / 1.58
+# at 1,920, 1.58 / 1.60 at 2,048, 2.47 / 2.01 at 3,072 (4 rows), 6.31 /
+# 3.01 at 6,144 (8), 108.4 / 10.2 at 15,872 (22); the fleet's 25 chunks
+# of 512 lanes at 1,536: 14.6 / 14.7.  Under three rows a lane the forms
+# cost the same (the rotated widths are the same) and the accepted
+# cells keep the program they had; the first width measured at which
+# the window wins is four rows (PERF.md PR 46)
+_WINDOW_MIN_ROWS = 4
 
 
 def lane_chunks(n_lanes: int) -> int:
@@ -65,6 +82,18 @@ def decode_refills(n_dp: int, n_words: int) -> int:
     return m3tsz_decode.decode_refills(n_dp + 1, n_words)
 
 
+def merge_form(n_cap: int, n_dp: int | None) -> str:
+    """How _merge_device rotates a row of `n_dp` cells (None: a row as
+    wide as the lane) to its offset in a lane of `n_cap`: "rotate" over
+    the lane's width, or "window" inside two rows' width where the lane
+    is _WINDOW_MIN_ROWS rows wide or more.  Like window_form a function
+    of the static buckets alone, so the engine can count which form
+    served a call without asking the program."""
+    if n_dp is None or n_cap < _WINDOW_MIN_ROWS * n_dp:
+        return "rotate"
+    return "window"
+
+
 def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
                   order=None):
     """Compact per-(series, block) decode grids into the packed
@@ -77,13 +106,25 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
     keeps a prefix of it).  So a row lands as one contiguous run at
     (slot, samples of the slot's earlier rows): round k moves the k-th
     row of every lane at once and rotates it to its offset with a
-    log-step select over the lane width.  The TPU compiler runs an
-    element-indexed scatter one cell at a time (71 ns a cell); here
-    nothing is indexed by cell.  Lanes go _MERGE_LANES at a time (the
-    last chunk overlaps its neighbour rather than pad), so temporaries
-    stay at [_MERGE_LANES, n_cap] whatever the fan-out.  Cells past a
-    lane's n_cap budget DROP, never spill into the next lane (callers
-    surface the overflow via counts).
+    log-step select.  The TPU compiler runs an element-indexed scatter
+    one cell at a time (71 ns a cell); here nothing is indexed by cell.
+    Lanes go _MERGE_LANES at a time (the last chunk overlaps its
+    neighbour rather than pad).  Cells past a lane's n_cap budget DROP,
+    never spill into the next lane (callers surface the overflow via
+    counts).
+
+    What is rotated is read off the static shapes (merge_form).
+    "rotate": the row padded to the lane's width, by the offset; a
+    round's temporaries are [_MERGE_LANES, n_cap] whatever the fan-out,
+    and stay on chip while the lane is a few rows wide.  "window" (a
+    lane many rows wide: a long range's 22 blocks): the offset is
+    q * T + r with T the rows' width, the row is rotated by r inside a
+    window of [_MERGE_LANES, 2T], and the window's halves are laid on
+    blocks q and q + 1 of the lane, kept as [blocks, _MERGE_LANES, T],
+    in the one pass that merges them in under the same mask: the
+    halves broadcast inside it, no [_MERGE_LANES, n_cap] array is built
+    for the row, and a round moves the lane once instead of once a bit
+    of the offset.  The block past the lane's end is never written.
 
     `order` [M], where rows were laid end to end from two sources (the
     decoded streams, then the rows that arrived as arrays), is the
@@ -101,15 +142,45 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
     # lengthen the loop
     used = jnp.max(jnp.where(row_counts > 0, jnp.arange(1, M + 1), 0))
     n_rows = jnp.minimum(first[1:], used) - first[:-1]  # [n_lanes]
-    col = jnp.arange(n_cap, dtype=I32)
-    fit = ((0, 0), (0, max(n_cap - T, 0)))
 
-    def place(x, row, off):
-        x = jnp.pad(x[:, :n_cap].at[row].get(mode="promise_in_bounds"), fit)
-        for b in range((n_cap - 1).bit_length()):
-            x = jnp.where((off >> b & 1)[:, None] == 1,
+    def rotated(x, row, by, width, reach):
+        # rows `row` of x at `width` cells, rotated right by `by` < reach
+        x = jnp.pad(x[:, :width].at[row].get(mode="promise_in_bounds"),
+                    ((0, 0), (0, max(width - T, 0))))
+        for b in range((reach - 1).bit_length()):
+            x = jnp.where((by >> b & 1)[:, None] == 1,
                           jnp.roll(x, 1 << b, axis=1), x)
         return x
+
+    if merge_form(n_cap, T) == "window":
+        n_blk = -(-n_cap // T)
+        shape = (n_blk, B, T)  # a chunk's lanes, in blocks of T cells
+        blk = jnp.arange(n_blk, dtype=I32)[:, None, None]
+        col = blk * T + jnp.arange(T, dtype=I32)
+
+        def per_lane(v):
+            return v[None, :, None]
+
+        def place(x, row, off):
+            q, r = jnp.divmod(off, T)
+            w = rotated(x, row, r, 2 * T, T)
+            return jnp.where(blk == per_lane(q), w[None, :, :T],
+                             w[None, :, T:])
+
+        def lanes_of(out):  # the blocks end to end
+            return jnp.concatenate(list(out), axis=1)[:, :n_cap]
+    else:
+        shape = (B, n_cap)
+        col = jnp.arange(n_cap, dtype=I32)
+
+        def per_lane(v):
+            return v[:, None]
+
+        def place(x, row, off):
+            return rotated(x, row, off, n_cap, n_cap)
+
+        def lanes_of(out):
+            return out
 
     def chunk(c, outs):
         lo = jnp.minimum(c * B, n_lanes - B)
@@ -123,17 +194,19 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
             if order is not None:
                 row = order[row]
             off = jnp.minimum(counts, n_cap)
-            take = (col >= off[:, None]) & (col < (off + cnt)[:, None])
+            take = (col >= per_lane(off)) & (col < per_lane(off + cnt))
             return (jnp.where(take, place(ts, row, off), out_t),
                     jnp.where(take, place(vs, row, off), out_v),
                     counts + cnt)
 
-        done = jax.lax.fori_loop(0, jnp.max(n_rows_c), body, (
-            jnp.full((B, n_cap), _INF, dtype=jnp.int64),
-            jnp.full((B, n_cap), jnp.nan, dtype=vs.dtype),
-            jnp.zeros((B,), I32)))
+        out_t, out_v, counts = jax.lax.fori_loop(
+            0, jnp.max(n_rows_c), body, (
+                jnp.full(shape, _INF, dtype=jnp.int64),
+                jnp.full(shape, jnp.nan, dtype=vs.dtype),
+                jnp.zeros((B,), I32)))
         return tuple(jax.lax.dynamic_update_slice_in_dim(o, d, lo, 0)
-                     for o, d in zip(outs, done))
+                     for o, d in zip(outs, (lanes_of(out_t),
+                                            lanes_of(out_v), counts)))
 
     return jax.lax.fori_loop(0, lane_chunks(n_lanes), chunk, (
         jnp.empty((n_lanes, n_cap), jnp.int64),

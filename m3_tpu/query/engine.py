@@ -1591,6 +1591,9 @@ class Engine:
         if window_form:
             instrument.counter("m3_device_window_form_total",
                                form=window_form).inc()
+        merge_form = query_pipeline.merge_form(pk["n_cap"], pk["n_dp"])
+        instrument.counter("m3_device_merge_form_total",
+                           form=merge_form).inc()
         self._publish_stats(
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
@@ -1609,7 +1612,7 @@ class Engine:
             # the decode scan's refills of its per-row word window
             decode_refills=query_pipeline.decode_refills(
                 pk["n_dp"], pk["words"].shape[1]),
-            window_form=window_form,
+            window_form=window_form, merge_form=merge_form,
             **stats, n_shards=n_shards)
         return out
 
@@ -2429,6 +2432,10 @@ class Engine:
                 # increase / delta): "select" or "gather" ("mixed"
                 # where a fused tree's leaves differ)
                 "window_form": stats.get("window_form"),
+                # how the merge rotated a row to its offset in its
+                # lane: "rotate" over the lane's width or "window"
+                # inside two rows' (a lane of many rows: a long range)
+                "merge_form": stats.get("merge_form"),
                 "fileset_scans": cost.fileset_scans,
                 "walk_rows": dict(cost.walk_rows),
                 "device_serving": bool(stats.get("device_serving")),

@@ -521,6 +521,15 @@ def _leaf_specs(sym, out):
     return out
 
 
+def _count_forms(counter: str, forms) -> str | None:
+    """Count each of a tree's `forms` once -> the word for the query's
+    record: the form, "mixed" where its leaves differ, None without
+    one."""
+    for form in sorted(forms):
+        instrument.counter(counter, form=form).inc()
+    return min(forms) if len(forms) == 1 else "mixed" if forms else None
+
+
 def serve_fused(engine, node, step_times):
     """Try to serve `node` with the fused whole-query device pipeline.
     Returns a Matrix, or None to decline (the engine's per-node paths
@@ -1095,12 +1104,13 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             labels = [labels[i] for i in order]
             values = values[order]
     # what the program ran at, for the query's record: how the rate
-    # family read its windows' ends (a function of a leaf's bucket)
-    for form in sorted(forms):
-        instrument.counter("m3_device_window_form_total",
-                           form=form).inc()
-    window_form = (min(forms) if len(forms) == 1
-                   else "mixed" if forms else None)
+    # family read its windows' ends (a function of a leaf's bucket), and
+    # how each leaf that arrived as words had its rows merged (statics:
+    # n_cap, n_dp)
+    window_form = _count_forms("m3_device_window_form_total", forms)
+    merge_form = _count_forms("m3_device_merge_form_total", {
+        qp.merge_form(statics[1], statics[2])
+        for _, kind, statics, _ in leaf_plan.values() if kind == "words"})
     fn_stat = next((f for f in counts["fns"] if f in LOOSE_FNS),
                    counts["fns"][0] if counts["fns"] else None)
     agg_stat = next((a for a in counts["aggs"] if a in LOOSE_AGGS),
@@ -1130,6 +1140,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                       for ent in leaf_plan.values()),
         groups=shape["groups"], topk_k=shape["topk_k"],
         rows_out=len(labels), window_form=window_form,
+        merge_form=merge_form,
         # the widest leaf's samples a lane and rows a lane, and the
         # steps' bucket
         n_cap=max((ent[3]["n_cap"] for ent in leaf_plan.values()),

@@ -27,6 +27,7 @@ for path in (BENCHMARK, BENCHMARK / "tests"):
 from harness import reference, trace_subscopes  # noqa: E402
 from harness.fleet import Fleet  # noqa: E402
 from test_control_2d import *  # noqa: E402,F401,F403 - its cases are run here
+from test_metric_merge_share_2d import *  # noqa: E402,F401,F403 - and its
 from traffic_kinds import query_longrange_loop  # noqa: E402
 
 SEC = 10**9
@@ -63,17 +64,17 @@ def _sealed_fleet(path, hours: int):
     return db, fleet
 
 
-@pytest.mark.parametrize("hours,form,n_cap,rows", [
-    pytest.param(44, "gather", 15872, 22, id="two_days"),
-    pytest.param(4, "select", 1536, 2, id="four_hours"),
+@pytest.mark.parametrize("hours,form,n_cap,rows,merge", [
+    pytest.param(44, "gather", 15872, 22, "window", id="two_days"),
+    pytest.param(4, "select", 1536, 2, "rotate", id="four_hours"),
 ])
 def test_panel_equals_the_reference_and_the_host_and_records_its_shape(
-        tmp_path, hours, form, n_cap, rows):
+        tmp_path, hours, form, n_cap, rows, merge):
     """The cell's query over every sealed block, served whole by the
     per-node device program: equal to the plain reference on the
     generator's arrays and to the host evaluator to 1e-9; the record
-    says which form read the windows' ends, at how many samples and
-    rows a lane and how many steps."""
+    says which form read the windows' ends and which merged the rows,
+    at how many samples and rows a lane and how many steps."""
     from m3_tpu.query import slowlog
     from m3_tpu.query.engine import Engine
 
@@ -97,6 +98,7 @@ def test_panel_equals_the_reference_and_the_host_and_records_its_shape(
     steps = np.arange(fleet.t0, fleet.seal_end + 1, MIX["step_s"])
     assert (rec["window_form"], rec["n_cap"], rec["rows_per_lane"]) == (
         form, n_cap, rows)
+    assert rec["merge_form"] == merge
     assert rec["steps_pad"] == -(-len(steps) // 64) * 64
     assert rec["lanes"] == fleet.instances and rec["lane_chunks"] == 1
     assert query_longrange_loop.off_the_gather_form(
@@ -220,6 +222,6 @@ def test_manifest_has_the_cell_and_its_metrics():
         "device_wait_ms", "d2h_ms", "device_queue_depth", "reply_ms",
         "engine_cpu_ms", "panel_p95_ms", "program_ms", "program_hbm_peak_mb",
         "program_roofline_pct", "temporal_share_pct", "samples_per_lane",
-        "rows_per_lane")}
+        "rows_per_lane", "merge_share_pct")}
     assert MIX["kind"] == "query_longrange_loop" and MIX["clients"] == 2
     assert MIX["gather_min_n_cap"] == 12289

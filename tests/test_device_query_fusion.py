@@ -527,6 +527,7 @@ def test_fused_topk_record_carries_its_phases_and_shape(engines):
     assert rec["rows_out"] == rec["series"] == len(md.labels)
     assert 2 <= rec["rows_out"] <= 3
     assert rec["window_form"] == "select" and rec["rows"] >= 6
+    assert rec["merge_form"] == "rotate"     # the one leaf's, as words
     # the decode scan of the one leaf, from its buckets alone (the fused
     # planner's: 256 samples a row, 128 words): its window's refills
     from m3_tpu.models import query_pipeline as qp
@@ -539,4 +540,5 @@ def test_fused_topk_record_carries_its_phases_and_shape(engines):
     rec = next(r for r in slowlog.log().records()
                if r["device_serving"])
     assert rec["topk_k"] == 0 and rec["window_form"] is None
+    assert rec["merge_form"] == "rotate"
     assert rec["groups"] == 3 and rec["rows_out"] == 3

@@ -249,6 +249,37 @@ def test_mutable_range_is_served_by_the_device_tier(db):
     assert (rec["rows"], rec["open_rows"]) == (16, 4)
 
 
+def _form_is_counted_and_recorded(db, monkeypatch, expr, counter, field,
+                                  forms, form, constant, value):
+    """A device-served `expr` counts `form` of `forms` once under
+    `counter`, its record says the same word under `field`, and its
+    answer is the host's; `constant` of query_pipeline patched to
+    `value` (unless None) to put the call on the other form."""
+    def forget():      # programs traced under another constant
+        qp.device_temporal_pipeline.__wrapped__.clear_cache()
+        qp.device_grouped_pipeline.__wrapped__.clear_cache()
+
+    if value is not None:
+        monkeypatch.setattr(qp, constant, value)
+        forget()
+    counters = {f: instrument.counter(counter, form=f) for f in forms}
+    before = {f: c.value for f, c in counters.items()}
+    eng = Engine(db, "default", device_serving=True)
+    _, mat = eng.query_range(expr, START, END, STEP)
+    rec = _record_of(expr)
+    assert rec["device_serving"] is True
+    assert rec[field] == form
+    assert {f: c.value - before[f] for f, c in counters.items()} == {
+        f: int(f == form) for f in counters}
+    _, host = Engine(db, "default", device_serving=False).query_range(
+        expr, START, END, STEP)
+    np.testing.assert_allclose(np.asarray(mat.values),
+                               np.asarray(host.values), rtol=1e-9,
+                               equal_nan=True)
+    if value is not None:
+        forget()
+
+
 @pytest.mark.parametrize("expr,form,max_n", [
     ("sum by (dc) (rate(sealed[8m]))", "select", None),
     ("increase(sealed[8m])", "select", None),
@@ -262,30 +293,25 @@ def test_window_form_is_counted_and_recorded(db, monkeypatch, expr, form,
     bucket takes (query_pipeline.window_form) and the record says the
     same word; another temporal function reads no window ends and
     counts none.  The answer does not depend on the form."""
-    def forget():      # programs traced under another constant
-        qp.device_temporal_pipeline.__wrapped__.clear_cache()
-        qp.device_grouped_pipeline.__wrapped__.clear_cache()
+    _form_is_counted_and_recorded(
+        db, monkeypatch, expr, "m3_device_window_form_total", "window_form",
+        ("select", "gather"), form, "_SELECT_MAX_N", max_n)
 
-    if max_n is not None:
-        monkeypatch.setattr(qp, "_SELECT_MAX_N", max_n)
-        forget()
-    counters = {f: instrument.counter("m3_device_window_form_total", form=f)
-                for f in ("select", "gather")}
-    before = {f: c.value for f, c in counters.items()}
-    eng = Engine(db, "default", device_serving=True)
-    _, mat = eng.query_range(expr, START, END, STEP)
-    rec = _record_of(expr)
-    assert rec["device_serving"] is True
-    assert rec["window_form"] == form
-    assert {f: c.value - before[f] for f, c in counters.items()} == {
-        f: int(f == form) for f in counters}
-    _, host = Engine(db, "default", device_serving=False).query_range(
-        expr, START, END, STEP)
-    np.testing.assert_allclose(np.asarray(mat.values),
-                               np.asarray(host.values), rtol=1e-9,
-                               equal_nan=True)
-    if max_n is not None:
-        forget()
+
+@pytest.mark.parametrize("expr,form,min_rows", [
+    ("sum by (dc) (rate(sealed[8m]))", "rotate", None),
+    ("max_over_time(sealed[8m])", "rotate", None),
+    ("sum by (dc) (rate(sealed[9m]))", "window", 0),
+    ("delta(sealed[9m])", "window", 0),
+])
+def test_merge_form_is_counted_and_recorded(db, monkeypatch, expr, form,
+                                            min_rows):
+    """Every per-node call counts the form its n_cap and n_dp buckets
+    take (query_pipeline.merge_form) and the record says the same word.
+    The answer does not depend on the form."""
+    _form_is_counted_and_recorded(
+        db, monkeypatch, expr, "m3_device_merge_form_total", "merge_form",
+        ("rotate", "window"), form, "_WINDOW_MIN_ROWS", min_rows)
 
 
 def test_http_query_leaves_frontend_in_its_record(db):

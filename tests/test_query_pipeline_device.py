@@ -107,30 +107,35 @@ def test_device_pipeline_truncation_flagged():
     assert not np.asarray(err_ok).any()
 
 
-def test_device_pipeline_lane_overflow_flagged():
+@pytest.mark.parametrize("blocks_per,form", [(3, "rotate"), (6, "window")])
+def test_device_pipeline_lane_overflow_flagged(blocks_per, form):
     """A lane whose streams exceed its n_cap budget must flag every
     contributing stream — and must NOT spill samples into the next
     lane's merged region."""
-    n_lanes, blocks_per, dp = 3, 3, 24
+    from m3_tpu.models.query_pipeline import merge_form
+
+    n_lanes, dp = 3, 24
+    assert merge_form((blocks_per - 1) * dp, dp) == form
     streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp, seed=8)
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(5, dtype=np.int64) * 120 * SEC + 600 * SEC
     range_nanos = 10 * 60 * SEC
-    # budget holds only 2 of the 3 blocks; streams are exactly dp long
+    # budget holds all blocks but the last; streams are exactly dp long
     # so per-stream truncation does NOT fire — only the lane overflow
-    n_cap = 2 * dp
+    n_cap = (blocks_per - 1) * dp
     rate, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits), jnp.asarray(slots),
         jnp.asarray(steps), n_lanes=n_lanes, n_cap=n_cap,
         fn="rate", range_nanos=range_nanos, n_dp=dp)
     assert np.asarray(err).all()
     # no cross-lane corruption: each lane's merged samples are its own
-    # first 2 blocks, so rates equal the host reference on that subset
+    # blocks but the last, so rates equal the host reference on that
+    # subset
     seen: dict[int, int] = {}
     kept = []
     for f in frags:
         seen[f[0]] = seen.get(f[0], 0) + 1
-        if seen[f[0]] <= 2:
+        if seen[f[0]] < blocks_per:
             kept.append(f)
     t_ref, v_ref, _ = cons.merge_packed(kept, n_lanes)
     want = cons.extrapolated_rate(t_ref, v_ref, steps, range_nanos,
@@ -158,18 +163,24 @@ def test_device_pipeline_range_is_not_a_compile_key():
     assert device_temporal_pipeline._cache_size() == 1
 
 
-def test_device_pipeline_unsorted_lane_flagged():
+@pytest.mark.parametrize("blocks_per,form", [(2, "rotate"), (4, "window")])
+def test_device_pipeline_unsorted_lane_flagged(blocks_per, form):
     """Overlapping blocks (out-of-order across a slot's streams) break
     the searchsorted window-selection assumption — the pipeline must
     flag the lane's streams, not return silently wrong windows.  The
     host tier detects the same condition and re-sorts (the engine falls
     back on the flag)."""
+    from m3_tpu.models.query_pipeline import merge_form
+
     n_lanes, dp = 3, 20
+    assert merge_form(blocks_per * dp, dp) == form
     streams, slots, frags = [], [], []
     for lane in range(n_lanes):
-        for b in range(2):
-            # lane 1's two blocks OVERLAP (same base); others stack
-            base = T0 if (lane == 1) else T0 + b * dp * 10 * SEC
+        for b in range(blocks_per):
+            # lane 1's last two blocks OVERLAP (same base); others stack
+            base = (T0 + (blocks_per - 2) * dp * 10 * SEC
+                    if lane == 1 and b == blocks_per - 1
+                    else T0 + b * dp * 10 * SEC)
             t = base + (np.arange(dp, dtype=np.int64) + 1) * 10 * SEC
             v = np.arange(dp, dtype=np.float64) + lane
             enc = tsz.Encoder(base)
@@ -183,11 +194,11 @@ def test_device_pipeline_unsorted_lane_flagged():
     _, err = device_temporal_pipeline(
         jnp.asarray(words), jnp.asarray(nbits),
         jnp.asarray(np.asarray(slots, dtype=np.int64)),
-        jnp.asarray(steps), n_lanes=n_lanes, n_cap=2 * dp,
+        jnp.asarray(steps), n_lanes=n_lanes, n_cap=blocks_per * dp,
         fn="rate", range_nanos=10 * 60 * SEC, n_dp=dp)
-    err = np.asarray(err)
-    assert err[2] and err[3], "overlapping lane's streams must flag"
-    assert not err[[0, 1, 4, 5]].any(), "clean lanes must not flag"
+    err = np.asarray(err).reshape(n_lanes, blocks_per)
+    assert err[1].all(), "overlapping lane's streams must flag"
+    assert not err[[0, 2]].any(), "clean lanes must not flag"
 
 
 def test_device_temporal_pipeline_matches_host():
@@ -654,14 +665,14 @@ def test_device_grouped_quantile_phi_sweep():
     assert device_grouped_pipeline._cache_size() == 1
 
 
-def _sharded_case(seed):
+def _sharded_case(seed, blocks_per=2, n_dp=None):
     """16 lanes, 2 a shard of the 8-device mesh, the slots local to
     their shard; -> (mesh, the entry points' positional arguments for
     the mesh and for one chip, keywords, frags, steps, range)."""
     from m3_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(n_series_shards=8, n_window_shards=1)
-    n_lanes, blocks_per, dp = 16, 2, 30
+    n_lanes, dp = 16, 30
     streams, slots, frags = _mk_streams(n_lanes, blocks_per, dp, seed=seed)
     words, nbits = pack_streams(streams)
     steps = T0 + np.arange(7, dtype=np.int64) * 120 * SEC + 600 * SEC
@@ -670,7 +681,7 @@ def _sharded_case(seed):
     local = head + (jnp.asarray(slots % (n_lanes // 8)), jnp.asarray(steps))
     whole = head + (jnp.asarray(slots), jnp.asarray(steps))
     kw = dict(n_lanes=n_lanes, n_cap=blocks_per * dp,
-              range_nanos=range_nanos)
+              range_nanos=range_nanos, n_dp=n_dp)
     return mesh, local, whole, kw, frags, steps, range_nanos
 
 
@@ -728,13 +739,19 @@ def test_device_grouped_on_mesh_collectives():
                 fn="holt_winters", agg="sum", mesh=m, **kw)
 
 
-def test_device_pipeline_sharded_psum():
+@pytest.mark.parametrize("blocks_per,n_dp,form", [
+    (2, None, "rotate"), (5, 30, "window")])
+def test_device_pipeline_sharded_psum(blocks_per, n_dp, form):
     """The temporal form given the mesh: per-series results sharded by
     series, no collective; the fleet sum over ICI is the grouped form
-    with one group (one psum)."""
+    with one group (one psum).  In either form of the merge."""
+    from m3_tpu.models.query_pipeline import merge_form
+
     if jax.device_count() < 8:
         pytest.skip("needs the virtual 8-device mesh")
-    mesh, local, whole, kw, frags, steps, range_nanos = _sharded_case(9)
+    mesh, local, whole, kw, frags, steps, range_nanos = _sharded_case(
+        9, blocks_per, n_dp)
+    assert merge_form(kw["n_cap"], n_dp) == form
     rate, err = device_temporal_pipeline(*local, fn="rate", mesh=mesh, **kw)
     assert not np.asarray(err).any()
     want = _host_reference(frags, 16, steps, range_nanos)
@@ -890,21 +907,54 @@ def _merge_cases():
                        [7]] + [[]], T, 16, 4, 30
     yield "row_wider_than_cap", [[12, 12], [3], [9, 9]] + [[]], T, 8, 3, 8
     yield "one_full_row_each", [[16]] * 7 + [[]], 16, 8, 7, 16
+    yield from _long_lane_cases()
 
 
+def _long_lane_cases():
+    """What a lane many rows wide brings (merge_form "window" at these
+    shapes): the offset q * T + r with r at both ends of its range, a
+    lane's width no multiple of the rows', a row cut at the lane's end,
+    rows so short that several meet in one block."""
+    rng = np.random.default_rng(78)
+    T = 8
+    # r = 0 after every full row; r = T - 1 after the row of 7
+    yield "offsets_at_both_ends", [[8, 8, 8, 8], [7, 8, 8], [8, 7, 1, 8],
+                                   [1, 7, 8], []], T, 16, 4, 37
+    # 5 blocks, the last of 4 cells: rows straddle it and are cut in
+    # it, one lands wholly past it, one ends on the lane's last cell
+    yield "row_cut_at_the_cap", [[8, 8, 8, 7, 8], [8, 8, 8, 8, 4, 8],
+                                 [8, 8, 8, 8, 8, 8, 8], [6, 8, 8, 8, 6],
+                                 [8, 8, 8, 8, 3, 8], [2]], T, 40, 5, 36
+    yield "short_rows_meet_in_a_block", \
+        _random_rows(rng, 6, T, 6, 14, 0.0, 0.2) + [[1] * 20, []], \
+        T, 96, 7, 45
+    # the two-day panel's 22 rows a lane, beside lanes of 0 and 1 rows
+    yield "lane_of_23_rows", [[8] * 22 + [5], [], [3], [7] * 23,
+                              [8] * 23, [], [4, 8]], T, 80, 6, 180
+    yield "rows_1_to_9_cap_not_a_multiple", \
+        _random_rows(rng, 10, T, 1, 9, 0.1, 0.1) + [[]], T, 64, 10, 61
+
+
+_FORMS = {"rotate": 1 << 30, "window": 0}  # _WINDOW_MIN_ROWS that forces it
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
 @pytest.mark.parametrize("chunk", [None, 4], ids=["one_chunk", "chunks_of_4"])
 @pytest.mark.parametrize(
     "rows_of,T,m_pad,pad_slot,n_cap",
     [pytest.param(*c[1:], id=c[0]) for c in _merge_cases()])
 def test_merge_matches_numpy_reference(rows_of, T, m_pad, pad_slot, n_cap,
-                                       chunk, monkeypatch):
+                                       chunk, form, monkeypatch):
     """_merge_device moves whole rows; the reference moves cells.  Bit
     for bit: times, the values' bit patterns, the fill, the counts —
-    also when the lanes go in chunks, the last one overlapping."""
+    also when the lanes go in chunks, the last one overlapping, and in
+    either form (merge_form), whichever the shapes would take."""
     from m3_tpu.models import query_pipeline as qp
 
     if chunk:
         monkeypatch.setattr(qp, "_MERGE_LANES", chunk)
+    monkeypatch.setattr(qp, "_WINDOW_MIN_ROWS", _FORMS[form])
+    assert qp.merge_form(n_cap, T) == form
     n_lanes = len(rows_of)
     ts, vs, valid, slots = _merge_layout(rows_of, T, m_pad, pad_slot, 3)
     got_t, got_v, got_c = jax.jit(      # a new function: a new trace
@@ -919,13 +969,146 @@ def test_merge_matches_numpy_reference(rows_of, T, m_pad, pad_slot, n_cap,
     np.testing.assert_array_equal(np.asarray(got_c), want_c)
 
 
-def test_merge_two_tiers_matches_numpy_reference():
+@pytest.mark.parametrize(
+    "T,n_cap", [pytest.param(c[2], c[5], id=c[0])
+                for c in _long_lane_cases()])
+def test_long_lane_cases_take_the_window_form_by_their_shapes(T, n_cap):
+    from m3_tpu.models.query_pipeline import merge_form
+
+    assert merge_form(n_cap, T) == "window"
+
+
+def _parents_merge_device(ts, vs, valid, slots, n_lanes, n_cap, order=None):
+    """_merge_device as it stood before merge_form (PR 45's tree), kept
+    to compare against: every row rotated over the lane's whole width."""
+    from m3_tpu.models import query_pipeline as qp
+    from m3_tpu.ops.bitstream import I32
+
+    M, T = ts.shape
+    B = min(n_lanes, qp._MERGE_LANES)
+    row_counts = valid.sum(axis=1, dtype=I32)
+    if order is not None:
+        slots, row_counts = slots[order], row_counts[order]
+    first = jnp.searchsorted(slots, jnp.arange(n_lanes + 1), side="left",
+                             method="scan_unrolled")
+    used = jnp.max(jnp.where(row_counts > 0, jnp.arange(1, M + 1), 0))
+    n_rows = jnp.minimum(first[1:], used) - first[:-1]
+    col = jnp.arange(n_cap, dtype=I32)
+    fit = ((0, 0), (0, max(n_cap - T, 0)))
+
+    def place(x, row, off):
+        x = jnp.pad(x[:, :n_cap].at[row].get(mode="promise_in_bounds"), fit)
+        for b in range((n_cap - 1).bit_length()):
+            x = jnp.where((off >> b & 1)[:, None] == 1,
+                          jnp.roll(x, 1 << b, axis=1), x)
+        return x
+
+    def chunk(c, outs):
+        lo = jnp.minimum(c * B, n_lanes - B)
+        first_c = jax.lax.dynamic_slice_in_dim(first, lo, B)
+        n_rows_c = jax.lax.dynamic_slice_in_dim(n_rows, lo, B)
+
+        def body(k, carry):
+            out_t, out_v, counts = carry
+            row = jnp.minimum(first_c + k, M - 1)
+            cnt = jnp.where(k < n_rows_c, row_counts[row], 0)
+            if order is not None:
+                row = order[row]
+            off = jnp.minimum(counts, n_cap)
+            take = (col >= off[:, None]) & (col < (off + cnt)[:, None])
+            return (jnp.where(take, place(ts, row, off), out_t),
+                    jnp.where(take, place(vs, row, off), out_v),
+                    counts + cnt)
+
+        done = jax.lax.fori_loop(0, jnp.max(n_rows_c), body, (
+            jnp.full((B, n_cap), qp._INF, dtype=jnp.int64),
+            jnp.full((B, n_cap), jnp.nan, dtype=vs.dtype),
+            jnp.zeros((B,), I32)))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, d, lo, 0)
+                     for o, d in zip(outs, done))
+
+    return jax.lax.fori_loop(0, qp.lane_chunks(n_lanes), chunk, (
+        jnp.empty((n_lanes, n_cap), jnp.int64),
+        jnp.empty((n_lanes, n_cap), vs.dtype),
+        jnp.empty((n_lanes,), I32)))
+
+
+@pytest.mark.parametrize("n_lanes,T,rows,n_cap,chunk", [
+    (40, 128, 5, 128 * 5 + 37, None),   # a partial last block
+    (21, 96, 22, 96 * 21, 8),           # 22 rows overflow 21 blocks
+    (9, 64, 6, 64 * 4, 4),              # the crossing itself, overflowing
+])
+def test_window_merge_equals_the_parents_rotation(n_lanes, T, rows, n_cap,
+                                                  chunk, monkeypatch):
+    """The long lane's merge against the parent's, at rows wide enough
+    for the window's halves to be whole tiles: bit for bit, the counts
+    past n_cap included."""
+    from m3_tpu.models import query_pipeline as qp
+
+    if chunk:
+        monkeypatch.setattr(qp, "_MERGE_LANES", chunk)
+    assert qp.merge_form(n_cap, T) == "window"
+    rng = np.random.default_rng(n_lanes)
+    rows_of = _random_rows(rng, n_lanes - 1, T, 0, rows, 0.1, 0.1) + [[]]
+    rows_of[0] = [T] * rows
+    m_pad = sum(map(len, rows_of)) + 5
+    args = [jnp.asarray(a) for a in _merge_layout(
+        rows_of, T, m_pad, n_lanes - 1, 11)]
+    got = jax.jit(lambda *a: qp._merge_device(*a, n_lanes, n_cap))(*args)
+    want = jax.jit(lambda *a: _parents_merge_device(*a, n_lanes, n_cap))(
+        *args)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.uint8), np.asarray(w).view(np.uint8))
+    assert int(np.asarray(got[2]).max()) > n_cap - T
+
+
+@pytest.mark.parametrize("n_cap,n_dp,form", [
+    (1536, 768, "rotate"),     # dash-sealed, fanout-fleet
+    (1920, 768, "rotate"),     # dash-live: two sealed rows and an open one
+    (2048, 1024, "rotate"),    # dash-topk, the fused planner's pow2 buckets
+    (15872, 768, "window"),    # dash-2d: 22 rows a lane
+    (3071, 768, "rotate"), (3072, 768, "window"),
+    (1536, None, "rotate"),    # a row as wide as the lane
+])
+def test_merge_form_at_the_cells_shapes(n_cap, n_dp, form):
+    """Which form a call takes is read off its static buckets: the four
+    4 h cells keep the rotation over the lane, the two-day panel takes
+    the window (PERF.md, PR 46)."""
+    from m3_tpu.models.query_pipeline import merge_form
+
+    assert merge_form(n_cap, n_dp) == form
+
+
+def test_accepted_cells_program_lowers_to_what_it_lowered_to(monkeypatch):
+    """`device_grouped_pipeline` at dash-sealed's static shapes, lowered
+    with the parent's merge in _merge_device's place and as it stands:
+    the same text (metadata apart, which as_text leaves out), so the
+    cells on "rotate" run the program they ran."""
+    from m3_tpu.models import query_pipeline as qp
+
+    args, kw = _grouped_program_at("dash-sealed")
+    assert qp.merge_form(kw["n_cap"], kw["n_dp"]) == "rotate"
+    fn = qp.device_grouped_pipeline.__wrapped__     # the jitted function
+    fn.clear_cache()
+    now = fn.lower(*args, **kw).as_text()
+    monkeypatch.setattr(qp, "_merge_device", _parents_merge_device)
+    fn.clear_cache()                                # a new trace
+    before = fn.lower(*args, **kw).as_text()
+    fn.clear_cache()
+    assert "stablehlo.while" in now and now == before
+
+
+@pytest.mark.parametrize("n_cap,form", [(24, "rotate"), (43, "window")])
+def test_merge_two_tiers_matches_numpy_reference(n_cap, form):
     """Two tiers: the coarse rows come first in a slot and the cut keeps
     a prefix of each, so the rows still land as contiguous runs and the
     lane stays ascending."""
-    from m3_tpu.models.query_pipeline import _merge_device, _tier_cut
+    from m3_tpu.models.query_pipeline import (_merge_device, _tier_cut,
+                                              merge_form)
 
-    n_lanes, T, n_cap = 5, 10, 24
+    n_lanes, T = 5, 10
+    assert merge_form(n_cap, T) == form
     slots, tiers, rows_t = [], [], []
     for lane in range(n_lanes - 1):
         # coarse block (60 s apart) then two fine ones (10 s) that
@@ -1167,6 +1350,17 @@ _STRUCTURE_SHAPES = {
 }
 
 
+def _grouped_program_at(shape):
+    """-> device_grouped_pipeline's arguments, as shapes, and keywords
+    at one of _STRUCTURE_SHAPES."""
+    M, W, L, S, n_cap, n_dp, n_groups = _STRUCTURE_SHAPES[shape]
+    sds = jax.ShapeDtypeStruct
+    args = (sds((M, W), np.uint32), sds((M,), np.int32),
+            sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
+    return args, dict(n_lanes=L, n_groups=n_groups, n_cap=n_cap, n_dp=n_dp,
+                      range_nanos=jnp.int64(300 * SEC))
+
+
 def _decode_loops(ops, n_dp, n_words):
     """The loops the decode stage holds at this bucket, having checked
     what is under `m3.decode`: the first record is decoded before any
@@ -1211,11 +1405,8 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     assert chunked == (shape == "fanout-fleet")
     gathers = window_form(n_cap) == "gather"
     assert gathers == (shape == "dash-2d")
+    args, kw = _grouped_program_at(shape)
     sds = jax.ShapeDtypeStruct
-    args = (sds((M, W), np.uint32), sds((M,), np.int32),
-            sds((M,), np.int64), sds((S,), np.int64), sds((L,), np.int64))
-    kw = dict(n_lanes=L, n_groups=n_groups, n_cap=n_cap, n_dp=n_dp,
-              range_nanos=jnp.int64(300 * SEC))
     fn = device_grouped_pipeline.__wrapped__     # the jitted function
     ops = list(_walk_jaxpr(
         jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr))
@@ -1346,12 +1537,14 @@ def test_open_rows_keep_the_grouped_programs_structure():
         debug_info=True)
 
 
-def test_open_rows_merge_behind_the_decoded_rows_of_their_lane():
+@pytest.mark.parametrize("n_cap,form", [(12, "rotate"), (18, "window")])
+def test_open_rows_merge_behind_the_decoded_rows_of_their_lane(n_cap, form):
     """_merge_device reaches rows through `order`: decoded rows and
     open rows laid end to end land in each lane block-ascending."""
-    from m3_tpu.models.query_pipeline import _merge_device
+    from m3_tpu.models.query_pipeline import _merge_device, merge_form
 
-    T, n_lanes, n_cap = 4, 3, 12
+    T, n_lanes = 4, 3
+    assert merge_form(n_cap, T) == form
     # decoded rows: lane 0 twice, lane 2 once; open rows: lanes 0 and 1
     ts = np.array([[1, 2, 3, 0], [4, 5, 0, 0], [1, 2, 0, 0],
                    [6, 7, 8, 9], [3, 0, 0, 0]], dtype=np.int64)
@@ -1372,11 +1565,16 @@ def test_open_rows_merge_behind_the_decoded_rows_of_their_lane():
     ) == [1, 2]
 
 
-def test_tier_cut_that_keeps_no_prefix_is_flagged():
+@pytest.mark.parametrize("n_cap,form", [(16, "rotate"), (40, "window")])
+def test_tier_cut_that_keeps_no_prefix_is_flagged(n_cap, form):
     """A coarse row out of time order can leave the cut a kept cell
     behind a dropped one.  The merge moves a row's first `count` cells,
     so such a row must flag (the engine falls back to the host tier),
     and a clean two-tier lane beside it must not."""
+    from m3_tpu.models.query_pipeline import merge_form
+
+    assert merge_form(n_cap, 8) == form
+
     def stream(t):
         enc = tsz.Encoder(T0)
         for ti in t:
@@ -1393,7 +1591,7 @@ def test_tier_cut_that_keeps_no_prefix_is_flagged():
         jnp.asarray(words), jnp.asarray(nbits),
         jnp.asarray(np.asarray([0, 0, 1, 1], dtype=np.int64)),
         jnp.asarray(T0 + np.asarray([240], dtype=np.int64) * SEC),
-        n_lanes=2, n_cap=16, fn="rate", range_nanos=300 * SEC, n_dp=8,
+        n_lanes=2, n_cap=n_cap, fn="rate", range_nanos=300 * SEC, n_dp=8,
         tiers=jnp.asarray(np.asarray([1, 0, 1, 0], dtype=np.int64)),
         n_tiers=2)
     assert np.asarray(err).tolist() == [True, False, False, False]

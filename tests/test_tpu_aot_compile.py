@@ -297,6 +297,7 @@ def test_grouped_pipeline_compiles_at_the_two_day_cells_shape(one_chip):
 
     M, W, L, S, n_cap = 11_008, 256, 512, 1_344, 15_872
     assert qp.window_form(n_cap) == "gather" and n_cap - 1 > qp._PREFIX_MAX_N
+    assert qp.merge_form(n_cap, 768) == "window"
     sds = lambda shape, dt: _sds(shape, dt, one_chip)   # noqa: E731
     t0 = time.perf_counter()
     compiled = _compile(
