@@ -1636,9 +1636,10 @@ def _expr_eval(plan, leaves, params, steps, errors,
 
     Scopes: a leaf's stages keep their own (`m3.decode`, `m3.merge`,
     `m3.temporal`), a grouped reduce is `m3.group`, a top-k selection
-    `m3.topk`, every other node's own operations `m3.expr`.  No scope
-    wraps another's stage: a trace is read by the first `m3.*` of an
-    operation's name."""
+    `m3.topk`, a histogram_quantile's bucket-row gather and
+    interpolation `m3.hq`, every other node's own operations `m3.expr`.
+    No scope wraps another's stage: a trace is read by the first `m3.*`
+    of an operation's name."""
     aux = ()
 
     def gather(vals, valid, node):
@@ -1659,7 +1660,7 @@ def _expr_eval(plan, leaves, params, steps, errors,
             kids = [ev(node[-1], steps_cur)]
         if tag in ("agg", "topk"):      # m3.group, m3.topk
             return apply(node, kids, steps_cur)
-        with jax.named_scope("m3.expr"):
+        with jax.named_scope("m3.hq" if tag == "hq" else "m3.expr"):
             return apply(node, kids, steps_cur)
 
     def leaf(node):

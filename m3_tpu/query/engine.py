@@ -2428,6 +2428,11 @@ class Engine:
                 "groups": stats.get("groups", 0),
                 "topk_k": stats.get("topk_k", 0),
                 "rows_out": stats.get("rows_out", 0),
+                # fused tier: the label combinations a
+                # histogram_quantile interpolated and the `le` buckets
+                # of its widest one
+                "hq_groups": stats.get("hq_groups", 0),
+                "hq_buckets": stats.get("hq_buckets", 0),
                 # how the program read its windows' ends (rate /
                 # increase / delta): "select" or "gather" ("mixed"
                 # where a fused tree's leaves differ)
@@ -2502,6 +2507,9 @@ class Engine:
             if rec["lanes"]:
                 instrument.counter("m3_query_lanes_total").inc(
                     rec["lanes"])
+            if rec["hq_groups"]:
+                instrument.counter("m3_query_hq_groups_total").inc(
+                    rec["hq_groups"])
             if attribution.enabled():
                 # read-path attribution for this query (datapoints
                 # scanned and device execute seconds are accounted at

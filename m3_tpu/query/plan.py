@@ -580,7 +580,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     leaf_plan = {}     # dedupe key -> (idx, kind, statics, pk)
     params = []        # traced per-node pytrees, by param index
     root_post = []     # host post-ops on the root matrix (sort/...)
-    shape = {"groups": 0, "topk_k": 0}   # for the query's record
+    # for the query's record
+    shape = {"groups": 0, "topk_k": 0, "hq_groups": 0, "hq_buckets": 0}
     forms = set()      # window_form of each rate-family leaf
     cost = engine._cost()
     s_pad = _bucket_pow2(len(step_times), 64)
@@ -826,7 +827,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                                   "(need >= 2 buckets and an +Inf "
                                   "top)", reason="hq_malformed")
             g_pad = _bucket_pow2(len(out_labels), 8)
-            b_pad = _bucket_pow2(max(len(r) for r in rows_g), 8)
+            widest = max(len(r) for r in rows_g)
+            b_pad = _bucket_pow2(widest, 8)
             rows_idx = np.zeros((g_pad, b_pad), dtype=np.int64)
             ubs_p = np.full((g_pad, b_pad), np.inf)
             caps = np.zeros(g_pad)
@@ -842,6 +844,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             pidx = len(params)
             params.append((rows_idx, ubs_p, caps, gvalid,
                            np.float64(phi)))
+            shape["hq_groups"] += len(out_labels)
+            shape["hq_buckets"] = max(shape["hq_buckets"], widest)
             return (("hq", g_pad, b_pad, pidx, plan_c), out_labels,
                     len(out_labels), g_pad)
         if tag == "absent":
@@ -1139,6 +1143,9 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         lanes_pad=sum(ent[3]["lanes_pad"]
                       for ent in leaf_plan.values()),
         groups=shape["groups"], topk_k=shape["topk_k"],
+        # a histogram_quantile's label combinations and the buckets of
+        # its widest one
+        hq_groups=shape["hq_groups"], hq_buckets=shape["hq_buckets"],
         rows_out=len(labels), window_form=window_form,
         merge_form=merge_form,
         # the widest leaf's samples a lane and rows a lane, and the

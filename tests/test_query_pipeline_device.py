@@ -1642,6 +1642,51 @@ def test_fused_topk_program_names_its_stages_at_the_cells_shape():
     assert _decode_loops(ops, n_dp, W) == 2
 
 
+def test_fused_hq_program_names_its_stages_at_the_cells_shape():
+    """`histogram_quantile(0.99, rate(.._bucket{job=J}[5m]))` of one job
+    as `dash-p99` runs it (lowered, never run): 4,096 streams x 256
+    words at the fused planner's pow2 buckets, 2,048 lanes x 2,048
+    samples in four chunks of `_MERGE_LANES`, 256 steps, 100 groups x
+    12 buckets in [128, 16].  The quantile's own operations (the
+    bucket-row gather, the running maximum over `le`, the
+    interpolation's element gathers) lower under `m3.hq`, in place of
+    `m3.expr` and around no stage of the leaf."""
+    from m3_tpu.models.query_pipeline import (device_expr_pipeline,
+                                              lane_chunks)
+
+    M, W, L, S, n_cap, n_dp, g_pad, b_pad = (4096, 256, 2048, 256, 2048,
+                                             1024, 128, 16)
+    assert lane_chunks(L) == 4
+    sds = jax.ShapeDtypeStruct
+    leaf = {"words": sds((M, W), np.uint32), "nbits": sds((M,), np.int32),
+            "slots": sds((M,), np.int64), "tiers": sds((M,), np.int64),
+            "steps": sds((S,), np.int64), "rng": sds((), np.int64),
+            "valid": sds((L,), np.bool_)}
+    plan = ("hq", g_pad, b_pad, 1,
+            ("leaf", 0, 0, "words", "rate", L, n_cap, n_dp, 1, M, W, S,
+             0.5, 0.5))
+    params = ((sds((), np.float64), sds((), np.float64)),
+              (sds((g_pad, b_pad), np.int64), sds((g_pad, b_pad), np.float64),
+               sds((g_pad,), np.float64), sds((g_pad,), np.bool_),
+               sds((), np.float64)))
+    low = device_expr_pipeline.lower(plan, (leaf,), params,
+                                     sds((S,), np.int64))
+    text = low.as_text(debug_info=True)
+    for scope in ("m3.decode", "m3.merge", "m3.temporal", "m3.hq"):
+        assert f"/{scope}/" in text, scope
+    assert "m3.expr" not in text        # the tree's one node is the quantile
+    for inner in ("m3.decode", "m3.merge", "m3.temporal"):
+        assert f"m3.hq/{inner}" not in text, inner
+    ops = list(_walk_jaxpr(jax.make_jaxpr(functools.partial(
+        device_expr_pipeline.__wrapped__, plan))((leaf,), params,
+                                                 sds((S,), np.int64)).jaxpr))
+    # the bucket rows' gather and the interpolation's four element
+    # gathers, and the running maximum over le: all the quantile's
+    under = [p for p, s in ops if "m3.hq" in s]
+    assert under.count("gather") == 5 and "cummax" in under, under
+    assert not [s for p, s in ops if p == "cummax" and "m3.hq" not in s]
+
+
 @pytest.mark.parametrize("n", [1, 2, 40, 1535, 4096, 4097, 5000, 15871])
 def test_prefix_sum_by_scan_equals_the_one_window(n):
     """The reset prefix sum is one reduce_window up to _PREFIX_MAX_N

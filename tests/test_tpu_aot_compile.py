@@ -309,6 +309,39 @@ def test_grouped_pipeline_compiles_at_the_two_day_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_fused_hq_pipeline_compiles_at_the_latency_cells_shape(one_chip):
+    """The benchmark cell `dash-p99`: `histogram_quantile(0.99,
+    rate(.._bucket{job=J}[5m]))` of one job's 100 instances x 12 `le`,
+    2,400 sealed streams at the fused planner's pow2 buckets -> 4,096
+    rows x 256 words, 2,048 lanes x 2,048 samples in four chunks of
+    `_MERGE_LANES`, 256 steps, then the `hq` node over 100 groups x 12
+    buckets in [128, 16, 256].  Its first request has 60 s (the mix's
+    `request_timeout_s`); the chunk loops keep the temporaries at a
+    chunk's size."""
+    import time
+
+    M, W, L, S, n_cap, n_dp, g_pad, b_pad = (4096, 256, 2048, 256, 2048,
+                                             1024, 128, 16)
+    assert qp.lane_chunks(L) == 4 and qp.window_form(n_cap) == "select"
+    sds = lambda shape, dt: _sds(shape, dt, one_chip)   # noqa: E731
+    leaf = {"words": sds((M, W), np.uint32), "nbits": sds((M,), np.int32),
+            "slots": sds((M,), np.int64), "tiers": sds((M,), np.int64),
+            "steps": sds((S,), np.int64), "rng": sds((), np.int64),
+            "valid": sds((L,), np.bool_)}
+    plan = ("hq", g_pad, b_pad, 1,
+            ("leaf", 0, 0, "words", "rate", L, n_cap, n_dp, 1, M, W, S,
+             0.5, 0.5))
+    params = ((sds((), np.float64), sds((), np.float64)),
+              (sds((g_pad, b_pad), np.int64), sds((g_pad, b_pad), np.float64),
+               sds((g_pad,), np.float64), sds((g_pad,), np.bool_),
+               sds((), np.float64)))
+    t0 = time.perf_counter()
+    compiled = _compile(qp.device_expr_pipeline, plan, (leaf,), params,
+                        sds((S,), np.int64))
+    assert time.perf_counter() - t0 < 60.0
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.slow
 def test_grouped_pipeline_compiles(one_chip, tiny_db, monkeypatch):
     """sum by (job)(rate(...)) as the engine dispatches it: the
