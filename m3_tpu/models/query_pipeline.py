@@ -214,22 +214,180 @@ def _merge_device(ts, vs, valid, slots, n_lanes: int, n_cap: int,
         jnp.empty((n_lanes,), I32)))
 
 
-def _window_bounds_device(times, steps, range_nanos):
-    """Per-(lane, step) index bounds of the [t - range, t] INCLUSIVE
-    window (the -1ns exclusive-start trick mirroring
-    consolidate._range_left) — the one definition both the rate and
-    reduce kernels share.  A bound is the count of samples at or
-    before it: one fused compare-and-sum over [L, N, S], where a binary
-    search would be log2(N) dependent element gathers.  Its operations
-    stand under `bounds` in the caller's scope (`m3.temporal/bounds` in
-    a trace), apart from the reads of the windows' ends (`take`)."""
+# steps a block of the band: the windows of so many consecutive steps
+# are searched inside one span of a lane (band_width).  Set from sweeps
+# of the rate stage alone (v5e, host clock around a call, regular lanes
+# 10 s apart of which a seventh started late, [5m]; full width against
+# blocks of 16 / 32 / 64 / 128 steps): 97.9 against 149.0 / 99.8 / 79.5
+# ms at the fleet's [12,544, 1,536] x 256 in 25 chunks (spans of 384,
+# 384, 640), 4.94 against 6.97 / 4.97 / 4.27 at one chunk of it, 19.4
+# against - / 18.5 / 14.0 at [2,048, 2,048] x 256 in four, 136.7 against
+# 115.8 / 114.6 / 111.0 / 119.5 at the two-day panel's [512, 15,872] x
+# 1,344 (bounds alone; spans of 384, 640, 1,024, 1,792).  A block costs
+# one coarse count and one selection of its span's tiles whatever its
+# steps, so halving the blocks halves both (0.8 ms a fleet chunk at 32).
+# The picks inside the spans earn their place at 64 and not at 32: with
+# the bounds alone in the band the fleet reads 94.9 / 93.9 at 32 / 64,
+# its one chunk 4.54 and [2,048, 2,048] 17.8 at 64 (PERF.md PR 48)
+_BAND_STEPS = 64
+# samples past a block's steps that a span holds for the windows' range
+# (at a 10 s cadence a range of some ten minutes; the fleet's stage
+# reads 78.8 ms without them and 79.5 with), and the multiple a span's
+# width is rounded up to (the chip's 128 lanes)
+_BAND_SLACK = 64
+_BAND_TILE = 128
+
+
+def band_width(n_cap: int, n_steps: int) -> int | None:
+    """Samples in the span of a lane in which the windowed stage looks
+    for the windows of one block of _BAND_STEPS steps, or None where it
+    searches the whole lane: what a block's steps reach of a lane that
+    covers the steps evenly, _BAND_SLACK more for the range, rounded up
+    to _BAND_TILE; None where that is not under half the lane (the
+    band would cost what it saves).  A function of the static buckets
+    alone, as window_form is: no query's range or step keys a
+    program."""
+    reach = _BAND_STEPS * n_cap // max(n_steps, 1) + _BAND_SLACK
+    width = (-(-reach // _BAND_TILE) + 1) * _BAND_TILE
+    return width if 2 * width <= n_cap and n_cap % _BAND_TILE == 0 else None
+
+
+def _count_at_or_before(times, bounds):
+    """For every lane of times [L, N] the count of its samples at or
+    before each of bounds ([Q], or a lane's own [L, Q]): one fused
+    compare-and-sum over [L, Q, N]."""
+    search = functools.partial(jnp.searchsorted, side="right",
+                               method="compare_all")
+    return jax.vmap(search, in_axes=(0, None if bounds.ndim == 1 else 0))(
+        times, bounds)
+
+
+def _spans_device(xs, tile, n_tiles: int):
+    """Of every [L, N] array of `xs` the `n_tiles` tiles of _BAND_TILE
+    cells from tile[l, b] on, as [L x B, n_tiles x _BAND_TILE]: a
+    span a (lane, block), each lane's at its own offset.  Tiles are
+    SELECTED (_select_at over a lane's N / _BAND_TILE tiles, a tile the
+    element), not gathered: this chip's compiler turns a gather of
+    contiguous spans into a loop of one dynamic slice a span, a
+    microsecond each (PERF.md PR 48), as it runs an element-indexed
+    gather an element at a time."""
+    L, B = tile.shape
+    tiled = tuple(x.reshape(L, -1, _BAND_TILE) for x in xs)
+    at = (tile[:, :, None] + jnp.arange(n_tiles, dtype=tile.dtype)).reshape(
+        L, B * n_tiles)
+    (spans,) = _select_at(tiled, (at,))
+    return tuple(x.reshape(L * B, n_tiles * _BAND_TILE) for x in spans)
+
+
+def _windows_device(xs, steps, range_nanos, pick: bool = False,
+                    band: bool = True):
+    """The windowed stage's search, for every caller: per-(lane, step)
+    index bounds of the [t - range, t] INCLUSIVE window (the -1ns
+    exclusive-start trick mirroring consolidate._range_left) over the
+    lanes xs[0] = times [L, N], and with `pick` every array of `xs` at
+    the windows' ends (clip(left), clip(right - 1): _select_at).  ->
+    (starts_excl, left, right, picks or None, lane chunks served at
+    the full width: 0 or 1).
+
+    A bound is the count of samples at or before it, by compare-and-sum
+    (a binary search would be log2(N) dependent element gathers, which
+    this chip runs an element at a time).  Lanes and steps are both
+    sorted, so the windows of _BAND_STEPS consecutive steps end inside
+    one short run of a lane.  THE BAND: the full-width count finds, a
+    (lane, block of steps), only where the block's earliest window
+    opens and its latest closes; the span of band_width(N, S) samples
+    from there is fetched (_spans_device), and the steps' bounds, and
+    the picks, are made inside it: `left` / `right` are the span's
+    offset plus the count in the span, the same integers, and a pick
+    is the same element.  Cells compared: N x S / _BAND_STEPS +
+    width x S a lane where the full width has N x S.
+
+    Proven on every call: the band holds a (lane, block) iff the
+    block's latest window closes inside the span that starts at the
+    tile its earliest opens in (and the lane ascends, as _decode_merge
+    flags it must): the coarse search has both numbers.  Where any lane of
+    the batch does not fit, a burst or a lane scraped ten times as
+    often or an hour's range, the whole batch takes the full-width
+    body under `lax.cond`: the parent's answer, bit for bit.  _INF
+    padding, duplicates, a lane that ended before a block and a lane
+    that began inside one are ordinary cases of the band; a span is a
+    lane's own, so a lane that started late shares a chunk with lanes
+    hundreds of samples ahead of it.  Where band_width is None, or
+    without `band` (under `vmap`, where a `cond` on a batched predicate
+    is a select that runs both bodies), the full width is the only
+    body.
+
+    In a trace the counts stand under `bounds`, the spans' fetch under
+    `span`, the picks under `take`, each in the caller's scope
+    (`m3.temporal/bounds`, ...)."""
+    times = xs[0]
+    L, N = times.shape
+    S = steps.shape[0]
     starts_excl = steps - range_nanos - 1
+
+    def ends(left, right):
+        return (jnp.clip(left, 0, N - 1), jnp.clip(right - 1, 0, N - 1))
+
+    def full_width():
+        with jax.named_scope("bounds"):
+            left = _count_at_or_before(times, starts_excl)
+            right = _count_at_or_before(times, steps)
+        if not pick:
+            return left, right, None
+        with jax.named_scope("take"):
+            return left, right, _select_at(xs, ends(left, right))
+
+    width = band_width(N, S) if band else None
+    if width is None:
+        return (starts_excl, *full_width(), jnp.ones((), I32))
+    sb, n_tiles = _BAND_STEPS, width // _BAND_TILE
+    n_blk = -(-S // sb)
     with jax.named_scope("bounds"):
-        left = jax.vmap(lambda t: jnp.searchsorted(
-            t, starts_excl, side="right", method="compare_all"))(times)
-        right = jax.vmap(lambda t: jnp.searchsorted(
-            t, steps, side="right", method="compare_all"))(times)
-    return starts_excl, left, right
+        # a block's steps in whatever order they came: its earliest
+        # window's start and its latest's end
+        blocks = jnp.pad(steps, (0, n_blk * sb - S),
+                         mode="edge").reshape(n_blk, sb)
+        coarse = _count_at_or_before(times, jnp.concatenate(
+            [blocks.min(axis=1) - range_nanos - 1, blocks.max(axis=1)]))
+        # the span: whole tiles from the one the earliest window opens in
+        tile = jnp.clip(coarse[:, :n_blk] // _BAND_TILE, 0,
+                        N // _BAND_TILE - n_tiles)
+        fits = (jnp.all(coarse[:, n_blk:] <= tile * _BAND_TILE + width)
+                & jnp.all(times[:, 1:] >= times[:, :-1]))
+
+    def banded():
+        with jax.named_scope("span"):
+            spans = _spans_device(xs, tile, n_tiles)
+        off = (tile * _BAND_TILE).reshape(L * n_blk, 1)
+        with jax.named_scope("bounds"):
+            at = jnp.broadcast_to(blocks, (L, n_blk, sb)).reshape(
+                L * n_blk, sb)
+            left = off + _count_at_or_before(spans[0],
+                                             at - range_nanos - 1)
+            right = off + _count_at_or_before(spans[0], at)
+        picks = None
+        if pick:
+            with jax.named_scope("take"):
+                picks = _select_at(spans, tuple(
+                    jnp.clip(i - off, 0, width - 1)
+                    for i in ends(left, right)))
+        return jax.tree.map(
+            lambda x: x.reshape(L, n_blk * sb)[:, :S], (left, right, picks))
+
+    left, right, picks = jax.lax.cond(fits, banded, full_width)
+    return starts_excl, left, right, picks, (~fits).astype(I32)
+
+
+def _window_bounds_device(times, steps, range_nanos, band: bool = True):
+    """Per-(lane, step) index bounds of the [t - range, t] INCLUSIVE
+    window — the one definition every windowed function shares
+    (_windows_device: searched in a band of the lane where its static
+    shape allows and the lanes fit, at the full width otherwise; the
+    same integers either way).  -> (starts_excl, left, right, lane
+    chunks served at the full width: 0 or 1)."""
+    starts_excl, left, right, _, full = _windows_device(
+        (times,), steps, range_nanos, band=band)
+    return starts_excl, left, right, full
 
 
 # samples a lane up to which _take_at_device selects and above which it
@@ -242,7 +400,12 @@ def _window_bounds_device(times, steps, range_nanos):
 # selection takes 9.4 ms at 1,536, 50.4 at 6,144, 101.0 at 12,288, 140.5
 # at 15,872 and 150.6 at 18,432 against the gathers' 85.7-92.0 at each;
 # they meet near 11,000, and at the constant the selection is 10%
-# dearer (PERF.md PR 45, the cell dash-2d runs the gathers' side)
+# dearer (PERF.md PR 45, the cell dash-2d runs the gathers' side).
+# Since PR 48 the selection up to the constant runs inside a band's
+# spans where the lanes fit them (_windows_device): the sweeps above
+# are the full width's, which is what a lane that does not fit still
+# runs.  Past the constant the band serves the bounds alone and the
+# gathers stay: dash-2d's check holds its records to them
 _SELECT_MAX_N = 12288
 
 
@@ -263,20 +426,27 @@ def _take_at_device(xs, idxs):
 
     The TPU compiler runs an element-indexed gather one element at a
     time, 10 ns each whatever N.  Up to _SELECT_MAX_N samples a lane
-    the read is a selection instead: ONE reduction over the sample
-    axis for all of `idxs` and `xs`, whose combiner only selects, never
-    adds or compares values, under the one-hot masks `arange(N) == idx`.
-    Masks and broadcasts fuse into the reduction; no [L, S, N] array
-    exists.  Exactly one cell a (lane, step) is flagged under each
-    index, so whatever order the partial results meet in, the flagged
-    one survives."""
-    L, N = xs[0].shape
-    if window_form(N) == "gather":
+    the read is a selection instead (_select_at)."""
+    if window_form(xs[0].shape[1]) == "gather":
         return [tuple(jnp.take_along_axis(x, idx, axis=1) for x in xs)
                 for idx in idxs]
+    return _select_at(xs, idxs)
+
+
+def _select_at(xs, idxs):
+    """_take_at_device's reads as a selection: ONE reduction over the
+    sample axis for all of `idxs` and `xs`, whose combiner only
+    selects, never adds or compares values, under the one-hot masks
+    `arange(N) == idx`.  Masks and broadcasts fuse into the reduction;
+    no [L, S, N] array exists.  Exactly one cell a (lane, step) is
+    flagged under each index, so whatever order the partial results
+    meet in, the flagged one survives.  The lanes may be a band's
+    spans, [lanes x blocks, width], and the steps a block's; an element
+    may be a whole tile of cells, xs [L, N, T] (_spans_device)."""
+    L, N, *tile = xs[0].shape
     S, k, n = idxs[0].shape[1], len(xs), len(idxs)
     cell = jnp.arange(N, dtype=I32)
-    wide = tuple(jnp.broadcast_to(x[:, None, :], (L, S, N)) for x in xs)
+    wide = tuple(jnp.broadcast_to(x[:, None], (L, S, N, *tile)) for x in xs)
 
     def pick(a, b):
         # an index's flag, and under it one accumulator an x
@@ -287,7 +457,9 @@ def _take_at_device(xs, idxs):
         return tuple(out)
 
     got = jax.lax.reduce(
-        tuple(cell == idx[:, :, None] for idx in idxs) + wide * n,
+        tuple(jnp.broadcast_to(
+            (cell == idx[:, :, None]).reshape(L, S, N, *(1,) * len(tile)),
+            wide[0].shape) for idx in idxs) + wide * n,
         (jnp.asarray(False),) * n + tuple(
             jnp.zeros((), x.dtype) for x in xs) * n,
         pick, (2,))
@@ -325,7 +497,7 @@ def _prefix_sum_device(x):
 
 
 def _rate_device(times, values, steps, range_nanos,
-                 is_counter: bool, is_rate: bool):
+                 is_counter: bool, is_rate: bool, band: bool = True):
     """Windowed extrapolated rate on device — the jnp port of
     consolidate.extrapolated_rate (upstream Prometheus semantics:
     >=2 samples, counter-reset prefix sums, 1.1x-avg-spacing
@@ -333,35 +505,35 @@ def _rate_device(times, values, steps, range_nanos,
     of at most _MERGE_LANES (the last overlaps its neighbour where
     they do not divide): the stage's [lanes, n_cap] and [lanes, S]
     temporaries, and what the compiler re-lays of a lane batch for the
-    prefix sums, are a chunk's and not the fan-out's."""
+    prefix sums, are a chunk's and not the fan-out's.  -> (the rates,
+    the lane chunks whose windows were searched at the full width and
+    not in a band: _windows_device)."""
     L = values.shape[0]
     n_chunks = lane_chunks(L)
     B = min(L, -(-L // (8 * n_chunks)) * 8)
     rate = functools.partial(_rate_lanes, steps=steps,
                              range_nanos=range_nanos,
-                             is_counter=is_counter, is_rate=is_rate)
+                             is_counter=is_counter, is_rate=is_rate,
+                             band=band)
     if n_chunks == 1:
         return rate(times, values)
 
-    def chunk(c, out):
+    def chunk(c, carry):
+        out, full = carry
         lo = jnp.minimum(c * B, L - B)
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, rate(jax.lax.dynamic_slice_in_dim(times, lo, B),
-                      jax.lax.dynamic_slice_in_dim(values, lo, B)), lo, 0)
+        rates, at_full = rate(jax.lax.dynamic_slice_in_dim(times, lo, B),
+                              jax.lax.dynamic_slice_in_dim(values, lo, B))
+        return (jax.lax.dynamic_update_slice_in_dim(out, rates, lo, 0),
+                full + at_full)
 
-    return jax.lax.fori_loop(0, n_chunks, chunk, jnp.empty(
-        (L, steps.shape[0]), values.dtype))
+    return jax.lax.fori_loop(0, n_chunks, chunk, (
+        jnp.empty((L, steps.shape[0]), values.dtype), jnp.zeros((), I32)))
 
 
 def _rate_lanes(times, values, steps, range_nanos,
-                is_counter: bool, is_rate: bool):
+                is_counter: bool, is_rate: bool, band: bool):
     """_rate_device of one chunk of lanes."""
     L, N = values.shape
-    starts_excl, left, right = _window_bounds_device(
-        times, steps, range_nanos)
-    has2 = (right - left) >= 2
-    i_first = jnp.clip(left, 0, N - 1)
-    i_last = jnp.clip(right - 1, 0, N - 1)
     ends = (times, values)
     if is_counter and N > 1:
         prev = values[:, :-1]
@@ -370,8 +542,18 @@ def _rate_lanes(times, values, steps, range_nanos,
         ends += (jnp.concatenate(
             [jnp.zeros((L, 1), values.dtype), _prefix_sum_device(resets)],
             axis=1),)
-    (t_first, v_first, *cum_first), (t_last, v_last, *cum_last) = (
-        _take_at_device(ends, (i_first, i_last)))
+    # the windows' first and last samples: up to _SELECT_MAX_N a lane
+    # selected where the bounds were counted (in the band's spans, or
+    # over the lane), past it gathered from the lane by the bounds
+    select = window_form(N) == "select"
+    starts_excl, left, right, picks, full = _windows_device(
+        ends if select else ends[:1], steps, range_nanos, pick=select,
+        band=band)
+    if not select:
+        picks = _take_at_device(ends, (jnp.clip(left, 0, N - 1),
+                                       jnp.clip(right - 1, 0, N - 1)))
+    (t_first, v_first, *cum_first), (t_last, v_last, *cum_last) = picks
+    has2 = (right - left) >= 2
     if cum_first:
         corr = jnp.where(has2, cum_last[0] - cum_first[0], 0.0)
     else:
@@ -398,7 +580,7 @@ def _rate_lanes(times, values, steps, range_nanos,
     out = result * (interval / jnp.maximum(sampled, 1.0))
     if is_rate:
         out = out / (range_nanos / 1e9)
-    return jnp.where(has2 & (sampled > 0), out, jnp.nan)
+    return jnp.where(has2 & (sampled > 0), out, jnp.nan), full
 
 
 def _tier_cut(ts, valid, slots, tiers, n_lanes: int, n_tiers: int):
@@ -482,7 +664,7 @@ def _decode_merge(words, nbits, slots, n_lanes: int, n_cap: int,
 _MINMAX_BLOCK = 32
 
 
-def _minmax_device(times, values, steps, range_nanos, is_max: bool):
+def _minmax_device(values, left, right, is_max: bool):
     """Windowed min/max_over_time on device: max/min have no prefix-sum
     form, so windows decompose over a two-level range-max structure —
     per-block prefix/suffix cummax + a sparse (doubling) table over
@@ -496,7 +678,6 @@ def _minmax_device(times, values, steps, range_nanos, is_max: bool):
     min runs as max over negated values."""
     L, N = values.shape
     B = _MINMAX_BLOCK
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     w = ~jnp.isnan(values)
     zero = jnp.zeros((L, 1), values.dtype)
     ccnt = jnp.concatenate([zero, jnp.cumsum(w, axis=1)], axis=1)
@@ -621,7 +802,7 @@ def _wf_merge(a, b):
     return n, mean, m2
 
 
-def _stdvar_device(times, values, steps, range_nanos, is_stddev: bool):
+def _stdvar_device(values, left, right, is_stddev: bool):
     """Windowed stddev/stdvar_over_time on device.  Variance has no
     per-window prefix-sum form that survives f64 (E[x^2]-E[x]^2
     cancels at counter magnitudes), but Welford summaries MERGE stably
@@ -640,7 +821,6 @@ def _stdvar_device(times, values, steps, range_nanos, is_stddev: bool):
     all -> NaN; nonempty-but-all-NaN window -> 0.0."""
     L, N = values.shape
     B = _MINMAX_BLOCK
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     m = ~jnp.isnan(values)
     x = jnp.where(m, values, 0.0)
     nf = m.astype(values.dtype)
@@ -692,15 +872,13 @@ def _stdvar_device(times, values, steps, range_nanos, is_stddev: bool):
     return jnp.where(right > left, var, jnp.nan)
 
 
-def _changes_device(times, values, steps, range_nanos,
-                    resets_only: bool):
+def _changes_device(values, left, right, resets_only: bool):
     """changes()/resets() on device: adjacent-pair event counts per
     window via a prefix sum over pair flags (pair (i, i+1) counted when
     left <= i and i+1 < right) — the jnp mirror of the host
     consolidate.window_changes/_pair_window_count.  Counts are
     integers: exact on every backend."""
     L, N = values.shape
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     prev, curr = values[:, :-1], values[:, 1:]
     flags = jnp.where(curr < prev, 1.0, 0.0) if resets_only else \
         jnp.where(curr != prev, 1.0, 0.0)
@@ -714,13 +892,12 @@ def _changes_device(times, values, steps, range_nanos,
     return jnp.where(right > left, out, jnp.nan)
 
 
-def _linreg_device(times, values, steps, range_nanos):
+def _linreg_device(times, values, steps, range_nanos, left, right):
     """Per-window least-squares fit on device — the jnp mirror of the
     host consolidate.window_linreg (same origin shift, same closed-form
     step-time recentring of the moment sums, so the two tiers agree to
     f64 associativity).  Returns (slope, intercept_at_step, n)."""
     L, N = values.shape
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     vz = jnp.nan_to_num(values)
     ok = (~jnp.isnan(values)).astype(values.dtype)
     origin = steps[0] - range_nanos
@@ -751,7 +928,8 @@ def _linreg_device(times, values, steps, range_nanos):
             jnp.where(valid, intercept, jnp.nan), n)
 
 
-def _reduce_device(times, values, steps, range_nanos, reducer: str):
+def _reduce_device(times, values, steps, range_nanos, left, right,
+                   reducer: str):
     """Windowed *_over_time reductions on device via NaN-masked prefix
     sums over the merged [L, N] batch (windows are contiguous index
     ranges once lanes are time-sorted).  Semantics mirror the host
@@ -763,19 +941,19 @@ def _reduce_device(times, values, steps, range_nanos, reducer: str):
     the two-level range-max structure (_minmax_device); stddev/stdvar
     through the mergeable-Welford analog (_stdvar_device)."""
     if reducer in ("min_over_time", "max_over_time"):
-        return _minmax_device(times, values, steps, range_nanos,
+        return _minmax_device(values, left, right,
                               reducer == "max_over_time")
     if reducer in ("stddev_over_time", "stdvar_over_time"):
-        return _stdvar_device(times, values, steps, range_nanos,
+        return _stdvar_device(values, left, right,
                               reducer == "stddev_over_time")
     if reducer in ("changes", "resets"):
-        return _changes_device(times, values, steps, range_nanos,
+        return _changes_device(values, left, right,
                                reducer == "resets")
     if reducer == "deriv":
-        slope, _, _ = _linreg_device(times, values, steps, range_nanos)
+        slope, _, _ = _linreg_device(times, values, steps, range_nanos,
+                                     left, right)
         return slope
     L, N = values.shape
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     empty = right == left
     if reducer == "last_over_time":
         picked = jnp.take_along_axis(
@@ -817,8 +995,7 @@ def _aff_combine(a, b):
             b10 * av0 + b11 * av1 + bv1)
 
 
-def _holt_winters_device(times, values, steps, range_nanos,
-                         sf: float, tf: float):
+def _holt_winters_device(values, left, right, sf: float, tf: float):
     """holt_winters (double exponential smoothing) on device.  The
     upstream recurrence is affine in the (level, trend) state:
 
@@ -844,7 +1021,6 @@ def _holt_winters_device(times, values, steps, range_nanos,
     windows with < 2 present samples -> NaN."""
     L, N = values.shape
     B = _MINMAX_BLOCK
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     m = ~jnp.isnan(values)
     x = jnp.where(m, values, 0.0)
     mf = m.astype(values.dtype)
@@ -925,7 +1101,7 @@ def _holt_winters_device(times, values, steps, range_nanos,
     return jnp.where(valid, lvl, jnp.nan)
 
 
-def _quantile_window_device(times, values, steps, range_nanos, phi):
+def _quantile_window_device(values, left, right, phi):
     """quantile_over_time on device by direct window materialization:
     gather each (lane, step) window's samples into a [L, S, N] grid,
     sort the window axis (absent/NaN keyed +inf past the present
@@ -940,7 +1116,6 @@ def _quantile_window_device(times, values, steps, range_nanos, phi):
     can never exceed the lane's N samples, so the gather is exact by
     construction."""
     L, N = values.shape
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     idxw = left[:, :, None] + jnp.arange(N)[None, None, :]
     inw = idxw < right[:, :, None]
     v = jnp.take_along_axis(values[:, None, :],
@@ -960,13 +1135,12 @@ def _quantile_window_device(times, values, steps, range_nanos, phi):
     return jnp.where(n > 0, q, jnp.nan)
 
 
-def _instant_device(times, values, steps, range_nanos, is_rate: bool):
+def _instant_device(times, values, left, right, is_rate: bool):
     """irate/idelta on device: delta of the window's last two samples
     (jnp port of the engine's _instant_delta, incl. the irate
     counter-reset rule: a drop means restart, delta = post-reset
     value)."""
     N = values.shape[1]
-    _, left, right = _window_bounds_device(times, steps, range_nanos)
     has2 = (right - left) >= 2
     i_last = jnp.clip(right - 1, 0, N - 1)
     i_prev = jnp.clip(right - 2, 0, N - 1)
@@ -990,28 +1164,62 @@ DEVICE_REDUCERS = ("sum_over_time", "avg_over_time", "count_over_time",
 @jax.named_scope("m3.temporal")
 def _temporal_eval(fn: str, times, values, steps, range_nanos,
                    horizon=0.0, hw_sf: float = 0.5, hw_tf: float = 0.5,
-                   phi=0.5):
+                   phi=0.5, band: bool = True):
     """One dispatch for the whole windowed temporal family, shared by
     the per-node pipelines and the fused expression interpreter so a
-    function gains (or loses) a device form in exactly one place."""
+    function gains (or loses) a device form in exactly one place: each
+    but the rate family, which goes a chunk of lanes at a time, is
+    handed its windows' bounds (left, right).  -> (out, windows):
+    windows is int32[2], the stage's lane chunks whose
+    windows were searched at the full width, and all of them (the
+    others went through the band, which `band` allows:
+    _window_bounds_device)."""
     if fn in ("rate", "increase", "delta"):
-        return _rate_device(times, values, steps, range_nanos,
-                            is_counter=fn != "delta",
-                            is_rate=fn == "rate")
+        # the one family that goes a chunk of lanes at a time, each
+        # chunk's windows searched on its own
+        out, full = _rate_device(times, values, steps, range_nanos,
+                                 is_counter=fn != "delta",
+                                 is_rate=fn == "rate", band=band)
+        return out, jnp.stack([full, jnp.asarray(
+            lane_chunks(values.shape[0]), I32)])
+    _, left, right, full = _window_bounds_device(times, steps, range_nanos,
+                                                 band)
     if fn in ("irate", "idelta"):
-        return _instant_device(times, values, steps, range_nanos,
-                               is_rate=fn == "irate")
-    if fn == "predict_linear":
+        out = _instant_device(times, values, left, right,
+                              is_rate=fn == "irate")
+    elif fn == "predict_linear":
         slope, intercept, _ = _linreg_device(times, values, steps,
-                                             range_nanos)
-        return intercept + slope * horizon
-    if fn == "holt_winters":
-        return _holt_winters_device(times, values, steps, range_nanos,
-                                    hw_sf, hw_tf)
-    if fn == "quantile_over_time":
-        return _quantile_window_device(times, values, steps,
-                                       range_nanos, phi)
-    return _reduce_device(times, values, steps, range_nanos, fn)
+                                             range_nanos, left, right)
+        out = intercept + slope * horizon
+    elif fn == "holt_winters":
+        out = _holt_winters_device(values, left, right, hw_sf, hw_tf)
+    elif fn == "quantile_over_time":
+        out = _quantile_window_device(values, left, right, phi)
+    else:
+        out = _reduce_device(times, values, steps, range_nanos, left,
+                             right, fn)
+    return out, jnp.stack([full, jnp.ones((), I32)])
+
+
+class Served(tuple):
+    """What a per-node program returns.  It unpacks as the (out, error)
+    it has been since the first caller, a benchmark's stand-ins among
+    them, and carries beside them `windows`, int32[2] (on a mesh a row
+    a shard: no collective carries them): the lane chunks its windowed
+    stage served at the full width, and all of them (_temporal_eval).
+    A tuple subclass only because benchmark/tests/test_broken_path.py
+    unpacks two from the real program and a perf_opt PR may edit no
+    benchmark file: a plain third value once a benchmark PR lets that
+    stand-in take one (ROADMAP.md Queue 3)."""
+
+    def __new__(cls, out, error, windows):
+        self = super().__new__(cls, (out, error))
+        self.windows = windows
+        return self
+
+
+jax.tree_util.register_pytree_node(
+    Served, lambda s: ((*s, s.windows), None), lambda _, leaves: Served(*leaves))
 
 
 def _per_node(words, nbits, slots, steps, groups, tiers, open_rows,
@@ -1025,17 +1233,19 @@ def _per_node(words, nbits, slots, steps, groups, tiers, open_rows,
     times, values, error = _decode_merge(words, nbits, slots, n_lanes,
                                          n_cap, n_dp, unit_nanos,
                                          tiers, n_tiers, open_rows)
-    if groups is None:
-        return _temporal_eval(fn, times, values, steps, range_nanos,
-                              horizon, hw_sf, hw_tf, phi), error
-    if fn in ("predict_linear", "holt_winters", "quantile_over_time"):
+    if groups is not None and fn in ("predict_linear", "holt_winters",
+                                     "quantile_over_time"):
         # parameterized temporals never reach the grouped form (the
         # engine's grouped-child gate is single-arg); keep the trace-time
         # error so a future routing bug falls back instead of serving a
         # default-parameter answer
         raise ValueError(f"no grouped device form for {fn}")
-    out = _temporal_eval(fn, times, values, steps, range_nanos)
-    return _grouped_reduce(out, groups, n_groups, agg, phi, axis=axis), error
+    out, windows = _temporal_eval(fn, times, values, steps, range_nanos,
+                                  horizon, hw_sf, hw_tf, phi)
+    if groups is not None:
+        out = _grouped_reduce(out, groups, n_groups, agg, phi, axis=axis)
+    return Served(out, error, windows[None] if axis is not None
+                  else windows)
 
 
 def _per_node_on(mesh, words, nbits, slots, steps, groups, tiers,
@@ -1068,7 +1278,8 @@ def _per_node_on(mesh, words, nbits, slots, steps, groups, tiers,
         mesh=mesh,
         in_specs=(P(SERIES_AXIS, None), rows, rows, P(),
                   None if groups is None else rows, rows, P(), P(), P()),
-        out_specs=(P(SERIES_AXIS, None) if groups is None else P(), rows),
+        out_specs=Served(P(SERIES_AXIS, None) if groups is None else P(),
+                         rows, P(SERIES_AXIS, None)),
         check_vma=False,
     )
     def step(words_l, nbits_l, slots_l, steps_l, groups_l, tiers_l,
@@ -1111,7 +1322,8 @@ def device_temporal_pipeline(
 ):
     """Compressed blocks -> the [n_lanes, S] matrix of any windowed
     temporal function (_temporal_eval), entirely on device.  Returns
-    (out f64[n_lanes, S], error bool[M]; bool[M + R] with open_rows).
+    (out f64[n_lanes, S], error bool[M]; bool[M + R] with open_rows),
+    as a `Served`: its `windows` beside them.
 
     `n_dp` bounds one stream (one sealed block); `n_cap` bounds one
     output lane (all of a series' blocks).  Decoding at block width and
@@ -1272,7 +1484,7 @@ def device_grouped_pipeline(
     dashboards aggregate thousands of lanes into a handful of groups,
     making this the transfer-optimal serving form.  Returns
     (out f64[n_groups, S], error bool[M]) with the shared error
-    contract (_decode_merge)."""
+    contract (_decode_merge), as a `Served`."""
     return _per_node_on(mesh, words, nbits, slots, steps, groups, tiers,
                         open_rows, range_nanos, 0.0, phi,
                         n_lanes=n_lanes, n_cap=n_cap, n_dp=n_dp,
@@ -1623,16 +1835,18 @@ def _plan_sharded(node) -> bool:
 
 
 def _expr_eval(plan, leaves, params, steps, errors,
-               axis=None, n_shards: int = 1):
+               axis=None, n_shards: int = 1, band: bool = True):
     """The fused-query interpreter body, shared by the single-chip and
     shard_map'd entry points.  With `axis` set, leaves decode only
     their shard's lane block (lanes_pad // n_shards) and replicating
     nodes insert the matching collective (psum for sum-like grouping
     and absent's presence bit, all_gather ahead of topk /
     histogram_quantile / vector-vector row gathers, whose index maps
-    are global).  Returns (out, aux) — aux is (present, rank) when the
-    root is a topk node (the host reorders rows by final-step rank
-    after the transfer), else ().
+    are global).  Returns (out, aux, windows) — aux is (present, rank)
+    when the root is a topk node (the host reorders rows by final-step
+    rank after the transfer), else (); windows is int32[2], the lane
+    chunks the tree's windowed stages searched at the full width and
+    all of them (_temporal_eval, which `band` is handed to).
 
     Scopes: a leaf's stages keep their own (`m3.decode`, `m3.merge`,
     `m3.temporal`), a grouped reduce is `m3.group`, a top-k selection
@@ -1641,6 +1855,7 @@ def _expr_eval(plan, leaves, params, steps, errors,
     No scope wraps another's stage: a trace is read by the first `m3.*`
     of an operation's name."""
     aux = ()
+    windows = []
 
     def gather(vals, valid, node):
         if axis is not None and _plan_sharded(node):
@@ -1676,9 +1891,11 @@ def _expr_eval(plan, leaves, params, steps, errors,
         else:
             times, values = lf["times"], lf["values"]
         horizon, phi = params[pidx]
-        out = _temporal_eval(fn, times, values, lf["steps"],
-                             lf["rng"], horizon=horizon,
-                             hw_sf=hw_sf, hw_tf=hw_tf, phi=phi)
+        out, searched = _temporal_eval(fn, times, values, lf["steps"],
+                                       lf["rng"], horizon=horizon,
+                                       hw_sf=hw_sf, hw_tf=hw_tf, phi=phi,
+                                       band=band)
+        windows.append(searched)
         return jnp.where(lf["valid"][:, None], out,
                          jnp.nan), lf["valid"]
 
@@ -1775,9 +1992,10 @@ def _expr_eval(plan, leaves, params, steps, errors,
                              sub_times[None, :], _INF)
             vm = jnp.where(tkey == _INF, jnp.nan, cv)
             t2, v2 = jax.lax.sort((tkey, vm), dimension=1, num_keys=1)
-            out = _temporal_eval(fn, t2, v2, steps_out, rng,
-                                 horizon=horizon, hw_sf=hw_sf,
-                                 hw_tf=hw_tf)
+            out, searched = _temporal_eval(fn, t2, v2, steps_out, rng,
+                                           horizon=horizon, hw_sf=hw_sf,
+                                           hw_tf=hw_tf, band=band)
+            windows.append(searched)
             return jnp.where(cvalid[:, None], out, jnp.nan), cvalid
         if tag == "gsel":
             # graphite row selection: a pure gather by host-computed
@@ -1803,7 +2021,7 @@ def _expr_eval(plan, leaves, params, steps, errors,
         raise ValueError(f"unknown plan node {tag!r}")
 
     out, _valid = ev(plan, steps)
-    return out, aux
+    return out, aux, sum(windows, jnp.zeros((2,), I32))
 
 
 @instrument_kernel("device_expr_pipeline")
@@ -1872,15 +2090,17 @@ def device_expr_pipeline(plan, leaves, params, steps):
     NaN^0 == 1, which would otherwise leak a padding row into a
     downstream group reduction).
 
-    Returns (out f64[rows, s_pad], aux, errors): aux is (present,
-    rank) for a topk root else (); errors is a tuple of decode-error
-    vectors for the words-kind leaves in ascending leaf index order
-    (the shared _decode_merge contract; any real-stream error flag
-    makes the engine fall the whole query back to host).
+    Returns (out f64[rows, s_pad], aux, errors, windows): aux is
+    (present, rank) for a topk root else (); errors is a tuple of
+    decode-error vectors for the words-kind leaves in ascending leaf
+    index order (the shared _decode_merge contract; any real-stream
+    error flag makes the engine fall the whole query back to host);
+    windows is int32[2], the lane chunks the tree's windowed stages
+    served at the full width and all of them (_temporal_eval).
     """
     errors = {}
-    out, aux = _expr_eval(plan, leaves, params, steps, errors)
-    return out, aux, tuple(errors[i] for i in sorted(errors))
+    out, aux, windows = _expr_eval(plan, leaves, params, steps, errors)
+    return out, aux, tuple(errors[i] for i in sorted(errors)), windows
 
 
 @instrument_kernel("device_expr_pipeline_batched")
@@ -1904,16 +2124,21 @@ def device_expr_pipeline_batched(plan, leaves, params, steps):
     queries over different time windows (same shape bucket) still
     share the program.
 
-    Returns the solo contract with a leading query axis:
-    (out f64[Q, rows, s_pad], aux, errors) — errors is a tuple of
-    [Q, ...] decode-error vectors for words-kind leaves in ascending
-    leaf index order.  The scheduler demuxes out[qi] back to each
-    query's row span and re-slices the error vectors per entry.
+    The windowed stages search the full width here and nowhere else
+    (`band=False`): under `vmap` the band's `cond` on a batched
+    predicate would be a select that runs BOTH bodies, the band on top
+    of the full width.
+
+    Returns the solo contract, without its `windows`, with a leading
+    query axis: (out f64[Q, rows, s_pad], aux, errors) — errors is a
+    tuple of [Q, ...] decode-error vectors for words-kind leaves in
+    ascending leaf index order.  The scheduler demuxes out[qi] back to
+    each query's row span and re-slices the error vectors per entry.
     """
     def one(leaves_q, params_q, steps_q):
         errors = {}
-        out, aux = _expr_eval(plan, leaves_q, params_q, steps_q,
-                              errors)
+        out, aux, _ = _expr_eval(plan, leaves_q, params_q, steps_q,
+                                 errors, band=False)
         return out, aux, tuple(errors[i] for i in sorted(errors))
 
     return jax.vmap(one)(leaves, params, steps)
@@ -1972,9 +2197,9 @@ def device_expr_pipeline_sharded(plan, mesh, leaves, params, steps):
     the shard count.  `mesh` is static alongside `plan` — the compile
     cache keys gain the mesh shape.
 
-    Returns the single-chip contract (out, aux, errors) with out/aux
-    replicated and each error vector gathered back to global stream
-    row order."""
+    Returns the single-chip contract (out, aux, errors, windows) with
+    out/aux replicated, each error vector gathered back to global
+    stream row order and the windows a row a shard."""
     n_shards = mesh.shape[SERIES_AXIS]
     leaves_spec = tuple(_leaf_in_spec(lf) for lf in leaves)
     params_spec = _sharded_param_specs(plan, params)
@@ -1986,14 +2211,15 @@ def device_expr_pipeline_sharded(plan, mesh, leaves, params, steps):
         shard_map,
         mesh=mesh,
         in_specs=(leaves_spec, params_spec, P()),
-        out_specs=(root_spec, aux_spec, err_spec),
+        out_specs=(root_spec, aux_spec, err_spec, P(SERIES_AXIS, None)),
         check_vma=False,
     )
     def step(leaves_l, params_l, steps_l):
         errors = {}
-        out, aux = _expr_eval(plan, leaves_l, params_l, steps_l,
-                              errors, axis=SERIES_AXIS,
-                              n_shards=n_shards)
-        return out, aux, tuple(errors[i] for i in sorted(errors))
+        out, aux, windows = _expr_eval(plan, leaves_l, params_l, steps_l,
+                                       errors, axis=SERIES_AXIS,
+                                       n_shards=n_shards)
+        return (out, aux, tuple(errors[i] for i in sorted(errors)),
+                windows[None])
 
     return step(leaves, params, steps)

@@ -36,6 +36,7 @@ from m3_tpu.ops import consolidate as cons
 from m3_tpu.ops.m3tsz_decode import (decode_streams_adaptive,
                                      decode_streams_merged)
 from m3_tpu.query import promql, slowlog
+from m3_tpu.query.plan import count_band_served
 from m3_tpu.storage.buffer import OpenRow, by_view
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.limits import QueryDeadlineExceeded, ResultMeta
@@ -1564,16 +1565,21 @@ class Engine:
                 entry = (query_pipeline.device_temporal_pipeline
                          if groups is None
                          else query_pipeline.device_grouped_pipeline)
-                out_d, err = entry(
+                served = entry(
                     *staged, n_lanes=pk["lanes_pad"], n_cap=pk["n_cap"],
                     range_nanos=pk["rng"], fn=fn, n_dp=pk["n_dp"],
                     tiers=tiers_d, n_tiers=pk["n_tiers"],
                     open_rows=open_d,
                     mesh=self.serving_mesh if n_shards > 1 else None,
                     **params)
+                out_d, err = served
                 with cost.phase("d2h"):
                     out = np.asarray(out_d)
                     err_np = np.asarray(err)
+                    # (a stand-in that hands back a plain pair: None)
+                    windows = getattr(served, "windows", None)
+                    if windows is not None:
+                        windows = np.asarray(windows)
         except Exception as exc:  # noqa: BLE001 - serving must not
             # hard-fail on a device runtime error (HBM OOM on a huge
             # fan-out): the host tier can still answer
@@ -1594,6 +1600,7 @@ class Engine:
         merge_form = query_pipeline.merge_form(pk["n_cap"], pk["n_dp"])
         instrument.counter("m3_device_merge_form_total",
                            form=merge_form).inc()
+        band_served_pct = count_band_served(windows)
         self._publish_stats(
             n_streams=pk["n_streams"],
             datapoints=pk["datapoints"],
@@ -1613,6 +1620,7 @@ class Engine:
             decode_refills=query_pipeline.decode_refills(
                 pk["n_dp"], pk["words"].shape[1]),
             window_form=window_form, merge_form=merge_form,
+            band_served_pct=band_served_pct,
             **stats, n_shards=n_shards)
         return out
 
@@ -2468,6 +2476,12 @@ class Engine:
                 # the host: {reason: n}, the slugs of
                 # m3_query_device_decline_total
                 rec["device_declines"] = dict(cost.declines)
+            if stats.get("band_served_pct") is not None:
+                # where a device program said how its windowed stages
+                # searched: the lane chunks that went through a band of
+                # the lane and not its full width, of 100 (0.0: a shape
+                # the band is not taken at, or lanes that did not fit)
+                rec["band_served_pct"] = stats["band_served_pct"]
             if cost.fused_nodes:
                 rec["device_tier"] = {
                     "compile_cache": cost.fused_compile_cache,

@@ -530,6 +530,22 @@ def _count_forms(counter: str, forms) -> str | None:
     return min(forms) if len(forms) == 1 else "mixed" if forms else None
 
 
+def count_band_served(windows) -> float | None:
+    """Count what a device program said of its windowed stages'
+    lane chunks (`windows`: those served at the full width, all of
+    them, on a mesh a row a shard; query_pipeline._temporal_eval) ->
+    the share of 100 that searched a band of the lane, for the query's
+    record; None where the program said nothing."""
+    if windows is None:
+        return None
+    full, chunks = (int(n) for n in np.reshape(windows, (-1, 2)).sum(axis=0))
+    instrument.counter("m3_device_window_band_total",
+                       served="band").inc(chunks - full)
+    instrument.counter("m3_device_window_band_total",
+                       served="full").inc(full)
+    return 100.0 * (chunks - full) / chunks if chunks else None
+
+
 def serve_fused(engine, node, step_times):
     """Try to serve `node` with the fused whole-query device pipeline.
     Returns a Matrix, or None to decline (the engine's per-node paths
@@ -996,6 +1012,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     else:
         serving.count_solo("sharded_mesh")
     binfo = None
+    windows = None      # a batched dispatch searches no band and says so
     if batched is not None:
         out_np, aux_np, errs_entry, binfo = batched
         errs_np = list(errs_entry)
@@ -1019,18 +1036,19 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                 with observe.device_ledger().borrow(
                         "query_megabatch", megabatch, count=n_bufs):
                     if n_shards > 1:
-                        out, aux, errs = \
+                        out, aux, errs, windows = \
                             qp.device_expr_pipeline_sharded(
                                 plan_t, engine.serving_mesh,
                                 tuple(leaves), tuple(params), steps_pad)
                     else:
-                        out, aux, errs = qp.device_expr_pipeline(
+                        out, aux, errs, windows = qp.device_expr_pipeline(
                             plan_t, tuple(leaves), tuple(params),
                             steps_pad)
                 with cost.phase("d2h"):
                     out_np = np.asarray(out)
                     aux_np = tuple(np.asarray(a) for a in aux)
                     errs_np = [np.asarray(e) for e in errs]
+                    windows = np.asarray(windows)
         except Exception as exc:  # noqa: BLE001 — a device runtime
             # error must not fail a query the host tier can answer
             engine.last_fetch_stats = {
@@ -1148,6 +1166,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         hq_groups=shape["hq_groups"], hq_buckets=shape["hq_buckets"],
         rows_out=len(labels), window_form=window_form,
         merge_form=merge_form,
+        band_served_pct=count_band_served(windows),
         # the widest leaf's samples a lane and rows a lane, and the
         # steps' bucket
         n_cap=max((ent[3]["n_cap"] for ent in leaf_plan.values()),

@@ -241,6 +241,6 @@ def test_manifest_has_the_cell_and_its_metrics():
         "device_wait_ms", "d2h_ms", "device_queue_depth", "reply_ms",
         "engine_cpu_ms", "panel_p95_ms", "program_ms", "program_hbm_peak_mb",
         "program_roofline_pct", "temporal_share_pct", "samples_per_lane",
-        "rows_per_lane", "merge_share_pct")}
+        "rows_per_lane", "merge_share_pct", "band_served_pct")}
     assert MIX["kind"] == "query_longrange_loop" and MIX["clients"] == 2
     assert MIX["gather_min_n_cap"] == 12289

@@ -502,6 +502,8 @@ def test_fused_topk_record_carries_its_phases_and_shape(engines):
     """A top-k panel is one fused program: its record has the planning
     stamped apart from the call, the staging inside it (numpy leaves:
     h2d_s 0.0), and the shape the program ran at."""
+    from m3_tpu.models.query_pipeline import band_width as qp_band_width
+
     host, dev = engines
     expr = "topk(2, sum by (job)(rate(http_req[5m])))"
     slowlog.log().clear()
@@ -528,6 +530,10 @@ def test_fused_topk_record_carries_its_phases_and_shape(engines):
     assert 2 <= rec["rows_out"] <= 3
     assert rec["window_form"] == "select" and rec["rows"] >= 6
     assert rec["merge_form"] == "rotate"     # the one leaf's, as words
+    # the leaf's windowed stage said how its one lane chunk was searched:
+    # at 64 lanes x 256 samples, 41 steps, no band is taken
+    assert qp_band_width(rec["n_cap"], rec["steps_pad"]) is None
+    assert rec["band_served_pct"] == 0.0
     # the decode scan of the one leaf, from its buckets alone (the fused
     # planner's: 256 samples a row, 128 words): its window's refills
     from m3_tpu.models import query_pipeline as qp

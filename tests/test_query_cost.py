@@ -249,16 +249,19 @@ def test_mutable_range_is_served_by_the_device_tier(db):
     assert (rec["rows"], rec["open_rows"]) == (16, 4)
 
 
+def _forget_per_node_programs():
+    """Programs traced under another constant of query_pipeline."""
+    qp.device_temporal_pipeline.__wrapped__.clear_cache()
+    qp.device_grouped_pipeline.__wrapped__.clear_cache()
+
+
 def _form_is_counted_and_recorded(db, monkeypatch, expr, counter, field,
                                   forms, form, constant, value):
     """A device-served `expr` counts `form` of `forms` once under
     `counter`, its record says the same word under `field`, and its
     answer is the host's; `constant` of query_pipeline patched to
     `value` (unless None) to put the call on the other form."""
-    def forget():      # programs traced under another constant
-        qp.device_temporal_pipeline.__wrapped__.clear_cache()
-        qp.device_grouped_pipeline.__wrapped__.clear_cache()
-
+    forget = _forget_per_node_programs
     if value is not None:
         monkeypatch.setattr(qp, constant, value)
         forget()
@@ -312,6 +315,70 @@ def test_merge_form_is_counted_and_recorded(db, monkeypatch, expr, form,
     _form_is_counted_and_recorded(
         db, monkeypatch, expr, "m3_device_merge_form_total", "merge_form",
         ("rotate", "window"), form, "_WINDOW_MIN_ROWS", min_rows)
+
+
+@pytest.mark.parametrize("expr,band,served", [
+    ("sum by (dc) (rate(sealed[2m]))", None, "full"),
+    ("sum by (dc) (rate(sealed[2m]))", (8, 8, 16), "band"),
+    ("max_over_time(sealed[2m])", (8, 8, 16), "band"),
+    ("sum by (dc) (rate(sealed[12m]))", (8, 8, 16), "full"),
+], ids=["no_band_at_this_shape", "in_the_band", "bounds_alone_in_the_band",
+        "a_range_past_the_span"])
+def test_band_is_counted_and_recorded(db, monkeypatch, expr, band, served):
+    """A per-node call counts its windowed stage's lane chunks by how
+    they were searched, as the program said (a band of the lane, or its
+    full width: a shape the band is not taken at, or windows that do not
+    fit their span: 12 m of samples 30 s apart where a span holds 32),
+    and the record carries the band's share.  The answer does not
+    depend on it."""
+    forget = _forget_per_node_programs
+    if band is not None:
+        for name, value in zip(("_BAND_STEPS", "_BAND_TILE", "_BAND_SLACK"),
+                               band):
+            monkeypatch.setattr(qp, name, value)
+        forget()
+    counters = {w: instrument.counter("m3_device_window_band_total",
+                                      served=w) for w in ("band", "full")}
+    before = {w: c.value for w, c in counters.items()}
+    eng = Engine(db, "default", device_serving=True)
+    _, mat = eng.query_range(expr, START, END, STEP)
+    rec = _record_of(expr)
+    assert rec["device_serving"] is True and rec["lane_chunks"] == 1
+    assert (qp.band_width(rec["n_cap"], rec["steps_pad"]) is None) == (
+        band is None)
+    assert {w: c.value - before[w] for w, c in counters.items()} == {
+        w: int(w == served) for w in counters}
+    assert rec["band_served_pct"] == (100.0 if served == "band" else 0.0)
+    _, host = Engine(db, "default", device_serving=False).query_range(
+        expr, START, END, STEP)
+    np.testing.assert_allclose(np.asarray(mat.values),
+                               np.asarray(host.values), rtol=1e-9,
+                               equal_nan=True)
+    if band is not None:
+        forget()
+
+
+def test_a_stand_ins_plain_pair_counts_no_band(db, monkeypatch):
+    """A program stood in on the module (a benchmark's control) may hand
+    back the plain (out, error) pair: the call is served, nothing is
+    counted and the record has no share."""
+    real = qp.device_grouped_pipeline
+
+    def plain(*args, **kwargs):
+        out, err = real(*args, **kwargs)
+        return out, err
+
+    monkeypatch.setattr(qp, "device_grouped_pipeline", plain)
+    counters = [instrument.counter("m3_device_window_band_total", served=w)
+                for w in ("band", "full")]
+    before = [c.value for c in counters]
+    expr = "sum by (dc) (rate(sealed[3m]))"
+    Engine(db, "default", device_serving=True).query_range(
+        expr, START, END, STEP)
+    rec = _record_of(expr)
+    assert rec["device_serving"] is True
+    assert "band_served_pct" not in rec
+    assert [c.value for c in counters] == before
 
 
 def test_http_query_leaves_frontend_in_its_record(db):

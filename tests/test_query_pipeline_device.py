@@ -832,7 +832,7 @@ def test_rate_family_through_the_temporal_form_is_rate_device(fn):
     times, values, _ = jax.jit(
         _decode_merge, static_argnums=(3, 4, 5, 6))(
             *args, n_lanes, blocks_per * dp, dp, SEC)
-    want = jax.jit(_rate_device, static_argnames=("is_counter", "is_rate"))(
+    want, _ = jax.jit(_rate_device, static_argnames=("is_counter", "is_rate"))(
         times, values, steps, 5 * 60 * SEC,
         is_counter=fn != "delta", is_rate=fn == "rate")
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
@@ -1169,7 +1169,7 @@ def test_window_bounds_match_searchsorted(case):
     if case == "steps_on_samples":          # both window edges inclusive
         steps = times[2, 10:10 + S].copy()
         range_nanos = int(steps[3] - times[2, 4])
-    starts, left, right = jax.jit(_window_bounds_device)(
+    starts, left, right, _ = jax.jit(_window_bounds_device)(
         jnp.asarray(times), jnp.asarray(steps), jnp.int64(range_nanos))
     np.testing.assert_array_equal(np.asarray(starts),
                                   steps - range_nanos - 1)
@@ -1180,6 +1180,133 @@ def test_window_bounds_match_searchsorted(case):
         np.testing.assert_array_equal(
             np.asarray(right)[lane],
             np.searchsorted(times[lane], steps, side="right"))
+
+
+@pytest.fixture
+def small_band(monkeypatch):
+    """The band's constants at a test's size: blocks of 8 steps, tiles
+    of 8 cells, 8 cells of slack; at [*, 256] x 40 a span of 72."""
+    from m3_tpu.models import query_pipeline as qp
+
+    for name in ("_BAND_STEPS", "_BAND_TILE", "_BAND_SLACK"):
+        monkeypatch.setattr(qp, name, 8)
+    assert qp.band_width(256, 40) == 72
+    return qp
+
+
+def _bounds_and_windows(qp, times, steps, range_nanos):
+    """-> the bounds as numpy and [the lane chunks searched at the full
+    width, all of them]."""
+    starts, left, right, full = jax.jit(qp._window_bounds_device)(
+        jnp.asarray(times), jnp.asarray(steps), jnp.int64(range_nanos))
+    return (np.asarray(starts), np.asarray(left), np.asarray(right),
+            [int(full), 1])
+
+
+def _band_case(case, seed=13):
+    """-> (times [7, 256], steps [40], range_nanos, the lane chunks at
+    the full width) of one batch that holds `case`: lanes at a 10 s
+    cadence, steps 20 s apart (a block of 8 reaches 16 samples), a
+    range of 50 s."""
+    rng = np.random.default_rng(seed)
+    L, N, S = 7, 256, 40
+    range_nanos, full = 50 * SEC, 0
+    gaps = np.full((L, N), 10)
+    gaps[:, 0] = rng.integers(0, 10, L)
+    if case == "duplicates":
+        gaps = rng.integers(0, 3, (L, N)) * 10
+    times = T0 + np.cumsum(gaps, axis=1) * SEC
+    steps = T0 + (np.arange(S, dtype=np.int64) * 20 + 600) * SEC
+    if case == "inf_padding":
+        fill = np.arange(N)[None, :] >= rng.integers(0, N + 1, (L, 1))
+        fill[0], fill[1] = True, False      # an empty lane, a full one
+        times = np.where(fill, _INF, times)
+    if case == "padded_steps":              # the engine repeats the last
+        steps[S - 11:] = steps[S - 12]
+    if case == "steps_on_samples":          # both window edges inclusive
+        steps = times[2, 70:70 + 2 * S:2].copy()
+        range_nanos = int(steps[3] - times[2, 72])
+    if case == "late_lane":                 # began inside the range
+        times[3] = np.where(np.arange(N) < 150, times[3] + 900 * SEC, _INF)
+    if case == "ended_lane":                # before the last step block
+        times[4, 100:] = _INF
+        assert times[4, 99] < steps[-8] - range_nanos
+    if case == "skewed_lanes":              # by more than a span's width
+        times[1] = np.where(np.arange(N) < 130, times[1] + 1200 * SEC, _INF)
+        times[5] -= 1100 * SEC
+        assert abs(np.searchsorted(times[1], steps[-1])
+                   - np.searchsorted(times[5], steps[-1])) > 72
+    if case == "burst_lane":                # 1 s apart: 160 samples a block
+        times[2] = T0 + (600 + np.arange(N)) * SEC
+        full = 1
+    if case == "long_range":                # longer than a block of steps
+        range_nanos = 200 * SEC
+        assert range_nanos > steps[7] - steps[0]
+    return times, steps, range_nanos, full
+
+
+@pytest.mark.parametrize("case", [
+    "duplicates", "inf_padding", "padded_steps", "steps_on_samples",
+    "late_lane", "ended_lane", "skewed_lanes", "burst_lane", "long_range"])
+def test_window_bounds_through_the_band_match_searchsorted(case, small_band):
+    """The bounds counted inside a (lane, step block)'s span are
+    np.searchsorted's, lane by lane: a lane's span is its own (a lane
+    that began late, one that ended early and lanes far apart share a
+    chunk in the band), and a lane that does not fit its span sends the
+    chunk to the full width, which says so."""
+    times, steps, range_nanos, full = _band_case(case)
+    starts, left, right, windows = _bounds_and_windows(
+        small_band, times, steps, range_nanos)
+    assert windows == [full, 1]
+    np.testing.assert_array_equal(starts, steps - range_nanos - 1)
+    for lane in range(len(times)):
+        np.testing.assert_array_equal(left[lane], np.searchsorted(
+            times[lane], steps - range_nanos - 1, side="right"))
+        np.testing.assert_array_equal(right[lane], np.searchsorted(
+            times[lane], steps, side="right"))
+
+
+def test_window_bounds_at_a_cells_width_take_the_band():
+    """The constants as they stand, at dash-sealed's lane width and
+    steps: a regular fleet, a third of it started late, stays in the
+    band; one lane scraped every second sends its chunk to the full
+    width; either way np.searchsorted's integers."""
+    from m3_tpu.models import query_pipeline as qp
+
+    L, N, S = 8, 1536, 256
+    assert qp.band_width(N, S) == 640
+    rng = np.random.default_rng(17)
+    times = T0 + (np.arange(N)[None, :] * 10 + rng.integers(0, 9, (L, 1))) * SEC
+    times = np.where(np.arange(N) < 1470, times, _INF)
+    times[2::3] = np.where(np.arange(N) < 900, times[2::3] + 5700 * SEC, _INF)
+    steps = T0 + (300 + np.arange(S, dtype=np.int64) * 60) * SEC
+    for burst, full in ((False, 0), (True, 1)):
+        if burst:
+            times[1] = T0 + (3000 + np.arange(N)) * SEC
+        _, left, right, windows = _bounds_and_windows(qp, times, steps,
+                                                      300 * SEC)
+        assert windows == [full, 1]
+        for lane in range(L):
+            np.testing.assert_array_equal(left[lane], np.searchsorted(
+                times[lane], steps - 300 * SEC - 1, side="right"))
+            np.testing.assert_array_equal(right[lane], np.searchsorted(
+                times[lane], steps, side="right"))
+
+
+@pytest.mark.parametrize("n_cap,n_steps,width", [
+    (1536, 256, 640), (1920, 256, 768), (2048, 256, 768),
+    (15872, 1344, 1024), (1536, 1344, 384), (1536, 8, None),
+    (40, 9, None), (32, 4, None), (1500, 256, None)],
+    ids=["dash-sealed_fanout-fleet", "dash-live", "dash-topk_dash-p99",
+         "dash-2d", "a_short_step", "few_steps", "a_stage_test", "tiny",
+         "no_whole_tiles"])
+def test_band_width_at_the_cells_shapes(n_cap, n_steps, width):
+    """The span a block of steps is searched in, from the static
+    buckets alone: whole tiles of 128, under half the lane or the full
+    width is the only body (the tests' shapes: the parent's program)."""
+    from m3_tpu.models.query_pipeline import band_width
+
+    assert band_width(n_cap, n_steps) == width
 
 
 def _window_case(case, N, seed=23):
@@ -1244,7 +1371,7 @@ def test_take_at_is_take_along_axis(case, N):
 
     times, values, steps, range_nanos = _window_case(case, N)
     assert window_form(N) == ("gather" if N > _SELECT_MAX_N else "select")
-    _, left, right = jax.jit(_window_bounds_device)(
+    _, left, right, _ = jax.jit(_window_bounds_device)(
         jnp.asarray(times), jnp.asarray(steps), jnp.int64(range_nanos))
     cum = np.cumsum(np.nan_to_num(values, posinf=7.0, neginf=-7.0) % 1e3,
                     axis=1)
@@ -1282,17 +1409,86 @@ def test_rate_family_by_selection_equals_by_gather(case, fn, monkeypatch):
             jnp.int64(range_nanos))
     assert qp.window_form(48) == "select"
     selected = np.asarray(jax.jit(functools.partial(
-        qp._temporal_eval, fn))(*args))
+        qp._temporal_eval, fn))(*args)[0])
     monkeypatch.setattr(qp, "_SELECT_MAX_N", 0)
     assert qp.window_form(48) == "gather"
     gathered = np.asarray(jax.jit(functools.partial(
-        qp._temporal_eval, fn))(*args))
+        qp._temporal_eval, fn))(*args)[0])
     assert np.array_equal(selected, gathered, equal_nan=True)
     real = ~np.isnan(selected)          # a computed NaN's sign is no one's
     assert np.array_equal(np.signbit(selected[real]),
                           np.signbit(gathered[real]))
     if case in ("duplicates", "counter_resets", "steps_on_samples"):
         assert np.isfinite(selected).any()
+
+
+def _rate_and_windows(qp, fn, args, band=True):
+    """The windowed `fn` over `args` and what its stage said of its
+    lane chunks: [those searched at the full width, all of them]."""
+    out, windows = jax.jit(functools.partial(
+        qp._temporal_eval, fn, band=band))(*args)
+    return np.asarray(out), [int(n) for n in windows]
+
+
+@pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
+@pytest.mark.parametrize("chunks", [1, 3], ids=["one_chunk", "chunked"])
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+def test_rate_family_through_the_band_equals_the_full_width(
+        case, chunks, fn, monkeypatch):
+    """The band finds the same bounds and selects the same elements
+    from fewer cells: rate / increase / delta through it and through
+    the full-width body (the parent's program) agree bit for bit, NaN
+    for NaN, in one chunk of lanes and in three; the windows' reads are
+    selections either way."""
+    from m3_tpu.models import query_pipeline as qp
+
+    N = 96
+    times, values, steps, range_nanos = _window_case(case, N)
+    if chunks > 1:
+        monkeypatch.setattr(qp, "_MERGE_LANES", 2)
+        assert qp.lane_chunks(len(times)) == chunks
+    monkeypatch.setattr(qp, "_BAND_STEPS", 3)
+    monkeypatch.setattr(qp, "_BAND_TILE", 4)
+    monkeypatch.setattr(qp, "_BAND_SLACK", 4)
+    assert qp.band_width(N, len(steps)) == 40 and qp.window_form(N) == "select"
+    args = (jnp.asarray(times), jnp.asarray(values), jnp.asarray(steps),
+            jnp.int64(range_nanos))
+    banded, windows = _rate_and_windows(qp, fn, args)
+    assert windows == [0, chunks], "the band was not taken"
+    full, windows = _rate_and_windows(qp, fn, args, band=False)
+    assert windows == [chunks, chunks]
+    assert np.array_equal(banded, full, equal_nan=True)
+    real = ~np.isnan(banded)            # a computed NaN's sign is no one's
+    assert np.array_equal(banded[real].view(np.uint64),
+                          full[real].view(np.uint64))
+    if case in ("duplicates", "counter_resets", "steps_on_samples"):
+        assert np.isfinite(banded).any()
+
+
+def test_a_burst_lane_sends_its_chunk_alone_to_the_full_width(monkeypatch):
+    """Three chunks of lanes, a lane scraped ten times as often in the
+    second: that chunk is served by the full-width body and the others
+    by the band, the program says 1 of 3, and every lane's answer is
+    the full width's bit for bit."""
+    from m3_tpu.models import query_pipeline as qp
+
+    rng = np.random.default_rng(31)
+    L, N, S = 24, 256, 40
+    times = T0 + (np.arange(N)[None, :] * 10 + rng.integers(0, 9, (L, 1))) * SEC
+    times[11] = T0 + (600 + np.arange(N)) * SEC
+    values = np.cumsum(rng.integers(0, 50, (L, N)), axis=1).astype(float)
+    values[::5, 100:] -= values[::5, 100:101]     # counter resets
+    steps = T0 + (np.arange(S, dtype=np.int64) * 20 + 600) * SEC
+    args = (jnp.asarray(times), jnp.asarray(values), jnp.asarray(steps),
+            jnp.int64(50 * SEC))
+    for name in ("_BAND_STEPS", "_BAND_TILE", "_BAND_SLACK", "_MERGE_LANES"):
+        monkeypatch.setattr(qp, name, 8)
+    banded, windows = _rate_and_windows(qp, "rate", args)
+    assert windows == [1, 3]
+    full, windows = _rate_and_windows(qp, "rate", args, band=False)
+    assert windows == [3, 3]
+    assert np.array_equal(banded.view(np.uint64), full.view(np.uint64))
+    assert np.isfinite(banded).any(axis=1).all()
 
 
 @pytest.mark.parametrize("fn", ["rate", "increase", "delta"])
@@ -1315,28 +1511,63 @@ def test_rate_lanes_at_a_time_equals_one_chunk(fn, lanes, at_a_time,
     args = (jnp.asarray(times), jnp.asarray(values), jnp.asarray(steps),
             jnp.int64(120 * SEC))
     whole = np.asarray(jax.jit(functools.partial(
-        qp._temporal_eval, fn))(*args))
+        qp._temporal_eval, fn))(*args)[0])
     monkeypatch.setattr(qp, "_MERGE_LANES", at_a_time)
     lowered = jax.jit(functools.partial(qp._temporal_eval, fn)).lower(*args)
     assert "stablehlo.while" in lowered.as_text()
-    chunked = np.asarray(lowered.compile()(*args))
+    chunked = np.asarray(lowered.compile()(*args)[0])
     assert np.array_equal(whole, chunked, equal_nan=True)
     assert np.isfinite(whole).any(axis=1).all()
 
 
-def _walk_jaxpr(jaxpr, scope=""):
-    """(primitive, named-scope path) of every equation, inner jits,
+def _walk_eqns(jaxpr, scope=""):
+    """(equation, named-scope path) of every equation, inner jits,
     loops and branches included."""
     for eqn in jaxpr.eqns:
         path = f"{scope}/{eqn.source_info.name_stack}"
-        yield eqn.primitive.name, path
+        yield eqn, path
         stack = list(eqn.params.values())
         while stack:
             v = stack.pop()
             if isinstance(v, (tuple, list)):
                 stack.extend(v)
             elif hasattr(v, "eqns") or hasattr(v, "jaxpr"):
-                yield from _walk_jaxpr(getattr(v, "jaxpr", v), path)
+                yield from _walk_eqns(getattr(v, "jaxpr", v), path)
+
+
+def _walk_jaxpr(jaxpr, scope=""):
+    """(primitive, named-scope path) of every equation."""
+    for eqn, path in _walk_eqns(jaxpr, scope):
+        yield eqn.primitive.name, path
+
+
+def _stage_addressing(jaxpr):
+    """What the windowed stage of a program addresses by index, having
+    checked its shape: -> (its gathers of ELEMENTS, every slice one
+    cell: what this chip runs a cell at a time; its conds).  A gather
+    whose slices are wider (contiguous spans) would be another thing,
+    and the stage holds none: a band's spans are selected as whole
+    tiles (_spans_device).  A cond is a band's: its two branches both
+    count bounds, one inside the spans and one over the lane (the
+    parent's body, kept under it for the lanes that do not fit)."""
+    stage = [(e, s) for e, s in _walk_eqns(jaxpr) if "m3.temporal" in s]
+    gathers = [(e, s) for e, s in stage if e.primitive.name == "gather"]
+    assert all(set(e.params["slice_sizes"]) == {1} for e, _ in gathers)
+    conds = [e for e, _ in stage if e.primitive.name == "cond"]
+    for cond in conds:
+        assert len(cond.params["branches"]) == 2
+        widths = []
+        for branch in cond.params["branches"]:
+            ops = list(_walk_eqns(branch.jaxpr))
+            counts = [e for e, s in ops if e.primitive.name == "reduce_sum"
+                      and "bounds" in s]
+            assert len(counts) == 2, "a branch counts both bounds"
+            # the sample axis a count reduces over: a span's, the lane's
+            widths.append(counts[0].invars[0].aval.shape[-1])
+            assert not [e for e, _ in ops
+                        if e.primitive.name in ("while", "scan", "cond")]
+        assert min(widths) * 2 <= max(widths), widths
+    return gathers, conds
 
 
 # M, W, L, S, n_cap, n_dp, n_groups of the grouped program: the size the
@@ -1397,7 +1628,8 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     past WIN_WORDS words a row (PR 39) and fills it without an indexed
     access a row; a later edit that brings one back fails here, on the
     CPU, at a dashboard row's shape and at the whole fleet's."""
-    from m3_tpu.models.query_pipeline import (device_grouped_pipeline,
+    from m3_tpu.models.query_pipeline import (band_width,
+                                              device_grouped_pipeline,
                                               lane_chunks, window_form)
 
     M, W, L, S, n_cap, n_dp, n_groups = _STRUCTURE_SHAPES[shape]
@@ -1416,11 +1648,19 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     # _SELECT_MAX_N samples a lane; past it (the two-day panel) by six
     # gathers, all of them under m3.temporal/take, and the bounds under
     # m3.temporal/bounds hold none
-    in_stage = [s for p, s in ops if p == "gather" and "m3.temporal" in s]
+    jaxpr = jax.make_jaxpr(functools.partial(fn, **kw))(*args).jaxpr
+    in_stage, conds = _stage_addressing(jaxpr)
     assert len(in_stage) == (6 if gathers else 0)
-    assert all("m3.temporal/take" in s for s in in_stage), in_stage
-    bounds = [p for p, s in ops if "m3.temporal/bounds" in s]
+    assert all("m3.temporal/take" in s for _, s in in_stage), in_stage
+    bounds = [p for p, s in ops if "m3.temporal" in s and "bounds" in s]
     assert "reduce_sum" in bounds and "gather" not in bounds
+    # at a cell's shape the bounds are searched in a band of the lane,
+    # the full-width body under the band's cond (once a chunk: inside
+    # the chunk loop where there is one); the stage test's shape is too
+    # narrow for a band and runs the parent's program
+    banded = band_width(n_cap, S) is not None
+    assert banded == (shape != "tiny")
+    assert len(conds) == banded
     # the loops: the decode scan's (the refills and a window's steps at
     # the cells' 256 words a row, the steps alone at the tiny shape's 8);
     # the merge's lane -> first row search, its chunks and a chunk's
@@ -1445,6 +1685,7 @@ def test_grouped_program_has_no_per_element_addressing(shape):
         kw["range_nanos"])
     text = stage.as_text()
     assert text.count("stablehlo.while") == chunked
+    assert text.count("stablehlo.case") == banded
     assert "stablehlo.scatter" not in text
     # (take_along_axis lowers to one function a dtype, called six times)
     assert ("stablehlo.gather" in text) == gathers
@@ -1464,7 +1705,7 @@ def test_grouped_program_has_no_per_element_addressing(shape):
     assert "m3.temporal/while/body" in named or not chunked
 
 
-def _rate_at(n_cap, L=8, S=4):
+def _rate_at(n_cap, L=8, S=256):
     """The windowed rate alone and abstract arguments at n_cap samples
     a lane."""
     from m3_tpu.models.query_pipeline import _temporal_eval
@@ -1481,27 +1722,40 @@ def test_rate_at_the_cells_width_has_no_per_element_addressing(n_cap):
     were 16.0 of the program's 21.2 ms): at the benchmark cells' lane
     width the windowed rate lowers without a gather, and still without
     a loop or a scatter."""
-    from m3_tpu.models.query_pipeline import window_form
+    from m3_tpu.models.query_pipeline import band_width, window_form
 
     assert window_form(n_cap) == "select"
     rate, args = _rate_at(n_cap)
+    assert band_width(n_cap, 256) is not None
     text = jax.jit(rate).lower(*args).as_text()
     for op in ("stablehlo.gather", "stablehlo.dynamic_gather",
                "stablehlo.while", "stablehlo.scatter"):
         assert op not in text, op
+    # the band and, for the lanes that do not fit it, the parent's body
+    assert text.count("stablehlo.case") == 1
+    gathers, conds = _stage_addressing(jax.make_jaxpr(rate)(*args).jaxpr)
+    assert not gathers and len(conds) == 1
 
 
 def test_rate_above_the_constant_keeps_its_six_gathers():
     """Selection costs L x N x S and the gather L x S: past
     _SELECT_MAX_N samples a lane the six reads are gathers again."""
-    from m3_tpu.models.query_pipeline import _SELECT_MAX_N, window_form
+    from m3_tpu.models.query_pipeline import (_SELECT_MAX_N, band_width,
+                                              window_form)
 
     n_cap = _SELECT_MAX_N + 128
     assert window_form(n_cap) == "gather"
-    rate, args = _rate_at(n_cap)
-    ops = [p for p, _ in _walk_jaxpr(jax.make_jaxpr(rate)(*args).jaxpr)]
+    assert band_width(n_cap, 1344) == 896
+    rate, args = _rate_at(n_cap, S=1344)
+    jaxpr = jax.make_jaxpr(rate)(*args).jaxpr
+    ops = [p for p, _ in _walk_jaxpr(jaxpr)]
     assert ops.count("gather") == 6
     assert not {"while", "scan", "scatter", "scatter-add"} & set(ops)
+    # the reads stay gathers of elements, outside the cond under which
+    # the long lane's BOUNDS alone are searched in the band
+    gathers, conds = _stage_addressing(jaxpr)
+    assert len(gathers) == 6 and len(conds) == 1
+    assert all("take" in s and "cond" not in s for _, s in gathers)
 
 
 def test_open_rows_keep_the_grouped_programs_structure():

@@ -280,7 +280,16 @@ def test_grouped_pipeline_compiles_at_the_fleet_cells_shape(one_chip):
         sds((M,), np.int32), sds((M,), np.int64), sds((S,), np.int64),
         sds((L,), np.int64), n_lanes=L, n_groups=32, n_cap=1536, n_dp=768,
         range_nanos=sds((), np.int64))
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1 << 30
+    # a chunk's windows are searched in a band of the lane (PR 48: a
+    # span of 640 of its 1,536 samples a block of 64 steps), the
+    # full-width body under the band's conditional inside the chunk
+    # loop; the spans are a chunk's too, and the program's peak stays
+    # within 5% of the 642.5 MB it had without them
+    assert qp.band_width(1536, S) == 640
+    assert "conditional(" in compiled.as_text()
+    assert getattr(memory, "peak_memory_in_bytes", 0) < 1.05 * 642.5e6
 
 
 def test_grouped_pipeline_compiles_at_the_two_day_cells_shape(one_chip):
@@ -307,6 +316,13 @@ def test_grouped_pipeline_compiles_at_the_two_day_cells_shape(one_chip):
         range_nanos=sds((), np.int64))
     assert time.perf_counter() - t0 < 60.0
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the long lane's bounds go through the band (PR 48: a span of 1,024
+    # of 15,872 samples), the parent's two full-width counts under its
+    # conditional; the reads of the windows' ends are still the gathers
+    # the cell's check holds its records to
+    assert qp.band_width(n_cap, S) == 1024
+    text = compiled.as_text()
+    assert "conditional(" in text and "gather(" in text
 
 
 def test_fused_hq_pipeline_compiles_at_the_latency_cells_shape(one_chip):
