@@ -2367,7 +2367,8 @@ class Engine:
         ``total_s`` minus the phases that tile it, so those and
         ``self_s`` sum to ``total_s``.  ``frontend_s`` is the HTTP
         front end's, added by query/http.py once the reply is
-        written; it lies outside ``total_s``.
+        written; it lies outside ``total_s``, and ``render_s`` (a range
+        query's matrix to its JSON bytes) inside ``frontend_s``.
 
         A clocked query's record (``QueryCost.cpu``) also carries
         ``cpu``, the thread's CPU seconds under the same keys
@@ -2388,7 +2389,7 @@ class Engine:
                        for k in self._TILING_PHASES + ("h2d_s", "d2h_s")}
                 out["self_s"] = total - sum(
                     out[k] for k in self._TILING_PHASES)
-                out["frontend_s"] = 0.0
+                out["frontend_s"] = out["render_s"] = 0.0
                 out["total_s"] = total
                 return out
 

@@ -38,6 +38,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import subprocess
 import threading
 import time
 import urllib.parse
@@ -59,7 +60,7 @@ from m3_tpu.storage.database import (ColdWriteError, Database,
 from m3_tpu.query import slowlog
 from m3_tpu import attribution
 from m3_tpu.resilience.admission import AdmissionRejected
-from m3_tpu.utils import clock, instrument, snappy, tracing
+from m3_tpu.utils import clock, instrument, native, snappy, tracing
 
 # accepted remote-write request sizes in samples: the group-commit
 # amortization upstream (m3_commitlog_group_batch_writes) only pays
@@ -123,6 +124,36 @@ def _matrix_json(step_times, mat):
                 }
             )
     return {"resultType": "matrix", "result": result}
+
+
+_REPLY_HEAD = b'{"status": "success", "data": '
+
+
+def _matrix_reply(step_times, mat, warnings=None) -> tuple[bytes, str]:
+    """A range query's success reply, ``{"status": "success", "data":
+    <_matrix_json's document>[, "warnings": warnings]}``, as the bytes
+    ``json.dumps`` gives it, and who rendered them: ``native``
+    (native/json_wire.cc, in one call outside the interpreter lock) or
+    ``python`` (``_matrix_json``: where the library cannot be built,
+    or the values are not the [rows, steps] block the library reads)."""
+    steps = np.ascontiguousarray(step_times, dtype=np.int64)
+    values = np.ascontiguousarray(mat.values, dtype=np.float64)
+    if values.shape == (len(mat.labels), len(steps)):
+        metrics = [
+            json.dumps({k.decode(): v.decode()
+                        for k, v in labels.items()}).encode()
+            for labels in mat.labels]
+        tail = (b"}" if warnings is None else
+                b', "warnings": %s}' % json.dumps(warnings).encode())
+        try:
+            return native.render_matrix_json_native(
+                _REPLY_HEAD, steps, values, metrics, tail), "native"
+        except (OSError, subprocess.CalledProcessError):
+            pass  # no compiler here: utils/native.load keeps the failure
+    body = {"status": "success", "data": _matrix_json(step_times, mat)}
+    if warnings is not None:
+        body["warnings"] = warnings
+    return json.dumps(body).encode(), "python"
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -262,6 +293,7 @@ class _Handler(BaseHTTPRequestHandler):
         # request runs: drop what an earlier request left on this thread
         slowlog.take_last_record()
         front: dict = {}
+        self._front_stamps = front
         # count on ENTRY: a client that saw this request's reply must
         # see it in a subsequent /metrics scrape (a finally-increment
         # races the next request on another server thread)
@@ -306,6 +338,8 @@ class _Handler(BaseHTTPRequestHandler):
                         if rec is not None:
                             phases = rec["phases"]
                             phases["frontend_s"] = front["frontend_s"]
+                            # a matrix's render, inside frontend_s
+                            phases["render_s"] = front.get("render_s", 0.0)
                             # what the front end waited for (a full
                             # collection under the render) beside
                             # what the engine did
@@ -329,6 +363,18 @@ class _Handler(BaseHTTPRequestHandler):
             # before the engine decided)
             rec = slowlog.last_record()
             self._front.start(None if rec is None else rec.get("cpu"))
+
+    def _reply_matrix(self, step_times, mat, warnings=None, headers=None):
+        """Send a range query's matrix, rendered under a stamp of its
+        own (``render_s``, inside ``frontend_s``; clocked where the
+        query is); the query's record says who rendered it."""
+        rec = slowlog.last_record() or {}
+        with tracing.phase("render", self._front_stamps, rec.get("cpu")):
+            payload, form = _matrix_reply(step_times, mat, warnings)
+        # the share the native library wrote: all or nothing a reply
+        rec["reply_native_pct"] = 100.0 * (form == "native")
+        instrument.counter("m3_http_reply_render_total", form=form).inc()
+        self._reply(200, payload, headers=headers)
 
     # set per-request in _route; the active context echoes back to the
     # caller in the response's traceparent header (see _reply)
@@ -1550,13 +1596,13 @@ class _Handler(BaseHTTPRequestHandler):
                            or "unknown degradation"),
                         error_type="query-limit-exceeded")
             return
-        body = {"status": "success",
-                "data": _matrix_json(step_times, mat)}
-        headers = None
         if meta.limited():
-            body["warnings"] = meta.warning_strings()
-            headers = {"M3-Results-Limited": meta.header_value() or "true"}
-        self._reply(200, body, headers=headers)
+            self._reply_matrix(
+                step_times, mat, warnings=meta.warning_strings(),
+                headers={"M3-Results-Limited":
+                         meta.header_value() or "true"})
+            return
+        self._reply_matrix(step_times, mat)
 
     def _engine_for(self, p):
         """Resolve the engine for a query request.  A ``namespace``
@@ -1634,8 +1680,7 @@ class _Handler(BaseHTTPRequestHandler):
         if with_meta:
             self._degraded_reply(step_times, mat, meta, limits)
             return
-        self._reply(200, {"status": "success",
-                          "data": _matrix_json(step_times, mat)})
+        self._reply_matrix(step_times, mat)
 
     def _query_range(self):
         self._range_query("query_range_with_meta", with_meta=True)

@@ -10,8 +10,10 @@ math lives in JAX/XLA.
 from __future__ import annotations
 
 import ctypes
+import os
 import pathlib
 import subprocess
+import threading
 
 import numpy as np
 
@@ -34,14 +36,20 @@ def load(name: str) -> ctypes.CDLL:
     so = _NATIVE_DIR / f"lib{name}.so"
     try:
         if not so.exists() or so.stat().st_mtime < src.stat().st_mtime:
+            # built under a name of this thread's own and moved into
+            # place whole: server threads and test workers that find
+            # the library missing at once each load a finished file
+            tmp = so.with_name(
+                f"lib{name}.{os.getpid()}.{threading.get_ident()}.so")
             subprocess.run(
                 # no -march=native: the object is cached beside the
                 # source and a copied tree may run on another CPU
                 ["g++", "-O2", "-shared", "-fPIC",
-                 "-pthread", "-o", str(so), str(src)],
+                 "-pthread", "-o", str(tmp), str(src)],
                 check=True,
                 capture_output=True,
             )
+            os.replace(tmp, so)
         lib = ctypes.CDLL(str(so))
     except Exception as exc:
         _LIB_CACHE[name] = exc
@@ -626,3 +634,38 @@ def decode_influx_native(data: bytes, mult: int, now_nanos: int):
     See _decode_text_lines for the return shape."""
     return _decode_text_lines("influx_decode_lines", data,
                               (now_nanos, mult))
+
+
+def render_matrix_json_native(
+    head: bytes, step_times: np.ndarray, values: np.ndarray,
+    metrics: list[bytes], tail: bytes,
+) -> bytes:
+    """``head`` + the JSON of a query_range matrix + ``tail``
+    (native/json_wire.cc): ``step_times`` int64[S] nanos, ``values``
+    float64[R, S] with NaN for no sample, ``metrics`` each row's
+    ``"metric"`` object already rendered.  The bytes are json.dumps'
+    of query/http.py ``_matrix_json``'s document."""
+    lib = load("json_wire")
+    fn = lib.matrix_json_render
+    if not getattr(fn, "_typed", False):
+        i64p = np.ctypeslib.ndpointer(np.int64, flags="C")
+        fn.restype = ctypes.c_int64
+        fn.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,
+            i64p, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.float64, flags="C"), ctypes.c_int64,
+            ctypes.c_char_p, i64p,
+            ctypes.c_char_p, ctypes.c_int64,
+            np.ctypeslib.ndpointer(np.uint8), ctypes.c_int64,
+        ]
+        fn._typed = True
+    n_rows, n_steps = values.shape
+    blob, offsets = blob_offsets(metrics)
+    # the library's own worst case: 56 bytes a point, 34 a row
+    out = np.empty(len(head) + len(tail) + len(blob) + 64
+                   + n_rows * (34 + 56 * n_steps), dtype=np.uint8)
+    n = fn(head, len(head), step_times, n_steps, values, n_rows,
+           blob, offsets, tail, len(tail), out, len(out))
+    if n < 0:
+        raise ValueError("matrix reply exceeds the render's buffer bound")
+    return out[:n].tobytes()

@@ -87,13 +87,14 @@ ENGINE_DEVICE = "engine.Device"
 DEVICE_H2D = "device.HostToDevice"
 DEVICE_D2H = "device.DeviceToHost"
 HTTP_FRONTEND = "http.Frontend"
+HTTP_RENDER = "http.Render"
 PHASE_SPANS = {
     "parse": ENGINE_PARSE, "fetch": ENGINE_GATHER,
     "open_read": ENGINE_OPEN_READ, "pack": ENGINE_PACK,
     "plan": ENGINE_PLAN,
     "decode": ENGINE_DECODE, "merge": ENGINE_MERGE,
     "device": ENGINE_DEVICE, "h2d": DEVICE_H2D, "d2h": DEVICE_D2H,
-    "frontend": HTTP_FRONTEND,
+    "frontend": HTTP_FRONTEND, "render": HTTP_RENDER,
 }
 
 

@@ -382,6 +382,7 @@ def test_manifest_holds_the_cell_and_lints():
         "device_wait_ms", "device_queue_depth", "d2h_ms", "reply_ms",
         "engine_cpu_ms", "interp_wait_ms", "program_ms",
         "program_hbm_peak_mb", "panel_median_ms", "rows_per_reply",
-        "hq_share_pct", "hq_groups", "hq_buckets", "program_roofline_pct")}
+        "hq_share_pct", "hq_groups", "hq_buckets", "program_roofline_pct",
+        "frontend_ms", "reply_native_pct")}
     assert all(m["moves"] in judged for m in layered.values())
     assert lint_manifest.lint() == []

@@ -31,7 +31,7 @@ START, END, STEP = T0 + 10 * 60 * SEC, T0 + 100 * 60 * SEC, 60 * SEC
 MIXED_LO, MIXED_HI = T0 + 30 * 60 * SEC, T0 + 2 * BLOCK + 20 * 60 * SEC
 PHASE_KEYS = {"parse_s", "plan_s", "fetch_s", "open_read_s", "pack_s",
               "decode_s", "merge_s", "device_s", "h2d_s", "d2h_s", "self_s",
-              "frontend_s", "total_s"}
+              "frontend_s", "render_s", "total_s"}
 WAIT_KEYS = {"db_lock_wait_s", "device_wait_s", "gc_pause_s"}
 
 
