@@ -661,6 +661,8 @@ def test_a_full_collection_is_charged_to_the_phase_it_interrupted():
 
 def test_http_front_end_is_clocked_with_its_query(db, monkeypatch):
     _unsampled(monkeypatch)
+    with tracing.span(tracing.HTTP_REQUEST):
+        pass        # the front end's first root span is its sampled one
     srv = CoordinatorServer(db, port=0).start()
     try:
         expr = "sum by (dc) (rate(sealed[19m]))"
@@ -735,3 +737,432 @@ def test_database_lock_bookkeeping_under_many_threads(db):
     db._lock.release()
     assert 0.0 < sum(c.value for c in held) - before[0] <= elapsed
     assert waited.value > before[1]
+
+
+# --- the record a query leaves, held to the letter (PR 50) ---
+
+def _pin_db(path: str) -> Database:
+    """The `db` fixture's series, and beside them a histogram
+    (`lat_bucket`: 6 hosts x 4 `le`) and three Graphite paths."""
+    db = Database(DatabaseOptions(path=path, num_shards=4,
+                                  commit_log_enabled=False))
+    db.create_namespace(NamespaceOptions(
+        name="default", retention=RetentionOptions(block_size=BLOCK)))
+    _write(db, b"sealed")
+    _write(db, b"cold", n_series=3)
+    n = 120
+    ts = [T0 + (k + 1) * 30 * SEC for k in range(n)]
+    for i in range(6):
+        for j, le in enumerate((b"0.1", b"0.5", b"1", b"+Inf")):
+            tags = {b"__name__": b"lat_bucket", b"host": b"h%02d" % i,
+                    b"dc": b"dc%d" % (i % 3), b"le": le}
+            vs = np.cumsum(np.full(n, (1.0 + i) * (1 + j))).tolist()
+            db.write_batch("default", [b"lat|h%02d|" % i + le] * n,
+                           [tags] * n, ts, vs)
+    for i in range(3):
+        path_b = b"servers.host%d.cpu" % i
+        tags = {b"__name__": path_b}
+        tags.update({b"__g%d__" % k: c
+                     for k, c in enumerate(path_b.split(b"."))})
+        db.write_batch("default", [path_b] * n, [tags] * n, ts,
+                       (np.arange(n, dtype=float) * (1 + i)).tolist())
+    db.tick(now_nanos=T0 + 2 * BLOCK)
+    db.flush()
+    db.write_batch("default", [b"cold|h00"],
+                   [{b"__name__": b"cold", b"host": b"h00", b"dc": b"dc0"}],
+                   [T0 + 45 * SEC], [0.5])
+    m = 60
+    for i in range(4):
+        tags = {b"__name__": b"sealed", b"host": b"h%02d" % i,
+                b"dc": b"dc%d" % (i % 3)}
+        ts = [T0 + 2 * BLOCK + (k + 1) * 30 * SEC for k in range(m)]
+        db.write_batch("default", [b"sealed|h%02d" % i] * m, [tags] * m,
+                       ts, (1e6 + np.arange(m, dtype=float)).tolist())
+    return db
+
+
+@pytest.fixture
+def pin_db(tmp_path):
+    db = _pin_db(str(tmp_path))
+    yield db
+    db.close()
+
+
+GROUPED = "sum by (dc) (rate(sealed[5m]))"
+TOPK = "topk(2, sum by (host) (rate(sealed[5m])))"
+HQ = "histogram_quantile(0.99, sum by (le, dc) (rate(lat_bucket[5m])))"
+GRAPHITE = "highestAverage(scale(servers.*.cpu, 2), 2)"
+# case -> (expression, device_serving, what else the case does)
+PIN_CASES = {
+    "grouped": (GROUPED, True, None),
+    "temporal": ("rate(sealed[5m])", True, None),
+    "topk": (TOPK, True, None),
+    "hq": (HQ, True, None),
+    "grouped_host": (GROUPED, False, None),
+    "temporal_host": ("rate(sealed[5m])", False, None),
+    "topk_host": (TOPK, False, None),
+    "hq_host": (HQ, False, None),
+    "open_rows": ("rate(sealed[6m])", True, "mixed_range"),
+    "decline": ("rate(cold[5m])", True, None),
+    "device_error": (GROUPED, True, "program_raises"),
+    "fused_error": (TOPK, True, "program_raises"),
+    "raises": ("sum by (dc) (rate(sealed[5m]", True, None),
+    "clocked": (GROUPED, True, "traceparent"),
+    "graphite": (GRAPHITE, True, "graphite"),
+    "graphite_host": (GRAPHITE, False, "graphite"),
+}
+_TIMINGS = ("total_s", "ts", "interp_wait_s", "compile_s", "compile_cache")
+
+
+def _pin_run(case: str, db, monkeypatch) -> tuple:
+    """Run `case`'s one query on a fresh engine -> (its record with the
+    timings blanked, the key set of the thread's last_fetch_stats)."""
+    from m3_tpu.query.graphite import GraphiteEngine
+
+    expr, device, how = PIN_CASES[case]
+    _unsampled(monkeypatch)
+    if how == "program_raises":
+        def broken(*args, **kwargs):
+            raise RuntimeError("the program is broken")
+
+        for entry in ("device_grouped_pipeline", "device_temporal_pipeline",
+                      "device_expr_pipeline"):
+            monkeypatch.setattr(qp, entry, broken)
+    lo, hi = (MIXED_LO, MIXED_HI) if how == "mixed_range" else (START, END)
+    if how == "graphite":
+        geng = GraphiteEngine(db, "default", device=device)
+        geng.render(expr, lo, hi, STEP)
+        eng, expr = geng._engine, f"graphite://{expr}"
+    else:
+        eng = Engine(db, "default", device_serving=device)
+        try:
+            if how == "traceparent":
+                with tracing.activate(tracing.TraceContext(41, 43, True)):
+                    eng.query_range(expr, lo, hi, STEP)
+            else:
+                eng.query_range(expr, lo, hi, STEP)
+        except ValueError:
+            assert case == "raises"
+    rec = dict(slowlog.log().records(limit=1)[0])
+    assert rec["expr"] == expr
+    assert set(rec.pop("phases")) == PHASE_KEYS | WAIT_KEYS
+    if "cpu" in rec:
+        assert set(rec["cpu"]) == PHASE_KEYS
+        rec["cpu"] = ...
+    if "device_tier" in rec:
+        rec["device_tier"] = dict(rec["device_tier"])
+    for holder in (rec, rec.get("device_tier", {})):
+        for key in _TIMINGS:
+            if key in holder:
+                holder[key] = ...
+    return rec, sorted(eng.last_fetch_stats or ())
+
+
+# taken at 6139ab6 (the parent of PR 50) by running _pin_run on each case
+PINNED = {
+    'grouped': (
+        {'expr': GROUPED, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 3, 'datapoints': 1440, 'rows': 12,
+         'open_rows': 0, 'lanes': 12, 'lanes_pad': 64, 'lane_chunks': 1,
+         'n_cap': 128, 'steps_pad': 128, 'rows_per_lane': 1,
+         'decode_refills': 16, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': 'select',
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 9, 'by_row': 3}, 'device_serving': True,
+         'fn': 'rate', 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'band_served_pct': 0.0, 'ts': ...},
+        ['agg', 'band_served_pct', 'd2h_s', 'datapoints', 'db_lock_wait_s',
+         'decode_refills', 'device_grouped', 'device_s', 'device_serving',
+         'device_wait_s', 'fetch_s', 'fn', 'gc_pause_s', 'h2d_s',
+         'lane_chunks', 'lanes', 'lanes_pad', 'merge_form', 'n_cap',
+         'n_groups', 'n_shards', 'n_streams', 'open_rows', 'pack_s',
+         'parse_s', 'rows', 'rows_per_lane', 'steps_pad', 'window_form']),
+    'temporal': (
+        {'expr': 'rate(sealed[5m])', 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 12, 'datapoints': 1440, 'rows': 12,
+         'open_rows': 0, 'lanes': 12, 'lanes_pad': 64, 'lane_chunks': 1,
+         'n_cap': 128, 'steps_pad': 128, 'rows_per_lane': 1,
+         'decode_refills': 16, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': 'select',
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 9, 'by_row': 3}, 'device_serving': True,
+         'fn': 'rate', 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'band_served_pct': 0.0, 'ts': ...},
+        ['band_served_pct', 'd2h_s', 'datapoints', 'db_lock_wait_s',
+         'decode_refills', 'device_s', 'device_serving', 'device_wait_s',
+         'fetch_s', 'fn', 'gc_pause_s', 'h2d_s', 'lane_chunks', 'lanes',
+         'lanes_pad', 'merge_form', 'n_cap', 'n_shards', 'n_streams',
+         'open_rows', 'pack_s', 'parse_s', 'rows', 'rows_per_lane',
+         'steps_pad', 'window_form']),
+    'topk': (
+        {'expr': TOPK, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 4, 'datapoints': 1440, 'rows': 12,
+         'open_rows': 0, 'lanes': 12, 'lanes_pad': 64, 'lane_chunks': 0,
+         'n_cap': 128, 'steps_pad': 128, 'rows_per_lane': 1,
+         'decode_refills': 16, 'groups': 12, 'topk_k': 2, 'rows_out': 4,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': 'select',
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 9, 'by_row': 3}, 'device_serving': True,
+         'fn': 'rate', 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache':
+          {'postings_misses': 1, 'postings_miss_bytes': 8,
+           'device_bridge_misses': 1, 'device_bridge_miss_bytes': 16384},
+         'band_served_pct': 0.0,
+         'device_tier':
+          {'compile_cache': ..., 'compile_s': ..., 'device_nodes': 5,
+           'host_nodes': 0, 'transfer_bytes': 16592, 'n_shards': 1},
+         'ts': ...},
+        ['agg', 'band_served_pct', 'compile_cache', 'compile_s', 'compiled',
+         'd2h_s', 'datapoints', 'db_lock_wait_s', 'decode_refills',
+         'device_fused', 'device_s', 'device_serving', 'device_wait_s',
+         'fetch_s', 'fn', 'fused_nodes', 'gc_pause_s', 'groups', 'hq_buckets',
+         'hq_groups', 'lanes', 'lanes_pad', 'merge_form', 'n_cap', 'n_shards',
+         'n_streams', 'pack_s', 'parse_s', 'plan_s', 'rows', 'rows_out',
+         'rows_per_lane', 'steps_pad', 'topk_k', 'transfer_bytes',
+         'window_form']),
+    'hq': (
+        {'expr': HQ, 'tenant': 'default', 'initiator': 'http', 'total_s': ...,
+         'series': 3, 'datapoints': 2880, 'rows': 24, 'open_rows': 0,
+         'lanes': 24, 'lanes_pad': 64, 'lane_chunks': 0, 'n_cap': 128,
+         'steps_pad': 128, 'rows_per_lane': 1, 'decode_refills': 16,
+         'groups': 12, 'topk_k': 0, 'rows_out': 3, 'hq_groups': 3,
+         'hq_buckets': 4, 'window_form': 'select', 'merge_form': 'rotate',
+         'fileset_scans': 0, 'walk_rows': {'columns': 21, 'by_row': 3},
+         'device_serving': True, 'fn': 'rate', 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache':
+          {'postings_misses': 1, 'postings_miss_bytes': 8,
+           'device_bridge_misses': 1, 'device_bridge_miss_bytes': 16384},
+         'band_served_pct': 0.0,
+         'device_tier':
+          {'compile_cache': ..., 'compile_s': ..., 'device_nodes': 5,
+           'host_nodes': 0, 'transfer_bytes': 8256, 'n_shards': 1},
+         'ts': ...},
+        ['agg', 'band_served_pct', 'compile_cache', 'compile_s', 'compiled',
+         'd2h_s', 'datapoints', 'db_lock_wait_s', 'decode_refills',
+         'device_fused', 'device_s', 'device_serving', 'device_wait_s',
+         'fetch_s', 'fn', 'fused_nodes', 'gc_pause_s', 'groups', 'hq_buckets',
+         'hq_groups', 'lanes', 'lanes_pad', 'merge_form', 'n_cap', 'n_shards',
+         'n_streams', 'pack_s', 'parse_s', 'plan_s', 'rows', 'rows_out',
+         'rows_per_lane', 'steps_pad', 'topk_k', 'transfer_bytes',
+         'window_form']),
+    'grouped_host': (
+        {'expr': GROUPED, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 3, 'datapoints': 1440, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 9, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'parse_s', 'read_bytes']),
+    'temporal_host': (
+        {'expr': 'rate(sealed[5m])', 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 12, 'datapoints': 1440, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 9, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'parse_s', 'read_bytes']),
+    'topk_host': (
+        {'expr': TOPK, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 4, 'datapoints': 1440, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 9, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'parse_s', 'read_bytes']),
+    'hq_host': (
+        {'expr': HQ, 'tenant': 'default', 'initiator': 'http', 'total_s': ...,
+         'series': 3, 'datapoints': 2880, 'rows': 0, 'open_rows': 0,
+         'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0, 'n_cap': 0,
+         'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0, 'groups': 0,
+         'topk_k': 0, 'rows_out': 0, 'hq_groups': 0, 'hq_buckets': 0,
+         'window_form': None, 'merge_form': None, 'fileset_scans': 0,
+         'walk_rows': {'columns': 21, 'by_row': 3}, 'device_serving': False,
+         'fn': None, 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'parse_s', 'read_bytes']),
+    'open_rows': (
+        {'expr': 'rate(sealed[6m])', 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 12, 'datapoints': 1680, 'rows': 16,
+         'open_rows': 4, 'lanes': 12, 'lanes_pad': 64, 'lane_chunks': 1,
+         'n_cap': 256, 'steps_pad': 256, 'rows_per_lane': 2,
+         'decode_refills': 16, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': 'select',
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 21, 'by_row': 3}, 'device_serving': True,
+         'fn': 'rate', 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'band_served_pct': 0.0, 'ts': ...},
+        ['band_served_pct', 'd2h_s', 'datapoints', 'db_lock_wait_s',
+         'decode_refills', 'device_s', 'device_serving', 'device_wait_s',
+         'fetch_s', 'fn', 'gc_pause_s', 'h2d_s', 'lane_chunks', 'lanes',
+         'lanes_pad', 'merge_form', 'n_cap', 'n_shards', 'n_streams',
+         'open_read_s', 'open_rows', 'pack_s', 'parse_s', 'rows',
+         'rows_per_lane', 'steps_pad', 'window_form']),
+    'decline': (
+        {'expr': 'rate(cold[5m])', 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 3, 'datapoints': 333, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 0, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'device_declines': {'cold_overlay': 1}, 'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'pack_s', 'parse_s',
+         'read_bytes']),
+    'device_error': (
+        {'expr': GROUPED, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 3, 'datapoints': 1440, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 9, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'device_declines': {'device_error': 2}, 'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_s',
+         'device_wait_s', 'fetch_s', 'gc_pause_s', 'h2d_s', 'n_streams',
+         'pack_s', 'parse_s', 'read_bytes']),
+    'fused_error': (
+        {'expr': TOPK, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 4, 'datapoints': 1440, 'rows': 0,
+         'open_rows': 0, 'lanes': 0, 'lanes_pad': 0, 'lane_chunks': 0,
+         'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0, 'decode_refills': 0,
+         'groups': 0, 'topk_k': 0, 'rows_out': 0, 'hq_groups': 0,
+         'hq_buckets': 0, 'window_form': None, 'merge_form': None,
+         'fileset_scans': 0, 'walk_rows': {'columns': 9, 'by_row': 3},
+         'device_serving': False, 'fn': None, 'n_shards': 1, 'warnings': [],
+         'exhaustive': True, 'error': None, 'trace_id': None,
+         'cache':
+          {'postings_misses': 1, 'postings_miss_bytes': 8,
+           'device_bridge_misses': 1, 'device_bridge_miss_bytes': 16384},
+         'device_declines': {'device_error': 2},
+         'device_tier_error': 'RuntimeError: the program is broken',
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_s',
+         'device_wait_s', 'fetch_s', 'gc_pause_s', 'h2d_s', 'n_streams',
+         'pack_s', 'parse_s', 'plan_s', 'read_bytes']),
+    'raises': (
+        {'expr': 'sum by (dc) (rate(sealed[5m]', 'tenant': 'default',
+         'initiator': 'http', 'total_s': ..., 'series': 0, 'datapoints': 0,
+         'rows': 0, 'open_rows': 0, 'lanes': 0, 'lanes_pad': 0,
+         'lane_chunks': 0, 'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0,
+         'decode_refills': 0, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': None,
+         'merge_form': None, 'fileset_scans': 0,
+         'walk_rows': {'columns': 0, 'by_row': 0}, 'device_serving': False,
+         'fn': None, 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': "ValueError: expected ')', got None", 'trace_id': None,
+         'cache': {}, 'ts': ...},
+        []),
+    'clocked': (
+        {'expr': GROUPED, 'tenant': 'default', 'initiator': 'http',
+         'total_s': ..., 'series': 3, 'datapoints': 1440, 'rows': 12,
+         'open_rows': 0, 'lanes': 12, 'lanes_pad': 64, 'lane_chunks': 1,
+         'n_cap': 128, 'steps_pad': 128, 'rows_per_lane': 1,
+         'decode_refills': 16, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': 'select',
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 9, 'by_row': 3}, 'device_serving': True,
+         'fn': 'rate', 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': '00000000000000000000000000000029',
+         'cache': {'postings_misses': 1, 'postings_miss_bytes': 8},
+         'cpu': ..., 'interp_wait_s': ..., 'band_served_pct': 0.0, 'ts': ...},
+        ['agg', 'band_served_pct', 'd2h_s', 'datapoints', 'db_lock_wait_s',
+         'decode_refills', 'device_grouped', 'device_s', 'device_serving',
+         'device_wait_s', 'fetch_s', 'fn', 'gc_pause_s', 'h2d_s',
+         'lane_chunks', 'lanes', 'lanes_pad', 'merge_form', 'n_cap',
+         'n_groups', 'n_shards', 'n_streams', 'open_rows', 'pack_s',
+         'parse_s', 'rows', 'rows_per_lane', 'steps_pad', 'window_form']),
+    'graphite': (
+        {'expr': "graphite://" + GRAPHITE, 'tenant': 'default',
+         'initiator': 'http', 'total_s': ..., 'series': 0, 'datapoints': 360,
+         'rows': 3, 'open_rows': 0, 'lanes': 3, 'lanes_pad': 64,
+         'lane_chunks': 0, 'n_cap': 128, 'steps_pad': 128, 'rows_per_lane': 1,
+         'decode_refills': 16, 'groups': 0, 'topk_k': 0, 'rows_out': 3,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': None,
+         'merge_form': 'rotate', 'fileset_scans': 0,
+         'walk_rows': {'columns': 1, 'by_row': 2}, 'device_serving': True,
+         'fn': None, 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache':
+          {'postings_misses': 2, 'regexp_misses': 2,
+           'postings_miss_bytes': 16, 'regexp_hits': 4,
+           'device_bridge_misses': 1, 'device_bridge_miss_bytes': 16384},
+         'band_served_pct': 0.0,
+         'device_tier':
+          {'compile_cache': ..., 'compile_s': ..., 'device_nodes': 2,
+           'host_nodes': 1, 'transfer_bytes': 8256, 'n_shards': 1,
+           'host_splits': {'graphite_host_fn': 1}},
+         'ts': ...},
+        ['agg', 'band_served_pct', 'compile_cache', 'compile_s', 'compiled',
+         'd2h_s', 'datapoints', 'db_lock_wait_s', 'decode_refills',
+         'device_fused', 'device_s', 'device_serving', 'device_wait_s',
+         'fetch_s', 'fn', 'fused_nodes', 'gc_pause_s', 'groups', 'hq_buckets',
+         'hq_groups', 'lanes', 'lanes_pad', 'merge_form', 'n_cap', 'n_shards',
+         'n_streams', 'pack_s', 'parse_s', 'plan_s', 'rows', 'rows_out',
+         'rows_per_lane', 'steps_pad', 'topk_k', 'transfer_bytes',
+         'window_form']),
+    'graphite_host': (
+        {'expr': "graphite://" + GRAPHITE, 'tenant': 'default',
+         'initiator': 'http', 'total_s': ..., 'series': 0, 'datapoints': 360,
+         'rows': 0, 'open_rows': 0, 'lanes': 0, 'lanes_pad': 0,
+         'lane_chunks': 0, 'n_cap': 0, 'steps_pad': 0, 'rows_per_lane': 0,
+         'decode_refills': 0, 'groups': 0, 'topk_k': 0, 'rows_out': 0,
+         'hq_groups': 0, 'hq_buckets': 0, 'window_form': None,
+         'merge_form': None, 'fileset_scans': 0,
+         'walk_rows': {'columns': 1, 'by_row': 2}, 'device_serving': False,
+         'fn': None, 'n_shards': 1, 'warnings': [], 'exhaustive': True,
+         'error': None, 'trace_id': None,
+         'cache':
+          {'postings_misses': 2, 'regexp_hits': 6, 'postings_miss_bytes': 16},
+         'ts': ...},
+        ['datapoints', 'db_lock_wait_s', 'decode_s', 'device_wait_s',
+         'fetch_s', 'gc_pause_s', 'n_streams', 'parse_s', 'read_bytes']),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PIN_CASES))
+def test_the_record_is_the_parents_to_the_letter(pin_db, monkeypatch, case):
+    """One query of each served shape leaves the record the parent of
+    PR 50 left: the same keys in the same order (`phases` aside, whose
+    key set _pin_run holds), every value that is no timing, and the same
+    keys in the thread's last_fetch_stats."""
+    rec, stats_keys = _pin_run(case, pin_db, monkeypatch)
+    want, want_stats_keys = PINNED[case]
+    assert list(rec) == list(want)
+    assert rec == want
+    assert stats_keys == want_stats_keys
