@@ -18,6 +18,7 @@ wherever it exists (the reference's aggregated-namespace read path).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import math
@@ -29,14 +30,17 @@ from collections import defaultdict, deque
 
 import numpy as np
 
-from m3_tpu import attribution, observe
+from m3_tpu import observe
 from m3_tpu.cache import stats as cache_stats
 from m3_tpu.metrics.policy import format_duration
 from m3_tpu.ops import consolidate as cons
 from m3_tpu.ops.m3tsz_decode import (decode_streams_adaptive,
                                      decode_streams_merged)
-from m3_tpu.query import promql, slowlog
-from m3_tpu.query.plan import count_band_served
+from m3_tpu.query import cost as qcost
+from m3_tpu.query import plan as qplan
+from m3_tpu.query import promql
+from m3_tpu.query.matrix import (DEFAULT_SUBQUERY_STEP, Matrix, expand_go,
+                                 signature)
 from m3_tpu.storage.buffer import OpenRow, by_view
 from m3_tpu.storage.database import Database
 from m3_tpu.storage.limits import QueryDeadlineExceeded, ResultMeta
@@ -44,87 +48,10 @@ from m3_tpu.storage.shard import ARRAYS, MIXED, OPEN, STREAMS
 from m3_tpu.utils import instrument, tracing
 
 DEFAULT_LOOKBACK = cons.DEFAULT_LOOKBACK
-DEFAULT_SUBQUERY_STEP = 60 * 1_000_000_000
 
 # test seam: lets the differential suite force the per-fragment stitch
 # path to cross-check the vectorized multi-tier branch
 _VECTORIZED_STITCH = True
-
-
-@dataclasses.dataclass
-class Matrix:
-    """Evaluation result: per-series labels + [L, S] step values."""
-
-    labels: list[dict[bytes, bytes]]
-    values: np.ndarray  # [L, S] float64, NaN = no sample
-
-    def drop_name(self) -> "Matrix":
-        return Matrix(
-            [{k: v for k, v in ls.items() if k != b"__name__"} for ls in self.labels],
-            self.values,
-        )
-
-
-def _expand_go(m: re.Match, repl: str) -> str:
-    """Go regexp.Expand semantics for label_replace replacements:
-    ``$1`` / ``$name`` (longest word run) / ``${name}``; ``$$`` is a
-    literal '$'; an unknown reference expands to the empty string.
-    Implemented directly — routing through re.Match.expand would
-    re-interpret backslashes in the literal text."""
-    out = []
-    i = 0
-    while i < len(repl):
-        c = repl[i]
-        if c != "$":
-            out.append(c)
-            i += 1
-            continue
-        if i + 1 >= len(repl):
-            out.append("$")
-            break
-        nxt = repl[i + 1]
-        if nxt == "$":
-            out.append("$")
-            i += 2
-            continue
-        if nxt == "{":
-            end = repl.find("}", i + 2)
-            if end == -1:
-                out.append(repl[i:])
-                break
-            name = repl[i + 2:end]
-            i = end + 1
-        else:
-            j = i + 1
-            while j < len(repl) and (repl[j].isalnum() or repl[j] == "_"):
-                j += 1
-            name = repl[i + 1:j]
-            i = j
-            if not name:
-                out.append("$")
-                continue
-        try:
-            group = m.group(int(name) if name.isdigit() else name)
-        except IndexError:  # unknown reference -> empty string
-            group = None
-        out.append(group or "")
-    return "".join(out)
-
-
-def _ast_size(node) -> int:
-    """Count AST nodes — the slow-query log's device-vs-host node
-    split is (fused nodes served) / (total - fused)."""
-    if isinstance(node, promql.Call):
-        return 1 + sum(_ast_size(a) for a in node.args)
-    if isinstance(node, promql.Agg):
-        n = 1 + _ast_size(node.expr)
-        return n + (_ast_size(node.param) if node.param is not None
-                    else 0)
-    if isinstance(node, promql.BinOp):
-        return 1 + _ast_size(node.lhs) + _ast_size(node.rhs)
-    if isinstance(node, promql.Subquery):
-        return 1 + _ast_size(node.expr)
-    return 1
 
 
 # what a row of the walk is, by the code the walk's `kind` column holds
@@ -159,87 +86,6 @@ class StreamRows:
         return zip(self.slots.tolist(), self.tiers.tolist(), self.streams)
 
 
-class QueryCost:
-    """What one query cost, written where it is paid: the phase stamps
-    (``phase``, seconds by ``<phase>_s``), the serving path's stats
-    (``Engine.last_fetch_stats``), the fused planner's tallies and the
-    device tier's declines.  One per query and thread, on
-    ``Engine._qrange_local.cost``; it stays until the thread's next
-    query begins, so a caller reads ``last_fetch_stats`` after the
-    call, and never another thread's."""
-
-    __slots__ = ("phases", "cpu", "cpu_t0_ns", "stats", "declines",
-                 "gather_bytes", "walk_rows",
-                 "fileset_scans", "ast_nodes", "fused_nodes",
-                 "fused_compile_cache", "fused_compile_s",
-                 "fused_transfer_bytes",
-                 "fused_n_shards", "fused_batched", "fused_batch_size",
-                 "fused_batch_wait_s", "fused_error", "fused_poisoned",
-                 "host_split_reasons", "rung_selections")
-
-    def __init__(self):
-        # the waits' keys are there from the start: a collection's
-        # callback may charge its pause at any point of the thread's
-        # code, and must not change the size of a dict being copied
-        self.phases: dict[str, float] = dict.fromkeys(
-            tracing.WAIT_KEYS, 0.0)
-        # a clocked query's (one in tracing.COST_CLOCK_1_IN): the
-        # thread's CPU seconds by phase, and the CPU clock's reading
-        # where the engine call began; None: no phase reads that clock
-        self.cpu: dict[str, float] | None = None
-        self.cpu_t0_ns = 0
-        self.stats: dict | None = None
-        self.declines: dict[str, int] = {}    # device tier, by reason
-        self.gather_bytes = 0
-        # directories the query's gathers had to list (a shard whose
-        # fileset listing was not yet kept): 0 on a served node
-        self.fileset_scans = 0
-        # rows the query's walks were handed as columns, and rows they
-        # had to classify one by one (a cold write beside a sealed
-        # stream: a MIXED block)
-        self.walk_rows = {"columns": 0, "by_row": 0}
-        # whole-query fusion (query/plan.py): how much of the tree the
-        # fused device program served, what it cost to (re)compile,
-        # and how many bytes crossed back
-        self.ast_nodes = 0
-        self.fused_nodes = 0
-        self.fused_compile_cache = None
-        self.fused_compile_s = 0.0
-        self.fused_transfer_bytes = 0
-        self.fused_n_shards = 1
-        self.fused_batched = False
-        self.fused_batch_size = 0
-        self.fused_batch_wait_s = 0.0
-        self.fused_error = None
-        self.fused_poisoned = False
-        self.host_split_reasons: dict[str, int] = {}
-        self.rung_selections: dict[str, int] = {}
-
-    def phase(self, name: str):
-        """``with cost.phase("pack"):`` — the one stamp of a phase
-        (utils/tracing.phase): record, span and trace annotation."""
-        return tracing.phase(name, self.phases, self.cpu)
-
-    def split(self, reason: str) -> None:
-        """A subtree the fused planner left to the host, by cause (the
-        slugs of ``m3_query_host_split_total``)."""
-        instrument.bounded_counter(
-            "m3_query_host_split_total").labels(reason=reason).inc()
-        self.host_split_reasons[reason] = (
-            self.host_split_reasons.get(reason, 0) + 1)
-
-
-def _sig(labels: dict, match: promql.VectorMatch | None) -> tuple:
-    """Label signature for vector matching (on/ignoring semantics)."""
-    if match is not None and match.on:
-        keep = {l.encode() for l in match.labels}
-        return tuple(sorted((k, v) for k, v in labels.items() if k in keep))
-    drop = {b"__name__"}
-    if match is not None:
-        drop |= {l.encode() for l in match.labels}
-    return tuple(sorted((k, v) for k, v in labels.items() if k not in drop))
-
-
 class Engine:
     def __init__(self, db: Database, namespace: str = "default",
                  lookback_nanos: int = DEFAULT_LOOKBACK,
@@ -253,9 +99,10 @@ class Engine:
         # recorded; None keeps the plain full-range namespace fan-out
         self.planner = planner
         self._qrange_local = threading.local()
-        # queries since the last one that read the CPU clock
-        self._unclocked = 0
-        self._unclocked_lock = threading.Lock()
+        # _cost(): the calling thread's cost object; _begin_cost(live):
+        # a new one, armed for one query (query/cost.py)
+        costs = qcost.Costs()
+        self._cost, self._begin_cost = costs.current, costs.begin
         # None = auto, resolved lazily per query from the backend JAX
         # reports (see _device_serving_active)
         self.device_serving = device_serving
@@ -350,32 +197,6 @@ class Engine:
 
     # --- fetch + decode ---
 
-    def _cost(self) -> QueryCost:
-        """The calling thread's cost object: the running query's, or
-        (a direct ``_fetch_raw`` caller, no query scope) one that the
-        thread keeps until its next query."""
-        cost = getattr(self._qrange_local, "cost", None)
-        if cost is None:
-            cost = self._qrange_local.cost = QueryCost()
-        return cost
-
-    def _begin_cost(self, live: bool = False) -> QueryCost:
-        """Arm the calling thread's cost object for one query, and
-        decide, once, whether the query reads the CPU clock: if its
-        span is `live` (sampled by the tracer, or forced by the
-        request's ``traceparent``) or it is this engine's
-        ``tracing.COST_CLOCK_1_IN``-th query since the last that did."""
-        cost = self._qrange_local.cost = QueryCost()
-        with self._unclocked_lock:
-            self._unclocked += 1
-            clocked = live or self._unclocked >= tracing.COST_CLOCK_1_IN
-            if clocked:
-                self._unclocked = 0
-        if clocked:
-            cost.cpu = {}
-            cost.cpu_t0_ns = time.thread_time_ns()
-        return cost
-
     @property
     def last_fetch_stats(self) -> dict | None:
         """Stats of the calling thread's most recent serving path
@@ -387,20 +208,6 @@ class Engine:
     @last_fetch_stats.setter
     def last_fetch_stats(self, stats: dict | None) -> None:
         self._cost().stats = stats
-
-    def _publish_stats(self, **fields) -> None:
-        """A serving path's stats: the phases stamped so far in this
-        query, unrounded, and the path's own fields."""
-        cost = self._cost()
-        cost.stats = {**cost.phases, **fields}
-
-    def _decline(self, reason: str) -> None:
-        """The per-node device tier hands a selector to the host tier:
-        counted by cause, and kept for the query's record."""
-        instrument.bounded_counter(
-            "m3_query_device_decline_total").labels(reason=reason).inc()
-        declines = self._cost().declines
-        declines[reason] = declines.get(reason, 0) + 1
 
     def _gather(self, matchers, start_nanos: int, end_nanos: int):
         """Collect the namespace fan-out's raw block payloads without
@@ -750,13 +557,12 @@ class Engine:
 
         def _assemble():
             from m3_tpu.ops import consolidate as cons
-            from m3_tpu.query.plan import _bucket_pow2
             stitched = self._stitch(parts)
             times, values, counts = cons.merge_packed(stitched,
                                                       len(labels))
             n_lanes = len(labels)
-            lanes_pad = _bucket_pow2(n_lanes, 64)
-            n_cap = _bucket_pow2(times.shape[1], 128)
+            lanes_pad = qplan._bucket_pow2(n_lanes, 64)
+            n_cap = qplan._bucket_pow2(times.shape[1], 128)
             times_p, values_p = cons.pad_grid(times, values, lanes_pad,
                                               n_cap)
             return {
@@ -785,10 +591,11 @@ class Engine:
         Doubles as the cooperative-cancel checkpoint: an operator
         cancel via /debug/tasks aborts the query here, and the task
         ledger's live phase tracks the checkpoint names."""
-        task = getattr(self._qrange_local, "task", None)
+        cost = self._cost()
+        task = cost.task
         if task is not None:
             task.set_phase(what)
-            if (self._cost().stats or {}).get("device_serving"):
+            if (cost.stats or {}).get("device_serving"):
                 task.device_tier = "device"
             task.check_cancelled()
         limits = getattr(self._qrange_local, "limits", None)
@@ -822,7 +629,7 @@ class Engine:
                     ts, vs, valid = decode_streams_adaptive(streams)
             if fused is not None:
                 times2, values2, lane_counts = fused
-                self._publish_stats(
+                cost.publish(
                     n_streams=len(streams),
                     datapoints=int(lane_counts.sum()),
                     read_bytes=int(cost.gather_bytes))
@@ -831,7 +638,7 @@ class Engine:
                 times2, values2, _ = cons.merge_grids(
                     slots, ts, vs, valid, len(labels),
                     t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
-            self._publish_stats(
+            cost.publish(
                 n_streams=len(streams),
                 datapoints=int(np.asarray(valid).sum()),
                 read_bytes=int(cost.gather_bytes))
@@ -860,7 +667,7 @@ class Engine:
                 times2, values2, _ = cons.merge_grids(
                     slots, ts, vs, valid, n_lanes,
                     t_min_excl=start_nanos - 1, t_max_incl=end_nanos)
-            self._publish_stats(
+            cost.publish(
                 n_streams=len(streams),
                 datapoints=int(valid.sum()),
                 read_bytes=int(cost.gather_bytes),
@@ -888,7 +695,7 @@ class Engine:
             values = np.where(inside, values, np.nan)
             tmask = inside & (times != cons._INF)
             times2, values2, _ = cons.pack_valid(times, values, tmask)
-        self._publish_stats(
+        cost.publish(
             n_streams=len(parts),  # raw + decoded-compressed fragments
             datapoints=int(tmask.sum()),
             read_bytes=int(cost.gather_bytes))
@@ -1117,7 +924,7 @@ class Engine:
                 m = rx.fullmatch(val)
                 new = dict(ls)
                 if m is not None:
-                    expanded = _expand_go(m, repl)
+                    expanded = expand_go(m, repl)
                     if expanded:
                         new[dst.encode()] = expanded.encode()
                     else:
@@ -1202,7 +1009,6 @@ class Engine:
             # path's per-band widening must serve this query
             cost.split("retention_coarse_lookback")
             return None
-        from m3_tpu.query import plan as qplan
         try:
             return qplan.serve_fused(self, node, step_times)
         except qplan.Unsupported as exc:
@@ -1270,8 +1076,8 @@ class Engine:
         because a cold write lies beside its sealed stream),
         ``open_multi_tier`` (array rows in a multi-namespace fan-out),
         ``empty``, ``unknown_counts``.  The per-node tier then falls
-        back to the host and counts the reason (``_decline``); the
-        fused planner tries its arrays bridge first.  The gather is
+        back to the host and counts the reason (``QueryCost.decline``);
+        the fused planner tries its arrays bridge first.  The gather is
         the ``fetch`` phase, laying the rows that arrive as arrays
         (open buffers, decoded blocks) out beside the words is
         ``open_read`` like the reading of them, and everything else
@@ -1508,12 +1314,12 @@ class Engine:
         cause counted."""
         pk, why = self._device_gather_pack(rv, step_times, range_nanos)
         if pk is None:
-            self._decline(why)
+            self._cost().decline(why)
             return None
         self._check_deadline("device decode")
         n_shards = self._serving_shards()
         if n_shards > 1 and pk["open"] is not None:
-            self._decline("open_rows_sharded")
+            self._cost().decline("open_rows_sharded")
             return None
         return pk, n_shards
 
@@ -1583,45 +1389,26 @@ class Engine:
         except Exception as exc:  # noqa: BLE001 - serving must not
             # hard-fail on a device runtime error (HBM OOM on a huge
             # fan-out): the host tier can still answer
-            self._decline("device_error")
-            self.last_fetch_stats = {
+            cost.decline("device_error")
+            cost.stats = {
                 "device_serving": False,
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
             }
             return None
         if self._rows_flagged(pk, err_np):
-            self._decline("decode_error")
+            cost.decline("decode_error")
             return None  # corrupt/unsorted stream: host tier re-decodes
-        window_form = (query_pipeline.window_form(pk["n_cap"])
-                       if fn in ("rate", "increase", "delta") else None)
-        if window_form:
-            instrument.counter("m3_device_window_form_total",
-                               form=window_form).inc()
-        merge_form = query_pipeline.merge_form(pk["n_cap"], pk["n_dp"])
-        instrument.counter("m3_device_merge_form_total",
-                           form=merge_form).inc()
-        band_served_pct = count_band_served(windows)
-        self._publish_stats(
-            n_streams=pk["n_streams"],
-            datapoints=pk["datapoints"],
-            rows=pk["n_rows"], open_rows=pk["open_rows"],
-            # the fan-out: series merged, the lane bucket the program
-            # was compiled for, and the rounds in which a shard's merge
-            # and windowed stage go through its lanes
-            lanes=pk["n_lanes"], lanes_pad=pk["lanes_pad"],
+        cost.publish(
+            **qcost.program_shape(
+                [pk], n_shards, len(pk["steps"]),
+                [pk] if fn in qcost.RATE_FAMILY else ()),
+            open_rows=pk["open_rows"],
+            # the rounds in which a shard's merge and windowed stage go
+            # through its lanes
             lane_chunks=query_pipeline.lane_chunks(
                 pk["lanes_pad"] // n_shards),
-            # the other buckets the windowed stage's cost is the
-            # product of (samples a lane, steps), and the widest lane's
-            # rows (a long range: 22 blocks a series; a dashboard row: 2)
-            n_cap=pk["n_cap"], steps_pad=len(pk["steps"]),
-            rows_per_lane=pk["rows_per_lane"],
-            # the decode scan's refills of its per-row word window
-            decode_refills=query_pipeline.decode_refills(
-                pk["n_dp"], pk["words"].shape[1]),
-            window_form=window_form, merge_form=merge_form,
-            band_served_pct=band_served_pct,
-            **stats, n_shards=n_shards)
+            band_served_pct=qcost.count_band_served(windows),
+            **stats)
         return out
 
     def _device_temporal(self, rv, step_times, fn: str,
@@ -1651,7 +1438,7 @@ class Engine:
             if elements > self._QOT_MAX_ELEMENTS:
                 instrument.counter(
                     "m3_device_hbm_gate_rejections_total").inc()
-                self._decline("hbm_gate")
+                self._cost().decline("hbm_gate")
                 return None  # PER-DEVICE window grid too large: host
                 # native kernel (sharded meshes split the lane axis, so
                 # each device materializes only its shard's slice)
@@ -2188,12 +1975,12 @@ class Engine:
         many_side, one_side = (rhs, lhs) if swap else (lhs, rhs)
         one_by_sig: dict[tuple, list[int]] = defaultdict(list)
         for j, ls in enumerate(one_side.labels):
-            one_by_sig[_sig(ls, m)].append(j)
+            one_by_sig[signature(ls, m)].append(j)
 
         labels, rows = [], []
         include = {l.encode() for l in (m.include if m else ())}
         for i, ls in enumerate(many_side.labels):
-            sig = _sig(ls, m)
+            sig = signature(ls, m)
             js = one_by_sig.get(sig)
             if not js:
                 continue
@@ -2239,13 +2026,13 @@ class Engine:
         S = lhs.values.shape[1] if len(lhs.labels) else rhs.values.shape[1]
         rhs_present: dict[tuple, np.ndarray] = {}
         for j, ls in enumerate(rhs.labels):
-            sig = _sig(ls, m)
+            sig = signature(ls, m)
             p = ~np.isnan(rhs.values[j])
             rhs_present[sig] = rhs_present.get(sig, np.zeros(S, bool)) | p
         if node.op == "and":
             labels, rows = [], []
             for i, ls in enumerate(lhs.labels):
-                p = rhs_present.get(_sig(ls, m))
+                p = rhs_present.get(signature(ls, m))
                 if p is None:
                     continue
                 labels.append(dict(ls))
@@ -2254,7 +2041,7 @@ class Engine:
         if node.op == "unless":
             labels, rows = [], []
             for i, ls in enumerate(lhs.labels):
-                p = rhs_present.get(_sig(ls, m), np.zeros(S, bool))
+                p = rhs_present.get(signature(ls, m), np.zeros(S, bool))
                 vals = np.where(p, np.nan, lhs.values[i])
                 labels.append(dict(ls))
                 rows.append(vals)
@@ -2262,13 +2049,13 @@ class Engine:
         # or: lhs plus rhs elements whose sig has no lhs value at the step
         lhs_present: dict[tuple, np.ndarray] = {}
         for i, ls in enumerate(lhs.labels):
-            sig = _sig(ls, m)
+            sig = signature(ls, m)
             p = ~np.isnan(lhs.values[i])
             lhs_present[sig] = lhs_present.get(sig, np.zeros(S, bool)) | p
         labels = [dict(ls) for ls in lhs.labels]
         rows = [lhs.values[i] for i in range(len(lhs.labels))]
         for j, ls in enumerate(rhs.labels):
-            shadow = lhs_present.get(_sig(ls, m), np.zeros(S, bool))
+            shadow = lhs_present.get(signature(ls, m), np.zeros(S, bool))
             vals = np.where(shadow, np.nan, rhs.values[j])
             if not np.isnan(vals).all():
                 labels.append(dict(ls))
@@ -2308,250 +2095,62 @@ class Engine:
             task.set_phase("parse")
             task.device_tier = ("device" if self._device_serving_active()
                                 else "host")
-            self._qrange_local.task = task
             self._qrange_local.limits = limits
             self._qrange_local.meta = meta
-            cost = self._begin_cost(live=ctx is not None)
-            # the gather memo exists ONLY between here and the finally
-            # below; _gather_cached bypasses memoization when it is None
-            self._qrange_local.gather_cache = {}
-            self._qrange_local.plan_cache = {}
-            result = None
-            error = None
-            cache_stats.begin()  # per-query cache hit/miss scoreboard
             try:
-                # what the thread waits for between two phases (the
-                # engine's self time) is charged to the query too
-                with tracing.sink_scope(cost.phases):
+                # inside the span, so the query's trace_id lands in
+                # the slow-query log
+                with self._query_scope(query, t0, meta, task) as cost:
                     step_times, result = self._query_range(
                         query, start_nanos, end_nanos, step_nanos)
+                    cost.series = len(result.labels)
                 return step_times, result, meta
-            except Exception as e:
-                error = f"{type(e).__name__}: {e}"[:300]
-                raise
             finally:
-                # the cost record is cut inside the span, so the
-                # query's trace_id lands in the slow-query log
-                self._record_query_cost(query, t0, result, meta, error)
-                cache_stats.end()
-                # release the per-thread gather memo: reuse is scoped
-                # to ONE query on purpose (a later query must see a
-                # fresh storage snapshot — cross-query caching belongs
-                # to m3_tpu/cache, which sees invalidations), and the
-                # memo would otherwise pin every raw payload and packed
-                # words batch of the last fan-out on an idle thread
-                self._qrange_local.gather_cache = None
-                self._qrange_local.plan_cache = None
                 self._qrange_local.limits = None
                 self._qrange_local.meta = None
                 task.finish()
-                self._qrange_local.task = None
 
-    # the stamped phases that tile a query's time; h2d and d2h lie
-    # inside device and are recorded beside it, as the waits
-    # (tracing.WAIT_KEYS) are beside the phases they interrupted
-    _TILING_PHASES = ("parse_s", "plan_s", "fetch_s", "open_read_s",
-                      "pack_s", "decode_s", "merge_s", "device_s")
-    # the phases, of those that tile it, that block on nothing but
-    # locks: where wall - CPU - the database lock's wait is the wait
-    # for the interpreter lock
-    _LOCK_ONLY_PHASES = _TILING_PHASES[:-1] + ("self_s",)
-
-    def _record_query_cost(self, query: str, t0_ns: int, result, meta,
-                           error: str | None) -> None:
-        """One Monarch-style cost record per query into the slow-query
-        ring; best-effort — accounting must never fail the query.
-
-        ``phases`` carries every key in every record (0.0 where the
-        path has no such step).  ``self_s`` is the engine's self time:
-        ``total_s`` minus the phases that tile it, so those and
-        ``self_s`` sum to ``total_s``.  ``frontend_s`` is the HTTP
-        front end's, added by query/http.py once the reply is
-        written; it lies outside ``total_s``, and ``render_s`` (a range
-        query's matrix to its JSON bytes) inside ``frontend_s``.
-
-        A clocked query's record (``QueryCost.cpu``) also carries
-        ``cpu``, the thread's CPU seconds under the same keys
-        (``total_s`` from two readings around the engine call, not a
-        sum), and ``interp_wait_s``: over ``_LOCK_ONLY_PHASES``, wall
-        minus CPU, minus ``db_lock_wait_s``: the interpreter lock and
-        whatever the host's scheduler took.  Any other record has
-        neither key."""
+    @contextlib.contextmanager
+    def _query_scope(self, expr: str, t0_ns: int, meta=None, task=None):
+        """One query on the calling thread, PromQL or Graphite: arm its
+        cost object (yielded), the gather memo, the plan cache and the
+        cache scoreboard, charge what the thread waits for between two
+        phases (the engine's self time) to it, and on the way out,
+        whatever was raised, cut its record and release the memos."""
+        ql = self._qrange_local
+        cost = self._begin_cost(live=tracing.current_context() is not None)
+        cost.task = task
+        # the gather memo exists ONLY inside this scope;
+        # _gather_cached bypasses memoization when it is None
+        ql.gather_cache = {}
+        ql.plan_cache = {}
+        error = None
+        cache_stats.begin()  # per-query cache hit/miss scoreboard
         try:
-            cost = self._cost()
-            cpu_total_s = (None if cost.cpu is None else (
-                time.thread_time_ns() - cost.cpu_t0_ns) / 1e9)
-            total_s = (time.perf_counter_ns() - t0_ns) / 1e9
-            stats = cost.stats or {}
-
-            def tiled(stamps: dict, total: float) -> dict:
-                out = {k: stamps.get(k, 0.0)
-                       for k in self._TILING_PHASES + ("h2d_s", "d2h_s")}
-                out["self_s"] = total - sum(
-                    out[k] for k in self._TILING_PHASES)
-                out["frontend_s"] = out["render_s"] = 0.0
-                out["total_s"] = total
-                return out
-
-            phases = tiled(cost.phases, total_s)
-            for k in tracing.WAIT_KEYS:
-                phases[k] = cost.phases.get(k, 0.0)
-            ctx = tracing.current_context()
-            tenant = tracing.current_tenant() or self.ns
-            rec = {
-                "expr": query[:500],
-                "tenant": tenant,
-                "initiator": slowlog.current_initiator(),
-                "total_s": total_s,
-                "phases": phases,
-                "series": (len(result.labels)
-                           if isinstance(result, Matrix) else 0),
-                "datapoints": stats.get("datapoints", 0),
-                # device tiers: rows handed to the program, and how
-                # many of them came from open buffers
-                "rows": stats.get("rows", 0),
-                "open_rows": stats.get("open_rows", 0),
-                # device tiers: the series the program merged and the
-                # lane bucket it ran at (over all leaves of a fused
-                # tree); per-node tier: its lane chunks (lanes x
-                # lane_chunks tells a fleet-wide panel from a
-                # dashboard row)
-                "lanes": stats.get("lanes", 0),
-                "lanes_pad": stats.get("lanes_pad", 0),
-                "lane_chunks": stats.get("lane_chunks", 0),
-                # device tiers: the samples-a-lane and step buckets the
-                # program ran at and the widest lane's rows (the
-                # largest of a fused tree's leaves): n_cap x steps_pad
-                # is what the windowed stage's bounds compare a lane
-                "n_cap": stats.get("n_cap", 0),
-                "steps_pad": stats.get("steps_pad", 0),
-                "rows_per_lane": stats.get("rows_per_lane", 0),
-                # device tiers: how often the decode scan refilled its
-                # per-row word window (0: rows no longer than the
-                # window, every step reads the row), over all leaves
-                # of a fused tree
-                "decode_refills": stats.get("decode_refills", 0),
-                # fused tier: the real groups of the tree's grouped
-                # reductions, a root topk / bottomk's k, and the rows
-                # of the answer after the root's host reorder
-                "groups": stats.get("groups", 0),
-                "topk_k": stats.get("topk_k", 0),
-                "rows_out": stats.get("rows_out", 0),
-                # fused tier: the label combinations a
-                # histogram_quantile interpolated and the `le` buckets
-                # of its widest one
-                "hq_groups": stats.get("hq_groups", 0),
-                "hq_buckets": stats.get("hq_buckets", 0),
-                # how the program read its windows' ends (rate /
-                # increase / delta): "select" or "gather" ("mixed"
-                # where a fused tree's leaves differ)
-                "window_form": stats.get("window_form"),
-                # how the merge rotated a row to its offset in its
-                # lane: "rotate" over the lane's width or "window"
-                # inside two rows' (a lane of many rows: a long range)
-                "merge_form": stats.get("merge_form"),
-                "fileset_scans": cost.fileset_scans,
-                "walk_rows": dict(cost.walk_rows),
-                "device_serving": bool(stats.get("device_serving")),
-                "fn": stats.get("fn"),
-                "n_shards": stats.get("n_shards", 1),
-                "warnings": (meta.warning_strings()
-                             if meta is not None else []),
-                "exhaustive": (meta.exhaustive
-                               if meta is not None else True),
-                "error": error,
-                "trace_id": (f"{ctx.trace_id:032x}"
-                             if ctx is not None else None),
-                # per-cache hit/miss counts for this query (postings /
-                # decoded_blocks / seek / device_bridge), from the
-                # thread-local scoreboard armed in query_range_with_meta
-                "cache": cache_stats.snapshot(),
-            }
-            if cpu_total_s is not None:
-                cpu = rec["cpu"] = tiled(cost.cpu, cpu_total_s)
-                rec["interp_wait_s"] = sum(
-                    phases[k] - cpu[k] for k in self._LOCK_ONLY_PHASES
-                ) - phases["db_lock_wait_s"]
-            if cost.declines:
-                # where the per-node device tier handed a selector to
-                # the host: {reason: n}, the slugs of
-                # m3_query_device_decline_total
-                rec["device_declines"] = dict(cost.declines)
-            if stats.get("band_served_pct") is not None:
-                # where a device program said how its windowed stages
-                # searched: the lane chunks that went through a band of
-                # the lane and not its full width, of 100 (0.0: a shape
-                # the band is not taken at, or lanes that did not fit)
-                rec["band_served_pct"] = stats["band_served_pct"]
-            if cost.fused_nodes:
-                rec["device_tier"] = {
-                    "compile_cache": cost.fused_compile_cache,
-                    "compile_s": cost.fused_compile_s,
-                    "device_nodes": cost.fused_nodes,
-                    "host_nodes": max(
-                        (cost.ast_nodes or cost.fused_nodes)
-                        - cost.fused_nodes, 0),
-                    "transfer_bytes": cost.fused_transfer_bytes,
-                    "n_shards": cost.fused_n_shards,
-                }
-                if cost.fused_batched:
-                    # served through a shared cross-query dispatch
-                    # (m3_tpu/serving/): how many queries shared the
-                    # program and what the admission window cost us
-                    rec["device_tier"]["batched"] = True
-                    rec["device_tier"]["batch_size"] = (
-                        cost.fused_batch_size)
-                    rec["device_tier"]["batch_wait_s"] = (
-                        cost.fused_batch_wait_s)
-                if cost.host_split_reasons:
-                    rec["device_tier"]["host_splits"] = dict(
-                        cost.host_split_reasons)
-            if cost.rung_selections:
-                # retention-ladder rung choices for this query:
-                # {resolution label: bands served at it}
-                rec.setdefault("device_tier", {})["rungs"] = dict(
-                    cost.rung_selections)
-                rec["device_tier"].setdefault("read_bytes",
-                                              stats.get("read_bytes", 0))
-            if cost.fused_error:
-                rec["device_tier_error"] = cost.fused_error
-            slowlog.log().record(rec)
-            if rec["open_rows"]:
-                instrument.counter("m3_query_open_rows_total").inc(
-                    rec["open_rows"])
-            if rec["lanes"]:
-                instrument.counter("m3_query_lanes_total").inc(
-                    rec["lanes"])
-            if rec["hq_groups"]:
-                instrument.counter("m3_query_hq_groups_total").inc(
-                    rec["hq_groups"])
-            if attribution.enabled():
-                # read-path attribution for this query (datapoints
-                # scanned and device execute seconds are accounted at
-                # their sources — fetch_tagged and InstrumentedKernel
-                # — so only the engine-scoped costs land here)
-                cache = rec["cache"] or {}
-                attribution.account_read(
-                    tenant,
-                    transfer_bytes=cost.fused_transfer_bytes,
-                    cache_hit_bytes=int(sum(
-                        v for k, v in cache.items()
-                        if k.endswith("_hit_bytes"))),
-                    cache_miss_bytes=int(sum(
-                        v for k, v in cache.items()
-                        if k.endswith("_miss_bytes"))))
-                attribution.account_query(
-                    tenant, query,
-                    cost=float(stats.get("datapoints", 0) or 0) + 1.0)
-        except Exception:  # noqa: BLE001 — accounting is best-effort
-            pass
+            with tracing.sink_scope(cost.phases):
+                yield cost
+        except Exception as e:
+            error = f"{type(e).__name__}: {e}"[:300]
+            raise
+        finally:
+            qcost.record(cost, expr, self.ns, t0_ns, meta, error)
+            cache_stats.end()
+            # release the per-thread gather memo: reuse is scoped
+            # to ONE query on purpose (a later query must see a
+            # fresh storage snapshot — cross-query caching belongs
+            # to m3_tpu/cache, which sees invalidations), and the
+            # memo would otherwise pin every raw payload and packed
+            # words batch of the last fan-out on an idle thread
+            ql.gather_cache = None
+            ql.plan_cache = None
+            cost.task = None
 
     def _query_range(self, query: str, start_nanos: int, end_nanos: int,
                      step_nanos: int):
         cost = self._cost()
         with cost.phase("parse"):
             ast = promql.parse(query)
-        cost.ast_nodes = _ast_size(ast)
+        cost.ast_nodes = promql.ast_size(ast)
         # @ start()/end() resolve against the outer query range,
         # regardless of subquery nesting (upstream semantics)
         self._qrange_local.value = (int(start_nanos), int(end_nanos))

@@ -23,10 +23,8 @@ import time
 
 import numpy as np
 
-from m3_tpu.cache import stats as cache_stats
 from m3_tpu.ops import consolidate as cons
 from m3_tpu.query.engine import Engine
-from m3_tpu.utils import tracing
 
 SECOND = 1_000_000_000
 
@@ -258,38 +256,21 @@ class GraphiteEngine:
         from m3_tpu.query import graphite_device as gdev
         t0 = time.perf_counter_ns()
         eng = self._engine
-        ql = eng._qrange_local
-        # arm the same per-query thread-local state the PromQL path
-        # sets up in query_range_with_meta/_query_range, so the fused
-        # lowerer's accounting and the gather memo work under render()
-        cost = eng._begin_cost(
-            live=tracing.current_context() is not None)
-        with cost.phase("parse"):
-            ast = parse(target)
-        cost.ast_nodes = gdev.ast_size(ast)
-        ql.value = (int(start_nanos), int(end_nanos))
-        ql.gather_cache = {}
-        ql.plan_cache = {}
-        error = None
-        cache_stats.begin()
-        try:
-            with tracing.sink_scope(cost.phases):
+        # the PromQL path's per-query scope: the fused lowerer's
+        # accounting, the gather memo and the slow-query record
+        with eng._query_scope(f"graphite://{target}", t0) as cost:
+            with cost.phase("parse"):
+                ast = parse(target)
+            cost.ast_nodes = gdev.ast_size(ast)
+            eng._qrange_local.value = (int(start_nanos), int(end_nanos))
+            try:
                 return self._eval(ast, steps, step_nanos)
-        except Exception as e:
-            error = f"{type(e).__name__}: {e}"[:300]
-            raise
-        finally:
-            self.last_render_stats = {
-                "ast_nodes": cost.ast_nodes,
-                "device_nodes": cost.fused_nodes,
-                "host_splits": dict(cost.host_split_reasons),
-            }
-            # slowlog cost record (device_tier et al.) — best-effort
-            eng._record_query_cost(f"graphite://{target}", t0, None,
-                                   None, error)
-            cache_stats.end()
-            ql.gather_cache = None
-            ql.plan_cache = None
+            finally:
+                self.last_render_stats = {
+                    "ast_nodes": cost.ast_nodes,
+                    "device_nodes": cost.fused_nodes,
+                    "host_splits": dict(cost.host_split_reasons),
+                }
 
     def _eval(self, node, step_times, step) -> SeriesList:
         if isinstance(node, (Path, Call)):

@@ -75,7 +75,10 @@ import numpy as np
 
 from m3_tpu.cache import stats as cache_stats
 from m3_tpu.ops import consolidate as cons
+from m3_tpu.query import cost as qcost
 from m3_tpu.query import promql
+from m3_tpu.query.matrix import (DEFAULT_SUBQUERY_STEP, Matrix, expand_go,
+                                 signature)
 from m3_tpu.utils import instrument
 
 
@@ -390,7 +393,6 @@ def _match_vv(node, lhs_labels, rhs_labels):
     iteration order, js[0] pick, and output label rules, but emitting
     (out_labels, lhs_row, rhs_row) gather indices instead of values —
     the device applies the op to the gathered rows."""
-    from m3_tpu.query.engine import _sig
     m = node.matching
     is_cmp = node.op in CMP_OPS
     group = m.group if m else ""
@@ -399,11 +401,11 @@ def _match_vv(node, lhs_labels, rhs_labels):
                                else (lhs_labels, rhs_labels))
     one_by_sig: dict = {}
     for j, ls in enumerate(one_labels):
-        one_by_sig.setdefault(_sig(ls, m), []).append(j)
+        one_by_sig.setdefault(signature(ls, m), []).append(j)
     include = {l.encode() for l in (m.include if m else ())}
     out_labels, lhs_rows, rhs_rows = [], [], []
     for i, ls in enumerate(many_labels):
-        js = one_by_sig.get(_sig(ls, m))
+        js = one_by_sig.get(signature(ls, m))
         if not js:
             continue
         j = js[0]
@@ -419,7 +421,7 @@ def _match_vv(node, lhs_labels, rhs_labels):
         elif is_cmp and not node.bool_mod:
             out_ls = dict(ls)
         else:
-            out_ls = dict(_sig(ls, m))
+            out_ls = dict(signature(ls, m))
         out_labels.append(out_ls)
         li, ri = (j, i) if swap else (i, j)
         lhs_rows.append(li)
@@ -439,7 +441,6 @@ def _apply_label_fn(node, labels):
                               "string literal", reason="label_fn_args")
         return a.value
 
-    from m3_tpu.query.engine import _expand_go
     if node.fn == "label_replace":
         dst, repl, src, regex = s(1), s(2), s(3), s(4)
         rx = re.compile(regex)
@@ -449,7 +450,7 @@ def _apply_label_fn(node, labels):
             m = rx.fullmatch(val)
             new = dict(ls)
             if m is not None:
-                expanded = _expand_go(m, repl)
+                expanded = expand_go(m, repl)
                 if expanded:
                     new[dst.encode()] = expanded.encode()
                 else:
@@ -521,31 +522,6 @@ def _leaf_specs(sym, out):
     return out
 
 
-def _count_forms(counter: str, forms) -> str | None:
-    """Count each of a tree's `forms` once -> the word for the query's
-    record: the form, "mixed" where its leaves differ, None without
-    one."""
-    for form in sorted(forms):
-        instrument.counter(counter, form=form).inc()
-    return min(forms) if len(forms) == 1 else "mixed" if forms else None
-
-
-def count_band_served(windows) -> float | None:
-    """Count what a device program said of its windowed stages'
-    lane chunks (`windows`: those served at the full width, all of
-    them, on a mesh a row a shard; query_pipeline._temporal_eval) ->
-    the share of 100 that searched a band of the lane, for the query's
-    record; None where the program said nothing."""
-    if windows is None:
-        return None
-    full, chunks = (int(n) for n in np.reshape(windows, (-1, 2)).sum(axis=0))
-    instrument.counter("m3_device_window_band_total",
-                       served="band").inc(chunks - full)
-    instrument.counter("m3_device_window_band_total",
-                       served="full").inc(full)
-    return 100.0 * (chunks - full) / chunks if chunks else None
-
-
 def serve_fused(engine, node, step_times):
     """Try to serve `node` with the fused whole-query device pipeline.
     Returns a Matrix, or None to decline (the engine's per-node paths
@@ -576,8 +552,8 @@ def serve_fused(engine, node, step_times):
         if not any_arrays:
             return None
 
-    from m3_tpu.query.engine import _ast_size
-    return run_sym(engine, sym, step_times, counts, _ast_size(node))
+    return run_sym(engine, sym, step_times, counts,
+                   promql.ast_size(node))
 
 
 def run_sym(engine, sym, step_times, counts, ast_nodes):
@@ -598,7 +574,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
     root_post = []     # host post-ops on the root matrix (sort/...)
     # for the query's record
     shape = {"groups": 0, "topk_k": 0, "hq_groups": 0, "hq_buckets": 0}
-    forms = set()      # window_form of each rate-family leaf
+    rate_leaves = []   # the packed leaves a rate-family function reads
     cost = engine._cost()
     s_pad = _bucket_pow2(len(step_times), 64)
     # the plan phase: the build below (leaf plan, group keys, params,
@@ -713,8 +689,8 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                 raise Unsupported("quantile_over_time window grid "
                                   "over the HBM budget",
                                   reason="qot_hbm_gate")
-        if fn in ("rate", "increase", "delta"):
-            forms.add(qp.window_form(statics[1]))
+        if fn in qcost.RATE_FAMILY:
+            rate_leaves.append(pk)
         pidx = len(params)
         params.append((np.float64(horizon), np.float64(phi)))
         labels = ([dict(ls) for ls in pk["labels"]] if keep_name
@@ -739,7 +715,6 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             return (("call", fn, pidx, plan_c), _drop_name(labels_c),
                     n_real, rows_pad)
         if tag == "agg":
-            from m3_tpu.query.engine import Matrix
             _, agg_node, phi, child = sym_node
             plan_c, labels_c, n_real, rows_pad = build(child, grid)
             keys = engine._group_keys(Matrix(labels_c[:n_real], None),
@@ -789,7 +764,6 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             return (("vv", bin_node.op, bin_node.bool_mod, out_pad,
                      pidx, plan_l, plan_r), out_labels, n_out, out_pad)
         if tag == "topkk":
-            from m3_tpu.query.engine import Matrix
             _, agg_node, k, child = sym_node
             plan_c, labels_c, n_real, rows_pad = build(child, grid)
             keys = engine._group_keys(Matrix(labels_c[:n_real], None),
@@ -883,7 +857,6 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
             return (plan_c, _apply_label_fn(call_node, labels_c),
                     n_real, rows_pad)
         if tag == "subq":
-            from m3_tpu.query.engine import DEFAULT_SUBQUERY_STEP
             _, sq, fn, horizon, hw_sf, hw_tf, child = sym_node
             shifted = engine._eval_times(sq, grid)
             rng = int(sq.range_nanos)
@@ -1051,7 +1024,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                     windows = np.asarray(windows)
         except Exception as exc:  # noqa: BLE001 — a device runtime
             # error must not fail a query the host tier can answer
-            engine.last_fetch_stats = {
+            cost.stats = {
                 "device_serving": False,
                 "device_error": f"{type(exc).__name__}: {exc}"[:200],
             }
@@ -1082,7 +1055,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
 
     # per-query accounting for the slow-query log's device_tier phase.
     # The thread-local tally counts AST nodes COVERED (a fused temporal
-    # leaf covers its Call and its Selector), so _record_query_cost's
+    # leaf covers its Call and its Selector), so cost.record's
     # host_nodes = ast_nodes - fused_nodes is exact under splitting.
     fused_nodes = counts["ops"] + len(leaf_plan)
     cost.fused_nodes += ast_nodes
@@ -1095,7 +1068,7 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
         cost.fused_batch_size = max(cost.fused_batch_size,
                                     binfo["batch_size"])
         cost.fused_batch_wait_s += binfo["waited_s"]
-        task = getattr(engine._qrange_local, "task", None)
+        task = cost.task
         if task is not None:
             # /debug/tasks shows which live queries rode a shared
             # dispatch and what the admission window cost them
@@ -1125,64 +1098,30 @@ def run_sym(engine, sym, step_times, counts, ast_nodes):
                 order = order[::-1]
             labels = [labels[i] for i in order]
             values = values[order]
-    # what the program ran at, for the query's record: how the rate
-    # family read its windows' ends (a function of a leaf's bucket), and
-    # how each leaf that arrived as words had its rows merged (statics:
-    # n_cap, n_dp)
-    window_form = _count_forms("m3_device_window_form_total", forms)
-    merge_form = _count_forms("m3_device_merge_form_total", {
-        qp.merge_form(statics[1], statics[2])
-        for _, kind, statics, _ in leaf_plan.values() if kind == "words"})
     fn_stat = next((f for f in counts["fns"] if f in LOOSE_FNS),
                    counts["fns"][0] if counts["fns"] else None)
     agg_stat = next((a for a in counts["aggs"] if a in LOOSE_AGGS),
                     counts["aggs"][0] if counts["aggs"] else None)
-    engine._publish_stats(
-        n_streams=sum(ent[3]["n_streams"]
-                      for ent in leaf_plan.values()),
-        datapoints=sum(ent[3]["datapoints"]
-                       for ent in leaf_plan.values()),
+    cost.publish(
+        **qcost.program_shape([ent[3] for ent in leaf_plan.values()],
+                              n_shards, len(steps_pad), rate_leaves),
         device_serving=True,
         device_fused=True,
         fused_nodes=fused_nodes,
         fn=fn_stat,
         agg=agg_stat,
-        n_shards=n_shards,
         compile_cache="hit" if cache_hit else "miss",
         compiled=compiled,
         compile_s=compile_s,
         transfer_bytes=transfer_bytes,
-        # the fan-out, as the per-node tier records it: rows handed to
-        # the program, series merged and their lane bucket over all
-        # leaves; then the tree's real groups, a root top-k's k and
-        # the rows of the answer
-        rows=sum(ent[3].get("n_rows", 0) for ent in leaf_plan.values()),
-        lanes=sum(ent[3]["n_lanes"] for ent in leaf_plan.values()),
-        lanes_pad=sum(ent[3]["lanes_pad"]
-                      for ent in leaf_plan.values()),
+        # the tree's real groups, a root top-k's k, a
+        # histogram_quantile's label combinations and the buckets of
+        # its widest one, and the rows of the answer
         groups=shape["groups"], topk_k=shape["topk_k"],
-        # a histogram_quantile's label combinations and the buckets of
-        # its widest one
         hq_groups=shape["hq_groups"], hq_buckets=shape["hq_buckets"],
-        rows_out=len(labels), window_form=window_form,
-        merge_form=merge_form,
-        band_served_pct=count_band_served(windows),
-        # the widest leaf's samples a lane and rows a lane, and the
-        # steps' bucket
-        n_cap=max((ent[3]["n_cap"] for ent in leaf_plan.values()),
-                  default=0),
-        steps_pad=len(steps_pad),
-        rows_per_lane=max((ent[3].get("rows_per_lane", 0)
-                           for ent in leaf_plan.values()), default=0),
-        # the decode scans of the leaves that arrive as words: their
-        # refills of the per-row word window, a function of a leaf's
-        # buckets (statics: n_dp, the words of a row)
-        decode_refills=sum(
-            qp.decode_refills(statics[2], statics[5])
-            for _, kind, statics, _ in leaf_plan.values()
-            if kind == "words"))
+        rows_out=len(labels),
+        band_served_pct=qcost.count_band_served(windows))
     if binfo is not None:
-        engine.last_fetch_stats["batched"] = True
-        engine.last_fetch_stats["batch_size"] = binfo["batch_size"]
-    from m3_tpu.query.engine import Matrix
+        cost.stats["batched"] = True
+        cost.stats["batch_size"] = binfo["batch_size"]
     return Matrix(labels, values)

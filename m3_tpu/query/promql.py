@@ -454,3 +454,19 @@ class Parser:
 
 def parse(query: str):
     return Parser(query).parse()
+
+
+def ast_size(node) -> int:
+    """Count AST nodes — the slow-query log's device-vs-host node
+    split is (fused nodes served) / (total - fused)."""
+    if isinstance(node, Call):
+        return 1 + sum(ast_size(a) for a in node.args)
+    if isinstance(node, Agg):
+        n = 1 + ast_size(node.expr)
+        return n + (ast_size(node.param) if node.param is not None
+                    else 0)
+    if isinstance(node, BinOp):
+        return 1 + ast_size(node.lhs) + ast_size(node.rhs)
+    if isinstance(node, Subquery):
+        return 1 + ast_size(node.expr)
+    return 1

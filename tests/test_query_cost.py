@@ -45,19 +45,21 @@ def _write(db, name: bytes, n_series: int = 12, n: int = 120):
                        [tags] * n, ts, vs)
 
 
-@pytest.fixture
-def db(tmp_path):
+def _make_db(path: str, also_sealed=None) -> Database:
     """`sealed` lives in a flushed block and, for four of its hosts,
     goes on in the mutable buffer of a later one: a range that reaches
     both holds open rows beside sealed streams.  `cold` lives in the
     same flushed block with a cold write beside it, which the shard
-    merges on the host: the device tier declines it."""
-    db = Database(DatabaseOptions(path=str(tmp_path), num_shards=4,
+    merges on the host: the device tier declines it.  `also_sealed`
+    writes what else the flushed block is to hold."""
+    db = Database(DatabaseOptions(path=path, num_shards=4,
                                   commit_log_enabled=False))
     db.create_namespace(NamespaceOptions(
         name="default", retention=RetentionOptions(block_size=BLOCK)))
     _write(db, b"sealed")
     _write(db, b"cold", n_series=3)
+    if also_sealed is not None:
+        also_sealed(db)
     db.tick(now_nanos=T0 + 2 * BLOCK)
     db.flush()
     db.write_batch("default", [b"cold|h00"],
@@ -70,6 +72,12 @@ def db(tmp_path):
         ts = [T0 + 2 * BLOCK + (k + 1) * 30 * SEC for k in range(n)]
         db.write_batch("default", [b"sealed|h%02d" % i] * n, [tags] * n,
                        ts, (1e6 + np.arange(n, dtype=float)).tolist())
+    return db
+
+
+@pytest.fixture
+def db(tmp_path):
+    db = _make_db(str(tmp_path))
     yield db
     db.close()
 
@@ -741,15 +749,9 @@ def test_database_lock_bookkeeping_under_many_threads(db):
 
 # --- the record a query leaves, held to the letter (PR 50) ---
 
-def _pin_db(path: str) -> Database:
-    """The `db` fixture's series, and beside them a histogram
-    (`lat_bucket`: 6 hosts x 4 `le`) and three Graphite paths."""
-    db = Database(DatabaseOptions(path=path, num_shards=4,
-                                  commit_log_enabled=False))
-    db.create_namespace(NamespaceOptions(
-        name="default", retention=RetentionOptions(block_size=BLOCK)))
-    _write(db, b"sealed")
-    _write(db, b"cold", n_series=3)
+def _write_histogram_and_paths(db) -> None:
+    """A histogram (`lat_bucket`: 6 hosts x 4 `le`) and three Graphite
+    paths."""
     n = 120
     ts = [T0 + (k + 1) * 30 * SEC for k in range(n)]
     for i in range(6):
@@ -766,19 +768,11 @@ def _pin_db(path: str) -> Database:
                      for k, c in enumerate(path_b.split(b"."))})
         db.write_batch("default", [path_b] * n, [tags] * n, ts,
                        (np.arange(n, dtype=float) * (1 + i)).tolist())
-    db.tick(now_nanos=T0 + 2 * BLOCK)
-    db.flush()
-    db.write_batch("default", [b"cold|h00"],
-                   [{b"__name__": b"cold", b"host": b"h00", b"dc": b"dc0"}],
-                   [T0 + 45 * SEC], [0.5])
-    m = 60
-    for i in range(4):
-        tags = {b"__name__": b"sealed", b"host": b"h%02d" % i,
-                b"dc": b"dc%d" % (i % 3)}
-        ts = [T0 + 2 * BLOCK + (k + 1) * 30 * SEC for k in range(m)]
-        db.write_batch("default", [b"sealed|h%02d" % i] * m, [tags] * m,
-                       ts, (1e6 + np.arange(m, dtype=float)).tolist())
-    return db
+
+
+def _pin_db(path: str) -> Database:
+    """The `db` fixture's series, the histogram and the paths."""
+    return _make_db(path, _write_histogram_and_paths)
 
 
 @pytest.fixture
